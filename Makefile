@@ -23,7 +23,8 @@ PY                ?= python
 
 .PHONY: build login push run jupyter smoke test test-fast test-smoke check \
         lint \
-        notebooks bench recertify decode-audit heavy-refresh obs-report \
+        notebooks bench chip-smoke recertify decode-audit heavy-refresh \
+        obs-report \
         obs-watch trace-report bench-trend accum-memory fault-suite \
         elastic-drill \
         serve-bench serve-bench-spec fleet-bench chaos-bench coloc-bench \
@@ -92,8 +93,13 @@ test-smoke:	## sub-minute loop: pure-host logic + mesh/collective semantics
 notebooks:	## execute the notebook tier headlessly; fails on any broken cell
 	$(PY) scripts/run_notebooks.py
 
-bench:
+bench:	## measures the TPU only: exits non-zero with no chip, prints no record
 	$(PY) bench.py
+
+chip-smoke:	## the quickest proof that trainer and server still start on
+	## the chip (one process; last line {"ok", "device"}; exits non-zero
+	## without a TPU). From a sandbox with no chip: chiprun -- python chip_smoke.py
+	$(PY) chip_smoke.py
 
 recertify:	## all headline protocols at one HEAD -> RECERT.json (round 5)
 	$(PY) scripts/recertify.py
@@ -197,14 +203,14 @@ trace-report:	## per-request critical-path digest for the newest runs/<dir>:
 	## orphans, per-step training attribution (OBS_RUN=dir, TOP=K)
 	$(PY) scripts/trace_report.py $(or $(OBS_RUN),$(shell ls -td runs/*/ 2>/dev/null | head -1)) --top $(or $(TOP),5)
 
-bench-trend:	## regression sentinel over BENCH_r*.json: fails on a >10%
-	## like-for-like drop; cpu/outage-tier rounds listed, never compared
-	$(PY) scripts/bench_trend.py
+bench-trend:	## regression sentinel over archived bench records
+	## (RECORDS='dir/BENCH_r*.json'): fails on a >10% like-for-like
+	## drop; rounds that did not measure are listed, never compared
+	$(PY) scripts/bench_trend.py --glob '$(RECORDS)'
 
 ## Native IO tier (built on demand by the Python bindings too)
-native:
-	g++ -O3 -std=c++17 -shared -fPIC -o native/libddl_native.so \
-	    native/ddl_native.cc -lpthread
+native:	## named after the hash of native/ddl_native.cc, so never stale
+	$(PY) -c "from distributeddeeplearning_tpu import native; assert native.native_available()"
 
 ## Cluster tier (reference 01_CreateResources / 01_Train*)
 # --tpu/--zone live on the PARENT parser (before the subcommand) and are

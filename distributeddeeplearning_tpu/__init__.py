@@ -14,12 +14,6 @@ Reference parity map lives in SURVEY.md §7 at the repo root.
 
 __version__ = "0.1.0"
 
-# Backfill missing jax APIs (shard_map/pcast/typeof/...) before any
-# module traces — inert on a current jax (utils/compat.py).
-from distributeddeeplearning_tpu.utils.compat import install as _compat_install
-
-_compat_install()
-
 from distributeddeeplearning_tpu.config import TrainConfig
 from distributeddeeplearning_tpu.parallel.mesh import MeshConfig, create_mesh
 from distributeddeeplearning_tpu.utils.timer import Timer, timer

@@ -46,6 +46,9 @@ _ENV_NAME_RE = re.compile(r"^[A-Z][A-Z0-9_]{2,}$")
 EXTERNAL_ENV = {
     "TPU_WORKER_HOSTNAMES",  # TPU-VM metadata (jax.distributed autodetect)
     "JAX_PLATFORMS", "XLA_FLAGS",  # jax/XLA runtime selection
+    # where jax keeps its persistent compile cache; read (never set) by
+    # training/warmup.enable_compile_cache
+    "JAX_COMPILATION_CACHE_DIR",
     "PATH", "HOME", "PWD", "USER",
 }
 

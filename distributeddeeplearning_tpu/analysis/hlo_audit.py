@@ -60,8 +60,10 @@ _WHILE_BODY_RE = re.compile(r"\bwhile\([^\n]*?body=%?([\w.\-]+)")
 _CALLED_RE = re.compile(
     r"(?:to_apply|body|condition|branch_computations)=\{?%?([\w.\-{}, %]+)"
 )
+# The result type is one array type or — when XLA's combiner merges the
+# gradient leaves into one collective — a parenthesised tuple of them.
 _ALLREDUCE_RE = re.compile(
-    r"=\s*\S+\s+(all-reduce|all-reduce-start)\b"
+    r"=\s*(?:\([^()]*\)|\S+)\s+(all-reduce|all-reduce-start)\("
 )
 
 

@@ -3,7 +3,7 @@
 ``RecordStreamDataset`` yields the vision batch contract
 ``(images, labels)`` from uint8 image + int32 label shard pairs. Images
 are stored RAW (un-normalized RGB bytes); staging decides what crosses
-the PCIe/tunnel link, exactly like the real readers (docs/DATA.md
+the PCIe link, exactly like the real readers (docs/DATA.md
 ``INPUT_STAGING``): a uint8 ``image_dtype`` passes bytes through for
 on-device normalization, float dtypes get the torchvision
 ``(x/255 - mean)/sd`` on host.

@@ -20,7 +20,10 @@ TPU-native redesign — no MPI, no SSH rendezvous:
   ``--tag-output`` / ``az batchai job file stream``) is built in. With
   ``--platform cpu --devices-per-process K`` the same code path runs on
   forced host devices — the reference's 2-process smoke test, no
-  hardware needed.
+  hardware needed. A local world of several processes is always such a
+  CPU world: the TPU chips of one host are driven by ONE process (a chip
+  belongs to one process at a time), so ``-n 2`` without the CPU
+  platform is refused instead of started and left to hang.
 * **pod mode** (``--tpu NAME``) wraps
   ``gcloud compute tpus tpu-vm ssh NAME --worker=all --command=…`` —
   every TPU-VM worker runs the same script and
@@ -106,11 +109,7 @@ def _child_env(
     env["DDL_NUM_PROCESSES"] = str(num_processes)
     env["DDL_PROCESS_ID"] = str(process_id)
     if platform:
-        # JAX_PLATFORMS alone is not enough when a TPU plugin force-sets
-        # jax_platforms at import; maybe_initialize re-applies DDL_PLATFORM
-        # via jax.config before touching the backend.
         env["JAX_PLATFORMS"] = platform
-        env["DDL_PLATFORM"] = platform
     if devices_per_process is not None:
         flags = _DEVCOUNT_RE.sub("", env.get("XLA_FLAGS", "")).strip()
         env["XLA_FLAGS"] = (
@@ -169,6 +168,38 @@ def _stream(
     return t
 
 
+def _require_host_device_world(
+    num_processes: int, platform: Optional[str], extra_env: Dict[str, str]
+) -> None:
+    """Refuse a local world of several processes unless it was asked to
+    run on the CPU.
+
+    A TPU chip belongs to one process at a time and every child of a
+    local world sees every chip of the host, so the first child to
+    initialise JAX takes them all and the rest fail or hang at start-up.
+    On one host, several chips are driven by ONE process (a mesh over
+    ``jax.devices()``); a local N-process world exists to exercise the
+    multi-process code path on forced host devices. This launcher stays
+    off JAX, so it cannot look for chips — it asks for the platform by
+    name instead."""
+    if num_processes <= 1:
+        return
+    resolved = (
+        platform
+        or extra_env.get("JAX_PLATFORMS")
+        or os.environ.get("JAX_PLATFORMS", "")
+    )
+    if resolved.strip().lower() != "cpu":
+        raise SystemExit(
+            f"launch: refusing to start {num_processes} local processes "
+            f"on platform {resolved or '<unset: JAX picks the TPU>'!r}: "
+            "each child would claim every TPU chip of this host and the "
+            "world would hang. Pass --platform cpu (with "
+            "--devices-per-process K) for a host-device world, or drive "
+            "this host's chips from one process (-n 1)."
+        )
+
+
 def launch_local(
     script: str,
     script_args: Sequence[str] = (),
@@ -220,9 +251,10 @@ def launch_local(
     children dump their flight-recorder rings before dying.
     """
     sink = sink or sys.stdout
+    extra_env = dict(env or {})
+    _require_host_device_world(num_processes, platform, extra_env)
     coordinator = f"127.0.0.1:{find_free_port()}"
     lbus = None
-    extra_env = dict(env or {})
     if hang_timeout:
         # Arm the children's compile-phase heartbeat (utils/heartbeat.py)
         # so a long silent AOT compile is not mistaken for a hang; the
@@ -486,11 +518,11 @@ def launch_supervised(
     * exports ``OBS_PROC_SUFFIX=-r<k>`` + a distinct launcher identity so
       each attempt's event/flight files survive into one merged failure
       timeline (rendered by ``scripts/obs_report.py``);
-    * exports ``DDL_RESTART=<k>`` for anything that wants to know;
-    * suffixes ``COMPILATION_CACHE_DIR`` per attempt (``<dir>-r<k>``)
-      when one is configured — same-host restarted worlds reusing one
-      persistent cache dir heap-corrupt this jax build (the r5 KNOWN
-      ISSUE), so each attempt compiles against its own dir.
+    * exports ``DDL_RESTART=<k>`` for anything that wants to know.
+
+    Every attempt compiles against the same persistent cache directory
+    (``training/warmup.enable_compile_cache``), so a restarted world
+    deserializes what the previous attempt compiled.
 
     Non-retryable exits (success, the non-finite-loss guard's 121,
     timeout 124, operator interrupt 130) return immediately. The return
@@ -553,15 +585,6 @@ def launch_supervised(
         # One run id for every attempt: the supervisor owns the run.
         base_env["OBS_RUN_ID"] = run_id
         sbus = EventBus(directory=obs_dir, run_id=run_id, proc="supervisor")
-    # KNOWN ISSUE guard (r5, tests/test_fault_tolerance.py): this jax
-    # build's persistent compilation cache heap-corrupts (SIGABRT) when
-    # a restarted multi-process world on one host reuses the SAME cache
-    # dir concurrently with the previous attempt's entries. Restart
-    # attempts therefore get a per-attempt suffixed cache dir — cold
-    # cache, but alive — instead of leaving the footgun to docs.
-    cache_dir = base_env.get("COMPILATION_CACHE_DIR") or os.environ.get(
-        "COMPILATION_CACHE_DIR"
-    )
     attempt = 0
     restarts_used = 0  # resizes are free; only FAILURES burn the budget
     try:
@@ -571,19 +594,6 @@ def launch_supervised(
                 extra["OBS_PROC_SUFFIX"] = f"-r{attempt}"
                 extra["DDL_RESTART"] = str(attempt)
                 extra["RESUME"] = "True"  # resume from the newest checkpoint
-                if cache_dir:
-                    suffixed = f"{cache_dir.rstrip(os.sep)}-r{attempt}"
-                    extra["COMPILATION_CACHE_DIR"] = suffixed
-                    sink.write(
-                        f"supervisor: restart attempt {attempt} uses "
-                        f"compilation cache dir {suffixed} (same-dir reuse "
-                        "across restarted worlds corrupts this jax build)\n"
-                    )
-                    if sbus is not None:
-                        sbus.point(
-                            "cache_dir_suffixed", attempt=attempt,
-                            dir=suffixed,
-                        )
             stop_check = None
             if elastic:
                 # The elasticity contract the children see: capacity

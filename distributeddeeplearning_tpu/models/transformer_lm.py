@@ -91,6 +91,10 @@ class TransformerLM(nn.Module):
     """
 
     variant: str = "tiny"
+    # Layers to build; 0 = the variant's own depth. A run that must fit a
+    # time or memory budget cuts depth and keeps every width
+    # (chip_smoke.py serves lm_large's widths at a few layers).
+    depth: int = 0
     vocab_size: int = 32_000
     max_seq_len: int = 2048
     dtype: Any = jnp.bfloat16
@@ -135,6 +139,7 @@ class TransformerLM(nn.Module):
         if self.variant not in _VARIANTS:
             raise ValueError(f"variant must be one of {sorted(_VARIANTS)}")
         hidden, depth, heads, mlp_dim = _VARIANTS[self.variant]
+        depth = self.depth or depth
         b, t = tokens.shape
         if t > self.max_seq_len:
             raise ValueError(f"sequence {t} exceeds max_seq_len {self.max_seq_len}")

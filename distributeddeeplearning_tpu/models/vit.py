@@ -164,9 +164,9 @@ class Attention(nn.Module):
     # runs the Pallas online-softmax kernel (ops/pallas/paged_decode.py)
     # that walks the block table / dense rows and dequantizes
     # in-register — same masked-score math, no full-length HBM
-    # round-trip. Applies to the vector-position decode paths (the
-    # serving engine); scalar-position callers (inference.generate,
-    # dense prefill) stay on the XLA path.
+    # round-trip. Applies to the vector-position decode windows of the
+    # serving engine (decode step, speculative verify); prefill and
+    # scalar-position callers (inference.generate) stay on the XLA path.
     decode_kernel: str = "xla"
 
     def _kv_quantized(self) -> bool:
@@ -177,13 +177,21 @@ class Attention(nn.Module):
         )
         return self.kv_dtype not in ("", "bf16")
 
-    def _decode_fused(self) -> bool:
+    def _decode_fused(self, t: int) -> bool:
+        """Whether a ``t``-row vector-position window runs the fused
+        kernel: decode steps and speculative verify windows do; a
+        prefill-sized window (the paged prefill's ``t`` = bucket) is
+        MXU work and keeps the einsum, as the dense prefill always has."""
         if self.decode_kernel not in ("xla", "fused"):
             raise ValueError(
                 f"decode_kernel must be one of ('xla', 'fused'), got "
                 f"{self.decode_kernel!r}"
             )
-        return self.decode_kernel == "fused"
+        from distributeddeeplearning_tpu.ops.pallas.paged_decode import (
+            MAX_QUERY_ROWS,
+        )
+
+        return self.decode_kernel == "fused" and t <= MAX_QUERY_ROWS
 
     def _paged_decode_attention(self, q, k, v, ci):
         """Block-table-indexed variant of the decode cache: same math
@@ -272,7 +280,7 @@ class Attention(nn.Module):
             .reshape(nb, bs, heads, dh)
         )
         ci.value = idx + t
-        if self._decode_fused():
+        if self._decode_fused(t):
             # Fused tier: the kernel walks the table itself — physical
             # blocks stream through VMEM in the storage dtype and
             # dequantize in-register; the [B, mb*bs, H, Dh] gathered
@@ -411,7 +419,7 @@ class Attention(nn.Module):
                 var.value = write(var.value, upd, idx)
             q_pos = idx[:, None] + jnp.arange(t)  # [B, t]
         ci.value = idx + t
-        if q_pos.ndim == 2 and self._decode_fused():
+        if q_pos.ndim == 2 and self._decode_fused(t):
             # Fused tier, dense rows: storage-dtype cache streams
             # through the kernel block-wise, dequant in-register — the
             # full-length dequantized copy below never materializes.

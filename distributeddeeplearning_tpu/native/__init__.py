@@ -9,13 +9,18 @@ the TPU-VM image) and carries **bit-identical pure-Python fallbacks** so
 every call works — just slower — when a toolchain is unavailable
 (``DDL_NATIVE=0`` forces the fallbacks).
 
-Build-on-demand: the first call compiles ``libddl_native.so`` next to the
-source with ``g++ -O3`` and caches it; rebuilds when the source is newer.
+Build-on-demand: the first call compiles the library next to the source
+with ``g++ -O3``, from ``native/ddl_native.cc`` alone, and names it after
+the hash of that source (``libddl_native-<sha256[:16]>.so``). A library is
+loaded only under the name of the source in the tree, so a stale or
+foreign build product is never trusted — whatever a copy of the tree did
+to file times. :func:`native_available` says which path is live.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import struct
@@ -26,17 +31,21 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 _SRC = Path(__file__).resolve().parents[2] / "native" / "ddl_native.cc"
-_LIB_PATH = _SRC.with_name("libddl_native.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_tried = False
 
 
-def _compile() -> bool:
+def _lib_path() -> Path:
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _SRC.with_name(f"libddl_native-{digest}.so")
+
+
+def _compile(lib_path: Path) -> bool:
     # Per-pid temp name: concurrent first-use builds (launch.py N-process
     # worlds) each write their own file; os.replace publishes atomically.
-    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC",
         "-o", tmp, str(_SRC), "-lpthread",
@@ -45,7 +54,7 @@ def _compile() -> bool:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
     except (OSError, subprocess.SubprocessError):
         return False
-    os.replace(tmp, _LIB_PATH)
+    os.replace(tmp, lib_path)
     return True
 
 
@@ -60,13 +69,11 @@ def load_library() -> Optional[ctypes.CDLL]:
             return None
         if not _SRC.exists():
             return None
-        fresh = _LIB_PATH.exists() and (
-            _LIB_PATH.stat().st_mtime >= _SRC.stat().st_mtime
-        )
-        if not fresh and not _compile():
+        lib_path = _lib_path()
+        if not lib_path.exists() and not _compile(lib_path):
             return None
         try:
-            lib = ctypes.CDLL(str(_LIB_PATH))
+            lib = ctypes.CDLL(str(lib_path))
         except OSError:
             return None
         lib.ddl_crc32c.restype = ctypes.c_uint32

@@ -7,7 +7,7 @@ buffer**, dequantize that copy, then run masked scores over it — the
 exact memory round-trip the paged layout was built to avoid
 (PagedAttention) and the exact fusion online softmax eliminates
 (FlashAttention). This kernel replaces the stitched chain with one
-program per ``(row, head)``:
+program per cache row, all heads at once:
 
 * walk the slot's **block table** (scalar-prefetched into SMEM so the
   table drives the K/V BlockSpec index maps — the gather never
@@ -22,9 +22,9 @@ program per ``(row, head)``:
 Numerics mirror ``Attention._masked_decode_scores``: queries are
 pre-scaled by ``head_dim**-0.5``, masked lanes take
 ``jnp.finfo(f32).min``, the softmax state is f32 throughout. The
-recurrence re-associates the sum, so fused-vs-XLA logits agree to ULP
-noise (exact for the common single-K-block serving shapes) — the greedy
-token-stream parity the serve_bench gate checks rides on that
+score and softmax sums are re-associated, so fused-vs-XLA logits agree
+to a few ULPs of the logits' scale — the greedy token-stream parity
+the serve_bench gate checks rides on that
 (``tests/test_paged_decode_kernel.py``).
 
 Masking subsumes the paged trash-block convention for free: an
@@ -61,7 +61,11 @@ from jax.experimental.pallas import tpu as pltpu
 # custom-call to look for).
 FUSED_SCOPE = "paged_decode_fused"
 
-_LANES = 128  # VPU lane width: m/l scratch rows are lane-replicated
+# Query rows per cache row the kernel serves: plain decode (1) and the
+# speculative verify window (spec_k + 1). Rows are a static unroll over
+# VPU work; a prefill-sized window — every prefill bucket is wider than
+# this — is MXU work and stays on the XLA path.
+MAX_QUERY_ROWS = 8
 
 # Scratch init: large-negative instead of -inf keeps exp() NaN-free.
 _NEG_INF = -1e30
@@ -90,27 +94,40 @@ def _pick_block(pref: int, t: int) -> int:
 
 def _decode_kernel(*refs, scale: float, kv_len: int, block_k: int,
                    quant: bool, paged: bool):
-    """One ``(row, head, k-block)`` program with K innermost.
+    """One ``(row, k-block)`` program, K innermost, all heads at once.
 
     ``refs`` order (static per instantiation): an SMEM block-table ref
-    leads iff ``paged``; then q, k, v, [k_scale, v_scale iff quant],
-    q_pos, the output, and the m/l/acc VMEM scratch. The online-softmax
-    state persists across the sequential K dimension exactly as in
-    ``flash.py``.
+    leads iff ``paged``; then the SMEM ``q_pos`` ref, q, k, v,
+    [k_scale, v_scale iff quant], the output, and the m/l/acc VMEM
+    scratch. The online-softmax state persists across the sequential K
+    dimension exactly as in ``flash.py``.
+
+    The cache keeps ``(H, d)`` as its two minor dims, so one key
+    position is one ``[H, d]`` tile and a single head is a sublane of
+    every tile: Mosaic cannot block on it (a block's second-minor dim
+    must be a multiple of 8 or the whole axis). The kernel therefore
+    takes whole ``[block_k, H, d]`` blocks and never separates heads —
+    scores are a lane reduction of ``k * q`` and the weighted sum a
+    reduction over the block's major dim, per query row. That is VPU
+    work, which a decode step (few query rows, bandwidth-bound) can
+    afford; prefill-sized ``t`` belongs to the XLA einsum
+    (``MAX_QUERY_ROWS``).
     """
     refs = list(refs)
     if paged:
         refs.pop(0)  # table ref: consumed by the index maps, not here
-    q_ref, k_ref, v_ref = refs[:3]
+    pos_ref, q_ref, k_ref, v_ref = refs[:4]
     ks_ref = vs_ref = None
-    i = 3
+    i = 4
     if quant:
-        ks_ref, vs_ref = refs[3:5]
-        i = 5
-    pos_ref, o_ref, m_scr, l_scr, acc_scr = refs[i:i + 5]
+        ks_ref, vs_ref = refs[4:6]
+        i = 6
+    o_ref, m_scr, l_scr, acc_scr = refs[i:i + 4]
 
-    j = pl.program_id(2)
-    t, d = q_ref.shape[1], q_ref.shape[3]
+    row = pl.program_id(0)
+    j = pl.program_id(1)
+    t = q_ref.shape[1]
+    dtype = q_ref.dtype
 
     @pl.when(j == 0)
     def _init():
@@ -118,58 +135,51 @@ def _decode_kernel(*refs, scale: float, kv_len: int, block_k: int,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :]  # [t, d], compute dtype
-    kq = k_ref[0, :, 0, :]  # [block_k, d], storage dtype
-    vq = v_ref[0, :, 0, :]
-    if quant:
-        # Dequantize in-register: the full-length HBM round-trip the
-        # stitched path paid is exactly what never happens here.
-        k = (kq.astype(jnp.float32) * ks_ref[0, :, 0, :]).astype(q.dtype)
-        v = (vq.astype(jnp.float32) * vs_ref[0, :, 0, :]).astype(q.dtype)
-    else:
-        k = kq.astype(q.dtype)
-        v = vq.astype(q.dtype)
+    # A block wholly past every query row's position (or past kv_len)
+    # is fully masked: p = 0 and the state is unchanged, so skip it.
+    last = pos_ref[row, 0]
+    for r in range(1, t):
+        last = jnp.maximum(last, pos_ref[row, r])
+    k_start = j * block_k
 
-    # Logical K positions are block-major in BOTH layouts: the paged
-    # grid walks the table in logical-block order, so block j always
-    # covers positions [j·bs, (j+1)·bs) regardless of which physical
-    # block the index map fetched.
-    k_idx = j * block_k + lax.broadcasted_iota(jnp.int32, (t, block_k), 1)
-    q_pos = pos_ref[0]  # [t, 1] int32
-    mask = jnp.logical_and(k_idx <= q_pos, k_idx < kv_len)
-    # Grid padding past kv_len reads undefined memory; the mask drops
-    # those scores, and zeroing v kills the 0·NaN poisoning path.
-    v = jnp.where(
-        (j * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0)) < kv_len,
-        v, jnp.zeros_like(v),
-    )
+    @pl.when(jnp.logical_and(k_start <= last, k_start < kv_len))
+    def _compute():
+        kf = k_ref[0].astype(jnp.float32)  # [block_k, H, d]
+        vf = v_ref[0].astype(jnp.float32)
+        if quant:
+            # Dequantize in-register (scales are [block_k, H, 1]): the
+            # full-length HBM round-trip the stitched path paid is
+            # exactly what never happens here. Rounded through the
+            # compute dtype like quant.dequantize_store.
+            kf = (kf * ks_ref[0]).astype(dtype).astype(jnp.float32)
+            vf = (vf * vs_ref[0]).astype(dtype).astype(jnp.float32)
+        # Logical K positions are block-major in BOTH layouts: the paged
+        # grid walks the table in logical-block order, so block j always
+        # covers positions [j·bs, (j+1)·bs) regardless of which physical
+        # block the index map fetched.
+        k_idx = k_start + lax.broadcasted_iota(jnp.int32, kf.shape[:2] + (1,), 0)
+        in_len = k_idx < kv_len
+        # Grid padding past kv_len reads undefined memory; the mask drops
+        # those scores, and zeroing v kills the 0·NaN poisoning path.
+        vf = jnp.where(in_len, vf, 0.0)
+        for r in range(t):
+            q = (q_ref[0, r] * scale).astype(dtype).astype(jnp.float32)
+            s = jnp.sum(kf * q[None], axis=-1, keepdims=True)  # [bk, H, 1]
+            mask = jnp.logical_and(in_len, k_idx <= pos_ref[row, r])
+            s = jnp.where(mask, s, _MASK_VALUE)
+            m_prev = m_scr[r]  # [H, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[None])
+            m_scr[r] = m_new
+            l_scr[r] = l_scr[r] * alpha + jnp.sum(p, axis=0)
+            acc_scr[r] = acc_scr[r] * alpha + jnp.sum(p * vf, axis=0)
 
-    s = lax.dot_general(
-        (q * scale).astype(q.dtype), k,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [t, block_k]
-    s = jnp.where(mask, s, _MASK_VALUE)
-
-    m_prev = m_scr[:]  # [t, _LANES], lane-replicated
-    l_prev = l_scr[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, :1])
-    m_scr[:] = m_new
-    l_scr[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[:] = acc_scr[:] * alpha[:, :1] + lax.dot_general(
-        p.astype(v.dtype), v,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        l = l_scr[:, :1]
+        l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
 def fused_decode_attention(
@@ -190,8 +200,8 @@ def fused_decode_attention(
 
     Args:
       q: ``[B, t, H, d]`` queries in the compute dtype (``t`` is 1 for
-        plain decode, ``K+1`` for the speculative verify view, or the
-        bucket length for vector-position prefill).
+        plain decode, ``K+1`` for the speculative verify view; at most
+        ``MAX_QUERY_ROWS``).
       k_cache / v_cache: dense ``[B, L, H, d]`` or (with
         ``block_table``) the paged pool ``[nb, block_size, H, d]``, in
         the storage dtype (compute dtype, int8, or fp8).
@@ -225,6 +235,12 @@ def fused_decode_attention(
         interpret = jax.default_backend() != "tpu"
 
     b, t, h, d = q.shape
+    if t > MAX_QUERY_ROWS:
+        raise ValueError(
+            f"fused decode attention takes at most {MAX_QUERY_ROWS} query "
+            f"rows per cache row, got t={t} (prefill-sized windows use "
+            f"the XLA path)"
+        )
     if paged:
         mb = block_table.shape[1]
         bk = block_size
@@ -242,66 +258,49 @@ def fused_decode_attention(
         block_k=bk, quant=quant, paged=paged,
     )
 
-    pos3 = q_pos.astype(jnp.int32)[:, :, None]  # [B, t, 1]: [t,1] blocks
-    q_spec = pl.BlockSpec((1, t, 1, d), lambda bb, hh, jj, *_: (bb, 0, hh, 0))
-    pos_spec = pl.BlockSpec((1, t, 1), lambda bb, hh, jj, *_: (bb, 0, 0))
-    out_spec = pl.BlockSpec((1, t, 1, d), lambda bb, hh, jj, *_: (bb, 0, hh, 0))
+    # Blocks keep the cache's two minor dims (H, d) whole — see the
+    # kernel docstring. Index maps receive the scalar-prefetched refs
+    # after the grid indices: the block table (paged) and q_pos.
+    qo_spec = pl.BlockSpec((1, t, h, d), lambda bb, jj, *_: (bb, 0, 0, 0))
     if paged:
         # The scalar-prefetched table drives the K/V index maps: grid
         # step j fetches physical block table[b, j] straight into VMEM.
-        def kv_idx(bb, hh, jj, table):
-            return (table[bb, jj], 0, hh, 0)
+        def kv_idx(bb, jj, table, pos):
+            return (table[bb, jj], 0, 0, 0)
     else:
-        def kv_idx(bb, hh, jj, *_):
-            return (bb, jj, hh, 0)
-    kv_spec = pl.BlockSpec((1, bk, 1, d), kv_idx)
-    scale_spec = pl.BlockSpec((1, bk, 1, 1), kv_idx)
+        def kv_idx(bb, jj, pos):
+            return (bb, jj, 0, 0)
+    kv_spec = pl.BlockSpec((1, bk, h, d), kv_idx)
+    scale_spec = pl.BlockSpec((1, bk, h, 1), kv_idx)
 
-    in_specs = [q_spec, kv_spec, kv_spec]
+    in_specs = [qo_spec, kv_spec, kv_spec]
     args = [q, k_cache, v_cache]
     if quant:
         in_specs += [scale_spec, scale_spec]
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
-    in_specs.append(pos_spec)
-    args.append(pos3)
-
-    scratch = [
-        pltpu.VMEM((t, _LANES), jnp.float32),
-        pltpu.VMEM((t, _LANES), jnp.float32),
-        pltpu.VMEM((t, d), jnp.float32),
-    ]
-    grid = (b, h, n_kb)
-    out_shape = jax.ShapeDtypeStruct((b, t, h, d), q.dtype)
-    # K (minor) carries the online-softmax recurrence and must stay
-    # sequential; rows and heads parallelise freely.
-    compiler_params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")
-    )
+    prefetch = [q_pos.astype(jnp.int32)]
+    if paged:
+        prefetch.insert(0, block_table.astype(jnp.int32))
 
     with jax.named_scope(FUSED_SCOPE):
-        if paged:
-            call = pl.pallas_call(
-                kernel,
-                grid_spec=pltpu.PrefetchScalarGridSpec(
-                    num_scalar_prefetch=1,
-                    grid=grid,
-                    in_specs=in_specs,
-                    out_specs=out_spec,
-                    scratch_shapes=scratch,
-                ),
-                out_shape=out_shape,
-                compiler_params=compiler_params,
-                interpret=interpret,
-            )
-            return call(block_table.astype(jnp.int32), *args)
-        call = pl.pallas_call(
+        return pl.pallas_call(
             kernel,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=out_spec,
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            compiler_params=compiler_params,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetch),
+                grid=(b, n_kb),
+                in_specs=in_specs,
+                out_specs=qo_spec,
+                scratch_shapes=[
+                    pltpu.VMEM((t, h, 1), jnp.float32),
+                    pltpu.VMEM((t, h, 1), jnp.float32),
+                    pltpu.VMEM((t, h, d), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, t, h, d), q.dtype),
+            # K (minor) carries the online-softmax recurrence and must
+            # stay sequential; rows parallelise freely.
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")
+            ),
             interpret=interpret,
-        )
-        return call(*args)
+        )(*prefetch, *args)

@@ -36,8 +36,7 @@ extra exponent bit; e5m2 remains the range-priority alternative and
 both dtypes are exported), KV stores ``float8_e4m3fn`` for the same
 reason. fp8 is **platform-gated**: :func:`fp8_supported` probes an
 actual jitted round-trip on the active backend, and the serving tier
-falls back to int8 (logged) where the probe fails — the byte count is
-identical either way, only the rounding model differs.
+refuses an fp8 request where the probe fails.
 
 Dtype *names* are validated through one registry (``KV_DTYPES`` /
 ``WEIGHT_DTYPES`` + :func:`validate_store_dtype`) so every boundary —
@@ -105,20 +104,18 @@ def validate_store_dtype(kind: str, value: str, *, extra: Tuple[str, ...] = ()) 
 def fp8_supported() -> bool:
     """Whether the active backend executes fp8 storage + casts. Probes a
     real jitted round-trip (compile + numerics) instead of trusting
-    dtype existence: older TPU generations and exotic backends can
-    expose the dtype yet fail at lowering. Callers treat ``False`` as
-    "fall back to int8" — the serving tier logs the substitution."""
-    if not hasattr(jnp, "float8_e4m3fn"):
-        return False
+    dtype existence: a backend can expose the dtype yet refuse to lower
+    it. The serving engine raises on ``False`` — an fp8 request is never
+    served from another format."""
     import jax
     import numpy as np
 
     try:
         q = jnp.asarray([0.5, -2.0], jnp.float32).astype(FP8_E4M3)
         out = jax.jit(lambda a: a.astype(jnp.float32) * 2.0)(q)
-        return bool(np.allclose(np.asarray(out), [1.0, -4.0]))
-    except Exception:
+    except (jax.errors.JaxRuntimeError, NotImplementedError):
         return False
+    return bool(np.allclose(np.asarray(out), [1.0, -4.0]))
 
 
 def kv_store_dtype(kv_dtype: str) -> Optional[Any]:

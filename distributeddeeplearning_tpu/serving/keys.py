@@ -12,8 +12,8 @@ with ``jax.random`` would compile a tiny program per distinct ``n``,
 noise the engine's zero-recompile guarantee would have to carve
 exceptions for. So the split is reimplemented here in pure numpy.
 
-This repo pins ``jax_threefry_partitionable=True`` (``utils/compat.py``
-— the modern, layout-invariant semantics), under which
+JAX generates partitionable (layout-invariant) random bits
+(``jax_threefry_partitionable``, the default), under which
 ``split(key, n)`` is *fold-like*: row ``i`` is the threefry2x32 cipher
 of the 64-bit counter ``i`` (hi/lo words) under ``key`` — and therefore
 prefix-stable in ``n``. The legacy non-partitionable derivation

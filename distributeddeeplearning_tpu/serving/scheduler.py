@@ -410,7 +410,7 @@ class ServeConfig:
     # dtype; "int8"/"fp8" store the KV pool / stream the inference
     # weights quantized + f32 scales (ops/quant.py — the registry
     # quant.KV_DTYPES/WEIGHT_DTYPES is the source of truth; fp8 is
-    # platform-gated with an int8 fallback). Orthogonal to kv_layout —
+    # platform-gated: refused where it does not lower). Orthogonal to kv_layout —
     # the paged pool quantizes too.
     kv_dtype: str = "bf16"
     weight_dtype: str = "bf16"

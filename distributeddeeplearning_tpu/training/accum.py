@@ -170,7 +170,7 @@ def accumulate_microbatches(
     zero-initialised carries must match the body outputs' varying axes —
     ``vary`` (grads + extra) and ``vary_metrics`` (metric scalars, which
     may be invariant over e.g. the pipe axis after an in-body psum) pcast
-    them (inert identity on jax builds without vma — utils/compat.py).
+    them.
     """
     gacc0 = jax.tree.map(
         lambda g: jnp.zeros(jnp.shape(g), jnp.float32), grads_like

@@ -161,6 +161,15 @@ def build_engine(
     engine = config.engine
     if engine not in ENGINES:
         raise ValueError(f"unknown engine {engine!r} (have {ENGINES})")
+    # Every front-end (loop.fit, explicit.setup, the keras/estimator
+    # skins) builds its engine here, and the state init below is the
+    # process's first compile: re-runs of the same program deserialize
+    # executables instead of re-invoking XLA.
+    from distributeddeeplearning_tpu.training.warmup import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     # In-step gradient accumulation (ACCUM_STEPS) divisibility — checked
     # here, the one dispatch point, so every front-end fails with the
     # actionable message before any compile (training/accum.py).
@@ -254,6 +263,15 @@ def build_eval_step(model, config: TrainConfig, mesh: Mesh):
     engine = config.engine
     if engine not in ENGINES:
         raise ValueError(f"unknown engine {engine!r} (have {ENGINES})")
+    # Every front-end (loop.fit, explicit.setup, the keras/estimator
+    # skins) builds its engine here, and the state init below is the
+    # process's first compile: re-runs of the same program deserialize
+    # executables instead of re-invoking XLA.
+    from distributeddeeplearning_tpu.training.warmup import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     model = adapt_model(model, engine, mesh, config)
     if engine == "pjit":
         from distributeddeeplearning_tpu.training.pjit_step import (

@@ -214,14 +214,6 @@ def fit(
     from distributeddeeplearning_tpu.obs import trace as obs_trace
 
     tracer = obs_trace.from_env()
-    if config.compilation_cache_dir:
-        # Before any compile (engine init included): re-runs of the same
-        # program deserialize executables instead of re-invoking XLA.
-        from distributeddeeplearning_tpu.training.warmup import (
-            enable_persistent_cache,
-        )
-
-        enable_persistent_cache(config.compilation_cache_dir)
     engine_name, mesh = resolve_engine(config, mesh)
     epochs = epochs if epochs is not None else config.epochs
     steps_per_epoch = train_data.steps_per_epoch

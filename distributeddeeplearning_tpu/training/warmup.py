@@ -1,22 +1,20 @@
 """Persistent compilation cache + AOT step warmup.
 
-Every process used to pay full XLA compile time on every run: nothing
-wired ``jax_compilation_cache_dir``, and the first training step ate the
-compile inside the (timed) hot loop. This module is the cheap-restart
-story:
+Compiling is the expensive part of a cold start — minutes for a full
+model on the TPU — and a compiled executable is reusable by any later
+process that asks for the same program on the same device. This module
+is the cheap-restart story:
 
-* :func:`enable_persistent_cache` turns on JAX's on-disk compilation
-  cache (config knob ``TrainConfig.compilation_cache_dir`` / env
-  ``COMPILATION_CACHE_DIR``): re-runs of ``bench.py``,
-  ``scripts/recertify.py`` and multi-epoch jobs deserialize the
-  executable instead of recompiling. Thresholds default to
-  "cache everything" — on the CPU test tier compiles are fast but still
-  dominate tiny runs, and on TPU a serialized executable is always
-  cheaper than XLA.
+* :func:`enable_compile_cache` is the ONE place that decides where
+  JAX's on-disk compilation cache lives. Every entry point
+  (``chip_smoke.py``, ``bench.py``, ``loop.fit``, ``Server.build`` /
+  ``build_fleet``, the ``scripts/*_bench.py``) calls it before its
+  first compile. The directory is part of the cache key's world: a
+  path that moves between runs never hits, so the rule has exactly two
+  outcomes, both fixed paths.
 * :func:`cache_stats` observes the cache's hit/miss monitoring events so
-  a warm-start can be *proved* (the round's oracle asserts hits > 0 on a
-  second warmup against a warm cache) instead of inferred from wall
-  clock.
+  a warm start can be *proved* (hits > 0 on a second run against the
+  same directory) instead of inferred from wall clock.
 * :func:`warmup_engine` — backing for ``Engine.warmup()`` — AOT-compiles
   the train (and optionally eval) step before any data flows, logs
   compile seconds and XLA cost-analysis FLOPs, and installs the
@@ -26,14 +24,22 @@ story:
 
 from __future__ import annotations
 
+import os
 import threading
+from pathlib import Path
 from typing import Any, Dict, Optional, Tuple
 
 import jax
+from jax.experimental.compilation_cache import compilation_cache
 
 from distributeddeeplearning_tpu import obs
 from distributeddeeplearning_tpu.utils import heartbeat
 from distributeddeeplearning_tpu.utils.logging import get_logger
+
+# Where the cache lives when nothing outside says otherwise: inside the
+# checkout (listed in .gitignore), so it is the same path on every run
+# from this tree.
+DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[2] / ".jax_cache")
 
 _stats = {"hits": 0, "misses": 0}
 _listener_lock = threading.Lock()
@@ -52,20 +58,13 @@ def _on_event(event: str, **kw) -> None:
         obs.counter("xla_cache_miss")
 
 
-def install_cache_listener() -> bool:
-    """Subscribe to the compilation-cache monitoring events (idempotent).
-    Returns False when this jax build exposes no monitoring hook."""
+def install_cache_listener() -> None:
+    """Subscribe to the compilation-cache monitoring events (idempotent)."""
     global _listener_installed
     with _listener_lock:
-        if _listener_installed:
-            return True
-        try:
-            from jax._src import monitoring
-        except ImportError:  # pragma: no cover - jax internals moved
-            return False
-        monitoring.register_event_listener(_on_event)
-        _listener_installed = True
-        return True
+        if not _listener_installed:
+            jax.monitoring.register_event_listener(_on_event)
+            _listener_installed = True
 
 
 def cache_stats() -> Tuple[int, int]:
@@ -73,43 +72,29 @@ def cache_stats() -> Tuple[int, int]:
     return _stats["hits"], _stats["misses"]
 
 
-def enable_persistent_cache(
-    cache_dir: Optional[str],
-    *,
-    min_compile_secs: float = 0.0,
-    min_entry_bytes: int = 0,
-) -> None:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory. Call before the first compile of the process.
 
-    ``None``/empty disables it again. The thresholds are deliberately
-    zero: JAX's defaults skip sub-second compiles, which is exactly the
-    CPU-tier regime where the cache oracle must be able to observe hits.
+    The rule: when ``JAX_COMPILATION_CACHE_DIR`` is set, whoever runs
+    the program has placed the cache — JAX read the variable at import
+    and no directory is set here. Otherwise the cache is
+    ``<checkout>/.jax_cache``. Every program is cached (JAX's default
+    skips compiles under a second, which is all of them on the CPU test
+    tier and none of the ones that matter on the TPU; one rule for both).
     """
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
-        jax.config.update("jax_compilation_cache_dir", None)
-        _reset_cache_state()
-        return
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update(
-        "jax_persistent_cache_min_compile_time_secs", float(min_compile_secs)
-    )
-    jax.config.update(
-        "jax_persistent_cache_min_entry_size_bytes", int(min_entry_bytes)
-    )
-    # jax latches "cache disabled" at the first compile of the process;
-    # enabling later (typical: fit() after library imports already
-    # compiled something) needs the latch cleared to take effect.
-    _reset_cache_state()
+        cache_dir = DEFAULT_CACHE_DIR
+        if jax.config.jax_compilation_cache_dir != cache_dir:
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
+            # jax latches "no cache" at the first compile of the
+            # process; a caller that compiled before asking for the
+            # cache needs the latch cleared for the directory to count.
+            compilation_cache.reset_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     install_cache_listener()
-
-
-def _reset_cache_state() -> None:
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover - jax internals moved
-        pass
+    return cache_dir
 
 
 def cost_analysis_flops(compiled: Any) -> Optional[float]:
@@ -143,7 +128,6 @@ def warmup_engine(
     logs a one-line summary.
     """
     log = get_logger()
-    install_cache_listener()
     hits0, misses0 = cache_stats()
     info: Dict[str, float] = {}
 

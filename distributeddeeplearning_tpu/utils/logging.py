@@ -33,25 +33,13 @@ def _get_rank() -> int:
     a single-host world. Pre-init, fall back to the launcher's
     ``DDL_PROCESS_ID``.
     """
-    try:
-        import jax
-        from jax._src import xla_bridge
-
-        if xla_bridge.backends_are_initialized():
-            return jax.process_index()
-    except AttributeError:
-        # Private probe moved in a jax upgrade: fall back to our own init
-        # flag so post-initialize ranks are still correct.
-        from distributeddeeplearning_tpu.parallel import distributed
-
-        if distributed._initialized:
-            import jax
-
-            return jax.process_index()
-    except Exception:
-        pass
     import os
 
+    import jax
+    from jax._src import xla_bridge  # no public "is a backend up" probe
+
+    if xla_bridge.backends_are_initialized():
+        return jax.process_index()
     return int(os.environ.get("DDL_PROCESS_ID", 0))
 
 

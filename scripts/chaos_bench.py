@@ -314,14 +314,11 @@ def main() -> int:
         obs.configure_from_env()
     import jax
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    if os.environ.get("COMPILATION_CACHE_DIR"):
-        from distributeddeeplearning_tpu.training.warmup import (
-            enable_persistent_cache,
-        )
+    from distributeddeeplearning_tpu.training.warmup import (
+        enable_compile_cache,
+    )
 
-        enable_persistent_cache(os.environ["COMPILATION_CACHE_DIR"])
+    enable_compile_cache()
 
     import flax.linen as nn
     import jax.numpy as jnp

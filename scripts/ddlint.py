@@ -30,11 +30,13 @@ budget, not silence.
 from __future__ import annotations
 
 # The HLO family lowers real programs: force the CPU backend and the
-# 8-device test mesh BEFORE anything imports jax (the package __init__
-# does, via the compat shim).
+# 8-device test mesh BEFORE anything imports jax.
 import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# The audit reads what it compiles and has no restart to make cheap: the
+# persistent compile cache the engine builders would switch on stays off.
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 _FLAG = "--xla_force_host_platform_device_count=8"
 if _FLAG not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (

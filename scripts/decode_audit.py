@@ -97,12 +97,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-# The chip constants live in ONE shared module (utils/roofline.py) so a
-# chip swap is a single edit; re-exported here for existing importers.
-from distributeddeeplearning_tpu.utils.roofline import (  # noqa: E402
-    FLOOR_BASIS,
-    HBM_GBPS,
-)
+# The chip peaks live in ONE shared table (utils/roofline.py), keyed by
+# the device_kind JAX reports.
+from distributeddeeplearning_tpu.utils import roofline  # noqa: E402
 
 
 def tree_bytes(tree) -> int:
@@ -390,13 +387,18 @@ def audit(model_name: str, prompt_len: int, new_tokens: int,
     rows = []
     platform = jax.devices()[0].platform
     on_tpu = platform == "tpu"
+    # On the chip the floor is the attached chip's (an unknown kind
+    # raises); off it the floor is analytic, for the chip named here.
+    chip = jax.devices()[0].device_kind if on_tpu else roofline.V5E
+    hbm_gbps = roofline.peaks(chip).hbm_gbps
+    floor_basis = roofline.floor_basis(chip)
     print(f"# {model_name} decode audit on {platform}: params "
           f"{param_bytes / 2**20:.1f} MiB "
           f"(weights {weight_dtype}, kv {kv_dtype}), max_len {max_len}",
           flush=True)
     if not on_tpu:
-        print(f"# NOTE: floor column is the ANALYTIC v5e byte floor "
-              f"({FLOOR_BASIS}); on {platform} it is not a roofline "
+        print(f"# NOTE: floor column is the ANALYTIC byte floor "
+              f"({floor_basis}); on {platform} it is not a roofline "
               "position — % of floor suppressed", flush=True)
     print(f"# {'b':>4} {'tok/s':>10} {'tok/s/seq':>10} {'floor tok/s':>12} "
           f"{'% of floor':>10} {'cache MiB':>10}", flush=True)
@@ -437,7 +439,7 @@ def audit(model_name: str, prompt_len: int, new_tokens: int,
                 decode_kernel=kernels[0],
             )
             commits = max(commits, 1e-9)
-            floor = b * commits * HBM_GBPS * 1e9 / bytes_per_tick
+            floor = b * commits * hbm_gbps * 1e9 / bytes_per_tick
             base_kv, base_scale = cache_byte_split(b)
             base_bytes = param_bytes + base_kv + base_scale
             row = sweep_row(b, tps, kv, bytes_per_tick, floor, on_tpu,
@@ -487,7 +489,7 @@ def audit(model_name: str, prompt_len: int, new_tokens: int,
                 # pool stream.
                 extra = dequant_extra if kern == "xla" else 0
                 bytes_per_step = base_bytes + extra
-                floor = b * HBM_GBPS * 1e9 / bytes_per_step
+                floor = b * hbm_gbps * 1e9 / bytes_per_step
                 tps = measure_engine(
                     model, params, b, prompt_len, new_tokens, vocab,
                     kv_layout=kv_layout, block_size=block_size,
@@ -508,7 +510,7 @@ def audit(model_name: str, prompt_len: int, new_tokens: int,
         else:
             kv, _ = cache_byte_split(b)
             bytes_per_step = param_bytes + kv
-            floor = b * HBM_GBPS * 1e9 / bytes_per_step
+            floor = b * hbm_gbps * 1e9 / bytes_per_step
             rng = np.random.RandomState(0)
             prompt = rng.randint(0, vocab, size=(b, prompt_len)).astype(
                 np.int32
@@ -544,8 +546,8 @@ def audit(model_name: str, prompt_len: int, new_tokens: int,
         "weight_dtype": weight_dtype,
         "decode_kernel": kernel,
         "param_bytes_mb": round(param_bytes / 2**20, 1),
-        "hbm_gbps": HBM_GBPS,
-        "floor_basis": FLOOR_BASIS,
+        "hbm_gbps": hbm_gbps,
+        "floor_basis": floor_basis,
         # the roofline claim is only a measured position on the chip the
         # floor constant describes
         "floor_applicable": on_tpu,
@@ -562,10 +564,6 @@ def audit(model_name: str, prompt_len: int, new_tokens: int,
 
 
 def main(argv=None) -> int:
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--model", default="lm_small")
     p.add_argument("--prompt-len", type=int, default=128)

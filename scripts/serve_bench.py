@@ -70,7 +70,7 @@ as a protocol change), ``SERVE_QUANT_MATCH_MIN`` (0.95),
 ``SERVE_SPEC_DRAFT`` (int8 | ngram), ``SERVE_SPEC_NGRAM_N`` (3),
 ``SERVE_SPEC_MIN_SPEEDUP`` (1.4),
 ``BENCH_MODEL`` (lm_tiny), ``BENCH_VOCAB`` (32000), plus the generic
-``OBS_DIR``/``--events`` and ``COMPILATION_CACHE_DIR`` plumbing
+``OBS_DIR``/``--events`` plumbing
 bench.py uses. With ``SLO_SPEC`` set (and ``OBS_DIR``) the bench runs
 under the live telemetry plane — rollups + SLO burn rates published to
 ``<OBS_DIR>/rollup.json`` while serving — and
@@ -593,14 +593,11 @@ def main() -> int:
         plane_stop, plane_thread = start_live_plane(os.environ["OBS_DIR"])
     import jax
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    if os.environ.get("COMPILATION_CACHE_DIR"):
-        from distributeddeeplearning_tpu.training.warmup import (
-            enable_persistent_cache,
-        )
+    from distributeddeeplearning_tpu.training.warmup import (
+        enable_compile_cache,
+    )
 
-        enable_persistent_cache(os.environ["COMPILATION_CACHE_DIR"])
+    enable_compile_cache()
 
     import flax.linen as nn
     import jax.numpy as jnp

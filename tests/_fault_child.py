@@ -27,10 +27,6 @@ def main() -> None:
     state_file = os.environ.get("STATE_FILE")
     path = f"{state_file}.{rank}" if state_file else None
 
-    cache = os.environ.get("COMPILATION_CACHE_DIR")
-    if cache:  # lets the supervisor e2e assert the per-attempt suffix
-        print(f"FAULT_CHILD_CACHE_DIR {rank} {cache}", flush=True)
-
     if os.environ.get("ELASTIC"):  # elastic drills assert the rescale
         print(
             f"FAULT_CHILD_WORLD rank={rank} "

@@ -477,15 +477,20 @@ def test_supervisor_elastic_shrink_and_grow_jaxlight(tmp_path):
             "--timeout", "120",
             "--obs-dir", str(obs_dir),
             "--env", "JAX_PLATFORMS=cpu",
-            "--env", "FAKE_STEPS=40",
+            "--env", "FAKE_STEPS=100",
             "--env", "BATCHSIZE=2",
             "--env", "ACCUM_STEPS=1",
             # rank=1 pins the directive to the casualty process, so the
             # world-1 relaunch (rank 0) can never re-fire it whatever
-            # step its state file persisted before the teardown
+            # step its state file persisted before the teardown.
+            # Capacity returns at step 40, not right after the shrink:
+            # rank 0 of the full world keeps stepping (50 ms each) until
+            # the 10 Hz supervisor tears it down, and a loaded machine
+            # let it pass step 6 — the shrunken world then resumed beyond
+            # the restore step and never grew back.
             "--env",
             "FAULT_PLAN=shrink:step=3,rank=1,ranks=1;"
-            "restore_capacity:step=6",
+            "restore_capacity:step=40",
             "--env", f"STATE_FILE={tmp_path}/state",
             "tests/_fault_child.py",
         ],

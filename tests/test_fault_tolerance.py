@@ -233,15 +233,6 @@ def _ft_env_args(tmp_path, engine, **extra):
         EPOCHS="2",
         ENGINE=engine,
         CHECKPOINT_ASYNC="0",
-        # NOTE: deliberately no COMPILATION_CACHE_DIR — this jax build's
-        # persistent cache heap-corrupts (glibc abort) under concurrent
-        # multi-process write+reread of one cache dir, which is exactly
-        # the restart pattern. Observed as SIGABRT ("corrupted
-        # double-linked list") in the relaunched world; reproducible by
-        # adding the knob back here WITH the supervisor's guard disabled.
-        # launch_supervised now auto-suffixes the dir per restart attempt
-        # (<dir>-r<k>) so configured caches no longer hit this
-        # (tests/test_faults.py::test_supervisor_suffixes_cache_dir).
     )
     env.update(extra)
     out = []

@@ -260,44 +260,6 @@ def test_supervisor_recovers_watchdog_killed_hang(tmp_path):
     assert "FAULT_CHILD_DONE 0 start=2" in out  # resumed past the hang
 
 
-def test_supervisor_suffixes_cache_dir(tmp_path):
-    """The r5 KNOWN ISSUE guard: a restarted world sharing one
-    ``COMPILATION_CACHE_DIR`` heap-corrupts this jax build, so every
-    restart attempt must compile against ``<dir>-r<k>`` — the attempt-0
-    dir is exported untouched, the relaunched world sees the suffix."""
-    obs_dir = tmp_path / "run"
-    cache = tmp_path / "xla-cache"
-    res = _run_launcher(
-        [
-            "--num-processes", "1",
-            "--max-restarts", "1",
-            "--restart-backoff", "0.1",
-            "--timeout", "120",
-            "--obs-dir", str(obs_dir),
-            "--env", "JAX_PLATFORMS=cpu",
-            "--env", f"COMPILATION_CACHE_DIR={cache}",
-            "--env", "FAULT_PLAN=kill:step=2,rank=0",
-            "--env", f"STATE_FILE={tmp_path}/state",
-            "tests/_fault_child.py",
-        ],
-        timeout=300,
-    )
-    out = res.stdout + res.stderr
-    assert res.returncode == 0, out[-4000:]
-    # attempt 0: the configured dir, untouched
-    assert f"FAULT_CHILD_CACHE_DIR 0 {cache}\n" in out
-    # attempt 1: the suffixed dir, announced by the supervisor and
-    # actually exported to the relaunched world
-    assert "supervisor: restart attempt 1 uses compilation cache dir" in out
-    assert f"FAULT_CHILD_CACHE_DIR 0 {cache}-r1" in out
-    recs = [
-        json.loads(ln) for ln in open(obs_dir / "events-supervisor.jsonl")
-    ]
-    suffixed = [r for r in recs if r.get("name") == "cache_dir_suffixed"]
-    assert len(suffixed) == 1
-    assert suffixed[0]["labels"]["dir"] == f"{cache}-r1"
-
-
 def test_supervisor_restart_budget_exhausts(tmp_path):
     """A fault that recurs on every attempt (no state file -> no resume,
     the kill step is re-hit) drains max-restarts and surfaces the

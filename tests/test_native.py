@@ -37,7 +37,14 @@ def test_crc32c_known_answer():
 def test_native_library_builds():
     """g++ is in the image (SURVEY/environment contract) — the native
     build must actually succeed here, not silently fall back."""
-    assert native.native_available(), "libddl_native.so failed to build"
+    assert native.native_available(), "the native library failed to build"
+    # named after the content of the source it was built from: freshness
+    # does not depend on file times a copy of the tree may have reset
+    import hashlib
+
+    digest = hashlib.sha256(native._SRC.read_bytes()).hexdigest()[:16]
+    assert native._lib_path().name == f"libddl_native-{digest}.so"
+    assert native._lib_path().exists()
 
 
 def test_python_fallback_matches_native(tmp_path, monkeypatch):

@@ -359,20 +359,27 @@ def test_fit_emits_epoch_step_perf_and_trace_events(
 # ---------------------------------------------------------------------------
 
 def test_bench_records_route_through_bus(tmp_path, capsys):
-    """bench.py --events contract: the canonical stdout JSON line is
-    unchanged AND the same record lands on the bus as bench_result."""
+    """bench.py --events contract: the canonical stdout JSON line names
+    its device AND the same record lands on the bus as bench_result. A
+    record made off the TPU is renamed so it cannot pass for the device
+    metric."""
     import bench
 
     bus = obs.configure(str(tmp_path))
     record = {"metric": "resnet50_synthetic_train_images_per_sec",
               "value": 123.4, "unit": "images/sec", "vs_baseline": 0.1}
-    bench._emit_record(record)
+    tpu = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    bench._emit_record(record, tpu)
     line = capsys.readouterr().out.strip()
-    assert json.loads(line) == record  # driver protocol intact
+    assert json.loads(line) == {**record, "device": tpu}
     events = [json.loads(ln) for ln in open(bus.path)][1:]
     assert events[-1]["name"] == "bench_result"
     assert events[-1]["labels"]["metric"] == record["metric"]
     assert events[-1]["labels"]["value"] == 123.4
+
+    bench._emit_record(record, {"platform": "cpu", "kind": "cpu", "count": 8})
+    off_chip = json.loads(capsys.readouterr().out.strip())
+    assert off_chip["metric"] == "cpu_smoke." + record["metric"]
 
 
 def test_heavy_refresh_duration_parsing():
@@ -401,6 +408,20 @@ def test_decode_audit_cpu_honest_rows():
     assert off_chip["analytic_floor_tokens_per_sec"] == 20000.0
     assert "%" in format_row(on_chip)
     assert "n/a" in format_row(off_chip)
+
+
+def test_roofline_peaks_are_keyed_by_device_kind():
+    """A floor is quoted against the attached chip's published peaks; a
+    device_kind the table does not hold is an error, never a default."""
+    from distributeddeeplearning_tpu.utils import roofline
+
+    v5e = roofline.peaks(roofline.V5E)
+    assert (v5e.hbm_gbps, v5e.bf16_tflops) == (819.0, 197.0)
+    assert v5e.source
+    assert roofline.floor_basis(roofline.V5E) == "TPU v5 lite-hbm-819GBps"
+    for kind in ("cpu", "TPU v4", ""):
+        with pytest.raises(KeyError, match="no roofline peaks"):
+            roofline.peaks(kind)
 
 
 def test_decode_audit_paged_floor_accounts_table_bytes():

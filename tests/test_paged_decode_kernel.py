@@ -37,13 +37,16 @@ import pytest
 from distributeddeeplearning_tpu.models.transformer_lm import TransformerLM
 from distributeddeeplearning_tpu.ops import quant
 from distributeddeeplearning_tpu.ops.pallas.paged_decode import (
+    MAX_QUERY_ROWS,
     fused_decode_attention,
 )
 from distributeddeeplearning_tpu.serving import ReqSpec, Request, Server, SlotEngine
 
 B, H, D, L = 2, 4, 32, 16
 VOCAB, MAX_LEN = 64, 32
-BUCKETS = (4, 8, 16)
+# The top bucket is wider than the kernel's query window
+# (MAX_QUERY_ROWS), so a fused paged engine's prefill takes the einsum.
+BUCKETS = (4, 8, 32)
 
 
 def _rand(rng, *shape):
@@ -227,6 +230,11 @@ def test_vector_position_contract_and_scale_pairing():
     with pytest.raises(ValueError, match="k_scale"):
         fused_decode_attention(q, kq, v, jnp.zeros((B, 1), jnp.int32),
                                k_scale=ks)
+    wide = MAX_QUERY_ROWS + 1
+    with pytest.raises(ValueError, match="query rows"):
+        fused_decode_attention(
+            _rand(rng, B, wide, H, D), k, v, jnp.zeros((B, wide), jnp.int32)
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -312,9 +320,12 @@ def test_engine_spec_verify_fused_bitwise_matches_xla(model, params):
 
 
 def test_engine_decode_logits_ulp_bounded(model, params):
-    """Per-step decode logits from the fused and XLA engines stay
-    within a small f32 ULP budget on identical pool state — the claim
-    the bitwise token-stream parity rests on."""
+    """Per-step decode logits from the fused and XLA engines differ by
+    a few f32 ULPs *of the largest logit* on identical pool state — the
+    claim the bitwise token-stream parity rests on. Argmax depends on
+    absolute gaps, so the budget is set at the logits' scale: a
+    per-element ULP count explodes on logits that happen to sit near
+    zero and says nothing about the ordering."""
     xla, fused = _engine_pair(model, params, kv_dtype="int8")
     prompt = np.arange(1, 7, dtype=np.int32)
     spec = ReqSpec(prompt=prompt, max_new_tokens=4)
@@ -331,4 +342,5 @@ def test_engine_decode_logits_ulp_bounded(model, params):
             train=False, mutable=["cache"],
         )
         logits.append(np.asarray(out[0, -1], np.float32))
-    assert int(_ulp_distance(logits[0], logits[1]).max()) <= 1024
+    err = np.abs(logits[0] - logits[1]).max()
+    assert err <= 64 * np.spacing(np.abs(logits[0]).max())

@@ -250,8 +250,8 @@ def test_fp8_registry_dispatch_and_support_probe():
     assert q.dtype == quantlib.FP8_KV_DTYPE
     with pytest.raises(ValueError, match="kv_dtype"):
         quantlib.validate_store_dtype("kv_dtype", "int4")
-    # CPU executes fp8 casts: the probe must say so (the TPU-gated
-    # fallback path is exercised by monkeypatching in serving tests)
+    # CPU executes fp8 casts: the probe must say so (the refusal where
+    # it does not is exercised by monkeypatching in serving tests)
     assert quantlib.fp8_supported() is True
 
 

@@ -336,17 +336,15 @@ def test_serve_config_kernel_and_fp8_env_surface():
         )
 
 
-def test_fp8_engine_falls_back_to_int8_when_unsupported(
-    model, params, monkeypatch
-):
-    """The platform gate: where the fp8 probe fails (older TPU gens,
-    exotic backends), the engine substitutes int8 — logged, and visible
-    in the stored dtypes / byte accounting rather than silently kept."""
+def test_fp8_engine_raises_when_unsupported(model, params, monkeypatch):
+    """The platform gate: where the fp8 probe fails the engine refuses
+    to build — an fp8 request is never quietly served from int8."""
     from distributeddeeplearning_tpu.ops import quant as quantlib
 
     monkeypatch.setattr(quantlib, "fp8_supported", lambda: False)
-    eng = SlotEngine(
-        model, params, num_slots=2, max_len=MAX_LEN, buckets=(8,),
-        kv_dtype="fp8", weight_dtype="fp8",
-    )
-    assert eng.kv_dtype == "int8" and eng.weight_dtype == "int8"
+    for kw in ({"kv_dtype": "fp8"}, {"weight_dtype": "fp8"}):
+        with pytest.raises(ValueError, match="fp8 storage is unsupported"):
+            SlotEngine(
+                model, params, num_slots=2, max_len=MAX_LEN, buckets=(8,),
+                **kw,
+            )

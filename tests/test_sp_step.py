@@ -151,13 +151,6 @@ def test_sp_step_rejects_overlong_global_sequence(sp_mesh):
 def test_ring_rejects_unsharded_sequence(sp_mesh):
     """A bound-but-unsharded ring axis must raise, not compute garbage."""
     from distributeddeeplearning_tpu.parallel.ring_attention import ring_attention
-    from distributeddeeplearning_tpu.utils import compat
-
-    if compat.shimmed("pcast"):
-        pytest.skip(
-            "detection needs the vma type system (ring_attention's pcast "
-            "probe); this jax has no vma — the check degrades to off"
-        )
 
     def f(q):
         return ring_attention(q, q, q, axis_name="seq")

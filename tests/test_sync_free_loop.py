@@ -254,40 +254,79 @@ def test_sync_invariant_holds_with_event_bus_enabled(mesh8, tmp_path):
         obs.reset()
 
 
-def test_warm_persistent_cache_skips_recompilation(mesh8, tmp_path):
-    """(3): second AOT warmup against a warm on-disk cache observes
-    cache hits; the executables really landed on disk the first time."""
-    from distributeddeeplearning_tpu.training import warmup as wu
+@pytest.fixture
+def placed_cache(tmp_path, monkeypatch):
+    """A persistent compile cache placed from outside, the way JAX sees
+    it when ``JAX_COMPILATION_CACHE_DIR`` is exported before start-up
+    (JAX reads the variable into its config at import) — and switched
+    on, which the suite otherwise keeps off (conftest)."""
+    from jax.experimental.compilation_cache import compilation_cache
 
     cache_dir = str(tmp_path / "xla-cache")
-    wu.enable_persistent_cache(cache_dir)
-    try:
-        cfg = _token_cfg("dp", aot_warmup=True)
-        data = _token_data(cfg)
-        eng = _build("lm_tiny", cfg, data, mesh8)
-        batch = next(
-            iter(prefetch_to_device(data.epoch(0), mesh8, size=0))
-        )
-        acc = init_accumulator(mesh8)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+    yield cache_dir
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_compilation_cache_dir", None)
+    compilation_cache.reset_cache()
 
-        info1 = eng.warmup(batch, acc=acc)
-        assert info1["train_compile_sec"] > 0
-        assert info1["compile_sec"] > 0
-        n_entries = len(os.listdir(cache_dir))
-        assert n_entries > 0  # the compile was persisted
 
-        # Fresh engine (fresh jit wrappers) + cleared in-memory caches:
-        # the only way the second compile can be cheap is the disk cache.
-        jax.clear_caches()
-        eng2 = _build("lm_tiny", cfg, data, mesh8)
-        info2 = eng2.warmup(batch, acc=acc)
-        assert info2["persistent_cache_hits"] > 0, info2
-        assert info2["persistent_cache_misses"] == 0, info2
-        # the warm pass may lazily persist small helper programs that
-        # were only in-memory before, but never re-writes the step
-        assert len(os.listdir(cache_dir)) >= n_entries
-    finally:
-        wu.enable_persistent_cache(None)
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout(
+    placed_cache, monkeypatch
+):
+    """The one cache rule (training/warmup.enable_compile_cache):
+    JAX_COMPILATION_CACHE_DIR set -> no directory is set in code;
+    unset -> ``<checkout>/.jax_cache``, a fixed path."""
+    from distributeddeeplearning_tpu.training import warmup as wu
+
+    updates = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        updates.append(name)
+        real_update(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    assert wu.enable_compile_cache() == placed_cache
+    assert "jax_compilation_cache_dir" not in updates
+    assert jax.config.jax_compilation_cache_dir == placed_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert wu.enable_compile_cache() == os.path.join(repo, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == wu.DEFAULT_CACHE_DIR
+    del updates[:]
+    wu.enable_compile_cache()  # same answer: nothing to move
+    assert "jax_compilation_cache_dir" not in updates
+
+
+def test_warm_persistent_cache_skips_recompilation(mesh8, placed_cache):
+    """(3): second AOT warmup against a warm on-disk cache observes
+    cache hits; the executables really landed on disk the first time."""
+    cfg = _token_cfg("dp", aot_warmup=True)
+    data = _token_data(cfg)
+    eng = _build("lm_tiny", cfg, data, mesh8)
+    batch = next(iter(prefetch_to_device(data.epoch(0), mesh8, size=0)))
+    acc = init_accumulator(mesh8)
+
+    info1 = eng.warmup(batch, acc=acc)
+    assert info1["train_compile_sec"] > 0
+    assert info1["compile_sec"] > 0
+    n_entries = len(os.listdir(placed_cache))
+    assert n_entries > 0  # the compile was persisted
+
+    # Fresh engine (fresh jit wrappers) + cleared in-memory caches:
+    # the only way the second compile can be cheap is the disk cache.
+    jax.clear_caches()
+    eng2 = _build("lm_tiny", cfg, data, mesh8)
+    info2 = eng2.warmup(batch, acc=acc)
+    assert info2["persistent_cache_hits"] > 0, info2
+    assert info2["persistent_cache_misses"] == 0, info2
+    # the warm pass may lazily persist small helper programs that
+    # were only in-memory before, but never re-writes the step
+    assert len(os.listdir(placed_cache)) >= n_entries
 
 
 def test_fit_aot_warmup_reports_compile_sec(mesh8):
@@ -309,13 +348,8 @@ def test_fit_aot_warmup_reports_compile_sec(mesh8):
 
 
 def test_config_env_contract():
-    cfg = TrainConfig.from_env(
-        {"COMPILATION_CACHE_DIR": "/tmp/xla", "AOT_WARMUP": "1"}
-    )
-    assert cfg.compilation_cache_dir == "/tmp/xla"
+    cfg = TrainConfig.from_env({"AOT_WARMUP": "1"})
     assert cfg.aot_warmup is True
-    # empty dir = explicitly off (recertify's opt-out contract)
-    assert (
-        TrainConfig.from_env({"COMPILATION_CACHE_DIR": ""}).compilation_cache_dir
-        is None
-    )
+    # the compile cache is placed by JAX_COMPILATION_CACHE_DIR alone:
+    # the repo's old second name for it is not a config field
+    assert not hasattr(cfg, "compilation_cache_dir")
