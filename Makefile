@@ -200,7 +200,9 @@ obs-watch:	## live dashboard for the newest runs/<dir>: rollups + SLO burn
 
 trace-report:	## per-request critical-path digest for the newest runs/<dir>:
 	## top-K-slowest decomposed per phase vs fleet p50, chaos causes,
-	## orphans, per-step training attribution (OBS_RUN=dir, TOP=K)
+	## orphans, per-step training attribution (OBS_RUN=dir, TOP=K); for
+	## each profiler capture under it, device time by scope group and
+	## idle gaps by ddl: span (OBS_RUN may be a bare capture directory)
 	$(PY) scripts/trace_report.py $(or $(OBS_RUN),$(shell ls -td runs/*/ 2>/dev/null | head -1)) --top $(or $(TOP),5)
 
 bench-trend:	## regression sentinel over archived bench records

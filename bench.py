@@ -172,8 +172,7 @@ def run_bench(
     # warm, re-runs deserialize instead of recompiling.
     from distributeddeeplearning_tpu import obs
 
-    with obs.span("compile", what="bench_step"):
-        _, compile_sec = step.aot_compile(state, batch)
+    _, compile_sec = step.aot_compile(state, batch)  # emits the `compile` span
 
     for _ in range(WARMUP_STEPS):
         state, metrics = step(state, batch)
@@ -265,8 +264,7 @@ def run_lm_bench(
 
     from distributeddeeplearning_tpu import obs
 
-    with obs.span("compile", what="bench_step"):
-        _, compile_sec = step.aot_compile(state, batch)  # see run_bench
+    _, compile_sec = step.aot_compile(state, batch)  # see run_bench
 
     for _ in range(WARMUP_STEPS):
         state, metrics = step(state, batch)

@@ -20,6 +20,7 @@ from typing import Any, Iterable, Iterator, Optional
 import jax
 from jax.sharding import Mesh, NamedSharding
 
+from distributeddeeplearning_tpu import obs
 from distributeddeeplearning_tpu.parallel.mesh import batch_sharding
 
 PyTree = Any
@@ -69,12 +70,24 @@ def prefetch_to_device(
     ``sharding`` may also be a callable ``batch -> sharding`` (single or
     pytree), resolved per batch — engines whose staging layout depends on
     the batch arity (SP: eval weights shard differently) use this.
+
+    Emits, a batch: span ``data.stage`` round the placement and counter
+    ``data.h2d_bytes`` (bytes of the host leaves placed) on the staging
+    thread, span ``data.stage_wait`` round the consumer's ``q.get()``.
     """
-    stage = (
-        (lambda b: shard_batch(b, mesh, sharding(b)))
-        if callable(sharding)
-        else (lambda b: shard_batch(b, mesh, sharding))
-    )
+    def stage(batch):
+        # On the thread that does the placement (the producer's, when
+        # there is one): its time, and the bytes it hands to the device.
+        with obs.span("data.stage"):
+            staged = shard_batch(
+                batch, mesh, sharding(batch) if callable(sharding) else sharding
+            )
+        obs.counter(
+            "data.h2d_bytes",
+            sum(int(getattr(x, "nbytes", 0)) for x in jax.tree.leaves(batch)),
+        )
+        return staged
+
     if size <= 0:
         for batch in it:
             yield stage(batch)
@@ -108,7 +121,10 @@ def prefetch_to_device(
     t.start()
     try:
         while True:
-            item = q.get()
+            # How long the step loop waited for a staged batch: the input
+            # layer's starvation, measured where it is felt.
+            with obs.span("data.stage_wait"):
+                item = q.get()
             if item is _END:
                 if err:
                     raise err[0]

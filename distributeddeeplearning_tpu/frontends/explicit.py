@@ -24,8 +24,10 @@ import jax
 import numpy as np
 import optax
 
+from distributeddeeplearning_tpu import obs
 from distributeddeeplearning_tpu.config import TrainConfig
 from distributeddeeplearning_tpu.data.pipeline import prefetch_to_device
+from distributeddeeplearning_tpu.training.metrics import dispatch_step, log_sync
 from distributeddeeplearning_tpu.training.optimizer import create_optimizer
 from distributeddeeplearning_tpu.training.state import TrainState
 from distributeddeeplearning_tpu.utils.logging import get_logger
@@ -70,13 +72,15 @@ def setup(
 
     from distributeddeeplearning_tpu.parallel.mesh import dp_size
 
-    _, mesh = resolve_engine(config, mesh)
-    spe = steps_per_epoch or config.steps_per_epoch()
-    tx, schedule = create_optimizer(config, spe, world_size=dp_size(mesh))
-    eng = build_engine(
-        model, config, tx, mesh,
-        input_shape=input_shape, input_dtype=input_dtype,
-    )
+    with obs.span("setup.engine", engine=config.engine):
+        _, mesh = resolve_engine(config, mesh)
+        spe = steps_per_epoch or config.steps_per_epoch()
+        tx, schedule = create_optimizer(config, spe, world_size=dp_size(mesh))
+        # mesh placement, the step builders and the seeded weight draw
+        eng = build_engine(
+            model, config, tx, mesh,
+            input_shape=input_shape, input_dtype=input_dtype,
+        )
     pieces = Pieces(
         model=eng.model,
         config=config,
@@ -98,7 +102,9 @@ def train_epoch(
     log_every: Optional[int] = None,
 ) -> TrainState:
     """One epoch (reference ``train()`` :204-221, incl. its per-100-steps
-    duration/loss logging)."""
+    duration/loss logging). Emits what ``loop.fit`` emits a step: the
+    ``step`` span round the dispatch, ``step.log_sync`` round the loss
+    read-back, and the staging spans of ``prefetch_to_device``."""
     log = get_logger()
     cfg = pieces.config
     log_every = log_every if log_every is not None else cfg.log_every_steps
@@ -109,9 +115,12 @@ def train_epoch(
             sharding=pieces.batch_sharding,
         )
     ):
-        state, metrics = pieces.train_step(state, batch)
+        state, metrics = dispatch_step(
+            pieces.train_step, state, batch, epoch=epoch
+        )
         if log_every and (i + 1) % log_every == 0:
-            loss = float(jax.device_get(metrics["loss"]))
+            with log_sync(epoch=epoch):
+                loss = float(jax.device_get(metrics["loss"]))
             log.info(
                 "step %d loss=%.4f elapsed=%.2fs", i + 1, loss, timer.elapsed,
                 extra={"epoch": epoch},

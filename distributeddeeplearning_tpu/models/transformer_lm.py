@@ -26,9 +26,11 @@ import functools
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
-from distributeddeeplearning_tpu.models.vit import Attention, MlpBlock
+from distributeddeeplearning_tpu.models.vit import ATTN_CORE, Attention, MlpBlock
+from distributeddeeplearning_tpu.obs.programs import part
 
 # name -> (hidden, depth, heads, mlp_dim)
 _VARIANTS = {
@@ -37,6 +39,32 @@ _VARIANTS = {
     "base": (768, 12, 12, 3072),
     "large": (1536, 24, 16, 6144),
 }
+
+
+# Scopes for what no module holds (a module's operations carry its name
+# already): the block's residual adds, the embedding lookups, the tied
+# output projection.
+RESIDUAL = "residual"
+EMBED = "embed"
+HEAD = "head"
+
+# The parts of this model's train step that a performance change treats
+# apart, for `obs/programs.device_seconds_by_scope`: in matching order,
+# so `attn_core` (scores, softmax, weighted sum: what a flash kernel
+# replaces) stands before the attention module that holds it. The model's
+# parts go by the names above and by its modules' names; `loss`,
+# `metrics`, `optimizer` and the gradient reduction's `overlap_allreduce`
+# (training/overlap.OVERLAP_SCOPE) are the step's own scopes
+# (training/train_step.py). An operation in none of them reads as
+# unscoped: a new part of the model gets a name, not a wider pattern.
+TRAIN_STEP_GROUPS = (
+    ("attn_core", part(ATTN_CORE)),
+    ("attn_proj", part("attn")),
+    ("mlp", part("mlp")),
+    ("head_loss", part(HEAD, "loss", "metrics")),
+    ("optimizer", part("optimizer", "overlap_allreduce")),
+    ("norm_residual", part(r"ln\w*", EMBED, RESIDUAL)),
+)
 
 
 class DecoderBlock(nn.Module):
@@ -60,7 +88,7 @@ class DecoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = True):
         y = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x).astype(self.dtype)
-        x = x + Attention(
+        a = Attention(
             self.num_heads,
             self.dtype,
             self.attn_impl,
@@ -74,8 +102,13 @@ class DecoderBlock(nn.Module):
             decode_kernel=self.decode_kernel,
             name="attn",
         )(y, train)
+        # `residual`: the block's own adds belong to neither module
+        with jax.named_scope(RESIDUAL):
+            x = x + a
         y = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x).astype(self.dtype)
-        x = x + MlpBlock(self.mlp_dim, self.dtype, self.dropout, name="mlp")(y, train)
+        m = MlpBlock(self.mlp_dim, self.dtype, self.dropout, name="mlp")(y, train)
+        with jax.named_scope(RESIDUAL):
+            x = x + m
         return x
 
 
@@ -152,54 +185,58 @@ class TransformerLM(nn.Module):
             (self.vocab_size, hidden),
             jnp.float32,
         )
-        x = embed[tokens].astype(self.dtype)
-        pos = self.param(
-            "pos_embed",
-            nn.with_logical_partitioning(
-                nn.initializers.normal(0.02), (None, "seq", "embed")
-            ),
-            (1, self.max_seq_len, hidden),
-            jnp.float32,
-        )
-        if self.seq_axis is not None and not self.is_initializing():
-            # Sequence-parallel: this shard holds global tokens
-            # [axis_index*t, (axis_index+1)*t). (Init traces outside
-            # shard_map where the axis is unbound; shapes don't depend
-            # on the slice, so init uses the prefix.)
-            from jax import lax
-
-            start = lax.axis_index(self.seq_axis) * t
-            pos_t = lax.dynamic_slice_in_dim(pos[0], start, t, axis=0)[None]
-        elif self.decode:
-            # Incremental decoding: these t tokens sit at absolute
-            # positions [pos_index, pos_index+t). The counter lives in
-            # the cache collection beside the attention KV caches. Like
-            # the attention cache_index it may be a scalar (lockstep
-            # batch, inference.generate) or a [B] vector of per-row
-            # positions (serving.SlotEngine) — the vector path gathers
-            # each row's positions independently.
-            from jax import lax
-
-            pidx = self.variable(
-                "cache", "pos_index", lambda: jnp.zeros((), jnp.int32)
+        # `embed`: token and position lookups are no module of their own,
+        # so they get the scope a module would give them (forward gather,
+        # backward scatter-add into the table).
+        with jax.named_scope(EMBED):
+            x = embed[tokens].astype(self.dtype)
+            pos = self.param(
+                "pos_embed",
+                nn.with_logical_partitioning(
+                    nn.initializers.normal(0.02), (None, "seq", "embed")
+                ),
+                (1, self.max_seq_len, hidden),
+                jnp.float32,
             )
-            if self.is_initializing():
-                pos_t = pos[:, :t]
-            else:
-                start = pidx.value
-                if jnp.ndim(start) == 0:
-                    pos_t = lax.dynamic_slice_in_dim(
-                        pos[0], start, t, axis=0
-                    )[None]
+            if self.seq_axis is not None and not self.is_initializing():
+                # Sequence-parallel: this shard holds global tokens
+                # [axis_index*t, (axis_index+1)*t). (Init traces outside
+                # shard_map where the axis is unbound; shapes don't depend
+                # on the slice, so init uses the prefix.)
+                from jax import lax
+
+                start = lax.axis_index(self.seq_axis) * t
+                pos_t = lax.dynamic_slice_in_dim(pos[0], start, t, axis=0)[None]
+            elif self.decode:
+                # Incremental decoding: these t tokens sit at absolute
+                # positions [pos_index, pos_index+t). The counter lives in
+                # the cache collection beside the attention KV caches. Like
+                # the attention cache_index it may be a scalar (lockstep
+                # batch, inference.generate) or a [B] vector of per-row
+                # positions (serving.SlotEngine) — the vector path gathers
+                # each row's positions independently.
+                from jax import lax
+
+                pidx = self.variable(
+                    "cache", "pos_index", lambda: jnp.zeros((), jnp.int32)
+                )
+                if self.is_initializing():
+                    pos_t = pos[:, :t]
                 else:
-                    # [B, t, hidden]: row b reads pos[start[b] .. +t)
-                    pos_t = jnp.take(
-                        pos[0], start[:, None] + jnp.arange(t), axis=0
-                    )
-                pidx.value = start + t
-        else:
-            pos_t = pos[:, :t]
-        x = x + pos_t.astype(self.dtype)
+                    start = pidx.value
+                    if jnp.ndim(start) == 0:
+                        pos_t = lax.dynamic_slice_in_dim(
+                            pos[0], start, t, axis=0
+                        )[None]
+                    else:
+                        # [B, t, hidden]: row b reads pos[start[b] .. +t)
+                        pos_t = jnp.take(
+                            pos[0], start[:, None] + jnp.arange(t), axis=0
+                        )
+                    pidx.value = start + t
+            else:
+                pos_t = pos[:, :t]
+            x = x + pos_t.astype(self.dtype)
         if self.dropout > 0:
             x = nn.Dropout(self.dropout, deterministic=not train)(x)
 
@@ -265,13 +302,16 @@ class TransformerLM(nn.Module):
         # projection backward's operand — stays bf16 too). The loss keeps
         # one f32 copy internally (CE residual; see
         # train_step._sparse_softmax_ce for the measured trade-off).
-        logits = jnp.einsum(
-            "btd,vd->btv",
-            x.astype(self.dtype),
-            embed.astype(self.dtype),
-            preferred_element_type=jnp.float32,
-        )
-        return logits.astype(self.dtype)
+        # `head`: the tied projection is no module of its own, so it gets
+        # the scope a module would give it.
+        with jax.named_scope(HEAD):
+            logits = jnp.einsum(
+                "btd,vd->btv",
+                x.astype(self.dtype),
+                embed.astype(self.dtype),
+                preferred_element_type=jnp.float32,
+            )
+            return logits.astype(self.dtype)
 
 
 LM_Tiny = functools.partial(TransformerLM, variant="tiny")

@@ -31,6 +31,11 @@ import jax.numpy as jnp
 
 from distributeddeeplearning_tpu.ops.attention import dot_product_attention
 
+# Sub-scope of an Attention module for scores, softmax and weighted sum
+# (whichever lowering runs them): what a flash kernel replaces, apart
+# from the q/k/v and output projections (obs/programs.py groups by it).
+ATTN_CORE = "attn_core"
+
 # name -> (hidden, depth, heads, mlp_dim)
 _VARIANTS = {
     "ti": (192, 12, 3, 768),
@@ -289,12 +294,13 @@ class Attention(nn.Module):
                 fused_decode_attention,
             )
 
-            return fused_decode_attention(
-                q, ck.value, cv.value, pos,
-                k_scale=cks.value if quant else None,
-                v_scale=cvs.value if quant else None,
-                block_table=table, block_size=bs,
-            )
+            with jax.named_scope(ATTN_CORE):
+                return fused_decode_attention(
+                    q, ck.value, cv.value, pos,
+                    k_scale=cks.value if quant else None,
+                    v_scale=cvs.value if quant else None,
+                    block_table=table, block_size=bs,
+                )
         # Gather this row's logical view [B, mb*bs, H, Dh]; positions
         # beyond the written depth are masked exactly like the dense
         # path's unwritten tail (bitwise-invariant: masked scores are
@@ -323,17 +329,18 @@ class Attention(nn.Module):
         attention of q ([B, t, H, Dh]) over the full static cache view."""
         length = k_all.shape[1]
         head_dim = q.shape[-1]
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", (q * head_dim**-0.5), k_all
-        ).astype(jnp.float32)
-        k_pos = jnp.arange(length)
-        if q_pos.ndim == 1:
-            mask = (k_pos[None, :] <= q_pos[:, None])[None, None]  # [1,1,t,L]
-        else:
-            mask = (k_pos[None, None, :] <= q_pos[:, :, None])[:, None]
-        scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores, axis=-1).astype(self.dtype)
-        return jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)
+        with jax.named_scope(ATTN_CORE):
+            scores = jnp.einsum(
+                "bqhd,bkhd->bhqk", (q * head_dim**-0.5), k_all
+            ).astype(jnp.float32)
+            k_pos = jnp.arange(length)
+            if q_pos.ndim == 1:
+                mask = (k_pos[None, :] <= q_pos[:, None])[None, None]  # [1,1,t,L]
+            else:
+                mask = (k_pos[None, None, :] <= q_pos[:, :, None])[:, None]
+            scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
+            probs = jax.nn.softmax(scores, axis=-1).astype(self.dtype)
+            return jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)
 
     def _decode_attention(self, q, k, v):
         """Single/few-token query against the growing KV cache. Static
@@ -430,11 +437,12 @@ class Attention(nn.Module):
                 fused_decode_attention,
             )
 
-            return fused_decode_attention(
-                q, ck.value, cv.value, q_pos,
-                k_scale=cks.value if quant else None,
-                v_scale=cvs.value if quant else None,
-            )
+            with jax.named_scope(ATTN_CORE):
+                return fused_decode_attention(
+                    q, ck.value, cv.value, q_pos,
+                    k_scale=cks.value if quant else None,
+                    v_scale=cvs.value if quant else None,
+                )
         if quant:
             k_all = dequantize_store(ck.value, cks.value, self.dtype)
             v_all = dequantize_store(cv.value, cvs.value, self.dtype)
@@ -480,9 +488,10 @@ class Attention(nn.Module):
                 fused_qkv_attention,
             )
 
-            out_flat = fused_qkv_attention(
-                qkv_flat, self.num_heads, causal=self.causal
-            )
+            with jax.named_scope(ATTN_CORE):
+                out_flat = fused_qkv_attention(
+                    qkv_flat, self.num_heads, causal=self.causal
+                )
         else:
             qkv = qkv_flat.reshape(*x.shape[:-1], 3, self.num_heads, head_dim)
             q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
@@ -491,14 +500,15 @@ class Attention(nn.Module):
                     raise ValueError("decode=True requires causal attention")
                 out = self._decode_attention(q, k, v)
             else:
-                out = dot_product_attention(
-                    q,
-                    k,
-                    v,
-                    causal=self.causal,
-                    impl=impl,
-                    axis_name=self.seq_axis,
-                )
+                with jax.named_scope(ATTN_CORE):
+                    out = dot_product_attention(
+                        q,
+                        k,
+                        v,
+                        causal=self.causal,
+                        impl=impl,
+                        axis_name=self.seq_axis,
+                    )
             out_flat = out.reshape(*x.shape[:-1], d)
         out = _dense(d, "proj", ("heads", "embed"), self.dtype)(out_flat)
         if self.dropout > 0:

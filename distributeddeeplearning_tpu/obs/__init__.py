@@ -12,9 +12,14 @@ The **live plane** reads the same files while the run is alive:
 (``SLO_SPEC`` objectives with multi-window burn rates, emitting
 ``slo_breach``/``slo_recover`` back into the bus). See
 ``docs/OBSERVABILITY.md`` for the schema and knobs.
+
+Beside the host's events, ``obs/programs.py`` puts names on the
+device's time: a scope table for each ahead-of-time compiled program
+and the reduction of a profiler trace by model part.
 """
 
 from distributeddeeplearning_tpu.obs.bus import (
+    ANNOTATION_PREFIX,
     DEFAULT_RING_SIZE,
     EventBus,
     TraceContext,
@@ -52,6 +57,7 @@ from distributeddeeplearning_tpu.obs.slo import (  # noqa: F401
 from distributeddeeplearning_tpu.obs.tail import Tailer  # noqa: F401
 
 __all__ = [
+    "ANNOTATION_PREFIX",
     "DEFAULT_RING_SIZE",
     "EventBus",
     "LivePlane",

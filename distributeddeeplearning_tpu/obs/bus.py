@@ -50,6 +50,18 @@ handoffs. Stamping is a host-side dict assignment; it adds zero host
 syncs and no device work. ``obs/traces.py`` reconstructs per-request
 critical paths from the stamped files.
 
+**On the profiler's clock:** the context-manager form
+(:meth:`EventBus.span`) also opens a ``jax.profiler.TraceAnnotation``
+named ``ddl:<span name>``, so that while a profiler session runs every
+such span lies in the ``.xplane.pb`` on the device trace's own clock, on
+the thread that did the work. With no session the annotation is one
+flag test in the runtime. :meth:`EventBus.span_event` reports a
+duration after the fact and cannot be mirrored.
+
+**Totals:** the ring forgets (512 events); :meth:`EventBus.totals`
+does not. Every span and counter name keeps a cumulative count and sum
+for the life of the bus, one dict update an emit.
+
 Knobs (env): ``OBS_DIR`` (run directory; unset = ring-only, no files),
 ``OBS_RUN_ID`` (shared by the launcher so all processes of one world
 agree), ``OBS_RING_SIZE`` (flight-recorder depth, default 512),
@@ -72,6 +84,8 @@ import time
 from typing import Any, Dict, Iterator, Optional, Union
 
 SCHEMA_VERSION = 1
+# Prefix of the profiler annotations the context-manager spans open.
+ANNOTATION_PREFIX = "ddl:"
 DEFAULT_RING_SIZE = 512
 _AUTOFLUSH_EVERY = 256
 DEFAULT_FLUSH_EVERY_S = 5.0
@@ -126,6 +140,16 @@ def _flush_every_s_from_env() -> float:
         return DEFAULT_FLUSH_EVERY_S
 
 
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for span ``name``, or None in
+    a process that never imported jax (the launcher, the report tools):
+    no profiler session can run there, and this module stays jax-free."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+
+
 def _proc_tag(proc: Union[int, str]) -> str:
     return f"p{proc}" if isinstance(proc, int) else str(proc)
 
@@ -171,6 +195,10 @@ class EventBus:
         self.ring: collections.deque = collections.deque(maxlen=max(ring_size, 1))
         self._buffer: list = []
         self._seq = 0
+        # name -> [kind, count, sum] of every span (sum of dur) and
+        # counter (sum of value) ever emitted: what the ring has aged
+        # out is still here (totals()).
+        self._totals: Dict[str, list] = {}
         # In-flight trace registry (trace_open/trace_close): what this
         # bus's process/replica is holding RIGHT NOW — dumped into the
         # flight-recorder header so a crash black box names the
@@ -237,10 +265,17 @@ class EventBus:
                 rec["parent"] = ctx.parent
             if ctx.cause:
                 rec["cause"] = ctx.cause
+        amount = dur if kind == "span" else value if kind == "counter" else None
         with self._lock:
             self._seq += 1
             rec["seq"] = self._seq
             self.ring.append(rec)
+            if isinstance(amount, (int, float)):
+                tot = self._totals.get(name)
+                if tot is None:
+                    tot = self._totals[name] = [kind, 0, 0.0]
+                tot[1] += 1
+                tot[2] += amount
             if self._fh is not None:
                 self._buffer.append(rec)
                 # Size threshold, OR the bounded-staleness clock: the
@@ -265,16 +300,20 @@ class EventBus:
         self.emit("point", name, labels=labels or None)
 
     @contextlib.contextmanager
-    def span(self, name: str, **labels: Any) -> Iterator[None]:
-        """Time a block; emits one ``span`` event at exit (t = start)."""
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            self.emit(
-                "span", name, t=t0, dur=time.monotonic() - t0,
-                labels=labels or None,
-            )
+    def span(self, name: str, **labels: Any) -> Iterator[Dict[str, Any]]:
+        """Time a block; emits one ``span`` event at exit (t = start).
+        The block also runs under a profiler annotation ``ddl:<name>``
+        (module docstring). Yields the labels, so that the block can add
+        what it only learns inside (``compile``'s ``cache_hit``)."""
+        with _annotation(name) or contextlib.nullcontext():
+            t0 = time.monotonic()
+            try:
+                yield labels
+            finally:
+                self.emit(
+                    "span", name, t=t0, dur=time.monotonic() - t0,
+                    labels=labels or None,
+                )
 
     def span_event(
         self, name: str, dur: float, t: Optional[float] = None, **labels: Any
@@ -318,6 +357,19 @@ class EventBus:
         with self._lock:
             return {k: dict(v) for k, v in self._active_traces.items()}
 
+    def totals(self) -> Dict[str, Dict[str, Any]]:
+        """``{name: {"kind", "count", "sum"}}`` over the bus's whole
+        life, for every span (sum of durations, seconds) and counter
+        (sum of increments)."""
+        with self._lock:
+            return self._totals_locked()
+
+    def _totals_locked(self) -> Dict[str, Dict[str, Any]]:
+        return {
+            name: {"kind": kind, "count": count, "sum": total}
+            for name, (kind, count, total) in self._totals.items()
+        }
+
     # -- persistence -------------------------------------------------------
 
     def _flush_locked(self) -> None:
@@ -344,6 +396,7 @@ class EventBus:
         next to the cwd so a crash still leaves evidence."""
         with self._lock:
             recs = list(self.ring)
+            totals = self._totals_locked()
             active = {k: dict(v) for k, v in self._active_traces.items()}
         if path is None:
             base = self.directory or os.getcwd()
@@ -353,6 +406,9 @@ class EventBus:
         header["reason"] = reason
         header["dump_wall"] = time.time()
         header["dump_t"] = time.monotonic()
+        # What the ring no longer holds: cumulative count and sum of
+        # every span and counter name since the bus was made.
+        header["totals"] = totals
         if active:
             # The requests this process was holding at crash time — a
             # post-mortem joins these trace ids against the fleet's

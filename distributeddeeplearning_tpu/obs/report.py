@@ -258,8 +258,11 @@ def summarize(loaded: Dict[str, Any]) -> Dict[str, Any]:
             procs[p]["pid"] = meta.get("pid")
             procs[p]["slice"] = meta.get("slice")
 
+    # `compile.lower` / `compile.backend` are the `compile` span's own
+    # children: counting them would count every compile twice.
     compile_s = sum(
-        v["total_s"] for k, v in span_stats.items() if "compile" in k
+        v["total_s"] for k, v in span_stats.items()
+        if "compile" in k and not k.startswith("compile.")
     )
     step_s = span_stats.get("step", {}).get("total_s", 0.0)
 

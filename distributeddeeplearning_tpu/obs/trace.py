@@ -10,6 +10,12 @@ action instead:
   marks the *next* epoch for capture, so a live production job can be
   profiled exactly when it misbehaves without restarting it.
 
+Captures start with the Python tracer off (the device planes and the
+bus's ``ddl:`` span annotations are what they are read for), and each
+stop leaves the compiled programs' scope tables beside the capture
+(``obs/programs.dump_tables``), so ``scripts/trace_report.py`` can
+print the device's time by model part and its idle gaps by span.
+
 Start/stop are epoch-boundary actions (the loop calls
 ``maybe_start``/``maybe_stop`` outside the dispatch clock), so capture
 never adds work inside the hot loop itself; each transition emits a
@@ -71,7 +77,13 @@ class TraceController:
         out = os.path.join(self.directory, f"trace-epoch{epoch:04d}")
         import jax
 
-        jax.profiler.start_trace(out)
+        # Python tracer off: the device planes and the bus's `ddl:`
+        # annotations are what a capture is read for
+        # (scripts/trace_report.py), and the Python tracer's events
+        # swell the file and slow the host.
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(out, profiler_options=options)
         self._active_dir = out
         _bus.point("trace_start", epoch=epoch, dir=out)
         return True
@@ -83,6 +95,11 @@ class TraceController:
         import jax
 
         jax.profiler.stop_trace()
+        # the scope tables of the programs compiled ahead, beside the
+        # capture: `make trace-report` names the device's time by them
+        from distributeddeeplearning_tpu.obs import programs
+
+        programs.dump_tables(self._active_dir)
         _bus.point("trace_stop", epoch=epoch, dir=self._active_dir)
         self._active_dir = None
         return True

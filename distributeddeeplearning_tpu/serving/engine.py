@@ -44,6 +44,7 @@ import numpy as np
 from jax import lax
 
 from distributeddeeplearning_tpu import obs
+from distributeddeeplearning_tpu.obs import programs as obs_programs
 from distributeddeeplearning_tpu.serving import keys as keylib
 from distributeddeeplearning_tpu.serving.blocks import (
     BlockAllocator,
@@ -943,6 +944,12 @@ class SlotEngine:
                     pending, pool.map(compile_one, pending)
                 ):
                     ps.install(compiled)
+                    # scope table (obs/programs.py): names on this
+                    # program's device time; parsed only when asked
+                    obs_programs.register(
+                        "jit_" + getattr(ps.fn, "__name__", ps.name),
+                        compiled, owner=self, key=ps.name,
+                    )
                     self.compile_sec += secs
                     self.compile_count += 1
         self._warmed = True

@@ -34,8 +34,10 @@ from distributeddeeplearning_tpu.training.checkpoint import (
     build_manifest,
 )
 from distributeddeeplearning_tpu.training.metrics import (
+    dispatch_step,
     finalize_accumulator,
     init_accumulator,
+    log_sync,
 )
 from distributeddeeplearning_tpu.training.optimizer import create_optimizer
 from distributeddeeplearning_tpu.training.state import TrainState
@@ -546,17 +548,20 @@ def fit(
                 if first_dispatch
                 else contextlib.nullcontext()
             ):
+                # The `step` span (dispatch_step) times the same
+                # dispatch on the bus, and on the profiler's clock when
+                # a capture runs; no device value is materialised.
                 if accumulates:
-                    state, metrics, acc = train_step(state, batch, acc)
+                    state, metrics, acc = dispatch_step(
+                        train_step, state, batch, acc, epoch=epoch
+                    )
                 else:
-                    state, metrics = train_step(state, batch)
+                    state, metrics = dispatch_step(
+                        train_step, state, batch, epoch=epoch
+                    )
             first_dispatch = False
             dispatch_s = time.perf_counter() - t0
             clock.note_dispatch(dispatch_s)
-            # Step span = dispatch time (host-side float, already in
-            # hand): the bus sees every step with no extra measurement
-            # and, critically, no materialisation of device values.
-            bus.span_event("step", dispatch_s, epoch=epoch)
             step_in_epoch += 1
             global_step += 1
             if ckpt is not None and ckpt.step_granular:
@@ -585,15 +590,17 @@ def fit(
                 and step_in_epoch % config.log_every_steps == 0
             ):
                 # Metrics/accumulator stay device-resident on purpose: a
-                # callback that float()s them pays (and owns) that sync.
-                callback_list.on_step_end(
-                    step_in_epoch,
-                    {
-                        "metrics": metrics,
-                        "state": state,
-                        "metric_accumulator": acc,
-                    },
-                )
+                # callback that float()s them pays (and owns) that sync;
+                # the span shows what it paid.
+                with log_sync(epoch=epoch):
+                    callback_list.on_step_end(
+                        step_in_epoch,
+                        {
+                            "metrics": metrics,
+                            "state": state,
+                            "metric_accumulator": acc,
+                        },
+                    )
         epoch_images = step_in_epoch * global_batch
         total_images += epoch_images
         # THE one host sync per epoch: materialise the on-device epoch

@@ -37,6 +37,10 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from distributeddeeplearning_tpu import obs
+from distributeddeeplearning_tpu.obs import programs
+from distributeddeeplearning_tpu.training.warmup import cache_stats
+
 PyTree = Any
 
 # Every engine's train step emits exactly these (cross-replica-reduced,
@@ -156,9 +160,46 @@ class StepFn:
         self, state, batch, acc: Optional[PyTree] = None
     ) -> Tuple[Any, float]:
         """Compile ahead of time; returns ``(compiled, seconds)`` and
-        installs the executable for matching calls."""
+        installs the executable for matching calls.
+
+        Every engine's warm-up comes through here, so this is where the
+        ``compile`` span sits (children ``compile.lower``: trace and
+        lower; ``compile.backend``: XLA's compile, or the load from the
+        persistent cache) and where the program's scope table is
+        registered (``obs/programs.py``; nothing is parsed until a
+        trace is read against it)."""
+        fn = self._resolve(state, acc is not None)
+        program = "jit_" + getattr(fn, "__name__", "step")
+        signature = self._signature(state, batch, acc is not None)
+        hits = cache_stats()[0]
         t0 = time.perf_counter()
-        compiled = self.lower(state, batch, acc).compile()
+        with obs.span("compile", program=program) as labels:
+            with obs.span("compile.lower", program=program):
+                lowered = self.lower(state, batch, acc)
+            with obs.span("compile.backend", program=program):
+                compiled = lowered.compile()
+            labels["cache_hit"] = cache_stats()[0] > hits
         seconds = time.perf_counter() - t0
-        self._aot[self._signature(state, batch, acc is not None)] = compiled
+        self._aot[signature] = compiled
+        programs.register(program, compiled, owner=self, key=signature)
         return compiled, seconds
+
+
+def dispatch_step(step: Callable, state, batch, acc: Optional[PyTree] = None,
+                  **labels: Any):
+    """One dispatch of a train step under the ``step`` span: the host's
+    time in the call, which is all the host sees of a step. That is the
+    time to enqueue it while the device's queue has room, and the
+    device's own step time once the queue is full and every dispatch
+    waits for a step to leave it. The explicit loop and ``loop.fit``
+    both dispatch through here, so the two emit the same name."""
+    with obs.span("step", **labels):
+        if acc is None:
+            return step(state, batch)
+        return step(state, batch, acc)
+
+
+def log_sync(**labels: Any):
+    """The span round a loop's logging sync: every ``log_every`` steps
+    the host reads a loss back and waits for the device to reach it."""
+    return obs.span("step.log_sync", **labels)

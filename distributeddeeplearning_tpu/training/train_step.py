@@ -23,6 +23,14 @@ Semantics parity notes:
   the reference needed a MetricAverageCallback (Keras ``:207``) /
   explicit ``hvd.allreduce`` (``:348``) to do this on the host.
 
+**Names on the device's time.** Flax scopes every model operation by
+module path; the step adds ``jax.named_scope`` for what no module
+holds: ``loss`` (:func:`cross_entropy_loss`), the gradient reduction
+(``training/overlap.OVERLAP_SCOPE``), ``optimizer`` (the update and its
+application) and ``metrics`` (accuracy's argmax over the logits, the
+gradient norm). They are metadata alone — no operation changes — and
+``obs/programs.py`` sums a device trace by them.
+
 The same step function runs on a 1-device mesh, an 8-device CPU test mesh
 (the reference's ``mpirun -np 2`` smoke analogue, §4.2), and a multi-host
 pod mesh — no code forks (§7 hard part (d)).
@@ -42,6 +50,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributeddeeplearning_tpu.config import TrainConfig
 from distributeddeeplearning_tpu.parallel.mesh import batch_axes, replicated_sharding
+from distributeddeeplearning_tpu.training.overlap import overlap_scope
 from distributeddeeplearning_tpu.training.state import TrainState
 
 PyTree = Any
@@ -118,23 +127,26 @@ def cross_entropy_loss(
     scatter-free custom-VJP kernel (:func:`_sparse_softmax_ce`).
     """
     num_classes = logits.shape[-1]
-    # Loss math is always f32; reduced-precision logits (the LM emits
-    # compute-dtype logits) upcast ONCE here — measured faster than
-    # upcasting on the fly inside the custom VJP (its docstring).
-    logits = logits.astype(jnp.float32)
-    if labels.ndim == logits.ndim:  # one-hot
-        targets = labels.astype(jnp.float32)
-        if label_smoothing > 0.0:
-            on = 1.0 - label_smoothing
-            off = label_smoothing / (num_classes - 1)
-            targets = targets * (on - off) + off
-        log_probs = jax.nn.log_softmax(logits)
-        return -jnp.mean(jnp.sum(targets * log_probs, axis=-1))
-    flat = logits.reshape(-1, num_classes)
-    per_example = _sparse_softmax_ce(
-        flat, labels.reshape(-1), float(label_smoothing)
-    )
-    return jnp.mean(per_example)
+    # The `loss` scope names these operations, forward and backward, in
+    # every engine's compiled step (obs/programs.py groups by it).
+    with jax.named_scope("loss"):
+        # Loss math is always f32; reduced-precision logits (the LM
+        # emits compute-dtype logits) upcast ONCE here — measured faster
+        # than upcasting on the fly inside the custom VJP (its docstring).
+        logits = logits.astype(jnp.float32)
+        if labels.ndim == logits.ndim:  # one-hot
+            targets = labels.astype(jnp.float32)
+            if label_smoothing > 0.0:
+                on = 1.0 - label_smoothing
+                off = label_smoothing / (num_classes - 1)
+                targets = targets * (on - off) + off
+            log_probs = jax.nn.log_softmax(logits)
+            return -jnp.mean(jnp.sum(targets * log_probs, axis=-1))
+        flat = logits.reshape(-1, num_classes)
+        per_example = _sparse_softmax_ce(
+            flat, labels.reshape(-1), float(label_smoothing)
+        )
+        return jnp.mean(per_example)
 
 
 def sown_aux_loss(mutated: PyTree) -> jnp.ndarray:
@@ -329,17 +341,25 @@ def make_train_step(
         # THE collective: Horovod's per-tensor ring allreduce becomes one
         # in-step pmean that XLA schedules onto ICI (staged ICI→DCN on
         # hybrid multi-slice meshes).
-        grads = _pmean_batch(grads)
+        with overlap_scope(cfg.async_collectives):
+            grads = _pmean_batch(grads)
         new_bs = _pmean_batch(new_bs)  # keep replicated state invariant
 
-        updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        new_params = jax.tree.map(lambda p, u: p + u, state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = tx.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = jax.tree.map(lambda p, u: p + u, state.params, updates)
 
-        hard = jnp.argmax(labels, -1) if labels.ndim == logits.ndim else labels
-        accuracy = jnp.mean((jnp.argmax(logits, -1) == hard).astype(jnp.float32))
-        metrics = _pmean_batch(
-            {"loss": loss, "accuracy": accuracy, "grad_norm": optax.global_norm(grads)}
-        )
+        with jax.named_scope("metrics"):
+            hard = jnp.argmax(labels, -1) if labels.ndim == logits.ndim else labels
+            accuracy = jnp.mean(
+                (jnp.argmax(logits, -1) == hard).astype(jnp.float32)
+            )
+            metrics = _pmean_batch({
+                "loss": loss, "accuracy": accuracy,
+                "grad_norm": optax.global_norm(grads),
+            })
         new_state = state.replace(
             step=state.step + 1,
             params=new_params,
@@ -398,14 +418,15 @@ def make_train_step(
             (loss, (logits, new_bs)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(params_v)
-            hard = (
-                jnp.argmax(mb_labels, -1)
-                if mb_labels.ndim == logits.ndim
-                else mb_labels
-            )
-            accuracy = jnp.mean(
-                (jnp.argmax(logits, -1) == hard).astype(jnp.float32)
-            )
+            with jax.named_scope("metrics"):
+                hard = (
+                    jnp.argmax(mb_labels, -1)
+                    if mb_labels.ndim == logits.ndim
+                    else mb_labels
+                )
+                accuracy = jnp.mean(
+                    (jnp.argmax(logits, -1) == hard).astype(jnp.float32)
+                )
             return grads, {"loss": loss, "accuracy": accuracy}, new_bs
 
         def vary(tree):
@@ -421,18 +442,23 @@ def make_train_step(
             extra0=state.batch_stats,
             vary=vary,
         )
-        grads = _pmean_batch(grads)
+        with overlap_scope(cfg.async_collectives):
+            grads = _pmean_batch(grads)
         new_bs = _pmean_batch(new_bs)  # keep replicated state invariant
 
-        updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        new_params = jax.tree.map(lambda p, u: p + u, state.params, updates)
-        metrics = _pmean_batch(
-            {
-                "loss": micro_metrics["loss"],
-                "accuracy": micro_metrics["accuracy"],
-                "grad_norm": optax.global_norm(grads),
-            }
-        )
+        with jax.named_scope("optimizer"):
+            updates, new_opt_state = tx.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = jax.tree.map(lambda p, u: p + u, state.params, updates)
+        with jax.named_scope("metrics"):
+            metrics = _pmean_batch(
+                {
+                    "loss": micro_metrics["loss"],
+                    "accuracy": micro_metrics["accuracy"],
+                    "grad_norm": optax.global_norm(grads),
+                }
+            )
         new_state = state.replace(
             step=state.step + 1,
             params=new_params,

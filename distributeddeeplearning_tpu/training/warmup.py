@@ -143,20 +143,16 @@ def warmup_engine(
         # Heartbeat while XLA works: an AOT compile is silent for
         # minutes at pod scale, and the launcher's hang watchdog counts
         # stdout as liveness — without this a healthy, compiling world
-        # gets killed at --hang-timeout (utils/heartbeat.py).
-        with obs.span(
-            "compile", what="train_step", engine=eng.name,
-            accum_steps=accum_steps,
-        ), heartbeat.during("aot_compile:train_step"):
+        # gets killed at --hang-timeout (utils/heartbeat.py). The
+        # `compile` span is StepFn.aot_compile's own.
+        with heartbeat.during("aot_compile:train_step"):
             compiled, secs = step.aot_compile(eng.state, batch, acc)
         info["train_compile_sec"] = secs
         flops = cost_analysis_flops(compiled)
         if flops is not None:
             info["train_flops_per_step"] = flops
     if eval_batch is not None and hasattr(eng.eval_step, "aot_compile"):
-        with obs.span(
-            "compile", what="eval_step", engine=eng.name
-        ), heartbeat.during("aot_compile:eval_step"):
+        with heartbeat.during("aot_compile:eval_step"):
             _, secs = eng.eval_step.aot_compile(eng.state, eval_batch)
         info["eval_compile_sec"] = secs
 

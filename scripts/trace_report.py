@@ -9,6 +9,15 @@ each slow request decomposed per phase against the fleet p50, naming
 the dominant culprit. Training runs get the same treatment per step
 (data wait vs dispatch vs collective).
 
+A path that holds a profiler capture (an ``.xplane.pb``, as
+``TRACE_EVERY_N_EPOCHS`` / SIGUSR1 leave under ``<OBS_DIR>/traces``)
+also gets the device's view: device seconds by scope group for each
+program compiled ahead (``obs/programs.py``; the scope tables lie
+beside the capture, the groups beside the model:
+``models/transformer_lm.TRAIN_STEP_GROUPS``), and
+the device's idle gaps by the innermost ``ddl:`` span — the bus's own
+spans on the profiler's clock — over each gap's middle.
+
 Usage::
 
     python scripts/trace_report.py RUN_DIR_OR_FILES... [--json] [--top K]
@@ -24,6 +33,7 @@ outcome) are listed — a healthy run has zero.
 from __future__ import annotations
 
 import argparse
+import glob
 import json
 import os
 import sys
@@ -117,6 +127,91 @@ def render(recon: dict, training, top_k: int) -> str:
     return "\n".join(out)
 
 
+def device_report(capture_dir: str, groups, profile=None, tables=None) -> dict:
+    """One capture reduced: for each program with a scope table beside
+    the capture, device seconds by scope group (``groups``) over its
+    runs on every device; for each device, the idle gaps by ``ddl:``
+    span."""
+    from distributeddeeplearning_tpu.obs import programs
+
+    if profile is None:
+        profile = programs.load_profile(capture_dir)
+    if tables is None:
+        tables = programs.load_tables(capture_dir)
+    out = {"capture": capture_dir, "programs": [], "idle": [],
+           "host_spans": sorted({name for name, _, _ in profile.host})}
+    for program, scopes in tables.items():
+        by = programs.program_by_scope(
+            profile.ops, profile.modules, program, scopes, groups
+        )
+        if by is not None:
+            out["programs"].append(dict(by, program=program))
+    for dev, ops in sorted(profile.ops.items()):
+        gaps = programs.idle_gaps_by_span(ops, profile.host)
+        window = (max(e[2] for e in ops) - min(e[1] for e in ops)) / 1e9
+        out["idle"].append({"device": dev, "window_s": window, "gaps": gaps})
+    return out
+
+
+def render_device(rep: dict) -> str:
+    out: List[str] = [f"device trace: {rep['capture']}"]
+    add = out.append
+    if not rep["idle"]:
+        add("  no device plane in this capture (a CPU run); host spans: "
+            + (", ".join(rep["host_spans"]) or "none"))
+    if rep["idle"] and not rep["programs"]:
+        add("  no scope table beside the capture (scope_tables.json): "
+            "device time by model part needs the programs compiled ahead")
+    for by in rep["programs"]:
+        n = by["runs"]
+        add(
+            f"  program {by['program']} on {by['devices']} device(s): {n} run(s), "
+            f"{1e3 * by['run_s'] / n:.2f} ms a run, "
+            f"{1e3 * by['total_s'] / n:.2f} ms in operations"
+        )
+        add(f"    {'group':<16}{'ms/run':>10}{'share':>8}{'backward':>10}")
+        total = by["total_s"] or 1.0
+        rows = sorted(by["groups"].items(), key=lambda kv: -kv[1]["seconds"])
+        for group, g in rows:
+            add(
+                f"    {group:<16}{1e3 * g['seconds'] / n:>10.3f}"
+                f"{100 * g['seconds'] / total:>7.1f}%"
+                f"{1e3 * g['backward_s'] / n:>10.3f}"
+            )
+        add(
+            f"    {'unscoped':<16}{1e3 * by['unscoped_s'] / n:>10.3f}"
+            f"{100 * by['unscoped_s'] / total:>7.1f}%   "
+            + ", ".join(f"{k} {1e3 * v / n:.3f}" for k, v in by["unscoped_top"][:5])
+        )
+    for idle in rep["idle"]:
+        gaps = idle["gaps"]
+        add(
+            f"  device {idle['device']} idle {sum(gaps.values()):.4f} s of "
+            f"{idle['window_s']:.4f} s, by innermost ddl: span: "
+            + (", ".join(
+                f"{k} {v:.4f}" for k, v in sorted(gaps.items(), key=lambda kv: -kv[1])
+            ) or "no gap")
+        )
+    return "\n".join(out)
+
+
+def find_captures(paths: List[str]) -> List[str]:
+    """Directories under ``paths`` that hold an ``.xplane.pb`` at their
+    top or below ``plugins/profile``: one per capture."""
+    found = []
+    for path in paths:
+        if not os.path.isdir(path):
+            continue
+        for pb in sorted(glob.glob(
+            os.path.join(path, "**", "*.xplane.pb"), recursive=True
+        )):
+            head = pb.split(os.sep + "plugins" + os.sep + "profile" + os.sep)[0]
+            capture = head if head != pb else os.path.dirname(pb)
+            if capture not in found:
+                found.append(capture)
+    return found
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("paths", nargs="+", help="run dir(s) and/or events*.jsonl")
@@ -126,9 +221,24 @@ def main(argv=None) -> int:
 
     from distributeddeeplearning_tpu.obs import report, traces
 
+    devices = []
+    captures = find_captures(args.paths)
+    if captures:
+        # the groups stand beside the model whose step the captures hold
+        from distributeddeeplearning_tpu.models.transformer_lm import (
+            TRAIN_STEP_GROUPS,
+        )
+
+        devices = [device_report(c, TRAIN_STEP_GROUPS) for c in captures]
     try:
         loaded = report.load(args.paths)
     except FileNotFoundError as e:
+        if devices:  # a bare capture directory: the device's view alone
+            if args.json:
+                print(json.dumps({"device_traces": devices}, default=str))
+            else:
+                print("\n".join(render_device(d) for d in devices))
+            return 0
         print(f"ERROR: no event files under {e}", file=sys.stderr)
         return 2
     recon = traces.reconstruct(loaded)
@@ -137,14 +247,19 @@ def main(argv=None) -> int:
         out = dict(recon)
         out["top_slow"] = traces.top_slow(recon["requests"], k=args.top)
         out["training"] = training
+        out["device_traces"] = devices
         print(json.dumps(out, default=str))
-    elif not recon["count"] and not recon["orphan_count"] and not training:
+        return 0
+    if not recon["count"] and not recon["orphan_count"] and not training:
         print(
             "no trace-stamped request events found (run predates the "
             "trace plane, or nothing was served)"
         )
     else:
         print(render(recon, training, args.top))
+    for d in devices:
+        print()
+        print(render_device(d))
     return 0
 
 

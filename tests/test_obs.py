@@ -268,7 +268,10 @@ def _fake_profiler(monkeypatch):
 
     calls = []
     fake = types.SimpleNamespace(
-        start_trace=lambda d: calls.append(("start", d)),
+        ProfileOptions=jax.profiler.ProfileOptions,
+        start_trace=lambda d, profiler_options=None: calls.append(
+            ("start", d, profiler_options)
+        ),
         stop_trace=lambda: calls.append(("stop",)),
     )
     monkeypatch.setattr(jax, "profiler", fake)
@@ -289,6 +292,8 @@ def test_trace_controller_periodic_and_on_demand(tmp_path, monkeypatch):
     assert [c[0] for c in calls] == ["start", "stop", "start", "stop"]
     assert "trace-epoch0000" in calls[0][1]
     assert "trace-epoch0001" in calls[2][1]
+    # captures start with the Python tracer off, as the benchmark's do
+    assert calls[0][2].python_tracer_level == 0
 
 
 def test_trace_from_env(tmp_path, monkeypatch):

@@ -1,0 +1,523 @@
+"""Names on the device's time and spans on the profiler's clock
+(``obs/programs.py``, ``EventBus.span``'s ``ddl:`` annotations,
+``EventBus.totals``) and the spans of the explicit train path."""
+
+import gc
+import json
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributeddeeplearning_tpu import obs
+from distributeddeeplearning_tpu.models.transformer_lm import TRAIN_STEP_GROUPS
+from distributeddeeplearning_tpu.obs import programs
+
+GROUPS = [name for name, _ in TRAIN_STEP_GROUPS]
+
+
+@pytest.fixture(autouse=True)
+def _fresh():
+    obs.reset()
+    programs.clear()
+    yield
+    obs.reset()
+    programs.clear()
+
+
+# -- a tiny trainer, as the benchmark builds one ----------------------------
+
+SEQ, ROWS, VOCAB = 32, 2, 256
+
+
+def _trainer():
+    from distributeddeeplearning_tpu.config import TrainConfig
+    from distributeddeeplearning_tpu.frontends import explicit
+    from distributeddeeplearning_tpu.models import get_model
+    from distributeddeeplearning_tpu.parallel.mesh import data_parallel_mesh
+
+    cfg = TrainConfig(
+        model="lm_tiny", num_classes=VOCAB, batch_size_per_device=ROWS,
+        optimizer="adamw", weight_decay=0.0, fake=True, epochs=1,
+        log_every_steps=2,
+    )
+    model = get_model(
+        "lm_tiny", num_classes=VOCAB, max_seq_len=SEQ, dtype="bfloat16",
+        attn_impl=cfg.attn_impl,
+    )
+    return explicit.setup(
+        model, cfg, mesh=data_parallel_mesh(1), steps_per_epoch=10,
+        input_shape=(1, SEQ), input_dtype=jnp.int32,
+    )
+
+
+class _Tokens:
+    """A dataset of ``steps`` token batches an epoch."""
+
+    seq_len = SEQ  # loop.fit sizes the model's init from this
+
+    def __init__(self, steps):
+        self.steps_per_epoch = steps
+        self.batch = (np.zeros((ROWS, SEQ), np.int32),) * 2
+
+    def epoch(self, i):  # noqa: ARG002
+        return iter([self.batch] * self.steps_per_epoch)
+
+
+@pytest.fixture(scope="module")
+def compiled_step():
+    """One lm_tiny train step compiled ahead on the CPU, twice."""
+    from distributeddeeplearning_tpu.data.pipeline import shard_batch
+
+    obs.reset()
+    programs.clear()
+    pieces, state = _trainer()
+    batch = shard_batch(_Tokens(1).batch, pieces.mesh)
+    pieces.train_step.aot_compile(state, batch)
+    first = programs.tables("jit_local_step")
+    first[0].scopes()  # read now: the second compile lets this executable go
+    pieces.train_step.aot_compile(state, batch)
+    again = programs.tables("jit_local_step")
+    spans = [e for e in obs.get_bus().ring if e["kind"] == "span"]
+    assert again[0].holds_executable  # unread while its step lives
+    del pieces, state  # the trainer torn down, as the benchmark does
+    gc.collect()
+    return first, again, spans
+
+
+@pytest.mark.parametrize("group", GROUPS)
+def test_scope_table_of_a_train_step_names_every_group(compiled_step, group):
+    _, (table,), _ = compiled_step
+    assert table.program == "jit_local_step"
+    assert group in programs.groups_in(table.scopes(), TRAIN_STEP_GROUPS)
+    # every group runs in the backward pass too, but the optimizer
+    backward = {
+        k: path for k, path in table.scopes().items() if programs.BACKWARD in path
+    }
+    assert (group in programs.groups_in(backward, TRAIN_STEP_GROUPS)) == (
+        group != "optimizer")
+
+
+def test_the_model_names_its_embeddings_and_residual_adds(compiled_step):
+    """No pattern stands for "the rest of the model": what the modules do
+    not name has a scope of its own, and a path under the model with no
+    such name reads as unscoped."""
+    from distributeddeeplearning_tpu.training.overlap import OVERLAP_SCOPE
+
+    _, (table,), _ = compiled_step
+    paths = set(table.scopes().values())
+    for scope in ("embed", "residual", "head", "loss", "optimizer", "metrics"):
+        assert any(f"/{scope}/" in p or f"({scope})" in p for p in paths), scope
+    group = lambda path: programs.group_of(path, TRAIN_STEP_GROUPS)  # noqa: E731
+    assert group("jit(local_step)/jvp(TransformerLM)/embed/gather") == "norm_residual"
+    assert group("jit(local_step)/jvp(TransformerLM)/block1/residual/add") == "norm_residual"
+    assert group(f"jit(local_step)/{OVERLAP_SCOPE}/psum") == "optimizer"
+    assert group("jit(local_step)/jvp(TransformerLM)/block1/convert_element_type") == "unscoped"
+    assert group("jit(local_step)/transpose(jvp(TransformerLM))/scatter-add") == "unscoped"
+
+
+def test_scope_table_survives_a_second_aot_compile_and_its_step(compiled_step):
+    (first,), (again,), spans = compiled_step
+    # the step is gone: the table read the names then, and let the executable go
+    assert not again.holds_executable and not first.holds_executable
+    # compiling the same signature again replaces the table, and says the same
+    assert again is not first and len(again) == len(first) > 100
+    assert again.scopes() == first.scopes()
+    names = [e["name"] for e in spans if e["name"].startswith("compile")]
+    assert names == ["compile.lower", "compile.backend", "compile"] * 2
+    whole = [e for e in spans if e["name"] == "compile"]
+    assert all(e["labels"]["program"] == "jit_local_step" for e in whole)
+    assert all(e["labels"]["cache_hit"] is False for e in whole)  # cache is off here
+
+
+class _Owner:
+    """Who runs a program (a StepFn, a serving engine)."""
+
+
+def test_a_table_holds_its_executable_no_longer_than_its_owner(monkeypatch):
+    owner, other = _Owner(), _Owner()
+    compiled = [_Text(HLO) for _ in range(3)]
+    for i, c in enumerate(compiled):
+        programs.register("jit_f", c, owner, key=i)
+    programs.register("jit_f_acc", compiled[0], other)
+    assert [t.program for t in programs.tables()] == ["jit_f"] * 3 + ["jit_f_acc"]
+    assert len(programs.tables("jit_f")) == 3  # not jit_f_acc: no prefix match
+    # the same program compiled again: the old table lets go unread
+    old = programs.tables("jit_f")[0]
+    programs.register("jit_f", _Text(HLO), owner, key=0)
+    assert not old.holds_executable and compiled[0].asked == 0 and len(old) == 0
+    assert all(t.holds_executable for t in programs.tables())
+    # the owner goes: its tables read the names then, and keep them alone
+    del owner
+    gc.collect()
+    mine = programs.tables("jit_f")
+    assert [t.holds_executable for t in mine] == [False] * 3
+    assert [c.asked for c in compiled] == [0, 1, 1]
+    assert all(t.scopes()["fusion.4"].endswith("optimizer/mul") for t in mine)
+    assert programs.tables("jit_f_acc")[0].holds_executable  # its owner lives
+    # of the tables that hold names alone, the newest few stay
+    monkeypatch.setattr(programs, "MAX_READ_TABLES", 2)
+    programs.register("jit_g", _Text(""), other)
+    assert [t.program for t in programs.tables()] == ["jit_f", "jit_f_acc", "jit_f", "jit_g"]
+
+
+# -- the reduction, on hand-made events ---------------------------------------
+
+HLO = """HloModule jit_local_step, entry_computation_layout={()->()}
+
+%fused_computation.1 (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  ROOT %multiply.9 = f32[4]{0} multiply(%p, %p), metadata={op_name="jit(local_step)/jvp(TransformerLM)/block0/ln1/mul"}
+}
+
+ENTRY %main () -> f32[4] {
+  %fusion.1 = f32[4]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(local_step)/transpose(jvp(TransformerLM))/block0/attn/attn_core/dot_general" source_file="x.py" source_line=3}
+  %fusion.2 = f32[4]{0} fusion(%a), kind=kLoop, calls=%f, metadata={op_name="jit(local_step)/jvp(TransformerLM)/block0/attn/qkv/dot_general"}
+  %convolution.3 = f32[4]{0} convolution(%a, %b), metadata={op_name="jit(local_step)/jvp(TransformerLM)/head/btd,vd->btv/dot_general"}
+  %fusion.4 = f32[4]{0} fusion(%a), kind=kLoop, calls=%f, metadata={op_name="jit(local_step)/optimizer/mul"}
+  fusion.5 = f32[4]{0} fusion(a), kind=kLoop, calls=f, metadata={op_name="jit(local_step)/transpose(jvp(loss))/sub"}
+  %while.6 = (s32[]) while(%t), condition=%c, body=%b, metadata={op_name="jit(local_step)/jvp(TransformerLM)/block0/mlp/while"}
+  %copy.7 = f32[4]{0} copy(%a)
+  %convert.10 = bf16[4]{0} convert(%a), metadata={op_name="jit(local_step)/jvp(TransformerLM)/block0/convert_element_type"}
+  ROOT %add.8 = f32[4]{0} add(%a, %a), metadata={op_name="jit(local_step)/jvp(TransformerLM)/block0/residual/add"}
+}
+"""
+
+
+class _Text:
+    def __init__(self, text):
+        self.text, self.asked = text, 0
+
+    def as_text(self):
+        self.asked += 1
+        return self.text
+
+
+def test_table_parses_lazily_and_once():
+    compiled, owner = _Text(HLO), _Owner()
+    table = programs.register("jit_local_step", compiled, owner)
+    assert compiled.asked == 0  # registering costs a reference
+    scopes = table.scopes()
+    assert scopes["fusion.5"] == "jit(local_step)/transpose(jvp(loss))/sub"
+    assert "copy.7" not in scopes and table.program == "jit_local_step"
+    assert "multiply.9" in scopes and len(table) == 9
+    assert compiled.asked == 1
+
+
+MS = 1_000_000  # ns
+
+
+def _by_scope(events, **kw):
+    scopes = programs.parse_hlo_scopes(HLO)
+    return programs.device_seconds_by_scope(events, scopes, TRAIN_STEP_GROUPS, **kw)
+
+
+@pytest.mark.parametrize("events,window,expect", [
+    # a fusion under transpose(jvp(...))/attn_core: attn_core, backward;
+    # the TPU's event names are whole HLO lines
+    ([("%fusion.1 = f32[4]{0} fusion(%a), kind=kLoop", 0, 4 * MS),
+      ("%fusion.2 = f32[4]{0} fusion(%a)", 4 * MS, 6 * MS)],
+     (None, None),
+     {"attn_core": (0.004, 0.004), "attn_proj": (0.002, 0.0), "unscoped": 0.0}),
+    # an instruction the table does not know, one with no metadata, and one
+    # of the model's that no module or scope names
+    ([("fusion.99", 0, MS), ("copy.7", MS, 3 * MS), ("convolution.3", 3 * MS, 4 * MS),
+      ("convert.10", 4 * MS, 5 * MS)],
+     (None, None),
+     {"head_loss": (0.001, 0.0), "unscoped": 0.004}),
+    # clipped to a window: half of the first, all of the second, none of the third
+    ([("fusion.4", 0, 2 * MS), ("fusion.5", 2 * MS, 3 * MS), ("add.8", 5 * MS, 6 * MS)],
+     (MS, 4 * MS),
+     {"optimizer": (0.001, 0.0), "head_loss": (0.001, 0.001),
+      "norm_residual": (0.0, 0.0), "unscoped": 0.0}),
+    # a while over the operations of its body: each instant once, for the
+    # innermost event; the loop keeps what its body leaves
+    ([("while.6", 0, 10 * MS), ("fusion.2", MS, 3 * MS), ("copy.7", 3 * MS, 4 * MS),
+      ("fusion.1", 6 * MS, 9 * MS)],
+     (None, None),
+     {"mlp": (0.004, 0.0), "attn_proj": (0.002, 0.0), "attn_core": (0.003, 0.003),
+      "unscoped": 0.001}),
+], ids=["backward-fusion", "unknown-unscoped", "window", "nested-while"])
+def test_device_seconds_by_scope(events, window, expect):
+    out = _by_scope(events, window=window)
+    unscoped = expect.pop("unscoped")
+    assert out["unscoped_s"] == pytest.approx(unscoped)
+    for group in GROUPS:
+        seconds, backward = expect.get(group, (0.0, 0.0))
+        assert out["groups"][group]["seconds"] == pytest.approx(seconds), group
+        assert out["groups"][group]["backward_s"] == pytest.approx(backward), group
+    total = sum(g["seconds"] for g in out["groups"].values()) + out["unscoped_s"]
+    assert out["total_s"] == pytest.approx(total)
+    assert sum(s for _, s in out["unscoped_top"]) == pytest.approx(unscoped)
+
+
+def test_within_keeps_the_events_of_a_programs_runs():
+    ops = [("a", 0, 10), ("b", 10, 20), ("c", 25, 35), ("d", 40, 50)]
+    assert programs.within(ops, [(0, 20), (38, 60)]) == [ops[0], ops[1], ops[3]]
+
+
+def test_program_by_scope_joins_a_programs_own_runs_over_devices():
+    scopes = programs.parse_hlo_scopes(HLO)
+    one = [("fusion.1", 0, 2 * MS), ("fusion.4", 2 * MS, 3 * MS),
+           ("fusion.1", 10 * MS, 14 * MS),  # under the other program's run
+           ("fusion.2", 20 * MS, 21 * MS), ("copy.7", 21 * MS, 22 * MS)]
+    ops = {0: one, 1: one[:2]}
+    modules = {
+        0: [("jit_local_step(7)", 0, 3 * MS), ("jit_local_step_acc(8)", 10 * MS, 14 * MS),
+            ("jit_local_step(7)", 20 * MS, 22 * MS)],
+        1: [("jit_local_step(7)", 0, 3 * MS)],
+    }
+    by = programs.program_by_scope(ops, modules, "jit_local_step", scopes, TRAIN_STEP_GROUPS)
+    assert (by["runs"], by["devices"]) == (3, 2) and by["run_s"] == pytest.approx(0.008)
+    assert by["groups"]["attn_core"]["seconds"] == pytest.approx(0.004)  # not the 4 ms
+    assert by["groups"]["optimizer"]["seconds"] == pytest.approx(0.002)
+    assert by["unscoped_s"] == pytest.approx(0.001) and by["total_s"] == pytest.approx(0.008)
+    # whole runs inside the window alone; a program that never ran: nothing
+    cut = programs.program_by_scope(
+        ops, modules, "jit_local_step", scopes, TRAIN_STEP_GROUPS, (0, 21 * MS))
+    assert cut["runs"] == 2 and cut["total_s"] == pytest.approx(0.006)
+    assert programs.program_by_scope(ops, modules, "jit_local", scopes, TRAIN_STEP_GROUPS) is None
+
+
+def test_groups_in_tells_a_table_with_another_trees_names():
+    scopes = programs.parse_hlo_scopes(HLO)
+    assert programs.groups_in(scopes, TRAIN_STEP_GROUPS) == set(GROUPS)
+    stale = {k: p.replace("/attn_core", "") for k, p in scopes.items()}
+    assert programs.groups_in(stale, TRAIN_STEP_GROUPS) == set(GROUPS) - {"attn_core"}
+
+
+def test_idle_gaps_fall_to_the_innermost_span():
+    ops = [("a", 10, 20), ("b", 30, 40), ("c", 70, 80)]
+    host = [("step", 0, 50), ("data.stage_wait", 22, 28)]
+    gaps = programs.idle_gaps_by_span(ops, host, window=(0, 100))
+    assert gaps == pytest.approx({
+        "step": 10e-9, "data.stage_wait": 10e-9, "unannotated": 50e-9,
+    })
+
+
+# -- spans on the profiler's clock, totals ------------------------------------
+
+def test_span_opens_a_ddl_annotation(monkeypatch):
+    seen = []
+
+    class Note:
+        def __init__(self, name):
+            seen.append(("new", name))
+
+        def __enter__(self):
+            seen.append("enter")
+
+        def __exit__(self, *exc):
+            seen.append("exit")
+
+    monkeypatch.setattr(sys.modules["jax.profiler"], "TraceAnnotation", Note)
+    bus = obs.EventBus()
+    with bus.span("data.stage", k=1) as labels:
+        labels["late"] = True  # what the block learns inside
+        seen.append("body")
+    assert seen == [("new", "ddl:data.stage"), "enter", "body", "exit"]
+    (rec,) = bus.ring
+    assert rec["name"] == "data.stage" and rec["labels"] == {"k": 1, "late": True}
+    bus.span_event("step", 0.5)  # after the fact: recorded, not mirrored
+    assert len(seen) == 4 and len(bus.ring) == 2
+
+
+def test_span_records_with_no_profiler_and_with_no_jax(monkeypatch):
+    bus = obs.EventBus()
+    with bus.span("step"):  # no profiler session: the annotation is a no-op
+        pass
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)  # a jax-free process
+    with bus.span("step"):
+        pass
+    with pytest.raises(ValueError), bus.span("step"):
+        raise ValueError("the block's own error passes through")
+    assert [e["name"] for e in bus.ring] == ["step"] * 3
+
+
+def test_ddl_spans_lie_in_a_profiler_capture(tmp_path):
+    import threading
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with obs.span("step"):
+            jnp.ones((8, 8)).sum().block_until_ready()
+
+        def staged():
+            with obs.span("data.stage"):
+                pass
+
+        t = threading.Thread(target=staged)
+        t.start()
+        t.join(timeout=30)
+    finally:
+        jax.profiler.stop_trace()
+    profile = programs.load_profile(str(tmp_path))
+    names = {name for name, _, _ in profile.host}
+    assert {"step", "data.stage"} <= names
+    assert all(b >= a for _, a, b in profile.host)
+    assert profile.ops == {}  # the CPU has no device plane
+
+
+def test_totals_outlive_the_ring(tmp_path):
+    bus = obs.EventBus(ring_size=4)
+    for _ in range(20):
+        bus.span_event("setup.engine", 0.25)
+        bus.counter("data.h2d_bytes", 1024)
+        bus.gauge("epoch.loss", 1.0)  # gauges have no totals
+    with bus.span("compile"):
+        pass
+    assert len(bus.ring) == 4
+    totals = bus.totals()
+    assert totals["setup.engine"] == {"kind": "span", "count": 20, "sum": 5.0}
+    assert totals["data.h2d_bytes"] == {"kind": "counter", "count": 20, "sum": 20480.0}
+    assert totals["compile"]["count"] == 1 and "epoch.loss" not in totals
+    path = bus.dump_flight("test", path=str(tmp_path / "flight.jsonl"))
+    with open(path) as fh:
+        header = json.loads(fh.readline())
+    assert header["totals"]["setup.engine"]["count"] == 20
+
+
+# -- the explicit train path, and loop.fit beside it ---------------------------
+
+def _names(kind=None):
+    return [
+        e["name"] for e in obs.get_bus().ring
+        if kind is None or e["kind"] == kind
+    ]
+
+
+def test_explicit_path_emits_setup_step_and_staging_events():
+    from distributeddeeplearning_tpu.frontends import explicit
+
+    pieces, state = _trainer()
+    setup = [name for name in _names("span") if name.startswith("setup")]
+    assert setup == ["setup.engine"]
+    explicit.train_epoch(pieces, state, _Tokens(3), 0)
+    ring = list(obs.get_bus().ring)
+    count = lambda name: sum(1 for e in ring if e["name"] == name)  # noqa: E731
+    assert count("step") == 3 and count("data.stage") == 3
+    assert count("data.stage_wait") == 4  # three batches and the end
+    assert count("step.log_sync") == 1  # log_every_steps=2
+    staged = [e["value"] for e in ring if e["name"] == "data.h2d_bytes"]
+    assert staged == [2 * ROWS * SEQ * 4] * 3  # tokens and labels, int32
+    assert obs.get_bus().totals()["data.h2d_bytes"]["sum"] == 3 * 2 * ROWS * SEQ * 4
+
+
+def test_fit_and_the_explicit_loop_emit_the_same_step_events():
+    from distributeddeeplearning_tpu.config import TrainConfig
+    from distributeddeeplearning_tpu.frontends import explicit
+    from distributeddeeplearning_tpu.models import get_model
+    from distributeddeeplearning_tpu.parallel.mesh import data_parallel_mesh
+    from distributeddeeplearning_tpu.training import loop
+
+    per_step = {"step", "step.log_sync", "data.stage", "data.stage_wait",
+                "data.h2d_bytes"}
+    pieces, state = _trainer()
+    explicit.train_epoch(pieces, state, _Tokens(4), 0)
+    explicit_names = set(_names()) & per_step
+
+    obs.reset()
+    cfg = TrainConfig(
+        model="lm_tiny", num_classes=VOCAB, batch_size_per_device=ROWS,
+        optimizer="adamw", weight_decay=0.0, fake=True, epochs=1,
+        log_every_steps=2,
+    )
+    model = get_model("lm_tiny", num_classes=VOCAB, max_seq_len=SEQ,
+                      dtype="bfloat16", attn_impl=cfg.attn_impl)
+    loop.fit(model, cfg, _Tokens(4), mesh=data_parallel_mesh(1), epochs=1)
+    fit_names = set(_names()) & per_step
+    assert explicit_names == fit_names == per_step
+
+
+# -- the operator's view: scripts/trace_report.py over a capture ---------------
+
+XPLANE = """
+planes { id: 1 name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Modules" timestamp_ns: 1000
+    events { metadata_id: 10 offset_ps: 0 duration_ps: 10000000000 }
+    events { metadata_id: 10 offset_ps: 20000000000 duration_ps: 10000000000 } }
+  lines { id: 2 name: "XLA Ops" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 4000000000 }
+    events { metadata_id: 2 offset_ps: 4000000000 duration_ps: 5000000000 }
+    events { metadata_id: 3 offset_ps: 9000000000 duration_ps: 1000000000 }
+    events { metadata_id: 1 offset_ps: 20000000000 duration_ps: 4000000000 }
+    events { metadata_id: 2 offset_ps: 24000000000 duration_ps: 5000000000 }
+    events { metadata_id: 3 offset_ps: 29000000000 duration_ps: 1000000000 } }
+  event_metadata { key: 1 value { id: 1 name: "%fusion.1 = f32[4]{0} fusion(%a), kind=kLoop" } }
+  event_metadata { key: 2 value { id: 2 name: "%fusion.4 = f32[4]{0} fusion(%a), kind=kLoop" } }
+  event_metadata { key: 3 value { id: 3 name: "%copy.7 = f32[4]{0} copy(%a)" } }
+  event_metadata { key: 10 value { id: 10 name: "jit_local_step(123)" } }
+}
+planes { id: 3 name: "/host:CPU"
+  lines { id: 7 name: "python3" timestamp_ns: 1000
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 30000000000 }
+    events { metadata_id: 2 offset_ps: 12000000000 duration_ps: 6000000000 }
+    events { metadata_id: 3 offset_ps: 0 duration_ps: 1000000 } }
+  event_metadata { key: 1 value { id: 1 name: "ddl:step" } }
+  event_metadata { key: 2 value { id: 2 name: "ddl:data.stage_wait" } }
+  event_metadata { key: 3 value { id: 3 name: "PjitFunction(step)" } }
+}
+"""
+
+
+def _trace_report():
+    import importlib.util
+    import os
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "scripts", "trace_report.py",
+    )
+    spec = importlib.util.spec_from_file_location("trace_report", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_trace_report_prints_device_time_by_scope_and_idle_gaps_by_span():
+    from jax.profiler import ProfileData
+
+    profile = programs.from_profile_data(ProfileData.from_text_proto(XPLANE))
+    assert sorted(n for n, _, _ in profile.host) == ["data.stage_wait", "step"]
+    scopes = programs.parse_hlo_scopes(HLO)
+    report = _trace_report()
+    groups = TRAIN_STEP_GROUPS
+    rep = report.device_report("capture", groups, profile, {"jit_local_step": scopes})
+    (by,) = rep["programs"]
+    assert by["runs"] == 2 and by["run_s"] == pytest.approx(0.020)
+    assert by["groups"]["attn_core"]["seconds"] == pytest.approx(0.008)
+    assert by["groups"]["optimizer"]["seconds"] == pytest.approx(0.010)
+    assert by["unscoped_s"] == pytest.approx(0.002)
+    # the one gap (10-20 ms) falls to the innermost span over its middle
+    assert rep["idle"][0]["gaps"] == pytest.approx({"data.stage_wait": 0.010})
+    text = report.render_device(rep)
+    assert "program jit_local_step on 1 device(s): 2 run(s), 10.00 ms a run" in text
+    assert "attn_core" in text and "unscoped" in text and "copy.7" in text
+    assert "data.stage_wait 0.0100" in text
+    # with no table beside the capture the gaps are still named
+    bare = report.render_device(report.device_report("capture", groups, profile, {}))
+    assert "no scope table" in bare and "data.stage_wait" in bare
+
+
+def test_trace_controller_leaves_the_scope_tables_beside_a_capture(tmp_path, capsys):
+    from distributeddeeplearning_tpu.obs import trace as obs_trace
+
+    owner = _Owner()
+    programs.register("jit_local_step", _Text(HLO), owner)
+    ctrl = obs_trace.TraceController(str(tmp_path), every_n=1)
+    assert ctrl.maybe_start(0)
+    with obs.span("step"):
+        pass
+    assert ctrl.maybe_stop(0)
+    capture = str(tmp_path / "trace-epoch0000")
+    tables = programs.load_tables(capture)
+    assert tables["jit_local_step"]["fusion.4"] == "jit(local_step)/optimizer/mul"
+    report = _trace_report()
+    assert report.find_captures([str(tmp_path)]) == [capture]
+    assert report.main([str(tmp_path)]) == 0  # a bare capture directory
+    out = capsys.readouterr().out
+    assert "no device plane" in out and "step" in out
