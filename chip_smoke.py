@@ -203,8 +203,9 @@ def train_resnet50() -> dict:
 
 
 def train_lm_flash() -> dict:
-    """lm_small at T=8,192 with ``ATTN_IMPL=pallas``: the flash kernel's
-    forward and both backward kernels, compiled by Mosaic."""
+    """lm_small at T=8,192 with no ``ATTN_IMPL``: the default ``"auto"``
+    resolves to the flash kernel here (``Attention._resolve_impl``), its
+    forward and both backward kernels compiled by Mosaic."""
     import jax
     import jax.numpy as jnp
 
@@ -213,8 +214,7 @@ def train_lm_flash() -> dict:
     from distributeddeeplearning_tpu.models import get_model
 
     config = TrainConfig(
-        model=LM_TRAIN, num_classes=VOCAB, batch_size_per_device=1,
-        attn_impl="pallas", epochs=1,
+        model=LM_TRAIN, num_classes=VOCAB, batch_size_per_device=1, epochs=1,
         fake_data_length=STEPS_KERNEL * jax.device_count(),
     )
     model = get_model(
@@ -235,13 +235,13 @@ def train_lm_flash() -> dict:
 
 
 def train_vit_packed() -> dict:
-    """ViT-B/16 with ``ATTN_IMPL=auto``: on a TPU
+    """ViT-B/16 with the default ``attn_impl="auto"``: on a TPU
     ``Attention._resolve_impl`` picks the packed kernel (T=197, d=64,
     ragged last block), which no CPU test ever compiles."""
     from distributeddeeplearning_tpu.data import make_dataset
     from distributeddeeplearning_tpu.models import get_model
 
-    config = _image_config(VIT, VIT_BATCH, STEPS_KERNEL, attn_impl="auto")
+    config = _image_config(VIT, VIT_BATCH, STEPS_KERNEL)
     model = get_model(config.model, **config.model_kwargs())
     out = _train(model, config, make_dataset(config, train=True))
     out["tpu_custom_calls"] = out["_hlo"].count(TPU_CUSTOM_CALL)

@@ -57,9 +57,13 @@ class TrainConfig:
     #                (PROFILE.md round-4 decomposition)
     #   "float32" | "bfloat16" — explicit overrides
     input_staging: str = "auto"
-    # Attention implementation for attention models (ViT):
-    # "xla" einsum | "pallas" flash kernel | "ring" sequence-parallel.
-    attn_impl: str = "xla"
+    # Attention core of the attention models (ViT, the LM families):
+    # "auto" (the default) lets each call choose from what it can see
+    # (models/vit.Attention._resolve_impl): on a TPU with local operands
+    # the packed kernel at T <= 512, the streaming flash kernel from
+    # T = 640 on, the XLA einsum elsewhere. "xla" | "pallas" (flash) |
+    # "fused" (packed) | "ring" (sequence-parallel) force a path.
+    attn_impl: str = "auto"
     # Mixture-of-Experts width for MoE-capable models (the LM families):
     # None keeps each model's own default (8 for lm_moe_*, dense for lm_*).
     moe_experts: Optional[int] = None

@@ -67,8 +67,9 @@ def get_model(
     be a jnp dtype or a string (``TrainConfig.compute_dtype``, e.g.
     ``"bfloat16"``/``"float32"`` — the compute dtype of the forward
     pass; params stay float32 either way). ``attn_impl``
-    (``TrainConfig.attn_impl``: xla/pallas/ring) is forwarded to models
-    registered with attention support and ignored for conv models.
+    (``TrainConfig.attn_impl``: auto/xla/pallas/fused/ring) is forwarded
+    to models registered with attention support and ignored for conv
+    models; ``None`` keeps the model's own default (``"auto"``).
     """
     key = name.lower()
     if key not in _REGISTRY:

@@ -201,7 +201,7 @@ class MoEDecoderBlock(nn.Module):
     num_selected: int = 2
     capacity_factor: float = 1.25
     dtype: Any = jnp.bfloat16
-    attn_impl: str = "xla"
+    attn_impl: str = "auto"
     dropout: float = 0.0
     seq_axis: Any = None
     decode: bool = False  # KV-cache inference (inference.generate)

@@ -6,7 +6,11 @@ the LM's causal attention routes through ``ops.dot_product_attention``,
 so the same module runs the XLA einsum path, the Pallas flash kernel
 (O(T·d) memory — the only way long contexts fit, see
 ``ops/pallas/flash.py``), or — inside a ``seq``-axis ``shard_map`` —
-ring sequence parallelism (``parallel/ring_attention.py``).
+ring sequence parallelism (``parallel/ring_attention.py``). Which one
+is the call's own choice by default (``attn_impl="auto"``,
+``models/vit.Attention._resolve_impl``): on a TPU with local operands
+the flash kernel from 640 tokens on (the packed kernel up to 512), the
+einsum elsewhere; no environment variable is needed to get the kernel.
 
 Design mirrors ``models/vit.py``: pre-norm blocks, bf16 compute / f32
 params, LayerNorm in f32, every weight annotated with logical axes
@@ -71,7 +75,7 @@ class DecoderBlock(nn.Module):
     num_heads: int
     mlp_dim: int
     dtype: Any = jnp.bfloat16
-    attn_impl: str = "xla"
+    attn_impl: str = "auto"
     dropout: float = 0.0
     seq_axis: Any = None
     decode: bool = False  # KV-cache inference (inference.generate)
@@ -131,7 +135,10 @@ class TransformerLM(nn.Module):
     vocab_size: int = 32_000
     max_seq_len: int = 2048
     dtype: Any = jnp.bfloat16
-    attn_impl: str = "xla"
+    # "auto": the flash kernel for a long sequence on a TPU, the packed
+    # kernel for a short one, the XLA einsum elsewhere (models/vit.
+    # Attention._resolve_impl); any other value forces a path.
+    attn_impl: str = "auto"
     dropout: float = 0.0
     seq_axis: Any = None
     # Mixture-of-Experts (expert-parallel tier, models/moe.py): 0 = dense.

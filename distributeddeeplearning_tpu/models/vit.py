@@ -13,7 +13,8 @@ Design is TPU-first throughout:
   these annotations.
 * Attention goes through ``ops.dot_product_attention`` so the impl can
   be swapped (XLA einsum / Pallas flash kernel / ring sequence-parallel)
-  per config.
+  per config; the default ``"auto"`` picks a kernel from shape and
+  platform (``Attention._resolve_impl``).
 * bf16 compute, f32 params; LayerNorm in f32 (TPU numerics practice).
 
 Variant table follows the standard ViT paper sizes; patch size via name
@@ -29,6 +30,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from distributeddeeplearning_tpu import obs
 from distributeddeeplearning_tpu.ops.attention import dot_product_attention
 
 # Sub-scope of an Attention module for scores, softmax and weighted sum
@@ -451,25 +453,43 @@ class Attention(nn.Module):
         return self._masked_decode_scores(q, k_all, v_all, q_pos)
 
     def _resolve_impl(self, x, head_dim: int) -> str:
-        """``"auto"`` → the packed small-T kernel when the shape fits and
-        the call site is one where a Pallas custom call is safe: on-TPU
-        and either single-device or inside ``shard_map`` (the dp/sp
-        engines — operands are already local). Under multi-device GSPMD
-        (pjit engine) operands carry no varying axes; a custom call there
-        would force replication, so auto falls back to the einsum."""
-        if self.attn_impl != "auto":
-            return self.attn_impl
-        from distributeddeeplearning_tpu.ops.pallas import flash_packed
+        """The attention core's lowering for this call, chosen from what
+        the call can see. An explicit ``attn_impl`` is taken as given.
+        ``"auto"`` takes a Pallas kernel where a custom call is safe and
+        pays: on a TPU, with operands that are already local (one
+        device, or inside ``shard_map``: the dp/sp engines; under
+        multi-device GSPMD, the pjit engine, operands carry no varying
+        axes and a custom call would force replication), and not while
+        initializing (parameters do not depend on the path, and the
+        weight draw should lower no kernel it never runs). There, by
+        shape: the packed small-T kernel where it takes the sequence
+        (``flash_packed.supports``: T <= 512, the ViT regime), the
+        streaming flash kernel where it is ahead (``flash.supports``:
+        T >= 640 on the v5e's measurement, head blocks that tile the
+        lanes), else the XLA einsum. ``decode=True`` never comes here.
+        What was chosen is counted at trace time (``attn.impl.<path>``)."""
+        impl = self.attn_impl
+        if impl == "auto":
+            from distributeddeeplearning_tpu.ops.pallas import flash, flash_packed
 
-        local = bool(getattr(jax.typeof(x), "vma", ())) or jax.device_count() == 1
-        if (
-            x.ndim == 3
-            and jax.default_backend() == "tpu"
-            and flash_packed.supports(x.shape[1], self.num_heads, head_dim)
-            and local
-        ):
-            return "fused"
-        return "xla"
+            impl = "xla"
+            local = bool(getattr(jax.typeof(x), "vma", ())) or jax.device_count() == 1
+            if (
+                x.ndim == 3
+                and jax.default_backend() == "tpu"
+                and local
+                and not self.is_initializing()
+            ):
+                shape = (x.shape[1], self.num_heads, head_dim)
+                if flash_packed.supports(*shape):
+                    impl = "fused"
+                elif flash.supports(*shape):
+                    impl = "pallas"
+        obs.counter(
+            f"attn.impl.{impl}", asked=self.attn_impl, shape=list(x.shape),
+            heads=self.num_heads,
+        )
+        return impl
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -481,17 +501,21 @@ class Attention(nn.Module):
         impl = None if self.decode else self._resolve_impl(x, head_dim)
         if impl == "ring" and self.is_initializing():
             impl = "xla"
+        packed = None
         if impl == "fused":
-            # Packed path: no [B, T, 3, H, d] reshape/slice at the XLA
-            # level — the kernel reads head columns from qkv directly.
             from distributeddeeplearning_tpu.ops.pallas.flash_packed import (
-                fused_qkv_attention,
+                fused_qkv_attention as packed,
             )
+        elif impl == "pallas":
+            from distributeddeeplearning_tpu.ops.pallas import flash
 
+            if flash.heads_per_program(self.num_heads, head_dim):
+                packed = flash.flash_qkv_attention
+        if packed is not None:
+            # Packed path: no [B, T, 3, H, d] reshape/slice at the XLA
+            # level — the kernels read head columns from qkv directly.
             with jax.named_scope(ATTN_CORE):
-                out_flat = fused_qkv_attention(
-                    qkv_flat, self.num_heads, causal=self.causal
-                )
+                out_flat = packed(qkv_flat, self.num_heads, causal=self.causal)
         else:
             qkv = qkv_flat.reshape(*x.shape[:-1], 3, self.num_heads, head_dim)
             q, k, v = qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]

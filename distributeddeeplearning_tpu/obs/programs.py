@@ -60,6 +60,10 @@ _INSTRUCTION = re.compile(
 )
 BACKWARD = "transpose(jvp("
 UNSCOPED = "unscoped"
+# A Pallas kernel is one custom call of this target on the TPU; the
+# parser hangs the target on the instruction's path as its last part.
+KERNEL_CALL = "tpu_custom_call"
+_KERNEL_TARGET = f'custom_call_target="{KERNEL_CALL}"'
 
 Groups = Sequence[Tuple[str, str]]  # (group name, pattern over the path)
 
@@ -125,14 +129,18 @@ class ScopeTable:
 def parse_hlo_scopes(text: str) -> Dict[str, str]:
     """``{instruction: op_name}`` from HLO text. Every computation's
     instructions are taken (a ``while`` body's operations run as events
-    of their own); instructions without metadata are not in the table."""
+    of their own); instructions without metadata are not in the table.
+    A Mosaic kernel's custom call gets ``/tpu_custom_call`` after its
+    path (its copies and tuple elements share the ``op_name``, and
+    :func:`kernel_calls_by_group` counts the kernels alone)."""
     scopes: Dict[str, str] = {}
     for line in text.splitlines():
         if 'op_name="' not in line:
             continue
         m = _INSTRUCTION.match(line)
         if m and m.group(2):
-            scopes[m.group(1)] = m.group(2)
+            kernel = "/" + KERNEL_CALL if _KERNEL_TARGET in line else ""
+            scopes[m.group(1)] = m.group(2) + kernel
     return scopes
 
 
@@ -288,6 +296,21 @@ def groups_in(scopes: Dict[str, str], groups: Groups) -> Set[str]:
     compiled = [(name, re.compile(p)) for name, p in groups]
     found = {group_of(path, compiled) for path in set(scopes.values())}
     return found - {UNSCOPED}
+
+
+def kernel_calls_by_group(scopes: Dict[str, str], groups: Groups) -> Dict[str, int]:
+    """How many Mosaic kernels (``tpu_custom_call`` instructions) a
+    table holds under each group, ``unscoped`` for those in none:
+    whether a model part runs the kernel it was given. A GPT-2 step on
+    the flash kernel holds 36 under ``attn_core``, a forward and two
+    backward kernels a layer."""
+    compiled = [(name, re.compile(p)) for name, p in groups]
+    out: Dict[str, int] = {}
+    for path in scopes.values():
+        if path.rsplit("/", 1)[-1] == KERNEL_CALL:
+            group = group_of(path, compiled)
+            out[group] = out.get(group, 0) + 1
+    return out
 
 
 def device_seconds_by_scope(

@@ -5,14 +5,20 @@ this op layer exists because the BASELINE.json configs add ViT-B/16 and
 because long-context support is first-class in this framework. One
 signature, three implementations:
 
-* ``xla``   — einsum softmax attention; XLA fuses it well for moderate T.
-* ``pallas`` — fused flash-attention TPU kernel (``ops/pallas/flash.py``)
-  for long T where materialising the [T, T] score matrix would blow HBM.
+* ``xla``   — einsum softmax attention: the ``[T, T]`` scores and the
+  softmax weights go through HBM, forward and backward.
+* ``pallas`` — flash-attention TPU kernels (``ops/pallas/flash.py``):
+  nothing of size ``T × T`` leaves the chip. Ahead of the einsum from
+  T = 640 on (v5e, d = 64; ``flash.supports``), and the only way a long
+  context fits.
 * ``ring``  — sequence-parallel blockwise attention over a ``seq`` mesh
   axis (``parallel/ring_attention.py``): K/V blocks rotate around the
   ring via ``ppermute`` while each shard holds only T/n of the sequence.
 
-All take ``[batch, seq, heads, head_dim]`` (BTHD) tensors.
+All take ``[batch, seq, heads, head_dim]`` (BTHD) tensors. A caller
+names one; ``models/vit.Attention`` with ``attn_impl="auto"`` (the
+models' default) picks by shape and platform, and takes the packed
+small-T kernel (``ops/pallas/flash_packed.py``) before this layer.
 """
 
 from __future__ import annotations

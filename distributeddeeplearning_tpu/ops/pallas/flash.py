@@ -3,32 +3,61 @@
 The framework's native-tier attention (SURVEY.md §2a maps the
 reference's CUDA/NCCL tier to first-party Pallas kernels). The XLA
 einsum path (``ops/attention.py``) materialises the ``[T, T]`` score
-matrix in HBM; this kernel streams K/V blocks through VMEM with the
-online-softmax recurrence, so peak memory is ``O(T·d)`` and the scores
-never leave the chip:
+matrix in HBM, forward and backward; these kernels keep one tile of it
+in VMEM at a time, with the online-softmax recurrence, so memory is
+``O(T·d)`` and nothing of size ``T × T`` leaves the chip.
 
-  forward : grid ``(batch·head, q-block, k-block)`` with K innermost —
-            one (q, k, v) tile resident in VMEM per program. Running
-            row-max ``m``, normaliser ``l`` and the f32 accumulator are
-            carried in VMEM scratch across the sequential K dimension;
-            the MXU sees two matmuls per block (``q·kᵀ`` and ``p·v``).
-  backward: custom VJP using the saved per-row logsumexp, recomputed
-            blockwise in pure JAX (a ``lax.scan`` over K blocks) — the
-            standard flash-attention backward recurrence, also without
-            a ``[T, T]`` residual.
+Three Mosaic kernels under one custom VJP, all of one shape: a grid
+``(batch, head group, owned block, resident block)`` in which a program
+owns one ``b``-row block of one operand (its accumulators live in VMEM
+scratch across the last, sequential grid axis) and meets a resident
+block of the other, ``sub`` blocks of ``b`` rows, as one wide tile
+``[b, sub·b]`` (at most ``_TILE_ELEMS`` scores: one matmul, one pass of
+the softmax, the program's fixed cost paid once; at T = 1,024 the
+resident block is the whole sequence and K and V are fetched once a
+head group):
 
-On non-TPU backends the kernel runs in Pallas interpreter mode, so the
-CPU test mesh exercises the identical code path (§7 hard part (d)).
+  forward : owns a q block. Running row-max ``m``, normaliser ``l`` and
+            the f32 accumulator in scratch; two matmuls a tile
+            (``q·kᵀ``, ``p·v``). Saves the per-row logsumexp.
+  dq      : owns a q block: ``p = exp(s − lse)``,
+            ``ds = p ⊙ (do·vᵀ − Δ)``, ``dq = Σ ds·k·scale``.
+  dk/dv   : owns a k/v block, meets q/do, on transposed tiles
+            (``sᵀ = k·qᵀ``: keys on sublanes, queries on lanes), so
+            ``dv = Σ pᵀ·do`` and ``dk = Σ dsᵀ·q·scale`` are plain
+            products and no score tile is transposed.
 
-Layout: inputs are BTHD ``[batch, seq, heads, head_dim]`` (the
-framework-wide attention layout, ``ops/attention.py``); internally the
-kernel works in BHTD so the last two dims tile onto (sublane, lane).
+Causal attention computes no block above the diagonal: of a resident
+block a q block meets the first ``c`` sub-blocks only, up to its own
+(a k block: from its own on), a static branch for each ``c``, and only
+the tile that holds the diagonal is masked (q and k blocks are one
+size). Resident blocks wholly above the diagonal are neither computed
+nor fetched. Without ``causal`` the one masked tile is the one that
+holds the keys' padding.
+
+Operands stay ``[B, T, H·d]``, as the projections wrote them: a
+program's blocks are 128 lanes wide and hold ``128/d`` heads side by
+side (two at d = 64; independent chains in one program), so no
+transpose stands round the custom calls. Head widths that do not tile
+the lanes (d = 96) are transposed to ``[B·H, T, d]`` first
+(``heads_per_program``).
+
+``lse`` and ``Δ = rowsum(do ⊙ o)`` travel as rows, ``[B, H, major, 8,
+sub·b]`` (queries on lanes, 8 equal sublanes: the smallest f32 tile),
+16 times smaller than lane-replicated columns; the dk/dv kernel reads
+them as they lie, the q-owning kernels turn their block once a program.
+
+``_flash_bwd_scan`` is the kept pure-JAX reference for the backward
+kernels. On non-TPU backends the kernels run in Pallas interpreter
+mode, so the CPU test mesh exercises the identical code path (§7 hard
+part (d)).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +66,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() NaN-free
+_LANES = 128  # VPU lane width: m/l scratch rows are lane-replicated
+_SUBLANES = 8
+# Elements of the widest score tile a program computes at once (2 MiB in
+# f32): a block's rows times the rows of the other operand that are
+# resident beside it, at most _MAX_SUB blocks of them (a static branch
+# each). Longer sequences stream such blocks along the last grid axis.
+_TILE_ELEMS = 512 * 1024
+_MAX_SUB = 8
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -51,382 +88,505 @@ def _vma(*arrays):
     return frozenset(out)
 
 
-def _pick_block(pref: int, t: int) -> int:
-    """Largest block ≤ ``pref`` that minimises trailing-block padding.
-
-    A fixed big block wastes up to a whole block of MXU work on awkward
-    lengths (T=513 @ 512 → 2x padding); halve down to 128 (below which
-    MXU tiles go idle) picking the smallest padded total.
-    """
-    if t <= 128:
-        return min(pref, _ceil_to(t, 8))
-    cands = []
-    c = max(pref, 128)
-    while c >= 128:
-        cands.append(c)
-        c //= 2
-    return min(cands, key=lambda c: (_ceil_to(t, c), -c))
+# The shortest sequence for which `"auto"` takes these kernels over the
+# XLA einsum (`supports`). Measured on the v5e, a GPT-2 layer's attention
+# core forward + backward at 16 x 12 heads, d = 64, causal, device ms
+# kernel | XLA: T = 1,024: 2.92 | 6.05; 768: 2.14 | 3.09; 640: 1.88 |
+# 2.27 (PERF.md section 6, PR 26). XLA's cost falls with T squared, the
+# kernel's with its 128-row blocks: by those readings the einsum is
+# ahead somewhere under 600, and between 513 and 639 nothing was run.
+MIN_T = 640
 
 
-_LANES = 128  # VPU lane width: m/l scratch rows are lane-replicated
+def _pick_block(t: int) -> int:
+    """Rows of a q and of a k block (one size: the diagonal tile is then
+    a constant triangle): of 512, 256 and 128 the one that pads ``t``
+    least, the larger on a tie. Measured at T = 1,024, d = 64 (as
+    above, one call earlier): 512 -> 3.15 ms, 256 -> 3.21, 1,024 (no
+    tile skipped) -> 3.43; larger tiles amortise a program's fixed cost,
+    smaller ones skip more of the causal triangle."""
+    return min((512, 256, 128), key=lambda c: (_ceil_to(t, c), -c))
+
+
+class _Plan(NamedTuple):
+    """How a length-``t`` operand is cut: blocks of ``b`` rows; walked,
+    it lies in ``major`` resident blocks of ``sub`` blocks each."""
+
+    b: int
+    sub: int
+    major: int
+
+    @property
+    def blocks(self) -> int:
+        return self.major * self.sub
+
+    @property
+    def rows(self) -> int:  # padded length
+        return self.blocks * self.b
+
+
+def _plan(t: int, b: int) -> _Plan:
+    n = -(-t // b)
+    major = -(-n // min(_MAX_SUB, max(1, _TILE_ELEMS // (b * b))))
+    return _Plan(b, -(-n // major), major)
+
+
+def heads_per_program(heads: int, d: int) -> int:
+    """Heads that share one program's 128-lane blocks of a ``[B, T, H·d]``
+    operand (the layout the projections write: no transpose round the
+    kernel), or 0 where head blocks do not tile the lanes (d = 96): the
+    operands are then transposed to ``[B·H, T, d]``, one head a program."""
+    if d % _LANES == 0:
+        return 1
+    hp = _LANES // d
+    return hp if _LANES % d == 0 and heads % hp == 0 else 0
+
+
+def supports(seq_len: int, num_heads: int, head_dim: int) -> bool:
+    """Where these kernels are the better attention core (the caller
+    also gates on backend and on local operands): sequences from
+    ``MIN_T`` on, heads whose blocks tile the lanes."""
+    return seq_len >= MIN_T and heads_per_program(num_heads, head_dim) > 0
+
+
+def _head(x, h: int, d: int):
+    """Head ``h``'s columns of a ``[rows, heads·d]`` tile."""
+    return x if x.shape[1] == d else x[:, h * d : (h + 1) * d]
+
+
+def _pad_rows(x, rows: int):
+    return jnp.pad(x, ((0, 0), (0, rows - x.shape[1]), (0, 0)))
+
+
+def _dot(a, b):
+    return lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _dot_nt(a, b):
+    return lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _scores(a, b, scale: float):
+    """``a·bᵀ·scale`` in f32 from operands in the input dtype (bf16 →
+    full-rate MXU). A power-of-two scale (d = 64, 256) is folded into
+    ``a`` first, which is exact and saves a pass over the tile."""
+    if math.frexp(scale)[0] == 0.5:
+        return _dot_nt(a * scale, b)
+    return _dot_nt(a, b) * scale
+
+
+def _across(x, n: int):
+    """Lane-replicated ``[b, 128]`` column statistics against a
+    ``[b, n]`` tile: whole vregs side by side where ``n`` allows, and no
+    broadcast from one lane a vreg (the forward kernel took 0.87 ms a
+    GPT-2 layer with ``x[:, :1]`` here, 0.65 so)."""
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    return x[:, :n] if n < _LANES else x[:, :1]
+
+
+def _down(x, rows: int):
+    """``[8, n]`` row statistics (equal sublanes) against a
+    ``[rows, n]`` tile, the same way."""
+    if rows % _SUBLANES == 0:
+        return jnp.tile(x, (rows // _SUBLANES, 1))
+    return x[:1]
+
+
+def _to_rows(x):
+    """Lane-replicated ``[b, 128]`` column statistics as ``[8, b]`` rows."""
+    return x.T[:_SUBLANES]
+
+
+def _to_cols(x):
+    """``[8, b]`` rows (all equal) as lane-replicated ``[b, 128]`` columns."""
+    return jnp.tile(x, (_LANES // _SUBLANES, 1)).T
+
+
+def _walk_keys(step, i, g0, n_sub: int, causal: bool, kv_len: int, b: int):
+    """For a q block ``i`` against the resident K/V block that starts at
+    global block ``g0``: one call ``step(c, keep)`` for the tile of its
+    first ``c`` sub-blocks, the live ones (a static branch a width).
+    ``keep`` masks the tile (queries on rows) where its last sub-block
+    is the diagonal one or, without ``causal``, holds the keys' padding;
+    a resident block wholly below the diagonal gets none."""
+    edge = i if causal else kv_len // b  # the one block that needs a mask
+    masked = bool(causal or kv_len % b)
+    ahead = edge - g0  # unmasked sub-blocks of this resident block
+
+    @pl.when(ahead >= n_sub)
+    def _whole():
+        step(n_sub, None)
+
+    for c in range(1, n_sub + 1):
+        width = c if masked else c - 1
+        if not width:
+            continue
+
+        @pl.when(ahead == c - 1)
+        def _part(c=c, width=width):
+            keep = None
+            if masked:
+                rows = lax.broadcasted_iota(jnp.int32, (b, c * b), 0)
+                cols = lax.broadcasted_iota(jnp.int32, (b, c * b), 1)
+                keep = (
+                    cols <= rows + (c - 1) * b if causal
+                    else cols < (c - 1) * b + kv_len % b
+                )
+            step(width, keep)
 
 
 def _flash_fwd_kernel(
-    q_ref,
-    k_ref,
-    v_ref,
-    o_ref,
-    lse_ref,
-    m_scr,
-    l_scr,
-    acc_scr,
-    *,
-    scale: float,
-    causal: bool,
-    kv_len: int,
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+    *, scale: float, causal: bool, kv_len: int, b: int, hp: int,
 ):
-    """One (batch·head, q-block, k-block) program with K innermost.
+    """One q block of ``hp`` heads against one resident K/V block. The
+    heads are independent chains in one program: the scheduler runs one
+    head's softmax under another's matmuls."""
+    i, jm = pl.program_id(2), pl.program_id(3)
+    n_sub = k_ref.shape[1] // b
+    d = q_ref.shape[2] // hp
 
-    Only one (block_q, d) + 2·(block_k, d) tile is resident in VMEM per
-    program — K/V genuinely stream, so sequence length is bounded by HBM,
-    not VMEM. The online-softmax state (running max ``m``, normaliser
-    ``l``, f32 accumulator) lives in VMEM scratch, which TPU Pallas
-    persists across the sequentially-executed minor grid dimension.
-    """
-    j = pl.program_id(2)
-    block_q, d = q_ref.shape[1], q_ref.shape[2]
-    block_k = k_ref.shape[1]
-    q_start = pl.program_id(1) * block_q
-
-    @pl.when(j == 0)
+    @pl.when(jm == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Causal: K blocks strictly past this q-block's last row contribute
-    # nothing — skip their matmuls entirely (~2x less MXU work at long T).
-    live = (j * block_k <= q_start + block_q - 1) if causal else True
+    def step(c, keep):
+        qs, ks, vs = q_ref[0], k_ref[0, : c * b, :], v_ref[0, : c * b, :]
+        for h in range(hp):
+            v = _head(vs, h, d)
+            s = _scores(_head(qs, h, d), _head(ks, h, d), scale)  # [b, c·b] f32
+            if keep is not None:
+                s = jnp.where(keep, s, _NEG_INF)
+            m_prev = m_scr[h]  # [b, _LANES], lane-replicated
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - _across(m_new, s.shape[1]))
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            m_scr[h] = m_new
+            acc_scr[h] = acc_scr[h] * _across(alpha, d) + _dot(p.astype(v.dtype), v)
 
-    @pl.when(live)
-    def _compute():
-        # Matmuls stay in the input dtype (bf16 → full-rate MXU) with f32
-        # accumulation via preferred_element_type; only the softmax state
-        # is f32.
-        q = q_ref[0]  # [block_q, d]
-        k = k_ref[0]  # [block_k, d]
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q,
-            k,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [block_q, block_k]
+    _walk_keys(step, i, jm * n_sub, n_sub, causal, kv_len, b)
 
-        # Mask K padding (and the causal future). Global indices:
-        k_idx = j * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = k_idx < kv_len
-        if causal:
-            q_idx = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            mask = jnp.logical_and(mask, q_idx >= k_idx)
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_prev = m_scr[:]  # [block_q, _LANES], lane-replicated
-        l_prev = l_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)  # lane-replicated
-        p = jnp.exp(s - m_new[:, :1])
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_scr[:] = m_new
-        l_scr[:] = l_new
-        acc_scr[:] = acc_scr[:] * alpha[:, :1] + jax.lax.dot_general(
-            p.astype(v.dtype),
-            v,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(jm == pl.num_programs(3) - 1)
     def _finalize():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked (padded) q rows
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        # Lane-replicated [block_q, _LANES]: Mosaic requires the last two
-        # block dims to tile (8, 128); a (1, block_q) row block does not.
-        lse_ref[0] = m_scr[:] + jnp.log(jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:]))
-
-
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
-    """Core: BHTD tensors, padded lengths handled here."""
-    bh, tq, d = q.shape
-    tk = k.shape[1]
-    bq = _pick_block(block_q, tq)
-    bk = _pick_block(block_k, tk)
-    tq_p = _ceil_to(tq, bq)
-    tk_p = _ceil_to(tk, bk)
-    qp = jnp.pad(q, ((0, 0), (0, tq_p - tq), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, tk_p - tk), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, tk_p - tk), (0, 0)))
-    num_kb = tk_p // bk
-
-    kernel = functools.partial(
-        _flash_fwd_kernel, scale=scale, causal=causal, kv_len=tk
-    )
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(bh, tq_p // bq, num_kb),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            # vma: inside shard_map (the DP/SP engines) outputs vary over
-            # the same mesh axes as the inputs; check_vma requires saying
-            # so explicitly.
-            jax.ShapeDtypeStruct((bh, tq_p, d), q.dtype, vma=_vma(qp, kp, vp)),
-            jax.ShapeDtypeStruct(
-                (bh, tq_p, _LANES), jnp.float32, vma=_vma(qp, kp, vp)
-            ),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
-        # K (minor) carries the online-softmax recurrence and must stay
-        # sequential; batch·head and q-blocks are free to parallelise.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(qp, kp, vp)
-    return out[:, :tq], lse[:, :tq, 0]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attention_bhtd(q, k, v, causal, scale, block_q, block_k, interpret):
-    out, _ = _flash(q, k, v, causal, scale, block_q, block_k, interpret)
-    return out
-
-
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret):
-    out, lse = _flash(q, k, v, causal, scale, block_q, block_k, interpret)
-    return out, (q, k, v, out, lse)
+        outs = []
+        for h in range(hp):
+            l = l_scr[h]
+            l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows
+            outs.append(acc_scr[h] / _across(l, d))
+            lse_ref[0, h, 0] = _to_rows(m_scr[h] + jnp.log(l))
+        o_ref[0] = jnp.concatenate(outs, axis=1).astype(o_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
-    *, scale: float, causal: bool, kv_len: int, q_len: int,
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+    dq_scr, lse_scr, delta_scr,
+    *, scale: float, causal: bool, kv_len: int, b: int, hp: int,
 ):
-    """dq: grid ``(batch·head, q-block, k-block)``, K innermost.
+    """dq of one q block: the forward's walk with the saved statistics."""
+    i, jm = pl.program_id(2), pl.program_id(3)
+    n_sub = k_ref.shape[1] // b
+    d = q_ref.shape[2] // hp
 
-    With p = exp(s − lse):  ds = p ⊙ (do·vᵀ − Δ)·scale, dq = Σ_k ds·k.
-    The f32 dq accumulator persists in VMEM scratch across the
-    sequential K dimension — the mirror image of the forward kernel.
-    """
-    j = pl.program_id(2)
-    block_q = q_ref.shape[1]
-    block_k = k_ref.shape[1]
-    q_start = pl.program_id(1) * block_q
-
-    @pl.when(j == 0)
+    @pl.when(jm == 0)
     def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+        for h in range(hp):
+            lse_scr[h] = _to_cols(lse_ref[0, h, 0])
+            delta_scr[h] = _to_cols(delta_ref[0, h, 0])
 
-    live = (j * block_k <= q_start + block_q - 1) if causal else True
+    def step(c, keep):
+        qs, dos = q_ref[0], do_ref[0]
+        ks, vs = k_ref[0, : c * b, :], v_ref[0, : c * b, :]
+        for h in range(hp):
+            k = _head(ks, h, d)
+            s = _scores(_head(qs, h, d), k, scale)
+            p = jnp.exp(s - _across(lse_scr[h], s.shape[1]))
+            if keep is not None:
+                p = jnp.where(keep, p, 0.0)
+            dp = _dot_nt(_head(dos, h, d), _head(vs, h, d))
+            ds = p * (dp - _across(delta_scr[h], s.shape[1]))
+            dq_scr[h] += _dot(ds.astype(k.dtype), k)
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        k_idx = j * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        q_idx = q_start + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        mask = jnp.logical_and(k_idx < kv_len, q_idx < q_len)
-        if causal:
-            mask = jnp.logical_and(mask, q_idx >= k_idx)
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0][:, :1]), 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta_ref[0][:, :1]) * scale
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    _walk_keys(step, i, jm * n_sub, n_sub, causal, kv_len, b)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(jm == pl.num_programs(3) - 1)
     def _finalize():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq = jnp.concatenate([dq_scr[h] for h in range(hp)], axis=1)
+        dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_scr, dv_scr,
-    *, scale: float, causal: bool, kv_len: int, q_len: int,
+    *, scale: float, causal: bool, b: int, hp: int,
 ):
-    """dk/dv: grid ``(batch·head, k-block, q-block)``, Q innermost.
+    """dk/dv of one k/v block against one resident Q/dO block, on a
+    transposed tile ``[keys, queries]`` of the sub-blocks from the
+    diagonal one on (causal: the ones before it are skipped, a static
+    branch a start). Padded query rows carry ``do = Δ = 0`` and add
+    nothing."""
+    i, jm = pl.program_id(2), pl.program_id(3)
+    n_sub = q_ref.shape[1] // b
+    d = k_ref.shape[2] // hp
 
-    dv = Σ_q pᵀ·do;  dk = Σ_q dsᵀ·q. Two f32 accumulators persist in
-    VMEM scratch across the sequential Q dimension. Causal skip: a
-    q-block strictly before this k-block contributes nothing.
-    """
-    j = pl.program_id(2)
-    block_k = k_ref.shape[1]
-    block_q = q_ref.shape[1]
-    k_start = pl.program_id(1) * block_k
-    q_start = j * block_q
-
-    @pl.when(j == 0)
+    @pl.when(jm == 0)
     def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    live = (q_start + block_q - 1 >= k_start) if causal else True
+    def step(lo, masked):
+        ks, vs = k_ref[0], v_ref[0]
+        qs, dos = q_ref[0, lo * b :, :], do_ref[0, lo * b :, :]
+        if masked:  # the first sub-block is the diagonal one
+            tile = (b, qs.shape[0])
+            keep = lax.broadcasted_iota(jnp.int32, tile, 0) <= (
+                lax.broadcasted_iota(jnp.int32, tile, 1)
+            )
+        for h in range(hp):
+            q, do = _head(qs, h, d), _head(dos, h, d)
+            lse = _down(lse_ref[0, h, 0, :, lo * b :], b)
+            pt = jnp.exp(_scores(_head(ks, h, d), q, scale) - lse)  # [b, c·b]
+            if masked:
+                pt = jnp.where(keep, pt, 0.0)
+            dv_scr[h] += _dot(pt.astype(do.dtype), do)
+            dpt = _dot_nt(_head(vs, h, d), do)
+            dst = pt * (dpt - _down(delta_ref[0, h, 0, :, lo * b :], b))
+            dk_scr[h] += _dot(dst.astype(q.dtype), q)
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [block_q, block_k]
-        k_idx = k_start + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        q_idx = q_start + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        mask = jnp.logical_and(k_idx < kv_len, q_idx < q_len)
-        if causal:
-            mask = jnp.logical_and(mask, q_idx >= k_idx)
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0][:, :1]), 0.0)
-        pc = p.astype(do.dtype)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            pc, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta_ref[0][:, :1]) * scale).astype(q.dtype)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    if causal:
+        behind = i - jm * n_sub  # q sub-blocks of this resident block before k's
 
-    @pl.when(j == pl.num_programs(2) - 1)
+        @pl.when(behind < 0)
+        def _whole():
+            step(0, False)
+
+        for lo in range(n_sub):
+            pl.when(behind == lo)(functools.partial(step, lo, True))
+    else:
+        step(0, False)
+
+    @pl.when(jm == pl.num_programs(3) - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        dk = jnp.concatenate([dk_scr[h] for h in range(hp)], axis=1)
+        dv = jnp.concatenate([dv_scr[h] for h in range(hp)], axis=1)
+        dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, res, do):
-    """Flash backward as two Mosaic kernels (dq; dk/dv) sharing the
-    forward's streaming structure — measured 2.0x faster than the
-    earlier pure-JAX ``lax.scan`` backward at T=32k (PROFILE.md).
-    ``_flash_bwd_scan`` below is the kept reference implementation
-    (parity-tested in ``tests/test_attention_ops.py``)."""
-    q, k, v, out, lse = res
-    bh, tq, d = q.shape
-    tk = k.shape[1]
-    bq = _pick_block(block_q, tq)
-    bk = _pick_block(block_k, tk)
-    tq_p = _ceil_to(tq, bq)
-    tk_p = _ceil_to(tk, bk)
-    qp = jnp.pad(q, ((0, 0), (0, tq_p - tq), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, tk_p - tk), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, tk_p - tk), (0, 0)))
-    dop = jnp.pad(do, ((0, 0), (0, tq_p - tq), (0, 0)))
-    delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    )  # [bh, tq]
-    # Lane-replicated [bh, tq_p, 128] like the forward's lse output
-    # (Mosaic blocks must tile (8, 128); a width-1 lane does not).
-    lse_rep = jnp.broadcast_to(
-        jnp.pad(lse, ((0, 0), (0, tq_p - tq)))[..., None], (bh, tq_p, _LANES)
+def _specs(owner: _Plan, walked: _Plan, w: int, hp: int, groups: int,
+           causal: bool, owner_first: bool):
+    """Block specs of a kernel's grid ``(batch, head group, owned block,
+    resident block)`` over ``[B, T, H·d]`` operands: ``own(part)`` a
+    ``[b, w]`` block (``w = hp·d`` lanes), ``walk(part)`` a resident
+    ``[sub·b, w]`` block, ``own_stat``/``walk_stat`` the row statistics
+    of either (``[B, H, major, 8, sub·b]``). ``part`` picks q, k or v
+    (0, 1, 2) where they are the thirds of one packed ``[B, T, 3·H·d]``
+    array: ``groups`` lane blocks each. Causal programs that have no
+    live tile in a resident block name the nearest one that has, so
+    nothing is fetched for them: a q owner (``owner_first``) lives at or
+    after its keys, a k owner at or before its queries."""
+    b = owner.b
+
+    def resident(i, jm):
+        if not causal:
+            return jm
+        near = i // walked.sub
+        return jnp.minimum(jm, near) if owner_first else jnp.maximum(jm, near)
+
+    def own(part=0):
+        return pl.BlockSpec(
+            (1, b, w), lambda n, g, i, jm: (n, i, part * groups + g)
+        )
+
+    def walk(part=0):
+        return pl.BlockSpec(
+            (1, walked.sub * b, w),
+            lambda n, g, i, jm: (n, resident(i, jm), part * groups + g),
+        )
+
+    own_stat = pl.BlockSpec(
+        (1, hp, 1, _SUBLANES, b),
+        lambda n, g, i, jm: (n, g, i // owner.sub, 0, i % owner.sub),
     )
-    delta_rep = jnp.broadcast_to(
-        jnp.pad(delta, ((0, 0), (0, tq_p - tq)))[..., None], (bh, tq_p, _LANES)
+    walk_stat = pl.BlockSpec(
+        (1, hp, 1, _SUBLANES, walked.sub * b),
+        lambda n, g, i, jm: (n, g, resident(i, jm), 0, 0),
+    )
+    return own, walk, own_stat, walk_stat
+
+
+# The last grid axis carries the accumulators and stays sequential; the
+# others are free to parallelise.
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=32 * 2**20,
+)
+
+
+def _geometry(q, k, heads: int, block: Optional[int], packed: bool):
+    """``(hp, d, pq, pk, parts)`` of one call: heads a program, head
+    width, the plans of the query and the key side (one block size), and
+    which third of its array each of q, k, v is (``packed``: one
+    ``[B, T, 3·H·d]`` array given three times)."""
+    d = q.shape[2] // heads // (3 if packed else 1)
+    b = block or _pick_block(max(q.shape[1], k.shape[1]))
+    hp = heads_per_program(heads, d) or 1  # 0: transposed, one head an array row
+    parts = (0, 1, 2) if packed else (0, 0, 0)
+    return hp, d, _plan(q.shape[1], b), _plan(k.shape[1], b), parts
+
+
+def _flash(q, k, v, heads, causal, scale, block, interpret, packed=False):
+    """Forward over ``[B, T, heads·d]`` operands (``packed``: q, k and v
+    are one ``[B, T, 3·heads·d]`` array). Returns ``out [B, T, heads·d]``
+    and the rows' logsumexp as ``[B, heads, major, 8, sub·b]`` (module
+    docstring)."""
+    n, tq = q.shape[:2]
+    tk = k.shape[1]
+    hp, d, pq, pk, (pq_, pk_, pv_) = _geometry(q, k, heads, block, packed)
+    w, b, hd = hp * d, pq.b, heads * d
+    # every block of the padded q is computed: the dk/dv kernel reads
+    # the statistics of all of them
+    qp = _pad_rows(q, pq.rows)
+    kp, vp = _pad_rows(k, pk.rows), _pad_rows(v, pk.rows)
+    own, walk, own_stat, _ = _specs(
+        pq, pk, w, hp, heads // hp, causal, owner_first=True
+    )
+    # vma: inside shard_map (the DP/SP engines) outputs vary over the
+    # same mesh axes as the inputs; check_vma requires saying so.
+    vma = _vma(q, k, v)
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _flash_fwd_kernel, scale=scale, causal=causal, kv_len=tk, b=b, hp=hp
+        ),
+        grid=(n, heads // hp, pq.blocks, pk.major),
+        in_specs=[own(pq_), walk(pk_), walk(pv_)],
+        out_specs=[own(), own_stat],
+        out_shape=[
+            jax.ShapeDtypeStruct((n, pq.rows, hd), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct(
+                (n, heads, pq.major, _SUBLANES, pq.sub * b), jnp.float32, vma=vma
+            ),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((hp, b, _LANES), jnp.float32),
+            pltpu.VMEM((hp, b, _LANES), jnp.float32),
+            pltpu.VMEM((hp, b, d), jnp.float32),
+        ],
+        compiler_params=_PARAMS,
+        interpret=interpret,
+    )(qp, kp, vp)
+    return out[:, :tq], lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, heads, causal, scale, block, interpret):
+    out, _ = _flash(q, k, v, heads, causal, scale, block, interpret)
+    return out
+
+
+def _flash_fwd_rule(q, k, v, heads, causal, scale, block, interpret):
+    out, lse = _flash(q, k, v, heads, causal, scale, block, interpret)
+    return out, (q, k, v, out, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
+def _flash_qkv_attention(qkv, heads, causal, scale, block, interpret):
+    """The same over one packed ``[B, T, 3·heads·d]`` array, read in
+    place: what the fused QKV projection writes, with no slice or
+    reshape between it and the kernels."""
+    out, _ = _flash(qkv, qkv, qkv, heads, causal, scale, block, interpret, True)
+    return out
+
+
+def _flash_qkv_fwd_rule(qkv, heads, causal, scale, block, interpret):
+    out, lse = _flash(qkv, qkv, qkv, heads, causal, scale, block, interpret, True)
+    return out, (qkv, out, lse)
+
+
+def _flash_qkv_bwd_rule(heads, causal, scale, block, interpret, res, do):
+    qkv, out, lse = res
+    grads = _flash_bwd_rule(
+        heads, causal, scale, block, interpret, (qkv, qkv, qkv, out, lse), do,
+        packed=True,
+    )
+    return (jnp.concatenate(grads, axis=-1),)
+
+
+def _flash_bwd_rule(heads, causal, scale, block, interpret, res, do, packed=False):
+    """Flash backward as two Mosaic kernels (dq; dk/dv) of the forward's
+    shape. ``_flash_bwd_scan`` below is the kept reference
+    implementation (parity-tested in ``tests/test_attention_ops.py``)."""
+    q, k, v, out, lse = res
+    n, tq = q.shape[:2]
+    tk = k.shape[1]
+    hp, d, pq, pk, (pq_, pk_, pv_) = _geometry(q, k, heads, block, packed)
+    w, b, hd = hp * d, pq.b, heads * d
+    qp, dop = _pad_rows(q, pq.rows), _pad_rows(do, pq.rows)
+    kp, vp = _pad_rows(k, pk.rows), _pad_rows(v, pk.rows)
+    delta = jnp.sum(
+        (do.astype(jnp.float32) * out.astype(jnp.float32)).reshape(n, tq, heads, d),
+        axis=-1,
+    )  # [n, tq, heads]
+    delta = jnp.pad(delta, ((0, 0), (0, pq.rows - tq), (0, 0)))
+    delta = jnp.broadcast_to(
+        delta.transpose(0, 2, 1).reshape(n, heads, pq.major, 1, -1), lse.shape
     )
     vma = _vma(q, k, v, do)
 
+    own, walk, own_stat, _ = _specs(
+        pq, pk, w, hp, heads // hp, causal, owner_first=True
+    )
     dq = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dq_kernel,
-            scale=scale, causal=causal, kv_len=tk, q_len=tq,
+            _flash_bwd_dq_kernel, scale=scale, causal=causal, kv_len=tk, b=b, hp=hp
         ),
-        grid=(bh, tq_p // bq, tk_p // bk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, i, 0)),
+        grid=(n, heads // hp, pq.blocks, pk.major),
+        in_specs=[own(pq_), walk(pk_), walk(pv_), own(), own_stat, own_stat],
+        out_specs=own(),
+        out_shape=jax.ShapeDtypeStruct((n, pq.rows, hd), q.dtype, vma=vma),
+        scratch_shapes=[
+            pltpu.VMEM((hp, b, d), jnp.float32),
+            pltpu.VMEM((hp, b, _LANES), jnp.float32),
+            pltpu.VMEM((hp, b, _LANES), jnp.float32),
         ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, tq_p, d), q.dtype, vma=vma),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(qp, kp, vp, dop, lse_rep, delta_rep)
+    )(qp, kp, vp, dop, lse, delta)
 
+    own, walk, _, walk_stat = _specs(
+        pk, pq, w, hp, heads // hp, causal, owner_first=False
+    )
     dk, dv = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dkv_kernel,
-            scale=scale, causal=causal, kv_len=tk, q_len=tq,
+            _flash_bwd_dkv_kernel, scale=scale, causal=causal, b=b, hp=hp
         ),
-        grid=(bh, tk_p // bk, tq_p // bq),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq, _LANES), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0)),
-        ],
+        grid=(n, heads // hp, pk.blocks, pq.major),
+        in_specs=[walk(pq_), own(pk_), own(pv_), walk(), walk_stat, walk_stat],
+        out_specs=[own(), own()],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tk_p, d), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, tk_p, d), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct((n, pk.rows, hd), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((n, pk.rows, hd), v.dtype, vma=vma),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((hp, b, d), jnp.float32),
+            pltpu.VMEM((hp, b, d), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(qp, kp, vp, dop, lse_rep, delta_rep)
+    )(qp, kp, vp, dop, lse, delta)
 
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
 
-def _flash_bwd_scan(causal, scale, block_q, block_k, interpret, res, do):
+def _flash_bwd_scan(heads, causal, scale, block, interpret, res, do):
     """Blockwise flash backward (pure JAX): lax.scan over K blocks.
 
     With p = exp(s − lse):  dv = pᵀ·do;  ds = p ⊙ (do·vᵀ − D) where
@@ -434,10 +594,18 @@ def _flash_bwd_scan(causal, scale, block_q, block_k, interpret, res, do):
     Peak memory is O(T·block_k) per (b,h) — no [T, T] residual. Kept as
     the independent reference implementation for the Mosaic backward.
     """
-    q, k, v, out, lse = res
-    bh, tq, d = q.shape
+    n, tq, hd = res[0].shape
+    d = hd // heads
+
+    def bhtd(x):  # [n, t, heads·d] -> [n·heads, t, d]
+        return x.reshape(n, -1, heads, d).transpose(0, 2, 1, 3).reshape(n * heads, -1, d)
+
+    q, k, v, out, do = (bhtd(x) for x in (*res[:4], do))
+    bh = n * heads
     tk = k.shape[1]
-    bk = min(block_k, _ceil_to(tk, 8))
+    bk = min(block or _pick_block(tk), _ceil_to(tk, 8))
+    # the kernels' rows, flat: [n, heads, major, 8, sub·b] -> [bh, tq]
+    lse = res[4][:, :, :, 0].reshape(bh, -1)[:, :tq]
     tk_p = _ceil_to(tk, bk)
     nkb = tk_p // bk
 
@@ -479,10 +647,39 @@ def _flash_bwd_scan(causal, scale, block_q, block_k, interpret, res, do):
     )
     dk = dk_blocks.transpose(1, 0, 2, 3).reshape(bh, tk_p, d)[:, :tk]
     dv = dv_blocks.transpose(1, 0, 2, 3).reshape(bh, tk_p, d)[:, :tk]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+    def packed(x, like):  # back to [n, t, heads·d]
+        x = x.reshape(n, heads, -1, d).transpose(0, 2, 1, 3)
+        return x.reshape(n, -1, hd).astype(like.dtype)
+
+    return tuple(packed(x, like) for x, like in zip((dq, dk, dv), res[:3]))
 
 
-_flash_attention_bhtd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+_flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+_flash_qkv_attention.defvjp(_flash_qkv_fwd_rule, _flash_qkv_bwd_rule)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("causal", "scale", "block", "interpret")
+)
+def _attention_core(q, k, v, *, causal, scale, block, interpret):
+    """The kernels over BTHD operands. Jitted so that the layers of a
+    model, which call it with one signature, trace and lower it once."""
+    b, tq, h, d = q.shape
+    if heads_per_program(h, d):
+        # heads side by side on the lanes, as the projections wrote them
+        heads = h
+        pack = lambda x: x.reshape(b, -1, h * d)
+        unpack = lambda x: x.reshape(b, -1, h, d)
+    else:
+        heads = 1
+        pack = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, -1, d)
+        unpack = lambda x: x.reshape(b, h, -1, d).transpose(0, 2, 1, 3)
+    out = _flash_attention(
+        pack(q), pack(k), pack(v), heads, causal, scale, block, interpret
+    )
+    return unpack(out)
 
 
 def flash_attention(
@@ -492,8 +689,7 @@ def flash_attention(
     *,
     causal: bool = False,
     scale: Optional[float] = None,
-    block_q: int = 512,
-    block_k: int = 1024,
+    block: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Flash attention over BTHD ``[batch, seq, heads, head_dim]`` tensors.
@@ -502,6 +698,8 @@ def flash_attention(
     ``impl='xla'``): same signature, same output, O(T·d) memory. For
     causal use, query and key lengths must match (self-attention).
 
+    The block size is the kernel's own choice from the lengths
+    (``_pick_block``); ``block`` overrides it for tests and sweeps.
     ``interpret=None`` auto-selects: compiled Mosaic kernel on TPU,
     Pallas interpreter elsewhere (so tests on the CPU mesh run the same
     kernel code).
@@ -512,15 +710,44 @@ def flash_attention(
         raise ValueError("causal flash attention requires equal q/k lengths")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    scale = scale if scale is not None else q.shape[-1] ** -0.5
-
-    b, tq, h, d = q.shape
-    tk = k.shape[1]
-    # BTHD -> BHTD, fold (b, h) into one grid axis.
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
-    out = _flash_attention_bhtd(
-        qt, kt, vt, causal, float(scale), block_q, block_k, interpret
+    scale = float(scale if scale is not None else q.shape[-1] ** -0.5)
+    return _attention_core(
+        q, k, v, causal=causal, scale=scale, block=block, interpret=interpret
     )
-    return out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("num_heads", "causal", "scale", "block", "interpret")
+)
+def _qkv_attention_core(qkv, *, num_heads, causal, scale, block, interpret):
+    return _flash_qkv_attention(qkv, num_heads, causal, scale, block, interpret)
+
+
+def flash_qkv_attention(
+    qkv: jnp.ndarray,
+    num_heads: int,
+    *,
+    causal: bool = False,
+    scale: Optional[float] = None,
+    block: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> jnp.ndarray:
+    """Self-attention over a packed ``[B, T, 3·H·d]`` QKV tensor, read
+    where the fused projection wrote it; returns ``[B, T, H·d]``, the
+    output projection's input. Column order is that of
+    ``qkv.reshape(B, T, 3, H, d)``, as in ``flash_packed.
+    fused_qkv_attention``, so the paths share parameters. For head
+    widths whose blocks tile the lanes (``heads_per_program``); others
+    go through :func:`flash_attention`."""
+    if qkv.ndim != 3 or qkv.shape[2] % (3 * num_heads):
+        raise ValueError(f"expected packed [B, T, 3*{num_heads}*d], got {qkv.shape}")
+    d = qkv.shape[2] // (3 * num_heads)
+    if not heads_per_program(num_heads, d):
+        raise ValueError(f"{num_heads} heads of width {d} do not tile the lanes")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _qkv_attention_core(
+        qkv, num_heads=num_heads, causal=causal,
+        scale=float(scale if scale is not None else d**-0.5),
+        block=block, interpret=interpret,
+    )

@@ -3,8 +3,8 @@
 The streaming flash kernel (``ops/pallas/flash.py``) wins at long T where
 the ``[T, T]`` score matrix cannot live on-chip; at ViT's T=197 it was
 measured *slower* than XLA (PROFILE.md): block padding dominates and the
-BTHD transposes it needs around the custom call cost more than the
-kernel saves. The XLA einsum path is not good either — the round-3
+BTHD transposes it then needed around the custom call cost more than
+the kernel saved. The XLA einsum path is not good either — the round-3
 trace showed ~165 ms of a 275 ms ViT-B/16 step inside attention: the
 ``[B, H, T, T]`` f32 score tensors in HBM, einsums running at 20-40
 TFLOP/s (T=197 pads badly onto (8, 128) tiles, d=64 half-fills the MXU
@@ -38,8 +38,9 @@ This kernel removes all three at once by changing the *boundary*:
   dq/dk/dv into VMEM scratch, parts 0/1/2 store them — no XLA concat.
 
 Used automatically by ``models/vit.py`` (``attn_impl="auto"``) for
-T ≤ ``MAX_T`` on TPU; the long-T streaming kernel and the XLA einsum
-remain the other regimes' implementations (``ops/attention.py``).
+T ≤ ``MAX_T`` on TPU; the streaming kernel (``flash.py``, from
+``flash.MIN_T`` on) and the XLA einsum are the other regimes'
+implementations (``ops/attention.py``).
 """
 
 from __future__ import annotations

@@ -126,8 +126,11 @@ def adapt_model(model, engine: str, mesh: Mesh, config: TrainConfig):
             num_stages=stages,
             n_layers=n_layers,
             dtype=model.dtype,
-            # ring is the SP impl; inside a stage plain attention applies
-            attn_impl="xla" if model.attn_impl == "ring" else model.attn_impl,
+            # ring is the SP impl, and "auto" has not been measured inside
+            # a stage's scan: plain attention unless a kernel is asked for
+            attn_impl=(
+                "xla" if model.attn_impl in ("ring", "auto") else model.attn_impl
+            ),
             dropout=model.dropout,
             remat=model.remat,
         )

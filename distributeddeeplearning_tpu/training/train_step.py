@@ -226,8 +226,10 @@ def _pallas_interpreted(model) -> bool:
     its own error message recommends check_vma=False), so the engines
     drop the check for exactly this case. The compiled TPU path keeps
     checking on — verified on hardware. Covers both explicit kernel
-    impls ("pallas" = streaming flash, "fused" = packed small-T); "auto"
-    resolves to "xla" off-TPU (models/vit.py) and needs no exception."""
+    impls ("pallas" = streaming flash, "fused" = packed small-T); "auto",
+    the models' default, takes either kernel on a TPU alone and resolves
+    to "xla" off it (models/vit.Attention._resolve_impl), so it needs no
+    exception."""
     import os
 
     uses_pallas = getattr(model, "attn_impl", None) in ("pallas", "fused") or (

@@ -2,7 +2,8 @@
 
 The long-context counterpart of the ImageNet examples: same engine, same
 launcher, per-token cross-entropy, causal attention through the
-configurable impl (``ATTN_IMPL=pallas`` runs the flash kernel).
+configurable impl (on a TPU the default ``auto`` runs the flash kernel
+from 640 tokens on; ``ATTN_IMPL=xla|pallas`` forces a path).
 
 Run locally (CPU mesh smoke)::
 

@@ -145,7 +145,10 @@ def device_report(capture_dir: str, groups, profile=None, tables=None) -> dict:
             profile.ops, profile.modules, program, scopes, groups
         )
         if by is not None:
-            out["programs"].append(dict(by, program=program))
+            out["programs"].append(dict(
+                by, program=program,
+                kernel_calls=programs.kernel_calls_by_group(scopes, groups),
+            ))
     for dev, ops in sorted(profile.ops.items()):
         gaps = programs.idle_gaps_by_span(ops, profile.host)
         window = (max(e[2] for e in ops) - min(e[1] for e in ops)) / 1e9
@@ -183,6 +186,9 @@ def render_device(rep: dict) -> str:
             f"{100 * by['unscoped_s'] / total:>7.1f}%   "
             + ", ".join(f"{k} {1e3 * v / n:.3f}" for k, v in by["unscoped_top"][:5])
         )
+        add("    Mosaic kernels in the program, by group: " + (", ".join(
+            f"{g} {k}" for g, k in sorted(by.get("kernel_calls", {}).items())
+        ) or "none"))
     for idle in rep["idle"]:
         gaps = idle["gaps"]
         add(
@@ -193,6 +199,21 @@ def render_device(rep: dict) -> str:
             ) or "no gap")
         )
     return "\n".join(out)
+
+
+def attention_paths(events) -> str:
+    """What ``models/vit.Attention`` chose for its core each time a
+    program was traced (counters ``attn.impl.<path>``), one line."""
+    chosen: dict = {}
+    for e in events:
+        name = str(e.get("name", ""))
+        if e.get("kind") == "counter" and name.startswith("attn.impl."):
+            shape = (e.get("labels") or {}).get("shape")
+            key = (name[len("attn.impl."):], tuple(shape or ()))
+            chosen[key] = chosen.get(key, 0) + int(e.get("value", 1))
+    return ", ".join(
+        f"{path} x{n} at {list(shape)}" for (path, shape), n in sorted(chosen.items())
+    )
 
 
 def find_captures(paths: List[str]) -> List[str]:
@@ -257,6 +278,9 @@ def main(argv=None) -> int:
         )
     else:
         print(render(recon, training, args.top))
+    paths_chosen = attention_paths(loaded["events"])
+    if paths_chosen:
+        print("attention core, as chosen at trace time: " + paths_chosen)
     for d in devices:
         print()
         print(render_device(d))

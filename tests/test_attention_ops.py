@@ -117,32 +117,140 @@ def test_unknown_impl_raises():
         dot_product_attention(q, k, v, impl="nope")
 
 
+def _packed(seed, n, t, heads, d):
+    """The kernels' own operand layout: ``[n, t, heads·d]``."""
+    rng = np.random.RandomState(seed)
+    return jnp.asarray(rng.randn(n, t, heads * d).astype(np.float32))
+
+
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_bwd_kernels_match_scan_reference(causal):
-    """The Mosaic backward kernels (dq; dk/dv — round 3) against the
-    kept pure-JAX scan backward they replaced, on ragged lengths so the
-    q/k padding masks are exercised."""
+@pytest.mark.parametrize(
+    "t,heads,d,block",
+    [
+        (70, 2, 8, 32),  # three ragged 32-blocks: q and k padding
+        (300, 2, 64, None),  # GPT-2's head width, the rule's own block:
+        # two heads a program, three 128-blocks on both axes
+    ],
+)
+def test_flash_bwd_kernels_match_scan_reference(causal, t, heads, d, block):
+    """The Mosaic backward kernels (dq; dk/dv on transposed tiles, the
+    statistics as rows) against the kept pure-JAX scan backward they
+    replaced, on ragged lengths that span several blocks on both axes,
+    so the causal skip and the padding masks are exercised."""
     from distributeddeeplearning_tpu.ops.pallas.flash import (
         _flash,
         _flash_bwd_rule,
         _flash_bwd_scan,
     )
 
-    rng = np.random.RandomState(3)
-    bh, t, d = 2, 70, 8  # t=70: two ragged 64-blocks with padding
-    q, k, v = (
-        jnp.asarray(rng.randn(bh, t, d).astype(np.float32)) for _ in range(3)
-    )
+    q, k, v, do = (_packed(seed, 2, t, heads, d) for seed in (3, 4, 5, 6))
     scale = d**-0.5
-    out, lse = _flash(q, k, v, causal, scale, 64, 64, True)
-    res = (q, k, v, out[:, :t], lse[:, :t])
-    do = jnp.asarray(rng.randn(bh, t, d).astype(np.float32))
-    got = _flash_bwd_rule(causal, scale, 64, 64, True, res, do)
-    ref = _flash_bwd_scan(causal, scale, 64, 64, True, res, do)
+    out, lse = _flash(q, k, v, heads, causal, scale, block, True)
+    res = (q, k, v, out, lse)
+    got = _flash_bwd_rule(heads, causal, scale, block, True, res, do)
+    ref = _flash_bwd_scan(heads, causal, scale, block, True, res, do)
     for name, a, b in zip(("dq", "dk", "dv"), got, ref):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=2e-4, err_msg=name
         )
+
+
+@pytest.mark.parametrize("tile_elems", [None, 2 * 128 * 128])
+def test_flash_matches_xla_at_gpt2_head_width(monkeypatch, tile_elems):
+    """Forward and gradients against the XLA path, causal, d = 64 (two
+    heads share a program's 128 lanes), T = 300: three 128-row blocks on
+    both axes, the last one padded. With a small tile budget the walked
+    operand lies in two resident blocks, so the accumulators cross grid
+    steps and whole resident blocks above the diagonal are skipped."""
+    from distributeddeeplearning_tpu.ops.pallas import flash
+
+    if tile_elems:
+        monkeypatch.setattr(flash, "_TILE_ELEMS", tile_elems)
+        assert flash._plan(300, 128) == (128, 2, 2)
+    q, k, v = _qkv(b=2, t=300, h=2, d=64, seed=1)
+    w = _qkv(b=2, t=300, h=2, d=64, seed=2)[0]
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    kernel = lambda q, k, v: flash_attention(q, k, v, causal=True)
+    xla = lambda q, k, v: dot_product_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(
+        np.asarray(kernel(q, k, v)), np.asarray(xla(q, k, v)), atol=1e-5
+    )
+    g_kernel = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+    g_xla = jax.grad(loss(xla), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), g_kernel, g_xla):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=1e-4, err_msg=name
+        )
+
+
+@pytest.mark.parametrize(
+    "b,t,h,d,causal",
+    [
+        (1, 200, 4, 32, False),  # four heads a program; keys' padding masked
+        (1, 100, 3, 24, True),  # heads do not tile the lanes: transposed
+        (1, 96, 2, 128, True),  # one head a program
+    ],
+)
+def test_flash_head_layouts_match_xla(b, t, h, d, causal):
+    q, k, v = _qkv(b=b, t=t, h=h, d=d, seed=7)
+    ref = dot_product_attention(q, k, v, causal=causal, impl="xla")
+    out = flash_attention(q, k, v, causal=causal, block=32)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "b,t,h,d,causal,block",
+    [
+        (2, 300, 4, 64, True, None),  # GPT-2's head width, three 128-blocks
+        (1, 100, 4, 32, False, 32),  # four heads a program, padded keys
+        (1, 96, 2, 128, True, 32),  # one head a program
+    ],
+)
+def test_flash_qkv_reads_the_packed_projection_in_place(b, t, h, d, causal, block):
+    """``flash_qkv_attention`` over ``[B, T, 3·H·d]`` (q, k and v as
+    thirds of one array, by block index alone) against the XLA path on
+    the slices, forward and gradient."""
+    from distributeddeeplearning_tpu.ops.pallas.flash import flash_qkv_attention
+
+    rng = np.random.RandomState(11)
+    qkv = jnp.asarray(rng.randn(b, t, 3 * h * d).astype(np.float32))
+    w = jnp.asarray(rng.randn(b, t, h * d).astype(np.float32))
+    kernel = lambda x: flash_qkv_attention(x, h, causal=causal, block=block)
+    ref = lambda x: _packed_ref(x, h, causal)
+    np.testing.assert_allclose(
+        np.asarray(kernel(qkv)), np.asarray(ref(qkv)), atol=1e-5
+    )
+    g_kernel = jax.grad(lambda x: jnp.sum(kernel(x) * w))(qkv)
+    g_ref = jax.grad(lambda x: jnp.sum(ref(x) * w))(qkv)
+    np.testing.assert_allclose(np.asarray(g_kernel), np.asarray(g_ref), atol=1e-4)
+    with pytest.raises(ValueError):
+        flash_qkv_attention(jnp.zeros((1, 64, 3 * 2 * 96)), 2)  # d = 96
+
+
+@pytest.mark.parametrize(
+    "t,block", [(1024, 512), (768, 256), (640, 128), (2048, 512), (8192, 512), (100, 128)]
+)
+def test_flash_block_rule(t, block):
+    from distributeddeeplearning_tpu.ops.pallas.flash import _pick_block
+
+    assert _pick_block(t) == block
+
+
+@pytest.mark.parametrize(
+    "t,h,d,ok",
+    [
+        (1024, 12, 64, True), (640, 12, 64, True), (639, 12, 64, False),
+        (513, 12, 64, False), (512, 12, 64, False), (1024, 8, 96, False),
+        (1024, 4, 128, True), (1024, 3, 64, False), (8192, 8, 64, True),
+    ],
+)
+def test_flash_supports_gating(t, h, d, ok):
+    from distributeddeeplearning_tpu.ops.pallas import flash
+
+    assert flash.supports(t, h, d) is ok
 
 
 # ---- packed small-T kernel (ops/pallas/flash_packed.py) ----

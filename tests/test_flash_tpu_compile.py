@@ -1,0 +1,93 @@
+"""The flash kernels through the TPU's own compiler, with no chip: the
+compiler is installed with libtpu and compiles for a v5e that is
+described and not attached. What interpret mode cannot show is shown
+here: that Mosaic takes the kernels at the widths the benchmark runs,
+and that a model left at its defaults reaches them, under the scope
+the device metrics read. Nothing runs, so nothing here is a speed.
+
+One file, and the topology inside a fixture: only the worker that is
+given this file loads the TPU's library.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distributeddeeplearning_tpu import obs
+from distributeddeeplearning_tpu.models import get_model
+from distributeddeeplearning_tpu.models.transformer_lm import TRAIN_STEP_GROUPS
+from distributeddeeplearning_tpu.obs import programs
+from distributeddeeplearning_tpu.ops.pallas.flash import flash_attention
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize(
+    "shape,causal",
+    [
+        ((4, 1024, 12, 64), True),  # GPT-2 124M: two heads a program, b = 512
+        ((4, 768, 12, 64), True),  # b = 256, three blocks
+        ((2, 1000, 12, 64), False),  # the keys' padding masked
+        ((2, 1024, 4, 128), True),  # one head a program
+        ((1, 8192, 8, 64), True),  # resident blocks stream along the grid
+        ((2, 1024, 8, 96), True),  # head blocks off the lanes: transposed
+    ],
+)
+def test_mosaic_takes_the_kernels(one_chip, shape, causal):
+    arg = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=causal, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(arg, arg, arg).compile()
+    # forward, dq, dk/dv
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_a_default_lm_reaches_the_kernel_under_attn_core(one_chip, monkeypatch):
+    """``get_model`` with no ``attn_impl``, a sequence the rule takes, the
+    backend query answered as on the chip: every layer's attention core
+    is three Pallas kernels, they stand under ``attn_core`` in the
+    compiled program's scope table (forward and backward: the custom
+    VJP keeps the scope), and the trace counted what it chose."""
+    seq = 640
+    model = get_model("lm_tiny", num_classes=256, max_seq_len=seq, dtype="bfloat16")
+    tokens = jnp.zeros((2, seq), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens, train=False)["params"]
+    )
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip), params
+    )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    obs.reset()
+
+    def loss(params, tokens):
+        logits = model.apply({"params": params}, tokens, train=True)
+        return jnp.sum(logits.astype(jnp.float32))
+
+    tok = jax.ShapeDtypeStruct(tokens.shape, tokens.dtype, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss)).lower(params, tok).compile()
+    totals = obs.get_bus().totals()
+    obs.reset()
+    layers = 2  # lm_tiny
+    assert totals["attn.impl.pallas"]["count"] == layers
+    assert not any(k.startswith("attn.impl.") and k != "attn.impl.pallas" for k in totals)
+    scopes = programs.parse_hlo_scopes(compiled.as_text())
+    assert programs.kernel_calls_by_group(scopes, TRAIN_STEP_GROUPS) == {
+        "attn_core": 3 * layers
+    }
+    kernels = [p for p in scopes.values() if p.endswith("/" + programs.KERNEL_CALL)]
+    assert sum(programs.BACKWARD in p for p in kernels) == 2 * layers

@@ -498,6 +498,7 @@ def test_trace_report_prints_device_time_by_scope_and_idle_gaps_by_span():
     assert "program jit_local_step on 1 device(s): 2 run(s), 10.00 ms a run" in text
     assert "attn_core" in text and "unscoped" in text and "copy.7" in text
     assert "data.stage_wait 0.0100" in text
+    assert "Mosaic kernels in the program, by group: none" in text
     # with no table beside the capture the gaps are still named
     bare = report.render_device(report.device_report("capture", groups, profile, {}))
     assert "no scope table" in bare and "data.stage_wait" in bare
@@ -521,3 +522,44 @@ def test_trace_controller_leaves_the_scope_tables_beside_a_capture(tmp_path, cap
     assert report.main([str(tmp_path)]) == 0  # a bare capture directory
     out = capsys.readouterr().out
     assert "no device plane" in out and "step" in out
+
+
+def test_the_resolved_attention_path_is_counted_at_trace_time_and_reported():
+    """``Attention._resolve_impl`` counts what it chose, once a trace:
+    ``bus.totals()`` holds it, the event carries the shape, and ``make
+    trace-report`` prints it from a run's event files."""
+    from distributeddeeplearning_tpu.models.vit import Attention
+
+    attn = Attention(4, jnp.float32, "auto", causal=True)
+    x = jnp.zeros((2, 16, 32), jnp.float32)
+    variables = attn.init(jax.random.PRNGKey(0), x, False)
+    obs.reset()
+    jax.jit(lambda v, x: attn.apply(v, x, False))(variables, x)
+    totals = obs.get_bus().totals()
+    assert totals["attn.impl.xla"] == {"kind": "counter", "count": 1, "sum": 1.0}
+    (event,) = [e for e in obs.get_bus().ring if e["name"] == "attn.impl.xla"]
+    assert event["labels"] == {"asked": "auto", "shape": [2, 16, 32], "heads": 4}
+    line = _trace_report().attention_paths([event, dict(event)])
+    assert line == "xla x2 at [2, 16, 32]"
+
+
+KERNEL_HLO = """ENTRY %main () -> f32[4] {
+  %_attention_core.6 = f32[4]{0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(local_step)/transpose(jvp(TransformerLM))/block0/attn/attn_core/jit(_attention_core)/pallas_call"}
+  %copy.8 = f32[4]{0} copy(%_attention_core.6), metadata={op_name="jit(local_step)/transpose(jvp(TransformerLM))/block0/attn/attn_core/jit(_attention_core)/pallas_call"}
+  %custom-call.2 = f32[4]{0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(local_step)/fused_grads/pallas_call"}
+  %custom-call.3 = f32[4]{0} custom-call(%a), custom_call_target="Sharding", metadata={op_name="jit(local_step)/jvp(TransformerLM)/block0/mlp/sharding_constraint"}
+}"""
+
+
+def test_kernel_calls_by_group_counts_the_mosaic_custom_calls_alone():
+    """A kernel's copies and tuple elements share its ``op_name``; the
+    parser marks the custom call itself, and the mark rides through the
+    dump that ``scripts/trace_report.py`` reads."""
+    scopes = programs.parse_hlo_scopes(KERNEL_HLO)
+    assert scopes["_attention_core.6"].endswith("/pallas_call/" + programs.KERNEL_CALL)
+    assert scopes["copy.8"].endswith("/pallas_call")
+    assert programs.kernel_calls_by_group(scopes, TRAIN_STEP_GROUPS) == {
+        "attn_core": 1, programs.UNSCOPED: 1,
+    }
+    assert programs.group_of(scopes["_attention_core.6"], TRAIN_STEP_GROUPS) == "attn_core"
+    assert programs.BACKWARD in scopes["_attention_core.6"]

@@ -154,3 +154,102 @@ def test_lm_trains_through_keras_frontend(mesh8):
     result = m.fit(data, epochs=1)
     assert np.isfinite(result.history[-1]["loss"])
     assert int(m.state.step) == 2  # 32/(2*8)
+
+
+# ---- the attention core's lowering, chosen from shape and platform ---------
+# (models/vit.Attention._resolve_impl; the counter `attn.impl.<path>` says
+# what a trace chose)
+
+
+def _chosen(monkeypatch, *, backend="tpu", devices=1, sharded=False, t=1024,
+            heads=12, d=64, decode=False, init=False, asked="auto"):
+    """Paths that one Attention call resolved to, with the backend query
+    answered as a platform would: nothing runs, the call is only traced."""
+    from jax.sharding import PartitionSpec as P
+
+    from distributeddeeplearning_tpu import obs
+    from distributeddeeplearning_tpu.models.vit import Attention
+    from distributeddeeplearning_tpu.parallel.mesh import data_parallel_mesh
+
+    attn = Attention(heads, jnp.bfloat16, asked, causal=True, decode=decode)
+    x = jax.ShapeDtypeStruct((8, t, heads * d), jnp.bfloat16)
+    init_fn = lambda x: attn.init(jax.random.PRNGKey(0), x, False)  # noqa: E731
+    variables = jax.eval_shape(init_fn, x)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+    obs.reset()
+    if init:  # a new function: the first one's trace is cached
+        jax.eval_shape(lambda x: attn.init(jax.random.PRNGKey(0), x, False), x)
+    else:
+        call = lambda v, x: attn.apply(v, x, False, mutable=["cache"])  # noqa: E731
+        if sharded:
+            call = jax.shard_map(
+                call, mesh=data_parallel_mesh(8),
+                in_specs=(P(), P("data")), out_specs=P("data"),
+            )
+        jax.eval_shape(call, variables, x)
+    totals = obs.get_bus().totals()
+    obs.reset()
+    return sorted(k[len("attn.impl."):] for k in totals if k.startswith("attn.impl."))
+
+
+@pytest.mark.parametrize(
+    "case,path",
+    [
+        (dict(t=1024), "pallas"),  # GPT-2's shape in the benchmark's cell
+        (dict(t=640), "pallas"),  # the rule's lower edge, as measured
+        (dict(t=639), "xla"),
+        (dict(t=513), "xla"),  # past the packed kernel, short of the flash one
+        (dict(t=512), "fused"),  # the packed small-T kernel's last length
+        (dict(t=197, heads=12, d=64), "fused"),  # ViT-B/16
+        (dict(t=1024, heads=8, d=96), "xla"),  # head blocks do not tile the lanes
+        (dict(t=1024, heads=4, d=128), "pallas"),
+        (dict(t=1024, backend="cpu"), "xla"),  # off the TPU
+        (dict(t=1024, backend="gpu"), "xla"),
+        (dict(t=1024, devices=8), "xla"),  # pjit engine: operands not local
+        (dict(t=1024, devices=8, sharded=True), "pallas"),  # dp engine: shard_map
+        (dict(t=1024, init=True), "xla"),  # the weight draw lowers no kernel
+        (dict(t=1024, decode=True), None),  # serving never asks the resolver
+        (dict(t=1024, asked="xla"), "xla"),  # explicit values force a path
+        (dict(t=1024, asked="pallas", backend="cpu"), "pallas"),
+        (dict(t=64, asked="pallas"), "pallas"),
+    ],
+)
+def test_attention_resolver_table(monkeypatch, case, path):
+    assert _chosen(monkeypatch, **case) == ([path] if path else [])
+
+
+def test_lm_auto_equals_xla_off_tpu():
+    """Off the TPU the default resolves to the einsum: logits and
+    gradients equal the explicit-xla build's bit for bit."""
+    tokens, _ = _batch(4)
+    m_auto, m_xla = _model("auto"), _model("xla")
+    variables = m_xla.init(jax.random.PRNGKey(0), tokens[:1], train=False)
+
+    def loss(model):
+        return lambda v: jnp.sum(model.apply(v, tokens, train=False) ** 2)
+
+    np.testing.assert_array_equal(
+        np.asarray(m_auto.apply(variables, tokens, train=False)),
+        np.asarray(m_xla.apply(variables, tokens, train=False)),
+    )
+    g_auto, g_xla = jax.grad(loss(m_auto))(variables), jax.grad(loss(m_xla))(variables)
+    for a, b in zip(jax.tree.leaves(g_auto), jax.tree.leaves(g_xla)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("name", ["lm_base", "lm_moe_tiny", "vit_b16"])
+def test_default_config_carries_the_resolving_default(name):
+    """``TrainConfig()`` untouched -> ``get_model``: the path every
+    front-end and the benchmark take carries ``"auto"``; ``ATTN_IMPL``
+    still overrides, and the pipeline engine keeps the einsum."""
+    from distributeddeeplearning_tpu.models import available_models
+
+    if name not in available_models():
+        pytest.skip(f"{name} is not registered")
+    config = TrainConfig()
+    assert config.attn_impl == "auto"
+    assert get_model(name, **config.model_kwargs()).attn_impl == "auto"
+    assert get_model(name).attn_impl == "auto"
+    forced = TrainConfig.from_env({"ATTN_IMPL": "xla"})
+    assert get_model(name, **forced.model_kwargs()).attn_impl == "xla"
