@@ -71,6 +71,18 @@ class TrainConfig:
     # stages): recompute activations in backward — O(depth) memory.
     remat: bool = False
 
+    # Training objective of a token model. "next_token": the dataset's
+    # (tokens, labels) batches as they are. "block_diffusion" (BD3-LM's
+    # masked objective; models with a block-diffusion mask, models/
+    # decoder.py): the staging noises each clean row by blocks of
+    # `diffusion_block` tokens at a level t ~ U(`diffusion_t_min`, 1) a
+    # block and hands the step ([noised ‖ clean], targets, weights 1/t)
+    # (data/noise.py). `mask_token_id` None = the vocabulary's last id.
+    objective: str = "next_token"
+    diffusion_block: int = 4
+    diffusion_t_min: float = 0.125
+    mask_token_id: Optional[int] = None
+
     # Optimization — reference constants: LR 0.001 × world size
     # (TF :154, PyTorch :333), momentum 0.9, L2 5e-5 (Keras :97-116),
     # warmup 5 epochs + ×0.1 decay @30/60/80 (Keras :211-224, arXiv:1706.02677).
@@ -343,6 +355,14 @@ class TrainConfig:
             kw["attn_impl"] = e["ATTN_IMPL"]
         if "MOE_EXPERTS" in e:
             kw["moe_experts"] = int(e["MOE_EXPERTS"])
+        if "OBJECTIVE" in e:
+            kw["objective"] = e["OBJECTIVE"]
+        if "DIFFUSION_BLOCK" in e:
+            kw["diffusion_block"] = int(e["DIFFUSION_BLOCK"])
+        if "DIFFUSION_T_MIN" in e:
+            kw["diffusion_t_min"] = float(e["DIFFUSION_T_MIN"])
+        if "MASK_TOKEN_ID" in e:
+            kw["mask_token_id"] = int(e["MASK_TOKEN_ID"])
         if "REMAT" in e:
             kw["remat"] = _str_to_bool(e["REMAT"])
         if "DATA_FORMAT" in e:

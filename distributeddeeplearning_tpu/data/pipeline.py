@@ -15,7 +15,7 @@ from __future__ import annotations
 import collections
 import queue
 import threading
-from typing import Any, Iterable, Iterator, Optional
+from typing import Any, Callable, Iterable, Iterator, Optional
 
 import jax
 from jax.sharding import Mesh, NamedSharding
@@ -58,6 +58,7 @@ def prefetch_to_device(
     *,
     size: int = 2,
     sharding: Optional[NamedSharding] = None,
+    transform: Optional[Callable[[PyTree], PyTree]] = None,
 ) -> Iterator[PyTree]:
     """Asynchronously stage batches onto the mesh, ``size`` deep.
 
@@ -71,6 +72,10 @@ def prefetch_to_device(
     pytree), resolved per batch — engines whose staging layout depends on
     the batch arity (SP: eval weights shard differently) use this.
 
+    ``transform`` is a host transform of the job's objective (``data/
+    noise.py``: block-diffusion noising), applied to each batch on the
+    staging thread before it is placed; it emits its own span.
+
     Emits, a batch: span ``data.stage`` round the placement and counter
     ``data.h2d_bytes`` (bytes of the host leaves placed) on the staging
     thread, span ``data.stage_wait`` round the consumer's ``q.get()``.
@@ -78,6 +83,8 @@ def prefetch_to_device(
     def stage(batch):
         # On the thread that does the placement (the producer's, when
         # there is one): its time, and the bytes it hands to the device.
+        if transform is not None:
+            batch = transform(batch)
         with obs.span("data.stage"):
             staged = shard_batch(
                 batch, mesh, sharding(batch) if callable(sharding) else sharding

@@ -27,7 +27,12 @@ import optax
 from distributeddeeplearning_tpu import obs
 from distributeddeeplearning_tpu.config import TrainConfig
 from distributeddeeplearning_tpu.data.pipeline import prefetch_to_device
-from distributeddeeplearning_tpu.training.metrics import dispatch_step, log_sync
+from distributeddeeplearning_tpu.data.noise import objective_transform
+from distributeddeeplearning_tpu.training.metrics import (
+    METRIC_KEYS,
+    dispatch_step,
+    log_sync,
+)
 from distributeddeeplearning_tpu.training.optimizer import create_optimizer
 from distributeddeeplearning_tpu.training.state import TrainState
 from distributeddeeplearning_tpu.utils.logging import get_logger
@@ -47,6 +52,9 @@ class Pieces:
     lr_schedule: optax.Schedule
     # Per-batch staging-sharding resolver (None → default over `data`).
     batch_sharding: Optional[Callable] = None
+    # The objective's host transform of a batch, applied by the staging
+    # (None → batches are placed as the dataset gives them).
+    batch_transform: Optional[Callable] = None
 
 
 def setup(
@@ -90,6 +98,7 @@ def setup(
         eval_step=eng.eval_step,
         lr_schedule=schedule,
         batch_sharding=eng.batch_sharding,
+        batch_transform=objective_transform(config),
     )
     return pieces, eng.state
 
@@ -112,7 +121,7 @@ def train_epoch(
     for i, batch in enumerate(
         prefetch_to_device(
             data.epoch(epoch), pieces.mesh, size=cfg.prefetch_batches,
-            sharding=pieces.batch_sharding,
+            sharding=pieces.batch_sharding, transform=pieces.batch_transform,
         )
     ):
         state, metrics = dispatch_step(
@@ -120,7 +129,15 @@ def train_epoch(
         )
         if log_every and (i + 1) % log_every == 0:
             with log_sync(epoch=epoch):
-                loss = float(jax.device_get(metrics["loss"]))
+                # one read-back: the loss and whatever the step reports
+                # beside the metric contract (an expert layer's counts)
+                read = jax.device_get(
+                    {k: v for k, v in metrics.items()
+                     if k == "loss" or k not in METRIC_KEYS}
+                )
+            loss = float(read.pop("loss"))
+            for name, value in read.items():
+                obs.counter(name, float(value), epoch=epoch)
             log.info(
                 "step %d loss=%.4f elapsed=%.2fs", i + 1, loss, timer.elapsed,
                 extra={"epoch": epoch},

@@ -12,6 +12,7 @@ from typing import Any, Callable, Dict
 
 import jax.numpy as jnp
 
+from distributeddeeplearning_tpu.models import decoder as _decoder_specs
 from distributeddeeplearning_tpu.models.efficientnet import EfficientNet
 from distributeddeeplearning_tpu.models.resnet import (
     ResNet,
@@ -130,6 +131,19 @@ for _v in ("tiny", "small", "base", "large"):
                 moe_experts=moe_experts, **kw)))(_v),
         attention=True,
         moe=True,
+        remat=True,
+    )
+
+# Decoders built from a layer spec (models/decoder.py): keyword
+# arguments beyond the usual ones state the run's share of the spec
+# (`layers`, `experts_held`, `first_expert`).
+for _name in _decoder_specs.SPECS:
+    register_model(
+        _name,
+        (lambda n: (lambda num_classes=32_000, dtype=jnp.bfloat16, **kw:
+                    _decoder_specs.build(
+                        n, num_classes=num_classes, dtype=dtype, **kw)))(_name),
+        attention=True,
         remat=True,
     )
 

@@ -61,10 +61,15 @@ HEAD = "head"
 # (training/overlap.OVERLAP_SCOPE) are the step's own scopes
 # (training/train_step.py). An operation in none of them reads as
 # unscoped: a new part of the model gets a name, not a wider pattern.
+# `ragged-dot-*`: XLA:TPU lowers `lax.ragged_dot` to Mosaic calls of its
+# own and names them anew, path and all (`ragged-dot-none`, and
+# `ragged-dot-metadata` for the groups' tile tables); a step's only
+# ragged products are an expert FFN's (ops/moe.py), so they count there.
+RAGGED_DOT = ("ragged-dot-none", "ragged-dot-metadata")
 TRAIN_STEP_GROUPS = (
     ("attn_core", part(ATTN_CORE)),
     ("attn_proj", part("attn")),
-    ("mlp", part("mlp")),
+    ("mlp", part("mlp", *RAGGED_DOT)),
     ("head_loss", part(HEAD, "loss", "metrics")),
     ("optimizer", part("optimizer", "overlap_allreduce")),
     ("norm_residual", part(r"ln\w*", EMBED, RESIDUAL)),

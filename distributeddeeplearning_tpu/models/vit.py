@@ -55,6 +55,20 @@ from distributeddeeplearning_tpu.models.sharding import (  # noqa: F401
 )
 
 
+def kernel_is_safe(x, initializing: bool) -> bool:
+    """Whether a Pallas kernel may stand in this call's attention core
+    (the first half of ``Attention._resolve_impl``'s rule, shared with
+    ``models/decoder.py``): on a TPU, ``[B, T, D]`` operands that are
+    already local, and not while initializing."""
+    local = bool(getattr(jax.typeof(x), "vma", ())) or jax.device_count() == 1
+    return (
+        x.ndim == 3
+        and jax.default_backend() == "tpu"
+        and local
+        and not initializing
+    )
+
+
 class _FusedGradDense(nn.Dense):
     """``nn.Dense`` whose backward computes dW and db in ONE pass over
     the upstream gradient (``ops/pallas/fused_grads.bias_dense``) instead
@@ -473,13 +487,7 @@ class Attention(nn.Module):
             from distributeddeeplearning_tpu.ops.pallas import flash, flash_packed
 
             impl = "xla"
-            local = bool(getattr(jax.typeof(x), "vma", ())) or jax.device_count() == 1
-            if (
-                x.ndim == 3
-                and jax.default_backend() == "tpu"
-                and local
-                and not self.is_initializing()
-            ):
+            if kernel_is_safe(x, self.is_initializing()):
                 shape = (x.shape[1], self.num_heads, head_dim)
                 if flash_packed.supports(*shape):
                     impl = "fused"

@@ -88,6 +88,43 @@ def _vma(*arrays):
     return frozenset(out)
 
 
+class Mask(NamedTuple):
+    """Which keys a query sees, as the kernels take it. ``causal``: no
+    key after the query's own place; resident blocks above the diagonal
+    are neither computed nor fetched. ``gran`` and ``strict`` coarsen
+    the diagonal tile's rule to blocks of ``gran`` positions (the
+    block-diffusion objective, ``ops/attention.block_diffusion_
+    attention``): a query of block β sees the keys of blocks ≤ β, or
+    with ``strict`` of blocks < β alone. A kernel block is a multiple of
+    ``gran`` rows, so the tiles off the diagonal stay whole or skipped.
+    A row that sees no key at all (``strict``: the first ``gran`` rows)
+    comes out with a logsumexp of ``_NEG_INF``: its output is weightless
+    in the caller's merge, and its gradients are nought. ``own`` (not
+    with ``causal``): a query sees the keys of its own block of ``gran``
+    alone; a q block then meets the one k block of its own index, one
+    tile a program, and no other is computed or fetched."""
+
+    causal: bool = False
+    gran: int = 1
+    strict: bool = False
+    own: bool = False
+
+
+def _as_mask(causal) -> Mask:
+    return causal if isinstance(causal, Mask) else Mask(bool(causal))
+
+
+def _sees(rows, cols, mask: Mask):
+    """Where query ``rows`` see key ``cols`` (one origin) under a causal
+    or an own-block ``mask``."""
+    if mask.own:
+        return rows // mask.gran == cols // mask.gran
+    if mask.gran == 1 and not mask.strict:
+        return cols <= rows
+    first_unseen = rows // mask.gran * mask.gran
+    return cols < (first_unseen if mask.strict else first_unseen + mask.gran)
+
+
 # The shortest sequence for which `"auto"` takes these kernels over the
 # XLA einsum (`supports`). Measured on the v5e, a GPT-2 layer's attention
 # core forward + backward at 16 x 12 heads, d = 64, causal, device ms
@@ -207,13 +244,20 @@ def _to_cols(x):
     return jnp.tile(x, (_LANES // _SUBLANES, 1)).T
 
 
-def _walk_keys(step, i, g0, n_sub: int, causal: bool, kv_len: int, b: int):
+def _walk_keys(step, i, g0, n_sub: int, mask: Mask, kv_len: int, b: int):
     """For a q block ``i`` against the resident K/V block that starts at
     global block ``g0``: one call ``step(c, keep)`` for the tile of its
     first ``c`` sub-blocks, the live ones (a static branch a width).
     ``keep`` masks the tile (queries on rows) where its last sub-block
     is the diagonal one or, without ``causal``, holds the keys' padding;
     a resident block wholly below the diagonal gets none."""
+    if mask.own:  # the resident block is the q block's own, one tile
+        step(1, _sees(
+            lax.broadcasted_iota(jnp.int32, (b, b), 0),
+            lax.broadcasted_iota(jnp.int32, (b, b), 1), mask,
+        ))
+        return
+    causal = mask.causal
     edge = i if causal else kv_len // b  # the one block that needs a mask
     masked = bool(causal or kv_len % b)
     ahead = edge - g0  # unmasked sub-blocks of this resident block
@@ -234,7 +278,7 @@ def _walk_keys(step, i, g0, n_sub: int, causal: bool, kv_len: int, b: int):
                 rows = lax.broadcasted_iota(jnp.int32, (b, c * b), 0)
                 cols = lax.broadcasted_iota(jnp.int32, (b, c * b), 1)
                 keep = (
-                    cols <= rows + (c - 1) * b if causal
+                    _sees(rows + (c - 1) * b, cols, mask) if causal
                     else cols < (c - 1) * b + kv_len % b
                 )
             step(width, keep)
@@ -242,7 +286,7 @@ def _walk_keys(step, i, g0, n_sub: int, causal: bool, kv_len: int, b: int):
 
 def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, scale: float, causal: bool, kv_len: int, b: int, hp: int,
+    *, scale: float, mask: Mask, kv_len: int, b: int, hp: int,
 ):
     """One q block of ``hp`` heads against one resident K/V block. The
     heads are independent chains in one program: the scheduler runs one
@@ -272,7 +316,7 @@ def _flash_fwd_kernel(
             m_scr[h] = m_new
             acc_scr[h] = acc_scr[h] * _across(alpha, d) + _dot(p.astype(v.dtype), v)
 
-    _walk_keys(step, i, jm * n_sub, n_sub, causal, kv_len, b)
+    _walk_keys(step, i, jm * n_sub, n_sub, mask, kv_len, b)
 
     @pl.when(jm == pl.num_programs(3) - 1)
     def _finalize():
@@ -288,7 +332,7 @@ def _flash_fwd_kernel(
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     dq_scr, lse_scr, delta_scr,
-    *, scale: float, causal: bool, kv_len: int, b: int, hp: int,
+    *, scale: float, mask: Mask, kv_len: int, b: int, hp: int,
 ):
     """dq of one q block: the forward's walk with the saved statistics."""
     i, jm = pl.program_id(2), pl.program_id(3)
@@ -315,7 +359,7 @@ def _flash_bwd_dq_kernel(
             ds = p * (dp - _across(delta_scr[h], s.shape[1]))
             dq_scr[h] += _dot(ds.astype(k.dtype), k)
 
-    _walk_keys(step, i, jm * n_sub, n_sub, causal, kv_len, b)
+    _walk_keys(step, i, jm * n_sub, n_sub, mask, kv_len, b)
 
     @pl.when(jm == pl.num_programs(3) - 1)
     def _finalize():
@@ -326,18 +370,23 @@ def _flash_bwd_dq_kernel(
 def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_scr, dv_scr,
-    *, scale: float, causal: bool, b: int, hp: int,
+    *, scale: float, mask: Mask, b: int, hp: int, major: int = 0,
 ):
     """dk/dv of one k/v block against one resident Q/dO block, on a
     transposed tile ``[keys, queries]`` of the sub-blocks from the
     diagonal one on (causal: the ones before it are skipped, a static
     branch a start). Padded query rows carry ``do = Δ = 0`` and add
-    nothing."""
+    nothing. ``major`` (grouped queries): the last grid axis walks the
+    query heads of this key head's group, ``major`` resident blocks
+    each, into the same accumulators."""
     i, jm = pl.program_id(2), pl.program_id(3)
+    first = jm == 0
+    if major:
+        jm = jm % major
     n_sub = q_ref.shape[1] // b
     d = k_ref.shape[2] // hp
 
-    @pl.when(jm == 0)
+    @pl.when(first)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -347,8 +396,9 @@ def _flash_bwd_dkv_kernel(
         qs, dos = q_ref[0, lo * b :, :], do_ref[0, lo * b :, :]
         if masked:  # the first sub-block is the diagonal one
             tile = (b, qs.shape[0])
-            keep = lax.broadcasted_iota(jnp.int32, tile, 0) <= (
-                lax.broadcasted_iota(jnp.int32, tile, 1)
+            keep = _sees(
+                lax.broadcasted_iota(jnp.int32, tile, 1),
+                lax.broadcasted_iota(jnp.int32, tile, 0), mask,
             )
         for h in range(hp):
             q, do = _head(qs, h, d), _head(dos, h, d)
@@ -361,7 +411,9 @@ def _flash_bwd_dkv_kernel(
             dst = pt * (dpt - _down(delta_ref[0, h, 0, :, lo * b :], b))
             dk_scr[h] += _dot(dst.astype(q.dtype), q)
 
-    if causal:
+    if mask.own:
+        step(0, True)
+    elif mask.causal:
         behind = i - jm * n_sub  # q sub-blocks of this resident block before k's
 
         @pl.when(behind < 0)
@@ -373,7 +425,7 @@ def _flash_bwd_dkv_kernel(
     else:
         step(0, False)
 
-    @pl.when(jm == pl.num_programs(3) - 1)
+    @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _finalize():
         dk = jnp.concatenate([dk_scr[h] for h in range(hp)], axis=1)
         dv = jnp.concatenate([dv_scr[h] for h in range(hp)], axis=1)
@@ -382,7 +434,8 @@ def _flash_bwd_dkv_kernel(
 
 
 def _specs(owner: _Plan, walked: _Plan, w: int, hp: int, groups: int,
-           causal: bool, owner_first: bool):
+           causal: bool, owner_first: bool, rep: int = 1,
+           diagonal: bool = False):
     """Block specs of a kernel's grid ``(batch, head group, owned block,
     resident block)`` over ``[B, T, H·d]`` operands: ``own(part)`` a
     ``[b, w]`` block (``w = hp·d`` lanes), ``walk(part)`` a resident
@@ -392,14 +445,33 @@ def _specs(owner: _Plan, walked: _Plan, w: int, hp: int, groups: int,
     array: ``groups`` lane blocks each. Causal programs that have no
     live tile in a resident block name the nearest one that has, so
     nothing is fetched for them: a q owner (``owner_first``) lives at or
-    after its keys, a k owner at or before its queries."""
+    after its keys, a k owner at or before its queries.
+
+    ``rep`` > 1 (grouped queries, one head a program): ``rep`` query
+    heads read one key head. A q owner's grid counts query heads and
+    its resident block is key head ``g // rep``'s; a k owner's grid
+    counts key heads, and its last axis walks the ``rep`` query heads of
+    its group, ``walked.major`` resident blocks each. ``diagonal``
+    (``Mask.own``): the resident block is the one of the owner's own
+    index, whatever the last axis says (one step a walked head)."""
     b = owner.b
+    major = 1 if diagonal else walked.major
 
     def resident(i, jm):
+        if diagonal:
+            return i
+        if rep > 1 and not owner_first:
+            jm = jm % major
         if not causal:
             return jm
         near = i // walked.sub
         return jnp.minimum(jm, near) if owner_first else jnp.maximum(jm, near)
+
+    def walked_head(g, jm):
+        """Lane block (and statistics row) of the walked operand."""
+        if rep == 1:
+            return g
+        return g // rep if owner_first else g * rep + jm // major
 
     def own(part=0):
         return pl.BlockSpec(
@@ -409,7 +481,9 @@ def _specs(owner: _Plan, walked: _Plan, w: int, hp: int, groups: int,
     def walk(part=0):
         return pl.BlockSpec(
             (1, walked.sub * b, w),
-            lambda n, g, i, jm: (n, resident(i, jm), part * groups + g),
+            lambda n, g, i, jm: (
+                n, resident(i, jm), part * groups + walked_head(g, jm)
+            ),
         )
 
     own_stat = pl.BlockSpec(
@@ -418,7 +492,7 @@ def _specs(owner: _Plan, walked: _Plan, w: int, hp: int, groups: int,
     )
     walk_stat = pl.BlockSpec(
         (1, hp, 1, _SUBLANES, walked.sub * b),
-        lambda n, g, i, jm: (n, g, resident(i, jm), 0, 0),
+        lambda n, g, i, jm: (n, walked_head(g, jm), resident(i, jm), 0, 0),
     )
     return own, walk, own_stat, walk_stat
 
@@ -431,42 +505,55 @@ _PARAMS = pltpu.CompilerParams(
 )
 
 
-def _geometry(q, k, heads: int, block: Optional[int], packed: bool):
+def _geometry(q, k, heads: int, block: Optional[int], packed: bool,
+              mask: Mask = Mask()):
     """``(hp, d, pq, pk, parts)`` of one call: heads a program, head
     width, the plans of the query and the key side (one block size), and
     which third of its array each of q, k, v is (``packed``: one
     ``[B, T, 3·H·d]`` array given three times)."""
     d = q.shape[2] // heads // (3 if packed else 1)
     b = block or _pick_block(max(q.shape[1], k.shape[1]))
+    if b % mask.gran:
+        raise ValueError(f"kernel block {b} is no multiple of the mask's {mask.gran}")
     hp = heads_per_program(heads, d) or 1  # 0: transposed, one head an array row
     parts = (0, 1, 2) if packed else (0, 0, 0)
+    if mask.own:  # a block meets its own: nothing resident beside it
+        blocks = _Plan(b, 1, -(-q.shape[1] // b))
+        return hp, d, blocks, blocks, parts
     return hp, d, _plan(q.shape[1], b), _plan(k.shape[1], b), parts
 
 
-def _flash(q, k, v, heads, causal, scale, block, interpret, packed=False):
+def _flash(q, k, v, heads, causal, scale, block, interpret, packed=False, rep=1):
     """Forward over ``[B, T, heads·d]`` operands (``packed``: q, k and v
-    are one ``[B, T, 3·heads·d]`` array). Returns ``out [B, T, heads·d]``
-    and the rows' logsumexp as ``[B, heads, major, 8, sub·b]`` (module
+    are one ``[B, T, 3·heads·d]`` array; ``rep`` > 1: k and v hold
+    ``heads // rep`` heads, each read by ``rep`` query heads). ``causal``
+    is a bool or a :class:`Mask`. Returns ``out [B, T, heads·d]`` and
+    the rows' logsumexp as ``[B, heads, major, 8, sub·b]`` (module
     docstring)."""
+    mask = _as_mask(causal)
+    causal = mask.causal
     n, tq = q.shape[:2]
     tk = k.shape[1]
-    hp, d, pq, pk, (pq_, pk_, pv_) = _geometry(q, k, heads, block, packed)
+    hp, d, pq, pk, (pq_, pk_, pv_) = _geometry(q, k, heads, block, packed, mask)
+    if rep > 1 and (hp != 1 or packed or d % _LANES):
+        raise ValueError("grouped queries need one head a program (d % 128 == 0)")
     w, b, hd = hp * d, pq.b, heads * d
     # every block of the padded q is computed: the dk/dv kernel reads
     # the statistics of all of them
     qp = _pad_rows(q, pq.rows)
     kp, vp = _pad_rows(k, pk.rows), _pad_rows(v, pk.rows)
     own, walk, own_stat, _ = _specs(
-        pq, pk, w, hp, heads // hp, causal, owner_first=True
+        pq, pk, w, hp, heads // hp, causal, owner_first=True, rep=rep,
+        diagonal=mask.own,
     )
     # vma: inside shard_map (the DP/SP engines) outputs vary over the
     # same mesh axes as the inputs; check_vma requires saying so.
     vma = _vma(q, k, v)
     out, lse = pl.pallas_call(
         functools.partial(
-            _flash_fwd_kernel, scale=scale, causal=causal, kv_len=tk, b=b, hp=hp
+            _flash_fwd_kernel, scale=scale, mask=mask, kv_len=tk, b=b, hp=hp
         ),
-        grid=(n, heads // hp, pq.blocks, pk.major),
+        grid=(n, heads // hp, pq.blocks, 1 if mask.own else pk.major),
         in_specs=[own(pq_), walk(pk_), walk(pv_)],
         out_specs=[own(), own_stat],
         out_shape=[
@@ -520,14 +607,19 @@ def _flash_qkv_bwd_rule(heads, causal, scale, block, interpret, res, do):
     return (jnp.concatenate(grads, axis=-1),)
 
 
-def _flash_bwd_rule(heads, causal, scale, block, interpret, res, do, packed=False):
+def _flash_bwd_rule(heads, causal, scale, block, interpret, res, do,
+                    packed=False, rep=1, dlse=None):
     """Flash backward as two Mosaic kernels (dq; dk/dv) of the forward's
     shape. ``_flash_bwd_scan`` below is the kept reference
-    implementation (parity-tested in ``tests/test_attention_ops.py``)."""
+    implementation (parity-tested in ``tests/test_attention_ops.py``).
+    ``dlse [B, T, heads]``: the cotangent of the rows' logsumexp where
+    the caller used it (``d lse / d s = p``, so it enters as ``−Δ``)."""
+    mask = _as_mask(causal)
+    causal = mask.causal
     q, k, v, out, lse = res
     n, tq = q.shape[:2]
     tk = k.shape[1]
-    hp, d, pq, pk, (pq_, pk_, pv_) = _geometry(q, k, heads, block, packed)
+    hp, d, pq, pk, (pq_, pk_, pv_) = _geometry(q, k, heads, block, packed, mask)
     w, b, hd = hp * d, pq.b, heads * d
     qp, dop = _pad_rows(q, pq.rows), _pad_rows(do, pq.rows)
     kp, vp = _pad_rows(k, pk.rows), _pad_rows(v, pk.rows)
@@ -535,6 +627,8 @@ def _flash_bwd_rule(heads, causal, scale, block, interpret, res, do, packed=Fals
         (do.astype(jnp.float32) * out.astype(jnp.float32)).reshape(n, tq, heads, d),
         axis=-1,
     )  # [n, tq, heads]
+    if dlse is not None:
+        delta = delta - dlse.astype(jnp.float32)
     delta = jnp.pad(delta, ((0, 0), (0, pq.rows - tq), (0, 0)))
     delta = jnp.broadcast_to(
         delta.transpose(0, 2, 1).reshape(n, heads, pq.major, 1, -1), lse.shape
@@ -542,13 +636,14 @@ def _flash_bwd_rule(heads, causal, scale, block, interpret, res, do, packed=Fals
     vma = _vma(q, k, v, do)
 
     own, walk, own_stat, _ = _specs(
-        pq, pk, w, hp, heads // hp, causal, owner_first=True
+        pq, pk, w, hp, heads // hp, causal, owner_first=True, rep=rep,
+        diagonal=mask.own,
     )
     dq = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dq_kernel, scale=scale, causal=causal, kv_len=tk, b=b, hp=hp
+            _flash_bwd_dq_kernel, scale=scale, mask=mask, kv_len=tk, b=b, hp=hp
         ),
-        grid=(n, heads // hp, pq.blocks, pk.major),
+        grid=(n, heads // hp, pq.blocks, 1 if mask.own else pk.major),
         in_specs=[own(pq_), walk(pk_), walk(pv_), own(), own_stat, own_stat],
         out_specs=own(),
         out_shape=jax.ShapeDtypeStruct((n, pq.rows, hd), q.dtype, vma=vma),
@@ -562,18 +657,21 @@ def _flash_bwd_rule(heads, causal, scale, block, interpret, res, do, packed=Fals
     )(qp, kp, vp, dop, lse, delta)
 
     own, walk, _, walk_stat = _specs(
-        pk, pq, w, hp, heads // hp, causal, owner_first=False
+        pk, pq, w, hp, heads // hp, causal, owner_first=False, rep=rep,
+        diagonal=mask.own,
     )
+    q_major = 1 if mask.own else pq.major
+    grouped = {"major": q_major} if rep > 1 else {}
     dk, dv = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dkv_kernel, scale=scale, causal=causal, b=b, hp=hp
+            _flash_bwd_dkv_kernel, scale=scale, mask=mask, b=b, hp=hp, **grouped
         ),
-        grid=(n, heads // hp, pk.blocks, pq.major),
+        grid=(n, heads // hp // rep, pk.blocks, rep * q_major),
         in_specs=[walk(pq_), own(pk_), own(pv_), walk(), walk_stat, walk_stat],
         out_specs=[own(), own()],
         out_shape=[
-            jax.ShapeDtypeStruct((n, pk.rows, hd), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((n, pk.rows, hd), v.dtype, vma=vma),
+            jax.ShapeDtypeStruct((n, pk.rows, hd // rep), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((n, pk.rows, hd // rep), v.dtype, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((hp, b, d), jnp.float32),
@@ -658,6 +756,92 @@ def _flash_bwd_scan(heads, causal, scale, block, interpret, res, do):
 
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 _flash_qkv_attention.defvjp(_flash_qkv_fwd_rule, _flash_qkv_bwd_rule)
+
+
+def _stat_rows(lse, tq: int):
+    """The kernels' row statistics ``[n, heads, major, 8, sub·b]`` as
+    ``[n, tq, heads]``."""
+    n, heads = lse.shape[:2]
+    return lse[:, :, :, 0].reshape(n, heads, -1)[:, :, :tq].transpose(0, 2, 1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_attention_stats(q, k, v, heads, rep, mask, scale, block, interpret):
+    """The forward with the rows' logsumexp beside the output, both
+    differentiable: what a caller needs to merge this pass with another
+    over further keys."""
+    out, lse = _flash(q, k, v, heads, mask, scale, block, interpret, rep=rep)
+    return out, _stat_rows(lse, q.shape[1])
+
+
+def _flash_stats_fwd_rule(q, k, v, heads, rep, mask, scale, block, interpret):
+    out, lse = _flash(q, k, v, heads, mask, scale, block, interpret, rep=rep)
+    return (out, _stat_rows(lse, q.shape[1])), (q, k, v, out, lse)
+
+
+def _flash_stats_bwd_rule(heads, rep, mask, scale, block, interpret, res, cts):
+    do, dlse = cts
+    return _flash_bwd_rule(
+        heads, mask, scale, block, interpret, res, do, rep=rep, dlse=dlse
+    )
+
+
+_flash_attention_stats.defvjp(_flash_stats_fwd_rule, _flash_stats_bwd_rule)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("mask", "scale", "block", "interpret")
+)
+def _stats_core(q, k, v, *, mask, scale, block, interpret):
+    b, tq, h, d = q.shape
+    rep = h // k.shape[2]
+    if rep > 1 and d % _LANES:
+        # narrow heads share a program's lanes (or go transposed): the
+        # key heads are then written out once a query head
+        k, v = (jnp.repeat(x, rep, axis=2) for x in (k, v))
+        rep = 1
+    if heads_per_program(h, d):
+        heads = h
+        pack = lambda x: x.reshape(b, x.shape[1], -1)
+        unpack = lambda o, lse: (o.reshape(b, -1, h, d), lse)
+    else:
+        heads = 1
+        pack = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, -1, d)
+        unpack = lambda o, lse: (
+            o.reshape(b, h, -1, d).transpose(0, 2, 1, 3),
+            lse.reshape(b, h, -1).transpose(0, 2, 1),
+        )
+    out, lse = _flash_attention_stats(
+        pack(q), pack(k), pack(v), heads, rep, mask, scale, block, interpret
+    )
+    return unpack(out, lse)
+
+
+def flash_attention_stats(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    *,
+    mask: Mask,
+    scale: Optional[float] = None,
+    block: Optional[int] = None,
+    interpret: Optional[bool] = None,
+):
+    """Flash attention under ``mask`` with grouped queries: ``q [B, T, H,
+    d]`` against ``k``, ``v`` ``[B, T, KV, d]``, ``H // KV`` query heads
+    to a key head (read in place where d is a multiple of 128). Returns
+    the output ``[B, T, H, d]`` and the rows' logsumexp ``[B, T, H]``,
+    both differentiable."""
+    if q.ndim != 4 or q.shape[2] % k.shape[2]:
+        raise ValueError(f"expected BTHD with grouped heads, got {q.shape}, {k.shape}")
+    if mask.causal and q.shape[1] != k.shape[1]:
+        raise ValueError("causal flash attention requires equal q/k lengths")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    scale = float(scale if scale is not None else q.shape[-1] ** -0.5)
+    return _stats_core(
+        q, k, v, mask=mask, scale=scale, block=block, interpret=interpret
+    )
 
 
 @functools.partial(

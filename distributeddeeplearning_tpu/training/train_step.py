@@ -149,6 +149,39 @@ def cross_entropy_loss(
         return jnp.mean(per_example)
 
 
+def weighted_cross_entropy_loss(
+    logits: jnp.ndarray,
+    targets: jnp.ndarray,
+    weights: jnp.ndarray,
+    label_smoothing: float = 0.0,
+) -> jnp.ndarray:
+    """``Σ w·CE / N`` over the positions whose target is not −1, ``N``
+    counting every position, ignored or not: the per-token weighted
+    objective (block diffusion: the masked positions, each by ``1/t``).
+    An ignored position adds nothing, forward or backward."""
+    with jax.named_scope("loss"):
+        flat = logits.astype(jnp.float32).reshape(-1, logits.shape[-1])
+        targets = targets.reshape(-1)
+        per_token = _sparse_softmax_ce(
+            flat, jnp.maximum(targets, 0), float(label_smoothing)
+        )
+        kept = jnp.where(targets >= 0, per_token * weights.reshape(-1), 0.0)
+        return jnp.sum(kept) / targets.size
+
+
+def sown_stats(mutated: PyTree) -> Dict[str, jnp.ndarray]:
+    """What the model's layers sowed into the ``"stats"`` collection
+    (``models/decoder.STATS``: an expert layer's pair counts), each name's
+    mean over the layers that sowed it. Empty for every other model."""
+    by_name: Dict[str, list] = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(mutated.get("stats", {})):
+        name = next(
+            k.key for k in reversed(path) if isinstance(getattr(k, "key", None), str)
+        )
+        by_name.setdefault(name, []).append(jnp.asarray(leaf, jnp.float32))
+    return {name: sum(xs) / len(xs) for name, xs in by_name.items()}
+
+
 def sown_aux_loss(mutated: PyTree) -> jnp.ndarray:
     """Sum of everything the model sowed into the ``"losses"`` collection
     (e.g. the MoE load-balance loss, ``models/moe.py``). Zero for models
@@ -300,7 +333,11 @@ def make_train_step(
         return flat_axis_index(mesh, axes)
 
     def local_step(state: TrainState, batch: Batch):
-        images, labels = batch
+        # (inputs, labels), or with per-token weights beside them
+        # (inputs, targets with −1 = ignore, weights): the weighted
+        # objective of `weighted_cross_entropy_loss`
+        images, labels, *rest = batch
+        weights = rest[0] if rest else None
         # uint8 staging: normalization folds into the first device pass
         from distributeddeeplearning_tpu.data.pipeline import (
             normalize_staged_images,
@@ -329,17 +366,24 @@ def make_train_step(
                 {"params": params, "batch_stats": state.batch_stats},
                 images,
                 train=True,
-                mutable=["batch_stats", "losses"],
+                mutable=["batch_stats", "losses", "stats"],
                 rngs={"dropout": dropout_rng},
             )
-            loss = cross_entropy_loss(logits, labels, cfg.label_smoothing)
+            if weights is None:
+                loss = cross_entropy_loss(logits, labels, cfg.label_smoothing)
+            else:
+                loss = weighted_cross_entropy_loss(
+                    logits, labels, weights, cfg.label_smoothing
+                )
             loss = loss + l2_kernel_penalty(params, cfg.weight_decay)
             loss = loss + sown_aux_loss(mutated)
-            return loss, (logits, mutated.get("batch_stats", {}))
+            return loss, (
+                logits, mutated.get("batch_stats", {}), sown_stats(mutated)
+            )
 
-        (loss, (logits, new_bs)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params_v
-        )
+        (loss, (logits, new_bs, stats)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True
+        )(params_v)
         # THE collective: Horovod's per-tensor ring allreduce becomes one
         # in-step pmean that XLA schedules onto ICI (staged ICI→DCN on
         # hybrid multi-slice meshes).
@@ -355,12 +399,17 @@ def make_train_step(
 
         with jax.named_scope("metrics"):
             hard = jnp.argmax(labels, -1) if labels.ndim == logits.ndim else labels
-            accuracy = jnp.mean(
-                (jnp.argmax(logits, -1) == hard).astype(jnp.float32)
-            )
+            hit = (jnp.argmax(logits, -1) == hard).astype(jnp.float32)
+            extra = dict(stats)
+            if weights is None:
+                accuracy = jnp.mean(hit)
+            else:  # over the positions the loss is over
+                kept = (labels >= 0).astype(jnp.float32)
+                accuracy = jnp.sum(hit * kept) / jnp.maximum(jnp.sum(kept), 1.0)
+                extra["loss.masked_share"] = jnp.mean(kept)
             metrics = _pmean_batch({
                 "loss": loss, "accuracy": accuracy,
-                "grad_norm": optax.global_norm(grads),
+                "grad_norm": optax.global_norm(grads), **extra,
             })
         new_state = state.replace(
             step=state.step + 1,
@@ -485,7 +534,7 @@ def make_train_step(
     sharded = jax.shard_map(
         local_step,
         mesh=mesh,
-        in_specs=(P(), (batch_spec, batch_spec)),
+        in_specs=(P(), batch_spec),  # every element of the batch tuple
         out_specs=(P(), P()),
         check_vma=check_vma,
     )
@@ -496,7 +545,7 @@ def make_train_step(
     sharded_acc = jax.shard_map(
         local_step_acc,
         mesh=mesh,
-        in_specs=(P(), (batch_spec, batch_spec), P()),
+        in_specs=(P(), batch_spec, P()),
         out_specs=(P(), P(), P()),
         check_vma=check_vma,
     )

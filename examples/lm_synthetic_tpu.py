@@ -15,6 +15,18 @@ or across 2 processes::
     python launch.py -n 2 --devices-per-process 4 --platform cpu \
         --env FAKE_DATA_LENGTH=512 --env BATCHSIZE=2 --env SEQ_LEN=64 \
         --env VOCAB=256 examples/lm_synthetic_tpu.py
+
+A decoder built from a layer spec (``models/decoder.py``) trains the same
+way; ``OBJECTIVE=block_diffusion`` makes the staging noise each clean row
+and the model read it as ``[noised | clean]`` (SDAR's block at a size for
+a smoke run; ``sdar_30b_a3b`` is the published layer, of which a run
+states its share through ``get_model``: ``layers``, ``experts_held``,
+``first_expert``, as ``benchmarks/programs/sdar.py`` does)::
+
+    python launch.py -n 2 --devices-per-process 4 --platform cpu \
+        --env MODEL=sdar_tiny --env OBJECTIVE=block_diffusion \
+        --env FAKE_DATA_LENGTH=512 --env BATCHSIZE=2 --env SEQ_LEN=64 \
+        --env VOCAB=256 examples/lm_synthetic_tpu.py
 """
 
 # Allow `python examples/<name>.py` from a repo checkout without an

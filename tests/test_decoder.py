@@ -1,0 +1,399 @@
+"""The decoder built from a spec (``models/decoder.py``), its expert
+layer (``ops/moe.py``), the block-diffusion attention core
+(``ops/attention.py``, ``ops/pallas/flash.py``), the noising
+(``data/noise.py``) and the weighted loss, each against the plain
+reference of the ``sdar`` family (``benchmarks/references/sdar.py``) at a
+small size: hidden 64, 2 layers, 4/2 heads of 16, 8 experts top-2,
+L = 32, B = 4, seeded weights, float32."""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.references import sdar as ref
+from distributeddeeplearning_tpu.data.noise import block_diffusion_noise
+from distributeddeeplearning_tpu.models import get_model
+from distributeddeeplearning_tpu.ops import moe
+from distributeddeeplearning_tpu.ops.attention import (
+    block_diffusion_attention,
+    block_diffusion_mask,
+)
+from distributeddeeplearning_tpu.ops.pallas import flash
+from distributeddeeplearning_tpu.training.train_step import (
+    weighted_cross_entropy_loss,
+)
+
+L, B, VOCAB = 32, 4, 96
+
+
+def config(held=8, first=0):
+    return {
+        "hidden_size": 64, "layers": 2, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 16, "moe_intermediate_size": 32,
+        "num_experts": held, "first_expert": first, "num_experts_per_tok": 2,
+        "vocab_size": VOCAB, "rms_norm_eps": 1e-6, "rope_theta": 1e6,
+        "published": {"num_experts": 8, "layers": 2},
+        "assumed": {"block_length": B, "t_min": 0.125, "mask_token_id": VOCAB - 1},
+    }
+
+
+def clean_rows(rows=3, seed=0):
+    return np.random.default_rng(seed).integers(0, VOCAB, (rows, L), dtype=np.int32)
+
+
+def gap(a, b):
+    return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30))
+
+
+# -- the model against the reference -----------------------------------------
+
+@pytest.fixture(scope="module")
+def noised():
+    return ref.noise_rows(clean_rows(), config())
+
+
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
+def test_the_model_matches_the_reference_on_logits_loss_and_gradients(noised, attn_impl):
+    cfg = config()
+    params = ref.init_params(cfg, 7)
+    inputs, targets, weights = (jnp.asarray(x) for x in noised)
+    model = get_model(
+        "sdar_tiny", num_classes=VOCAB, dtype="float32", attn_impl=attn_impl
+    )
+
+    def loss(params):
+        logits = model.apply({"params": params}, inputs, train=True)
+        return weighted_cross_entropy_loss(logits, targets, weights), logits
+
+    (l, logits), grads = jax.value_and_grad(loss, has_aux=True)(params)
+    want, _ = ref.forward(params, inputs, cfg)
+    assert logits.shape == (3, L, VOCAB) and gap(logits, want) < 1e-4
+    lr, gr = jax.value_and_grad(ref.diffusion_loss)(params, inputs, targets, weights, cfg)
+    assert abs(float(l) - float(lr)) < 1e-5 * abs(float(lr))
+    flat_got, flat_want = ref.flatten(grads), ref.flatten(gr)
+    assert set(flat_got) == set(flat_want)
+    for k in flat_want:  # every parameter, not the comparison's pooled leaves
+        assert gap(flat_got[k], flat_want[k]) < 2e-3, k
+    pooled = ref.leaf_norms(gr)
+    assert "mlp/routers" in pooled and "mlp/experts" in pooled
+    assert len(pooled) == len(flat_want) - 5 - 1  # two layers: six expert kernels as one, two routers as one
+
+
+def test_a_gpt2_spec_is_the_lm_of_that_size():
+    """``gpt2_tiny`` of the spec builder computes what ``lm_tiny``
+    computes, the fused qkv kernel cut in its thirds."""
+    tokens = jnp.asarray(clean_rows(2))
+    lm = get_model("lm_tiny", num_classes=VOCAB, dtype="float32", max_seq_len=L)
+    params = jax.tree.map(
+        lambda p: p + 0.01, lm.init(jax.random.PRNGKey(1), tokens, train=False)["params"]
+    )
+    import flax.linen as nn
+
+    params = nn.unbox(params)
+    mine = {k: v for k, v in params.items() if not k.startswith("block")}
+    for i in range(2):
+        blk = dict(params[f"block{i}"])
+        attn = blk.pop("attn")
+        qkv_k, qkv_b = attn["qkv"]["kernel"], attn["qkv"]["bias"]
+        d = qkv_k.shape[0]
+        # the fused columns are [3, heads, head_dim]
+        ks, bs = qkv_k.reshape(d, 3, d), qkv_b.reshape(3, d)
+        blk["attn"] = {
+            name: {"kernel": ks[:, j], "bias": bs[j]} for j, name in enumerate("qkv")
+        }
+        blk["attn"]["o"] = attn["proj"]
+        mine[f"block{i}"] = blk
+    spec = get_model("gpt2_tiny", num_classes=VOCAB, dtype="float32", max_seq_len=L)
+    got = spec.apply({"params": mine}, tokens, train=False)
+    assert gap(got, lm.apply({"params": params}, tokens, train=False)) < 1e-5
+
+
+# -- the attention core -------------------------------------------------------
+
+def test_the_mask_is_the_reference_s():
+    i = jnp.arange(2 * L)
+    want = ref.allowed(i[:, None], i[None, :], L, B)
+    assert bool(jnp.all(block_diffusion_mask(L, B) == want))
+    # a row of the noised half sees its own block and the clean blocks before
+    assert int(want[5].sum()) == B + B and int(want[L + 5].sum()) == 2 * B
+
+
+def _qkv(length, heads, kv, d, seed=0):
+    key = jax.random.PRNGKey(seed)
+    return (
+        jax.random.normal(key, (2, 2 * length, heads, d)),
+        jax.random.normal(jax.random.fold_in(key, 1), (2, 2 * length, kv, d)),
+        jax.random.normal(jax.random.fold_in(key, 2), (2, 2 * length, kv, d)),
+        jax.random.normal(jax.random.fold_in(key, 3), (2, 2 * length, heads, d)),
+    )
+
+
+def test_the_einsum_core_is_the_reference_s_dense_attention():
+    q, k, v, _ = _qkv(L, 4, 2, 16)
+    got = block_diffusion_attention(q, k, v, block_len=B, impl="xla")
+    want = ref._attention(q, k, v, L, B).reshape(got.shape)
+    assert gap(got, want) < 1e-5
+
+
+@pytest.mark.parametrize(
+    "length,heads,kv,d",
+    [(64, 4, 2, 16), (128, 4, 2, 128)],
+    ids=["narrow-heads", "grouped-in-place"],
+)
+def test_the_kernels_match_the_einsum_forward_and_backward(length, heads, kv, d):
+    q, k, v, w = _qkv(length, heads, kv, d)
+
+    def loss(impl):
+        return lambda q, k, v: jnp.sum(
+            block_diffusion_attention(q, k, v, block_len=B, impl=impl) * w
+        )
+
+    out = block_diffusion_attention(q, k, v, block_len=B, impl="pallas")
+    assert gap(out, block_diffusion_attention(q, k, v, block_len=B, impl="xla")) < 1e-5
+    got = jax.grad(loss("pallas"), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss("xla"), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert gap(a, b) < 1e-5
+
+
+@pytest.mark.parametrize("strict", [False, True])
+def test_a_block_causal_pass_gives_the_rows_logsumexp_and_its_gradient(strict):
+    q, k, v, w = _qkv(64, 4, 2, 128)
+    t = q.shape[1]
+    live = jnp.arange(t) >= (B if strict else 0)  # the first block sees nothing
+
+    def dense(q, k, v):
+        kk, vv = jnp.repeat(k, 2, 2), jnp.repeat(v, 2, 2)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * 128**-0.5
+        r, c = jnp.arange(t)[:, None] // B, jnp.arange(t)[None, :] // B
+        m = c < r if strict else c <= r
+        lse = jax.nn.logsumexp(jnp.where(m, s, -1e30), -1)
+        p = jnp.where(m, jnp.exp(jnp.where(m, s, -1e30) - lse[..., None]), 0.0)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, vv), lse.transpose(0, 2, 1)
+
+    def kernel(q, k, v):
+        return flash.flash_attention_stats(
+            q, k, v, mask=flash.Mask(True, B, strict), block=64, interpret=True
+        )
+
+    def loss(f):
+        def g(q, k, v):
+            out, lse = f(q, k, v)
+            return jnp.sum(jnp.where(live[None, :, None, None], out * w, 0.0)) + jnp.sum(
+                jnp.where(live[None, :, None], lse * w[..., 0], 0.0)
+            )
+        return g
+
+    got = jax.grad(loss(kernel), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert bool(jnp.all(jnp.isfinite(a))) and gap(a, b) < 1e-5
+    if strict:
+        assert float(kernel(q, k, v)[1][0, 0, 0]) < -1e29
+
+
+@pytest.mark.parametrize(
+    "mask", [flash.Mask(True), flash.Mask(True, B), flash.Mask(True, B, strict=True),
+             flash.Mask(False, B, own=True)],
+    ids=["causal", "blocks<=", "blocks<", "own-block"],
+)
+def test_a_pass_over_several_resident_blocks(mask):
+    """Sixteen blocks in two resident ones: a program's index maps have
+    to name the resident block and the walked head they mean (a wrong
+    one reads a neighbour's rows, which short sequences cannot show)."""
+    t, heads, kv, d = 1024, 2, 1, 128
+    key = jax.random.PRNGKey(3)
+    q = jax.random.normal(key, (1, t, heads, d))
+    k, v = (jax.random.normal(jax.random.fold_in(key, i), (1, t, kv, d)) for i in (1, 2))
+    live = jnp.arange(t) >= (B if mask.strict else 0)
+    r, c = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    if mask.own:
+        m = r // B == c // B
+    else:
+        m = (c // mask.gran < r // mask.gran) if mask.strict else (c // mask.gran <= r // mask.gran)
+
+    def dense(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, heads, 2)) * d**-0.5
+        p = jax.nn.softmax(jnp.where(m, s, -1e30), -1) * m
+        return jnp.einsum("bhqk,bkhd->bqhd", p, jnp.repeat(v, heads, 2))
+
+    def kernel(q, k, v):
+        return flash.flash_attention_stats(q, k, v, mask=mask, block=64, interpret=True)[0]
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(
+            jnp.where(live[None, :, None, None], jnp.sin(f(q, k, v)), 0.0)
+        )
+
+    assert gap(kernel(q, k, v)[:, B:], dense(q, k, v)[:, B:]) < 1e-5
+    got = jax.grad(loss(kernel), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert gap(a, b) < 1e-5
+
+
+# -- the expert layer ---------------------------------------------------------
+
+def _layer(seed=0, tokens=64, d=64, f=32, experts=8):
+    key = jax.random.PRNGKey(seed)
+    x = jax.random.normal(key, (tokens, d))
+    p = {
+        "router": {"kernel": jax.random.normal(jax.random.fold_in(key, 1), (d, experts))},
+        "w1": {"kernel": 0.2 * jax.random.normal(jax.random.fold_in(key, 2), (experts, d, f))},
+        "w3": {"kernel": 0.2 * jax.random.normal(jax.random.fold_in(key, 3), (experts, d, f))},
+        "w2": {"kernel": 0.2 * jax.random.normal(jax.random.fold_in(key, 4), (experts, f, d))},
+    }
+    return x, p
+
+
+def _share(x, p, first, held, top_k=2):
+    routed = moe.route_top_k(
+        jnp.matmul(x, p["router"]["kernel"], precision="highest"), top_k
+    )
+    cut = slice(first, first + held)
+    return moe.held_experts_ffn(
+        x, routed, p["w1"]["kernel"][cut], p["w3"]["kernel"][cut],
+        p["w2"]["kernel"][cut], first=first,
+        num_experts=p["router"]["kernel"].shape[1],
+    )
+
+
+def _reference_layer(x, p, cfg):
+    return ref._experts(x, p, ref.sizes(cfg), None)[0]
+
+
+def test_the_shares_of_the_experts_add_up_to_the_uncut_layer():
+    """8 experts as 4 shares of 2: what the four give, added, is what
+    the reference gives for the whole layer; and one share is what the
+    reference gives for that share."""
+    x, p = _layer()
+    parts = [_share(x, p, first, 2)[0] for first in (0, 2, 4, 6)]
+    assert gap(sum(parts), _reference_layer(x, p, config(held=8))) < 1e-5
+    cut = {k: {"kernel": v["kernel"][2:4]} for k, v in p.items() if k != "router"}
+    cut["router"] = p["router"]
+    assert gap(parts[1], _reference_layer(x, cut, config(held=2, first=2))) < 1e-5
+    drawn = sum(int(_share(x, p, first, 2)[1].sum()) for first in (0, 2, 4, 6))
+    assert drawn == x.shape[0] * 2  # every pair falls to one share
+
+
+@pytest.mark.parametrize("skew", [0.0, 50.0], ids=["even", "onto-one-expert"])
+def test_no_token_is_dropped(monkeypatch, skew):
+    """A router skewed onto one held expert sends it every token, four
+    token, more than a stretch holds: the further stretches take the rest, and output
+    and gradients are the dense sum's."""
+    monkeypatch.setattr(moe, "_ROW_TILE", 8)
+    x, p = _layer(tokens=256)
+    x = x.at[:, 0].set(1.0)  # a feature every token has, for the skew to pull on
+    p["router"]["kernel"] = p["router"]["kernel"].at[0, 2].add(skew)
+    assert moe.usual_cap(512, 2, 8) == 256
+
+    def mine(x, p):
+        return _share(x, p, 2, 2)[0]
+
+    def dense(x, p):
+        cut = {k: {"kernel": v["kernel"][2:4]} for k, v in p.items() if k != "router"}
+        cut["router"] = p["router"]
+        return _reference_layer(x, cut, config(held=2, first=2))
+
+    y, drawn = _share(x, p, 2, 2)
+    if skew:
+        assert int(drawn[0]) == 256 and int(drawn.sum()) > 256
+    assert gap(y, dense(x, p)) < 1e-5
+    got = jax.grad(lambda x, p: jnp.sum(jnp.sin(mine(x, p))), (0, 1))(x, p)
+    want = jax.grad(lambda x, p: jnp.sum(jnp.sin(dense(x, p))), (0, 1))(x, p)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert gap(a, b) < 1e-4
+
+
+def test_the_model_reports_its_experts_load():
+    model = get_model(
+        "sdar_tiny", num_classes=VOCAB, dtype="float32", experts_held=4,
+        first_expert=2,
+    )
+    tokens = jnp.asarray(ref.noise_rows(clean_rows(), config())[0])
+    variables = model.init(jax.random.PRNGKey(0), tokens, train=False)
+    assert variables["params"]["block0"]["mlp"]["w1"]["kernel"].shape == (4, 64, 32)
+    assert variables["params"]["block0"]["mlp"]["router"]["kernel"].shape == (64, 8)
+    _, seen = model.apply(
+        {"params": variables["params"]}, tokens, train=True,
+        mutable=["stats", "intermediates"],
+    )
+    stats = seen["stats"]["block1"]["mlp"]
+    chosen = seen["intermediates"]["block1"]["mlp"]["experts"][0]
+    held = int(((chosen >= 2) & (chosen < 6)).sum())
+    assert float(stats["moe.pairs_local"][0]) == held
+    assert float(stats["moe.expert_load_max_over_mean"][0]) >= 1.0
+
+
+# -- noising and loss ---------------------------------------------------------
+
+def test_the_noising_is_the_reference_s_and_a_function_of_the_rows():
+    rows = clean_rows(5, seed=3)
+    got = block_diffusion_noise(rows, block_len=B, t_min=0.125, mask_id=VOCAB - 1)
+    want = ref.noise_rows(rows, config())
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    inputs, targets, weights = got
+    again = block_diffusion_noise(rows[::-1], block_len=B, t_min=0.125, mask_id=VOCAB - 1)
+    assert np.array_equal(again[0][::-1], inputs)  # a row's draw is its own
+    masked = targets >= 0
+    assert np.array_equal(inputs[:, L:], rows)
+    assert np.all(inputs[:, :L][masked] == VOCAB - 1)
+    assert np.array_equal(inputs[:, :L][~masked], rows[~masked])
+    assert np.all(weights[masked] >= 1.0) and np.all(weights[masked] <= 8.0)
+    assert np.all(weights[~masked] == 0.0)
+    # one level a block
+    per_block = weights.reshape(5, L // B, B)
+    level = per_block.max(-1, keepdims=True)
+    assert np.all((per_block == 0.0) | (per_block == level))
+
+
+def test_the_weighted_loss_against_a_hand_count():
+    logits = jnp.log(jnp.asarray([
+        [[0.5, 0.25, 0.25], [0.1, 0.8, 0.1]],
+        [[0.2, 0.2, 0.6], [1 / 3, 1 / 3, 1 / 3]],
+    ]))
+    targets = jnp.asarray([[0, -1], [2, 1]])
+    weights = jnp.asarray([[2.0, 5.0], [1.0, 4.0]])  # the ignored one's is not read
+    want = (2.0 * -np.log(0.5) + 1.0 * -np.log(0.6) + 4.0 * -np.log(1 / 3)) / 4
+    got = weighted_cross_entropy_loss(logits, targets, weights)
+    assert abs(float(got) - want) < 1e-6
+    grad = jax.grad(lambda z: weighted_cross_entropy_loss(z, targets, weights))(logits)
+    assert float(jnp.abs(grad[0, 1]).max()) == 0.0  # an ignored position has no say
+    assert abs(float(grad[1, 0, 2]) - (0.6 - 1.0) / 4) < 1e-6
+
+
+# -- every other model's step -------------------------------------------------
+
+def test_the_two_element_batch_compiles_to_the_step_it_compiled_to():
+    """``lm_tiny``'s train step over ``(tokens, labels)``, lowered, is
+    text for text what the parent commit of PR 27 lowered (the weighted
+    objective, the sown statistics and the batch's prefix spec add
+    nothing to a step that does not use them)."""
+    if jax.__version__ != "0.9.0" or jax.device_count() != 8:
+        pytest.skip("the text was taken under jax 0.9.0 on the tests' 8 host devices")
+    from distributeddeeplearning_tpu.config import TrainConfig
+    from distributeddeeplearning_tpu.parallel.mesh import data_parallel_mesh
+    from distributeddeeplearning_tpu.training.optimizer import create_optimizer
+    from distributeddeeplearning_tpu.training.train_step import (
+        create_train_state,
+        make_train_step,
+    )
+
+    cfg = TrainConfig(
+        model="lm_tiny", num_classes=256, compute_dtype="float32",
+        batch_size_per_device=2, optimizer="adamw", weight_decay=0.0,
+        warmup_epochs=0, lr_schedule="constant", fake=True, epochs=1,
+    )
+    model = get_model("lm_tiny", num_classes=256, dtype="float32", max_seq_len=32)
+    tx, _ = create_optimizer(cfg, 10, world_size=1)
+    state = create_train_state(model, cfg, tx, input_shape=(1, 32), input_dtype=jnp.int32)
+    step = make_train_step(model, tx, data_parallel_mesh(1), cfg)
+    x = jnp.zeros((2, 32), jnp.int32)
+    text = step._resolve(state, False).lower(state, (x, x)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "3b7774e8651c6d170b7fc836166e672397f7c9cd8e9bc2b3b089be9c7dc205ff"
+    )

@@ -91,3 +91,71 @@ def test_a_default_lm_reaches_the_kernel_under_attn_core(one_chip, monkeypatch):
     }
     kernels = [p for p in scopes.values() if p.endswith("/" + programs.KERNEL_CALL)]
     assert sum(programs.BACKWARD in p for p in kernels) == 2 * layers
+
+
+@pytest.mark.parametrize(
+    "mask", [(True, 4, False), (True, 4, True), (False, 4, False, True)],
+    ids=["blocks<=", "blocks<", "own-block"],
+)
+def test_mosaic_takes_the_block_diffusion_passes(one_chip, mask):
+    """The three passes of ``ops/attention.block_diffusion_attention`` at
+    the SDAR cell's shapes: 32 query heads reading 4 key heads of 128 in
+    place, 4,096 positions, the diagonal tile masked in units of 4 (or
+    nothing but it computed); forward, dq and a dk/dv kernel whose last
+    grid axis walks the eight query heads of a key head."""
+    from distributeddeeplearning_tpu.ops.pallas.flash import Mask, flash_attention_stats
+
+    q = jax.ShapeDtypeStruct((1, 4096, 32, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4096, 4, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out, lse = flash_attention_stats(
+            q, k, v, mask=Mask(*mask), interpret=False
+        )
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_an_sdar_layer_reaches_its_kernels_under_their_scopes(one_chip, monkeypatch):
+    """One layer of ``sdar_30b_a3b`` at its published widths (16 of the
+    128 experts held), left at its defaults and asked as on the chip:
+    nine flash kernels under ``attn_core`` (three passes), the grouped
+    products under ``moe_experts``, and every scope group of both tables
+    present in the compiled program."""
+    from distributeddeeplearning_tpu.models.decoder import MOE_GROUPS
+
+    model = get_model(
+        "sdar_30b_a3b", num_classes=18992, dtype="bfloat16", layers=1,
+        experts_held=16,
+    )
+    tokens = jnp.zeros((1, 8192), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens, train=False)["params"]
+    )
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip), params
+    )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    obs.reset()
+
+    def objective(params, tokens):  # not `loss`: jit(loss) would read as the loss scope
+        logits = model.apply({"params": params}, tokens, train=True)
+        return jnp.sum(logits.astype(jnp.float32))
+
+    tok = jax.ShapeDtypeStruct(tokens.shape, tokens.dtype, sharding=one_chip)
+    compiled = jax.jit(jax.grad(objective)).lower(params, tok).compile()
+    totals = obs.get_bus().totals()
+    obs.reset()
+    assert totals["attn.impl.pallas"]["count"] == 1
+    assert totals["attn.mask.block_diffusion"]["count"] == 1
+    assert totals["moe.impl.ragged_dot"]["count"] == 1
+    scopes = programs.parse_hlo_scopes(compiled.as_text())
+    assert programs.kernel_calls_by_group(scopes, TRAIN_STEP_GROUPS)["attn_core"] == 9
+    assert programs.kernel_calls_by_group(scopes, MOE_GROUPS).get("moe_experts", 0) >= 3
+    assert programs.groups_in(scopes, MOE_GROUPS) == {g for g, _ in MOE_GROUPS}
+    assert programs.groups_in(scopes, TRAIN_STEP_GROUPS) >= {
+        "attn_core", "attn_proj", "mlp", "head_loss", "norm_residual"
+    }
