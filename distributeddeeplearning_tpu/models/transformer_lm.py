@@ -311,9 +311,10 @@ class TransformerLM(nn.Module):
         # MXU runs at full bf16 rate with f32 accumulation; the [B, T, V]
         # logits tensor is then STORED in the compute dtype (at vocab-32k
         # it is the model's largest activation, and its cotangent — the
-        # projection backward's operand — stays bf16 too). The loss keeps
-        # one f32 copy internally (CE residual; see
-        # train_step._sparse_softmax_ce for the measured trade-off).
+        # projection backward's operand — stays bf16 too). The loss reads
+        # them as they are, upcasting inside its reductions, and keeps
+        # them as its residual: no f32 copy exists
+        # (train_step._sparse_softmax_ce).
         # `head`: the tied projection is no module of its own, so it gets
         # the scope a module would give it.
         with jax.named_scope(HEAD):

@@ -25,9 +25,9 @@ Semantics parity notes:
 
 **Names on the device's time.** Flax scopes every model operation by
 module path; the step adds ``jax.named_scope`` for what no module
-holds: ``loss`` (:func:`cross_entropy_loss`), the gradient reduction
+holds: ``loss`` (:func:`loss_and_hits`), the gradient reduction
 (``training/overlap.OVERLAP_SCOPE``), ``optimizer`` (the update and its
-application) and ``metrics`` (accuracy's argmax over the logits, the
+application) and ``metrics`` (the accuracy from the loss's hits, the
 gradient norm). They are metadata alone — no operation changes — and
 ``obs/programs.py`` sums a device trace by them.
 
@@ -48,6 +48,7 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from distributeddeeplearning_tpu import obs
 from distributeddeeplearning_tpu.config import TrainConfig
 from distributeddeeplearning_tpu.parallel.mesh import batch_axes, replicated_sharding
 from distributeddeeplearning_tpu.training.overlap import overlap_scope
@@ -59,94 +60,138 @@ Batch = Tuple[jnp.ndarray, jnp.ndarray]  # (images NHWC, int labels)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _sparse_softmax_ce(logits, labels, label_smoothing):
-    """Per-example sparse softmax CE ``[N, V] × [N] → [N]`` with a
-    hand-written backward: ``d_logits = g·(softmax − targets)`` built
-    from an ``iota == label`` comparison. AD of the take_along_axis
-    formulation instead lowers to a scatter-add over a fresh zeros
-    ``[N, V]`` f32 buffer — at LM scale (T=32k, V=32k) that single
-    buffer is 3.9 GB and was the allocation that pushed long-context
-    training out of HBM.
+    """Per-example sparse softmax CE and the hit, ``[N, V] × [N] → ([N],
+    [N])`` float32, reading the logits **in the dtype the model emitted**
+    (bfloat16 from the LMs, float32 from the image models): every
+    element is upcast inside the reductions that read it, so nothing of
+    shape ``[N, V]`` in float32 is written. The target's logit is a
+    masked sum (``where(iota == label, x, 0)``), not a gather, so it
+    rides in the pass that sums the exponentials, as do the sum of the
+    logits (label smoothing) and the hit: ``label ==`` the first index
+    of the row's maximum, ``jnp.argmax``'s rule with its ties (a row
+    holding a NaN counts as a miss). The hit carries no gradient.
 
-    Callers pass f32 logits (``cross_entropy_loss`` upcasts): a
-    bf16-residual variant that upcast on the fly inside fwd/bwd was
-    measured 15 % SLOWER end-to-end (234k vs 276k tok/s, lm_small
-    T=1024) — the gather cannot fuse with an on-the-fly upcast, so the
-    f32 copy materializes anyway and the extra casts just add passes."""
-    loss, _ = _sparse_ce_primal(logits, labels, label_smoothing)
-    return loss
+    The backward is hand-written: ``d_logits = g·(softmax − targets)``
+    from the upcast logits, the float32 ``lse [N]`` and an ``iota ==
+    label`` comparison, written once, in the logits' dtype (the
+    cotangent of a bfloat16 tensor is bfloat16 either way). The
+    residual is the logits as they came. AD of a ``take_along_axis``
+    instead lowers to a scatter-add over a fresh zeros ``[N, V]`` f32
+    buffer: at LM scale (T=32k, V=32k) 3.9 GB."""
+    loss, hit, _ = _sparse_ce_primal(logits, labels, label_smoothing)
+    return loss, hit
 
 
 def _sparse_ce_primal(logits, labels, label_smoothing):
-    """One place for the loss formula (primal and fwd share it)."""
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+    """One place for the loss formula (primal and fwd share it):
+    ``(loss, hit, lse)``, each ``[N]`` float32."""
+    x = logits.astype(jnp.float32)
+    v = x.shape[-1]
+    col = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    top = jnp.max(x, axis=-1)
+    # as jax.nn.logsumexp: a row whose maximum is not finite shifts by 0
+    shift = jnp.where(jnp.isfinite(top), top, 0.0)
+    lse = shift + jnp.log(jnp.sum(jnp.exp(x - shift[:, None]), axis=-1))
+    picked = jnp.sum(jnp.where(col == labels[:, None], x, 0.0), axis=-1)
+    # the label is the maximum's first index: it holds the maximum and no
+    # column before it does. A sum like the others, so one pass takes all.
+    tied_before = jnp.sum(
+        jnp.where((x == top[:, None]) & (col < labels[:, None]), 1.0, 0.0), axis=-1
+    )
+    hit = ((picked == top) & (tied_before == 0.0)).astype(jnp.float32)
     if label_smoothing > 0.0:
-        v = logits.shape[-1]
         on = 1.0 - label_smoothing
         off = label_smoothing / (v - 1)
         # -Σ targets·logp with targets = onehot·(on−off) + off
-        return lse - (on - off) * picked - off * jnp.sum(logits, axis=-1), lse
-    return lse - picked, lse
+        return lse - (on - off) * picked - off * jnp.sum(x, axis=-1), hit, lse
+    return lse - picked, hit, lse
 
 
 def _sparse_softmax_ce_fwd(logits, labels, label_smoothing):
-    loss, lse = _sparse_ce_primal(logits, labels, label_smoothing)
-    return loss, (logits, labels, lse)
+    loss, hit, lse = _sparse_ce_primal(logits, labels, label_smoothing)
+    return (loss, hit), (logits, labels, lse)
 
 
 def _sparse_softmax_ce_bwd(label_smoothing, res, g):
     logits, labels, lse = res
-    v = logits.shape[-1]
-    p = jnp.exp(logits - lse[:, None])
-    onehot = (
-        lax.broadcasted_iota(labels.dtype, logits.shape, 1) == labels[:, None]
-    ).astype(logits.dtype)
+    g_loss, _ = g  # the hit has no gradient
+    p = jnp.exp(logits.astype(jnp.float32) - lse[:, None])
+    at_label = lax.broadcasted_iota(jnp.int32, logits.shape, 1) == labels[:, None]
     if label_smoothing > 0.0:
-        on = 1.0 - label_smoothing
-        off = label_smoothing / (v - 1)
-        targets = onehot * (on - off) + off
+        off = label_smoothing / (logits.shape[-1] - 1)
+        targets = jnp.where(at_label, 1.0 - label_smoothing, off)
     else:
-        targets = onehot
-    return ((p - targets) * g[:, None], None)
+        targets = at_label.astype(jnp.float32)
+    return (((p - targets) * g_loss[:, None]).astype(logits.dtype), None)
 
 
 _sparse_softmax_ce.defvjp(_sparse_softmax_ce_fwd, _sparse_softmax_ce_bwd)
 
 
-def cross_entropy_loss(
-    logits: jnp.ndarray, labels: jnp.ndarray, label_smoothing: float = 0.0
-) -> jnp.ndarray:
-    """Mean sparse softmax cross-entropy (reference TF ``:197-200``).
+def loss_and_hits(
+    logits: jnp.ndarray,
+    labels: jnp.ndarray,
+    label_smoothing: float = 0.0,
+    weights: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The step's loss and, beside it, which positions the model's first
+    choice got right: ``(loss, hits)``, ``hits`` float32 over the labels'
+    leading dims and without gradient, so the accuracy costs no pass of
+    its own over the logits.
 
     ``logits`` may carry any leading dims (``[B, C]`` classification,
-    ``[B, T, C]`` token prediction); ``labels`` matches the leading dims.
-    One-hot (float, rank-of-logits) labels are accepted too — the
-    reference Keras path's ``categorical_crossentropy`` with its one-hot
+    ``[B, T, C]`` token prediction) and come in the dtype the model
+    emitted; the loss math is float32 (:func:`_sparse_softmax_ce`).
+    Without ``weights`` the loss is the mean sparse softmax
+    cross-entropy (reference TF ``:197-200``); one-hot (float,
+    rank-of-logits) labels are accepted too — the reference Keras
+    path's ``categorical_crossentropy`` with its one-hot
     ``FakeDataGenerator`` (``imagenet_keras_horovod.py:307``,
-    ``data_generator.py:48-53``). Sparse labels route through the
-    scatter-free custom-VJP kernel (:func:`_sparse_softmax_ce`).
-    """
+    ``data_generator.py:48-53``). With ``weights`` it is ``Σ w·CE / N``
+    over the positions whose label is not −1, ``N`` counting every
+    position, ignored or not: the per-token weighted objective (block
+    diffusion: the masked positions, each by ``1/t``). An ignored
+    position adds nothing, forward or backward; its hit means nothing."""
     num_classes = logits.shape[-1]
     # The `loss` scope names these operations, forward and backward, in
     # every engine's compiled step (obs/programs.py groups by it).
     with jax.named_scope("loss"):
-        # Loss math is always f32; reduced-precision logits (the LM
-        # emits compute-dtype logits) upcast ONCE here — measured faster
-        # than upcasting on the fly inside the custom VJP (its docstring).
-        logits = logits.astype(jnp.float32)
-        if labels.ndim == logits.ndim:  # one-hot
+        onehot = weights is None and labels.ndim == logits.ndim
+        obs.counter(
+            f"loss.impl.{'onehot' if onehot else 'xla'}",
+            dtype=str(logits.dtype), shape=list(logits.shape),
+        )
+        if onehot:
+            logits = logits.astype(jnp.float32)
             targets = labels.astype(jnp.float32)
+            hits = jnp.argmax(logits, -1) == jnp.argmax(targets, -1)
             if label_smoothing > 0.0:
                 on = 1.0 - label_smoothing
                 off = label_smoothing / (num_classes - 1)
                 targets = targets * (on - off) + off
             log_probs = jax.nn.log_softmax(logits)
-            return -jnp.mean(jnp.sum(targets * log_probs, axis=-1))
-        flat = logits.reshape(-1, num_classes)
-        per_example = _sparse_softmax_ce(
-            flat, labels.reshape(-1), float(label_smoothing)
+            loss = -jnp.mean(jnp.sum(targets * log_probs, axis=-1))
+            return loss, hits.astype(jnp.float32)
+        flat_labels = labels.reshape(-1)
+        per_example, hits = _sparse_softmax_ce(
+            logits.reshape(-1, num_classes),
+            jnp.maximum(flat_labels, 0),
+            float(label_smoothing),
         )
-        return jnp.mean(per_example)
+        if weights is None:
+            loss = jnp.mean(per_example)
+        else:
+            kept = jnp.where(flat_labels >= 0, per_example * weights.reshape(-1), 0.0)
+            loss = jnp.sum(kept) / flat_labels.size
+        return loss, hits.reshape(labels.shape)
+
+
+def cross_entropy_loss(
+    logits: jnp.ndarray, labels: jnp.ndarray, label_smoothing: float = 0.0
+) -> jnp.ndarray:
+    """Mean sparse softmax cross-entropy: :func:`loss_and_hits`' loss
+    (the hits' reductions, unread, compile away)."""
+    return loss_and_hits(logits, labels, label_smoothing)[0]
 
 
 def weighted_cross_entropy_loss(
@@ -155,18 +200,9 @@ def weighted_cross_entropy_loss(
     weights: jnp.ndarray,
     label_smoothing: float = 0.0,
 ) -> jnp.ndarray:
-    """``Σ w·CE / N`` over the positions whose target is not −1, ``N``
-    counting every position, ignored or not: the per-token weighted
-    objective (block diffusion: the masked positions, each by ``1/t``).
-    An ignored position adds nothing, forward or backward."""
-    with jax.named_scope("loss"):
-        flat = logits.astype(jnp.float32).reshape(-1, logits.shape[-1])
-        targets = targets.reshape(-1)
-        per_token = _sparse_softmax_ce(
-            flat, jnp.maximum(targets, 0), float(label_smoothing)
-        )
-        kept = jnp.where(targets >= 0, per_token * weights.reshape(-1), 0.0)
-        return jnp.sum(kept) / targets.size
+    """``Σ w·CE / N`` over the positions whose target is not −1:
+    :func:`loss_and_hits`' loss under per-token weights."""
+    return loss_and_hits(logits, targets, label_smoothing, weights)[0]
 
 
 def sown_stats(mutated: PyTree) -> Dict[str, jnp.ndarray]:
@@ -369,19 +405,16 @@ def make_train_step(
                 mutable=["batch_stats", "losses", "stats"],
                 rngs={"dropout": dropout_rng},
             )
-            if weights is None:
-                loss = cross_entropy_loss(logits, labels, cfg.label_smoothing)
-            else:
-                loss = weighted_cross_entropy_loss(
-                    logits, labels, weights, cfg.label_smoothing
-                )
+            loss, hit = loss_and_hits(
+                logits, labels, cfg.label_smoothing, weights
+            )
             loss = loss + l2_kernel_penalty(params, cfg.weight_decay)
             loss = loss + sown_aux_loss(mutated)
             return loss, (
-                logits, mutated.get("batch_stats", {}), sown_stats(mutated)
+                hit, mutated.get("batch_stats", {}), sown_stats(mutated)
             )
 
-        (loss, (logits, new_bs, stats)), grads = jax.value_and_grad(
+        (loss, (hit, new_bs, stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(params_v)
         # THE collective: Horovod's per-tensor ring allreduce becomes one
@@ -398,8 +431,6 @@ def make_train_step(
             new_params = jax.tree.map(lambda p, u: p + u, state.params, updates)
 
         with jax.named_scope("metrics"):
-            hard = jnp.argmax(labels, -1) if labels.ndim == logits.ndim else labels
-            hit = (jnp.argmax(logits, -1) == hard).astype(jnp.float32)
             extra = dict(stats)
             if weights is None:
                 accuracy = jnp.mean(hit)
@@ -459,25 +490,18 @@ def make_train_step(
                     mutable=["batch_stats", "losses"],
                     rngs={"dropout": jax.random.fold_in(step_rng, idx)},
                 )
-                loss = cross_entropy_loss(
+                loss, hit = loss_and_hits(
                     logits, mb_labels, cfg.label_smoothing
                 )
                 loss = loss + l2_kernel_penalty(params, cfg.weight_decay)
                 loss = loss + sown_aux_loss(mutated)
-                return loss, (logits, mutated.get("batch_stats", bs))
+                return loss, (hit, mutated.get("batch_stats", bs))
 
-            (loss, (logits, new_bs)), grads = jax.value_and_grad(
+            (loss, (hit, new_bs)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(params_v)
             with jax.named_scope("metrics"):
-                hard = (
-                    jnp.argmax(mb_labels, -1)
-                    if mb_labels.ndim == logits.ndim
-                    else mb_labels
-                )
-                accuracy = jnp.mean(
-                    (jnp.argmax(logits, -1) == hard).astype(jnp.float32)
-                )
+                accuracy = jnp.mean(hit)
             return grads, {"loss": loss, "accuracy": accuracy}, new_bs
 
         def vary(tree):

@@ -201,15 +201,17 @@ def render_device(rep: dict) -> str:
     return "\n".join(out)
 
 
-def attention_paths(events) -> str:
-    """What ``models/vit.Attention`` chose for its core each time a
-    program was traced (counters ``attn.impl.<path>``), one line."""
+def chosen_paths(events, prefix: str = "attn.impl.") -> str:
+    """What the program chose each time it was traced, one line, from
+    the counters under ``prefix``: ``attn.impl.<path>`` (the attention
+    core, ``models/vit.Attention``) or ``loss.impl.<path>`` (the loss,
+    ``training/train_step.loss_and_hits``)."""
     chosen: dict = {}
     for e in events:
         name = str(e.get("name", ""))
-        if e.get("kind") == "counter" and name.startswith("attn.impl."):
+        if e.get("kind") == "counter" and name.startswith(prefix):
             shape = (e.get("labels") or {}).get("shape")
-            key = (name[len("attn.impl."):], tuple(shape or ()))
+            key = (name[len(prefix):], tuple(shape or ()))
             chosen[key] = chosen.get(key, 0) + int(e.get("value", 1))
     return ", ".join(
         f"{path} x{n} at {list(shape)}" for (path, shape), n in sorted(chosen.items())
@@ -278,9 +280,10 @@ def main(argv=None) -> int:
         )
     else:
         print(render(recon, training, args.top))
-    paths_chosen = attention_paths(loaded["events"])
-    if paths_chosen:
-        print("attention core, as chosen at trace time: " + paths_chosen)
+    for what, prefix in (("attention core", "attn.impl."), ("loss", "loss.impl.")):
+        paths_chosen = chosen_paths(loaded["events"], prefix)
+        if paths_chosen:
+            print(f"{what}, as chosen at trace time: " + paths_chosen)
     for d in devices:
         print()
         print(render_device(d))

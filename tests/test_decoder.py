@@ -370,9 +370,10 @@ def test_the_weighted_loss_against_a_hand_count():
 
 def test_the_two_element_batch_compiles_to_the_step_it_compiled_to():
     """``lm_tiny``'s train step over ``(tokens, labels)``, lowered, is
-    text for text what the parent commit of PR 27 lowered (the weighted
-    objective, the sown statistics and the batch's prefix spec add
-    nothing to a step that does not use them)."""
+    text for text what it was (the weighted objective, the sown
+    statistics and the batch's prefix spec add nothing to a step that
+    does not use them). Taken anew in PR 28, whose loss reads the logits
+    in place: PR 27's text held the float32 copy and the gather."""
     if jax.__version__ != "0.9.0" or jax.device_count() != 8:
         pytest.skip("the text was taken under jax 0.9.0 on the tests' 8 host devices")
     from distributeddeeplearning_tpu.config import TrainConfig
@@ -395,5 +396,5 @@ def test_the_two_element_batch_compiles_to_the_step_it_compiled_to():
     x = jnp.zeros((2, 32), jnp.int32)
     text = step._resolve(state, False).lower(state, (x, x)).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "3b7774e8651c6d170b7fc836166e672397f7c9cd8e9bc2b3b089be9c7dc205ff"
+        "21b602cce0a9be9f0f1452c84abd757c2917c280f837262ec88c16ce7a72e663"
     )

@@ -539,8 +539,51 @@ def test_the_resolved_attention_path_is_counted_at_trace_time_and_reported():
     assert totals["attn.impl.xla"] == {"kind": "counter", "count": 1, "sum": 1.0}
     (event,) = [e for e in obs.get_bus().ring if e["name"] == "attn.impl.xla"]
     assert event["labels"] == {"asked": "auto", "shape": [2, 16, 32], "heads": 4}
-    line = _trace_report().attention_paths([event, dict(event)])
+    line = _trace_report().chosen_paths([event, dict(event)])
     assert line == "xla x2 at [2, 16, 32]"
+
+
+def test_the_loss_path_is_reported_beside_the_attentions():
+    """``loss.impl.<path>`` rides the same report line by its prefix."""
+    from distributeddeeplearning_tpu.training.train_step import cross_entropy_loss
+
+    obs.reset()
+    logits = jnp.zeros((2, 16, 300), jnp.bfloat16)
+    jax.jit(lambda l: cross_entropy_loss(l, jnp.zeros((2, 16), jnp.int32))).lower(logits)
+    events = [e for e in obs.get_bus().ring if e["kind"] == "counter"]
+    report = _trace_report()
+    assert report.chosen_paths(events, "loss.impl.") == "xla x1 at [2, 16, 300]"
+    assert report.chosen_paths(events) == ""  # no attention was traced
+    obs.reset()
+
+
+@pytest.mark.parametrize("path", [
+    "jit(local_step)/jvp(TransformerLM)/head/btd,vd->btv/dot_general",
+    "jit(local_step)/jvp(loss)/reduce_max",
+    "jit(local_step)/jvp(loss)/reduce_sum",
+    "jit(local_step)/jvp(loss)/select_n",
+    "jit(local_step)/transpose(jvp(loss))/mul",
+    "jit(local_step)/metrics/reduce_sum",
+])
+def test_what_the_loss_runs_counts_as_head_loss(path):
+    assert programs.group_of(path, TRAIN_STEP_GROUPS) == "head_loss"
+
+
+def test_the_compiled_loss_stands_whole_under_head_loss(compiled_step):
+    """Every instruction of the compiled lm_tiny step that the ``loss``
+    scope names, forward and backward, falls in ``head_loss``, the
+    reductions the loss now makes itself among them (the rows' maximum,
+    the sums); it gathers nothing, and ``metrics`` holds no argmax over
+    the logits: ``head_loss_device_ms.train`` reads the whole of it."""
+    _, (table,), _ = compiled_step
+    paths = set(table.scopes().values())
+    of_loss = {p for p in paths if "(loss)" in p or "/loss/" in p}
+    assert {programs.group_of(p, TRAIN_STEP_GROUPS) for p in of_loss} == {"head_loss"}
+    assert any(programs.BACKWARD in p for p in of_loss)
+    assert any(p.endswith("/reduce_max") for p in of_loss)
+    assert any(p.endswith("/reduce_sum") for p in of_loss)
+    assert not [p for p in of_loss if "gather" in p or "scatter" in p]
+    assert not [p for p in paths if "/metrics/" in p and "argmax" in p]
 
 
 KERNEL_HLO = """ENTRY %main () -> f32[4] {

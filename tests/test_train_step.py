@@ -236,39 +236,171 @@ def test_replica_axis_mesh_matches_plain_dp(mesh8):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
-def test_sparse_ce_custom_vjp_matches_ad_reference():
-    """The scatter-free CE backward (custom VJP) against plain AD of the
-    take_along_axis/one-hot formulations, values and grads, with and
-    without label smoothing, [B,C] and [B,T,C]."""
+def _ref_ce(logits, labels, ls=0.0):
+    """Plain float32 CE for AD: take_along_axis, or one-hot targets."""
+    c = logits.shape[-1]
+    logp = jax.nn.log_softmax(logits)
+    if ls > 0.0:
+        on, off = 1.0 - ls, ls / (c - 1)
+        targets = jax.nn.one_hot(labels, c) * (on - off) + off
+        return -jnp.mean(jnp.sum(targets * logp, axis=-1))
+    return -jnp.mean(jnp.take_along_axis(logp, labels[..., None], axis=-1))
+
+
+VOCAB = 257  # no multiple of 128: the lanes' remainder is a case of its own
+
+
+def _logits_with_a_tie(shape, dtype, seed=0):
+    """Random logits whose first two rows hold their maximum twice, and
+    labels that name the later of the two in row 0 (a miss, by
+    ``jnp.argmax``'s rule: the first index wins) and the first in row 1."""
+    rng = np.random.RandomState(seed)
+    logits = (rng.randn(*shape, VOCAB) * 3).astype(np.float32)
+    labels = rng.randint(0, VOCAB, shape).astype(np.int32)
+    flat, flat_labels = logits.reshape(-1, VOCAB), labels.reshape(-1)
+    for row, label in ((0, 200), (1, 17)):
+        flat[row, [17, 200]] = 20.0
+        flat_labels[row] = label
+    flat_labels[2] = int(np.argmax(flat[2]))  # and a plain hit
+    return jnp.asarray(logits).astype(dtype), jnp.asarray(labels)
+
+
+@pytest.mark.parametrize("shape", [(8,), (2, 5)], ids=["BC", "BTC"])
+@pytest.mark.parametrize("ls", [0.0, 0.1])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_sparse_ce_custom_vjp_matches_ad_reference(dtype, ls, shape):
+    """The loss on the logits as they came (custom VJP, no float32 copy,
+    no gather) against plain AD of the float32 reference on the upcast
+    logits: value, gradient (in bfloat16: to one rounding, in the
+    logits' dtype), and the hits against ``jnp.argmax`` with a tie."""
+    from distributeddeeplearning_tpu.training.train_step import loss_and_hits
+
+    logits, labels = _logits_with_a_tie(shape, dtype)
+    (v_new, hits), g_new = jax.value_and_grad(
+        lambda l: loss_and_hits(l, labels, ls), has_aux=True
+    )(logits)
+    v_ref, g_ref = jax.value_and_grad(lambda l: _ref_ce(l, labels, ls))(
+        logits.astype(jnp.float32)
+    )
+    np.testing.assert_allclose(float(v_new), float(v_ref), rtol=1e-6)
+    assert g_new.dtype == dtype
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(np.asarray(g_new), np.asarray(g_ref), atol=1e-5)
+    else:  # round to nearest: half a unit of bfloat16's 8 bits
+        np.testing.assert_allclose(
+            np.asarray(g_new.astype(jnp.float32)), np.asarray(g_ref),
+            rtol=2.0**-8, atol=1e-7,
+        )
+    want = jnp.argmax(logits, -1) == labels
+    assert hits.shape == labels.shape and hits.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(hits), np.asarray(want, np.float32))
+    assert [float(h) for h in hits.reshape(-1)[:3]] == [0.0, 1.0, 1.0]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_weighted_loss_leaves_an_ignored_position_out(dtype):
+    """Targets of −1: nothing forward (the loss is that of the kept
+    positions over all N), nothing backward (a zero row), and whatever
+    its logits say, no part in the accuracy's sum."""
     from distributeddeeplearning_tpu.training.train_step import (
-        cross_entropy_loss,
+        loss_and_hits,
+        weighted_cross_entropy_loss,
     )
 
-    def ref_ce(logits, labels, ls=0.0):
-        c = logits.shape[-1]
-        if ls > 0.0:
-            on, off = 1.0 - ls, ls / (c - 1)
-            targets = jax.nn.one_hot(labels, c) * (on - off) + off
-            return -jnp.mean(
-                jnp.sum(targets * jax.nn.log_softmax(logits), axis=-1)
-            )
-        logp = jax.nn.log_softmax(logits)
-        return -jnp.mean(
-            jnp.take_along_axis(logp, labels[..., None], axis=-1)
-        )
+    logits, labels = _logits_with_a_tie((2, 6), dtype, seed=1)
+    ignored = np.zeros((2, 6), bool)
+    ignored[0, 0] = ignored[1, 3] = ignored[1, 5] = True
+    targets = jnp.where(ignored, -1, labels)
+    weights = jnp.asarray(np.random.RandomState(2).uniform(1, 8, (2, 6)), jnp.float32)
+    (loss, hits), grad = jax.value_and_grad(
+        lambda l: loss_and_hits(l, targets, 0.0, weights), has_aux=True
+    )(logits)
+    x = logits.astype(jnp.float32)
+    per_token = -jnp.take_along_axis(jax.nn.log_softmax(x), labels[..., None], -1)[..., 0]
+    want = jnp.sum(jnp.where(ignored, 0.0, per_token * weights)) / ignored.size
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
+    assert float(loss) == float(weighted_cross_entropy_loss(logits, targets, weights))
+    assert not np.asarray(grad.astype(jnp.float32))[ignored].any()
+    assert np.asarray(grad.astype(jnp.float32))[~ignored].any(axis=-1).all()
+    # the ignored rows' logits are nobody's business
+    moved = jnp.where(ignored[..., None], -logits, logits)
+    loss2, hits2 = loss_and_hits(moved, targets, 0.0, weights)
+    assert float(loss2) == float(loss)
+    kept = ~ignored
+    argmax_hits = np.asarray(jnp.argmax(logits, -1) == labels)
+    np.testing.assert_array_equal(np.asarray(hits)[kept], argmax_hits[kept])
+    np.testing.assert_array_equal(np.asarray(hits2)[kept], argmax_hits[kept])
 
-    rng = np.random.RandomState(0)
-    for shape, ls in [((8, 16), 0.0), ((8, 16), 0.1),
-                      ((2, 5, 16), 0.0), ((2, 5, 16), 0.1)]:
-        logits = jnp.asarray(rng.randn(*shape).astype(np.float32)) * 3
-        labels = jnp.asarray(rng.randint(0, 16, shape[:-1]).astype(np.int32))
-        v_new, g_new = jax.value_and_grad(
-            lambda l: cross_entropy_loss(l, labels, ls)
-        )(logits)
-        v_ref, g_ref = jax.value_and_grad(
-            lambda l: ref_ce(l, labels, ls)
-        )(logits)
-        np.testing.assert_allclose(float(v_new), float(v_ref), rtol=1e-6)
-        np.testing.assert_allclose(
-            np.asarray(g_new), np.asarray(g_ref), atol=1e-5
-        )
+
+@pytest.mark.parametrize("weighted", [False, True], ids=["mean", "weighted"])
+def test_the_loss_keeps_no_float32_logits_and_gathers_nothing(weighted):
+    """What the backward pass keeps of bfloat16 logits is the logits as
+    they came, the labels and a float32 ``lse [N]``; and neither pass
+    holds a gather or a scatter (the target's logit is a masked sum)."""
+    from distributeddeeplearning_tpu.training.train_step import (
+        _sparse_softmax_ce_fwd,
+        loss_and_hits,
+    )
+
+    logits, labels = _logits_with_a_tie((4, 8), jnp.bfloat16)
+    flat, flat_labels = logits.reshape(-1, VOCAB), labels.reshape(-1)
+    _, residuals = _sparse_softmax_ce_fwd(flat, flat_labels, 0.0)
+    kept = jax.tree.leaves(residuals)
+    assert not [r for r in kept if r.shape == flat.shape and r.dtype != jnp.bfloat16]
+    assert sorted((r.shape, str(r.dtype)) for r in kept) == sorted(
+        [(flat.shape, "bfloat16"), ((32,), "int32"), ((32,), "float32")]
+    )
+    weights = jnp.ones(labels.shape, jnp.float32) if weighted else None
+    text = str(jax.make_jaxpr(jax.value_and_grad(
+        lambda l: loss_and_hits(l, labels, 0.0, weights), has_aux=True
+    ))(logits))
+    assert "gather" not in text and "scatter" not in text and "argmax" not in text
+
+
+@pytest.mark.parametrize("labels_kind", ["sparse", "onehot"])
+def test_the_loss_counts_the_path_it_took_once_a_trace(labels_kind):
+    """``loss.impl.<path>`` at trace time, as ``attn.impl.<path>`` is:
+    one count a traced loss, forward and backward together."""
+    from distributeddeeplearning_tpu import obs
+    from distributeddeeplearning_tpu.training.train_step import cross_entropy_loss
+
+    logits, labels = _logits_with_a_tie((2, 4), jnp.bfloat16)
+    name = "loss.impl.xla"
+    if labels_kind == "onehot":
+        labels, name = jax.nn.one_hot(labels, VOCAB), "loss.impl.onehot"
+    obs.reset()
+    jax.jit(jax.value_and_grad(lambda l: cross_entropy_loss(l, labels))).lower(logits)
+    totals = obs.get_bus().totals()
+    assert totals[name] == {"kind": "counter", "count": 1, "sum": 1.0}
+    assert [k for k in totals if k.startswith("loss.impl.")] == [name]
+    (event,) = [e for e in obs.get_bus().ring if e["name"] == name]
+    assert event["labels"] == {"dtype": "bfloat16", "shape": [2, 4, VOCAB]}
+    obs.reset()
+
+
+@pytest.mark.parametrize("name", ["lm_tiny", "gpt2_tiny"])
+def test_the_steps_accuracy_is_the_argmaxs(name, mesh8):
+    """``accuracy`` out of ``make_train_step`` comes from the loss's
+    hits; it equals the share of positions whose argmax is the label, on
+    the LM of ``models/transformer_lm.py`` and on the spec-built one."""
+    from distributeddeeplearning_tpu.models import get_model
+
+    seq, vocab = 16, 256
+    cfg = TrainConfig(
+        model=name, num_classes=vocab, compute_dtype="bfloat16",
+        batch_size_per_device=1, weight_decay=0.0,
+    )
+    model = get_model(name, num_classes=vocab, dtype="bfloat16", max_seq_len=seq)
+    tx = optax.sgd(0.1)
+    state = create_train_state(
+        model, cfg, tx, input_shape=(1, seq), input_dtype=jnp.int32
+    )
+    tokens = np.random.RandomState(3).randint(0, vocab, (8, seq)).astype(np.int32)
+    logits = model.apply({"params": state.params}, jnp.asarray(tokens), train=True)
+    first = np.asarray(jnp.argmax(logits, -1))
+    # the model is right at the even positions and off by one elsewhere
+    even = (np.arange(seq) % 2 == 0)[None, :]
+    labels = np.where(even, first, (first + 1) % vocab).astype(np.int32)
+    step = make_train_step(model, tx, mesh8, cfg, donate_state=False)
+    _, metrics = step(replicate_state(state, mesh8), shard_batch((tokens, labels), mesh8))
+    assert float(metrics["accuracy"]) == 0.5
