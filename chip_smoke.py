@@ -204,7 +204,7 @@ def train_resnet50() -> dict:
 
 def train_lm_flash() -> dict:
     """lm_small at T=8,192 with no ``ATTN_IMPL``: the default ``"auto"``
-    resolves to the flash kernel here (``Attention._resolve_impl``), its
+    resolves to the flash kernel here (``ops/attention.resolve_impl``), its
     forward and both backward kernels compiled by Mosaic."""
     import jax
     import jax.numpy as jnp
@@ -236,7 +236,7 @@ def train_lm_flash() -> dict:
 
 def train_vit_packed() -> dict:
     """ViT-B/16 with the default ``attn_impl="auto"``: on a TPU
-    ``Attention._resolve_impl`` picks the packed kernel (T=197, d=64,
+    ``ops/attention.resolve_impl`` picks the packed kernel (T=197, d=64,
     ragged last block), which no CPU test ever compiles."""
     from distributeddeeplearning_tpu.data import make_dataset
     from distributeddeeplearning_tpu.models import get_model

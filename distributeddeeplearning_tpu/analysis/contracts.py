@@ -1,12 +1,19 @@
-"""Contract cross-checkers: env docs, obs registry, protocol scrub list.
+"""Contract cross-checkers: env docs, env-free tiers, obs registry,
+protocol scrub list.
 
-Three contracts live in prose/data and rot silently when code moves:
+These contracts live in prose/data and rot silently when code moves:
 
 * ``env-docs`` — the ORCHESTRATION.md / OBSERVABILITY.md env tables are
   the operator's API. Every ``os.environ`` read in the package (and the
   ``e = os.environ if env is None else env`` from_env idiom) must name a
   var those docs carry — an undocumented knob is a contract the operator
   can't see.
+* ``env-free-tiers`` — ``models/`` and ``ops/`` read no environment:
+  what a model or an op does follows from its arguments and from what it
+  can observe of the call (shapes, platform), so a traced program is a
+  function of its config. A knob belongs in ``TrainConfig`` /
+  ``ServeConfig`` (``from_env`` is the one reader) and reaches a model
+  as a field.
 * ``obs-registry`` — docs/OBSERVABILITY.md's "What is instrumented"
   section is the event-name registry every report/rollup/SLO consumer
   keys on. Every literal ``obs.counter/gauge/point/span`` name emitted
@@ -19,7 +26,7 @@ Three contracts live in prose/data and rot silently when code moves:
   parsed by a config surface without joining the list. Both checked
   here, against recertify's own AST (no import side effects).
 
-All three fail with the exact missing/stale names.
+All fail with the exact missing/stale names.
 """
 
 from __future__ import annotations
@@ -196,6 +203,49 @@ def run_env_docs() -> List[Finding]:
                     f"env table (the operator contract)",
                 ))
     return findings
+
+
+# ---------------------------------------------------------------------------
+# env-free-tiers
+# ---------------------------------------------------------------------------
+
+ENV_FREE_TIERS = ("models", "ops")
+
+
+def env_touches(source: str) -> List[int]:
+    """Lines that touch the process environment at all: ``os.environ``
+    (read, written or aliased), ``os.getenv``, ``os.putenv``, or either
+    imported by name."""
+    lines: List[int] = []
+    for node in ast.walk(ast.parse(source)):
+        if isinstance(node, ast.Attribute) and _dotted(node) in (
+            "os.environ", "os.getenv", "os.putenv"
+        ):
+            lines.append(node.lineno)
+        elif isinstance(node, ast.ImportFrom) and node.module == "os" and any(
+            a.name in ("environ", "getenv", "putenv") for a in node.names
+        ):
+            lines.append(node.lineno)
+    return sorted(set(lines))
+
+
+@register(
+    "env-free-tiers", "contract",
+    "no file under models/ or ops/ touches the process environment: a "
+    "model's or an op's behaviour follows from its arguments and the call",
+)
+def run_env_free_tiers() -> List[Finding]:
+    roots = [os.path.join(PACKAGE_ROOT, tier) for tier in ENV_FREE_TIERS]
+    return [
+        Finding(
+            "env-free-tiers", path, line,
+            "the environment is touched under models/ or ops/ — take the "
+            "value as an argument or a module field (config.from_env is "
+            "the reader), or decide from what the call can observe",
+        )
+        for path, src in sorted(package_sources(roots).items())
+        for line in env_touches(src)
+    ]
 
 
 # ---------------------------------------------------------------------------

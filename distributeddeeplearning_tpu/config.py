@@ -59,7 +59,7 @@ class TrainConfig:
     input_staging: str = "auto"
     # Attention core of the attention models (ViT, the LM families):
     # "auto" (the default) lets each call choose from what it can see
-    # (models/vit.Attention._resolve_impl): on a TPU with local operands
+    # (ops/attention.resolve_impl): on a TPU with local operands
     # the packed kernel at T <= 512, the streaming flash kernel from
     # T = 640 on, the XLA einsum elsewhere. "xla" | "pallas" (flash) |
     # "fused" (packed) | "ring" (sequence-parallel) force a path.
@@ -183,8 +183,7 @@ class TrainConfig:
     # batch-splits BN statistics per data shard (models/norm.py), which
     # equals the dp engine's (and the reference's) per-replica BN —
     # oracle-tested. This opt-in switches to GLOBAL-batch (sync-BN)
-    # statistics instead (and is required for ResNet(fused=True), whose
-    # in-kernel statistics cannot be batch-split).
+    # statistics instead.
     allow_sync_bn: bool = False
 
     # AOT warmup (env AOT_WARMUP): compile the train step before the

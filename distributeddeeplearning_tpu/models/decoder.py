@@ -9,7 +9,11 @@ rotary or learned positions, grouped-query heads whose width is not
 ``hidden / heads``, a per-head q/k norm, biases or none, a GELU MLP or
 routed gated experts of which this process holds a share
 (``ops/moe.py``), a tied or an untied head, and a causal mask or the
-block-diffusion one (``ops/attention.block_diffusion_attention``).
+block-diffusion one. Both masks' cores are ``ops/attention``'s
+(``dot_product_attention``, ``block_diffusion_attention``), and which
+lowering a call takes is that module's rule (``resolve_impl``): this
+model states its heads and its mask, and that no fused QKV projection
+feeds the core.
 
 Scope names are the ones ``transformer_lm.TRAIN_STEP_GROUPS`` reads:
 module ``attn`` with ``attn_core`` inside it, the FFN module ``mlp``,
@@ -42,10 +46,14 @@ from distributeddeeplearning_tpu.models.transformer_lm import (
     RAGGED_DOT,
     RESIDUAL,
 )
-from distributeddeeplearning_tpu.models.vit import ATTN_CORE, MlpBlock, kernel_is_safe
+from distributeddeeplearning_tpu.models.vit import ATTN_CORE, MlpBlock
 from distributeddeeplearning_tpu.obs.programs import part
 from distributeddeeplearning_tpu.ops import moe as moe_ops
-from distributeddeeplearning_tpu.ops.attention import block_diffusion_attention
+from distributeddeeplearning_tpu.ops.attention import (
+    block_diffusion_attention,
+    dot_product_attention,
+    resolve_impl,
+)
 
 # Collection the expert layers sow their counts into; the train step
 # reports each name's mean over the layers beside its own metrics.
@@ -156,54 +164,10 @@ def _dense(features: int, name: str, spec: DecoderSpec, dtype):
     )
 
 
-def _causal_attention(q, k, v, impl: str):
-    """Grouped-query causal attention, BTHD."""
-    if impl == "pallas":
-        from distributeddeeplearning_tpu.ops.pallas.flash import (
-            Mask,
-            flash_attention_stats,
-        )
-
-        return flash_attention_stats(q, k, v, mask=Mask(True))[0]
-    b, t, h, d = q.shape
-    kv = k.shape[2]
-    scores = jnp.einsum(
-        "bqgrd,bkgd->bgrqk", q.reshape(b, t, kv, h // kv, d), k
-    ) * d**-0.5
-    scores = jnp.where(
-        jnp.tril(jnp.ones((t, t), bool)), scores, jnp.finfo(scores.dtype).min
-    )
-    weights = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bgrqk,bkgd->bqgrd", weights, v).reshape(b, t, h, d)
-
-
 class SpecAttention(nn.Module):
     spec: DecoderSpec
     dtype: Any = jnp.bfloat16
     attn_impl: str = "auto"
-
-    def _resolve_impl(self, x, seq_len: int) -> str:
-        """``models/vit.Attention._resolve_impl``'s rule for this core:
-        an explicit ``attn_impl`` is taken as given; ``"auto"`` takes
-        the flash kernels where a custom call is safe and they are ahead
-        (``flash.supports`` of the length one pass walks: a half of the
-        row under the block-diffusion mask), else the einsum."""
-        spec, impl = self.spec, self.attn_impl
-        if impl == "auto":
-            from distributeddeeplearning_tpu.ops.pallas import flash
-
-            impl = "xla"
-            if kernel_is_safe(x, self.is_initializing()) and flash.supports(
-                seq_len, spec.heads, spec.head_dim
-            ):
-                impl = "pallas"
-        mask = "block_diffusion" if spec.block_len else "causal"
-        obs.counter(
-            f"attn.impl.{impl}", asked=self.attn_impl, shape=list(x.shape),
-            heads=spec.heads, kv_heads=spec.kv_heads, mask=mask,
-        )
-        obs.counter(f"attn.mask.{mask}", impl=impl)
-        return impl
 
     @nn.compact
     def __call__(self, x, positions):
@@ -220,14 +184,19 @@ class SpecAttention(nn.Module):
             q = rotary(q, positions, spec.rope_theta)
             k = rotary(k, positions, spec.rope_theta)
         q, k = q.astype(self.dtype), k.astype(self.dtype)
-        impl = self._resolve_impl(x, t // 2 if spec.block_len else t)
+        # separate q/k/v projections: the packed kernel is not on offer
+        impl = resolve_impl(
+            self.attn_impl, x, heads=h, head_dim=hd,
+            initializing=self.is_initializing(), kv_heads=kv,
+            mask="block_diffusion" if spec.block_len else "causal",
+        )
         with jax.named_scope(ATTN_CORE):
             if spec.block_len:
                 out = block_diffusion_attention(
                     q, k, v, block_len=spec.block_len, impl=impl
                 )
             else:
-                out = _causal_attention(q, k, v, impl)
+                out = dot_product_attention(q, k, v, causal=True, impl=impl)
         return _dense(spec.hidden, "o", spec, self.dtype)(out.reshape(b, t, h * hd))
 
 

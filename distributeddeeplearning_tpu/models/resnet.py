@@ -25,7 +25,6 @@ import functools
 from typing import Any, Callable, Sequence, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 ModuleDef = Any
@@ -42,18 +41,6 @@ _STAGES = {
 
 _KERNEL_INIT = nn.initializers.variance_scaling(2.0, "fan_out", "truncated_normal")
 _BN_EPS = 1e-5  # reference constant (resnet_model.py:10-11); ONE copy
-
-
-def space_to_depth(x: jnp.ndarray, block: int = 2) -> jnp.ndarray:
-    """NHWC space-to-depth: (N, H, W, C) → (N, H/b, W/b, C·b²).
-
-    The MLPerf ResNet input trick: folds the 2× stem stride into the
-    channel dim so the stem conv sees 12 input channels instead of 3 and
-    tiles the MXU's 128-lane contraction instead of padding 3→128."""
-    n, h, w, c = x.shape
-    x = x.reshape(n, h // block, block, w // block, block, c)
-    x = x.transpose(0, 1, 3, 2, 4, 5)
-    return x.reshape(n, h // block, w // block, c * block * block)
 
 
 def _conv(
@@ -89,15 +76,10 @@ def _batch_norm(
     dtype,
     zero_init: bool = False,
     name: str = None,
-    stats_dtype=jnp.float32,
 ):
     """BN with reference constants: momentum .9, eps 1e-5
     (resnet_model.py:10-11); optionally zero-init gamma (:150, :201).
-
-    ``stats_dtype`` != float32 turns off flax's f32 promotion of the
-    batch-statistics reduction (PROFILE.md roadmap item 2 — measured a
-    no-win on v5e, and its fast-variance form cancels catastrophically
-    for channels with std ≪ |mean| in bf16; default stays f32).
+    The batch statistics reduce in float32 (flax's default).
 
     Uses the per-replica-capable subclass (``models/norm.py``): the
     pjit engine's batch-split grouping engages through it, the dp
@@ -111,75 +93,9 @@ def _batch_norm(
         epsilon=_BN_EPS,
         dtype=dtype,
         param_dtype=jnp.float32,
-        force_float32_reductions=jnp.dtype(stats_dtype) == jnp.float32,
         scale_init=nn.initializers.zeros if zero_init else nn.initializers.ones,
         name=name,
     )
-
-
-class _SplitBN(nn.Module):
-    """BatchNorm bookkeeping with the *reduction done elsewhere*: takes
-    the batch mean/var (computed by a fused Pallas epilogue or a plain
-    XLA pass), owns the scale/bias params and the running-stats update,
-    and returns the statistics to normalize with. Variable names and
-    shapes match ``nn.BatchNorm`` exactly (pass ``name="BatchNorm_k"``),
-    so the fused and unfused blocks share checkpoints."""
-
-    use_running_average: bool
-    momentum: float = 0.9
-    zero_init: bool = False
-
-    @nn.compact
-    def __call__(self, batch_mean, batch_var):
-        c = batch_mean.shape[-1]
-        ra_mean = self.variable(
-            "batch_stats", "mean", lambda: jnp.zeros((c,), jnp.float32)
-        )
-        ra_var = self.variable(
-            "batch_stats", "var", lambda: jnp.ones((c,), jnp.float32)
-        )
-        scale = self.param(
-            "scale",
-            nn.initializers.zeros if self.zero_init else nn.initializers.ones,
-            (c,), jnp.float32,
-        )
-        bias = self.param("bias", nn.initializers.zeros, (c,), jnp.float32)
-        if self.use_running_average:
-            return ra_mean.value, ra_var.value, scale, bias
-        mean = batch_mean.astype(jnp.float32)
-        var = batch_var.astype(jnp.float32)
-        if not self.is_initializing():
-            m = self.momentum
-            ra_mean.value = m * ra_mean.value + (1.0 - m) * mean
-            ra_var.value = m * ra_var.value + (1.0 - m) * var
-        return mean, var, scale, bias
-
-
-class _Conv1x1Kernel(nn.Module):
-    """The kernel param of a bias-free 1×1 conv, same path/shape as
-    ``nn.Conv`` (pass ``name="Conv_k"``) — the matmul itself runs inside
-    the fused Pallas op."""
-
-    features: int
-
-    @nn.compact
-    def __call__(self, in_features: int):
-        return self.param(
-            "kernel", _KERNEL_INIT, (1, 1, in_features, self.features),
-            jnp.float32,
-        )
-
-
-def _bn_apply(y, mean, var, scale, bias, eps, dtype):
-    inv = jax.lax.rsqrt(var + eps) * scale
-    return (
-        y.astype(jnp.float32) * inv[None, :] + (bias - mean * inv)[None, :]
-    ).astype(dtype)
-
-
-def _moments(s, ss, count):
-    mean = s / count
-    return mean, ss / count - mean * mean
 
 
 class BasicBlock(nn.Module):
@@ -188,13 +104,10 @@ class BasicBlock(nn.Module):
     filters: int
     strides: int = 1
     dtype: Any = jnp.bfloat16
-    stats_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x, train: bool = True):
-        bn = functools.partial(
-            _batch_norm, train, self.dtype, stats_dtype=self.stats_dtype
-        )
+        bn = functools.partial(_batch_norm, train, self.dtype)
         residual = x
         y = _conv(self.filters, 3, self.strides, self.dtype)(x)
         y = bn()(y)
@@ -213,24 +126,10 @@ class BottleneckBlock(nn.Module):
     filters: int
     strides: int = 1
     dtype: Any = jnp.bfloat16
-    stats_dtype: Any = jnp.float32
-    # Fused Pallas path (PROFILE.md roadmap item 1, partial): the two 1×1
-    # convs run as single-pass matmul kernels with the BN statistics
-    # accumulated in the same pass, and the BN2→ReLU activation feeding
-    # conv3 lives only in VMEM. Identical math and identical param /
-    # batch_stats tree as the unfused path (oracle-tested). Measured a
-    # net LOSS on v5e (PROFILE.md) — kept as the recorded experiment.
-    # The in-block statistics are always f32 here (`stats_dtype` applies
-    # to the unfused path and the projection BN only).
-    fused: bool = False
 
     @nn.compact
     def __call__(self, x, train: bool = True):
-        if self.fused:
-            return self._call_fused(x, train)
-        bn = functools.partial(
-            _batch_norm, train, self.dtype, stats_dtype=self.stats_dtype
-        )
+        bn = functools.partial(_batch_norm, train, self.dtype)
         residual = x
         y = _conv(self.filters, 1, 1, self.dtype)(x)
         y = bn()(y)
@@ -245,60 +144,6 @@ class BottleneckBlock(nn.Module):
             residual = bn(name="proj_bn")(residual)
         return nn.relu(y + residual)
 
-    def _call_fused(self, x, train: bool):
-        from distributeddeeplearning_tpu.ops.pallas.fused_block import (
-            bn_relu_matmul_stats,
-            matmul_stats,
-        )
-
-        eps = _BN_EPS
-        f = self.filters
-        b, h, w, cin = x.shape
-        # --- conv1 (1×1) with BN0-stats epilogue ---
-        k1 = _Conv1x1Kernel(f, name="Conv_0")(cin)
-        y1, s1, ss1 = matmul_stats(
-            x.reshape(-1, cin), k1.reshape(cin, f).astype(self.dtype)
-        )
-        bn0 = _SplitBN(use_running_average=not train, name="BatchNorm_0")
-        mean1, var1, sc1, bi1 = bn0(*_moments(s1, ss1, y1.shape[0]))
-        z1 = nn.relu(
-            _bn_apply(y1, mean1, var1, sc1, bi1, eps, self.dtype)
-        ).reshape(b, h, w, f)
-        # --- conv2 (3×3, XLA) → BN1 stats via a plain pass ---
-        y2 = _conv(f, 3, self.strides, self.dtype, name="Conv_1")(z1)
-        # output spatial dims come from the conv (ceil division under
-        # "fixed" padding), not from h // strides
-        _, h_out, w_out, _ = y2.shape
-        y2f = y2.reshape(-1, f)
-        y2_32 = y2f.astype(jnp.float32)
-        m2 = jnp.mean(y2_32, axis=0)
-        v2 = jnp.mean(y2_32 * y2_32, axis=0) - m2 * m2
-        bn1 = _SplitBN(use_running_average=not train, name="BatchNorm_1")
-        mean2, var2, sc2, bi2 = bn1(m2, v2)
-        # --- BN1-apply → ReLU → conv3 (1×1) → BN2-stats, one kernel ---
-        k3 = _Conv1x1Kernel(4 * f, name="Conv_2")(f)
-        y3, s3, ss3 = bn_relu_matmul_stats(
-            y2f, mean2, var2, sc2, bi2,
-            k3.reshape(f, 4 * f).astype(self.dtype), eps,
-        )
-        bn2 = _SplitBN(
-            use_running_average=not train, zero_init=True, name="BatchNorm_2"
-        )
-        mean3, var3, sc3, bi3 = bn2(*_moments(s3, ss3, y3.shape[0]))
-        y = _bn_apply(y3, mean3, var3, sc3, bi3, eps, self.dtype).reshape(
-            b, h_out, w_out, 4 * f
-        )
-        residual = x
-        if residual.shape != y.shape:
-            residual = _conv(
-                4 * f, 1, self.strides, self.dtype, name="proj_conv"
-            )(x)
-            residual = _batch_norm(
-                train, self.dtype, name="proj_bn",
-                stats_dtype=self.stats_dtype,
-            )(residual)
-        return nn.relu(y + residual)
-
 
 class ResNet(nn.Module):
     """ResNet v1 (reference ``resnet_v1_generator`` :237-301).
@@ -311,23 +156,12 @@ class ResNet(nn.Module):
     depth: int = 50
     num_classes: int = 1000
     dtype: Any = jnp.bfloat16
-    # PROFILE.md byte-reduction knobs (default off = exact round-2
-    # semantics). stats_dtype: dtype of the BN batch-statistics reduction.
-    # s2d_stem: MLPerf space-to-depth input — the 7×7/2 stem conv on
-    # 224²×3 becomes a 4×4/1 conv on 112²×12 (same 2× downsample, the
-    # 8×8-pixel support supersets the original 7×7 receptive field).
-    stats_dtype: Any = jnp.float32
-    s2d_stem: bool = False
-    # Fused Pallas bottleneck segments (see BottleneckBlock.fused);
-    # ignored for the basic-block depths.
-    fused: bool = False
 
     @property
     def per_replica_bn_capable(self) -> bool:
-        """The pjit engine's batch-split per-replica BN (models/norm.py)
-        works through every BN here EXCEPT the fused experiment's
-        in-kernel statistics (``_SplitBN`` takes pre-reduced moments)."""
-        return not self.fused
+        """Every BN is the group-capable subclass (models/norm.py): the
+        pjit engine's batch-split per-replica BN applies."""
+        return True
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -339,26 +173,19 @@ class ResNet(nn.Module):
         block = BasicBlock if kind == "basic" else BottleneckBlock
 
         x = jnp.asarray(x, self.dtype)
-        if self.s2d_stem:
-            x = space_to_depth(x, 2)
-            x = _conv(64, 4, 1, self.dtype, name="stem_conv_s2d")(x)
-        else:
-            x = _conv(64, 7, 2, self.dtype, name="stem_conv")(x)
-        x = _batch_norm(train, self.dtype, name="stem_bn", stats_dtype=self.stats_dtype)(x)
+        x = _conv(64, 7, 2, self.dtype, name="stem_conv")(x)
+        x = _batch_norm(train, self.dtype, name="stem_bn")(x)
         x = nn.relu(x)
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
 
         for stage, n_blocks in enumerate(stage_sizes):
             for b in range(n_blocks):
                 strides = 2 if (stage > 0 and b == 0) else 1
-                kw = {"fused": self.fused} if kind == "bottleneck" else {}
                 x = block(
                     filters=64 * 2**stage,
                     strides=strides,
                     dtype=self.dtype,
-                    stats_dtype=self.stats_dtype,
                     name=f"stage{stage + 1}_block{b + 1}",
-                    **kw,
                 )(x, train=train)
 
         x = jnp.mean(x, axis=(1, 2))  # global average pool

@@ -8,7 +8,7 @@ so the same module runs the XLA einsum path, the Pallas flash kernel
 ``ops/pallas/flash.py``), or — inside a ``seq``-axis ``shard_map`` —
 ring sequence parallelism (``parallel/ring_attention.py``). Which one
 is the call's own choice by default (``attn_impl="auto"``,
-``models/vit.Attention._resolve_impl``): on a TPU with local operands
+the rule is ``ops/attention.resolve_impl``): on a TPU with local operands
 the flash kernel from 640 tokens on (the packed kernel up to 512), the
 einsum elsewhere; no environment variable is needed to get the kernel.
 
@@ -141,8 +141,8 @@ class TransformerLM(nn.Module):
     max_seq_len: int = 2048
     dtype: Any = jnp.bfloat16
     # "auto": the flash kernel for a long sequence on a TPU, the packed
-    # kernel for a short one, the XLA einsum elsewhere (models/vit.
-    # Attention._resolve_impl); any other value forces a path.
+    # kernel for a short one, the XLA einsum elsewhere (ops/attention.
+    # resolve_impl); any other value forces a path.
     attn_impl: str = "auto"
     dropout: float = 0.0
     seq_axis: Any = None

@@ -14,7 +14,7 @@ Design is TPU-first throughout:
 * Attention goes through ``ops.dot_product_attention`` so the impl can
   be swapped (XLA einsum / Pallas flash kernel / ring sequence-parallel)
   per config; the default ``"auto"`` picks a kernel from shape and
-  platform (``Attention._resolve_impl``).
+  platform (the rule lives in ``ops/attention.resolve_impl``).
 * bf16 compute, f32 params; LayerNorm in f32 (TPU numerics practice).
 
 Variant table follows the standard ViT paper sizes; patch size via name
@@ -30,8 +30,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from distributeddeeplearning_tpu import obs
-from distributeddeeplearning_tpu.ops.attention import dot_product_attention
+from distributeddeeplearning_tpu.ops.attention import (
+    dot_product_attention,
+    resolve_impl,
+)
 
 # Sub-scope of an Attention module for scores, softmax and weighted sum
 # (whichever lowering runs them): what a flash kernel replaces, apart
@@ -55,62 +57,8 @@ from distributeddeeplearning_tpu.models.sharding import (  # noqa: F401
 )
 
 
-def kernel_is_safe(x, initializing: bool) -> bool:
-    """Whether a Pallas kernel may stand in this call's attention core
-    (the first half of ``Attention._resolve_impl``'s rule, shared with
-    ``models/decoder.py``): on a TPU, ``[B, T, D]`` operands that are
-    already local, and not while initializing."""
-    local = bool(getattr(jax.typeof(x), "vma", ())) or jax.device_count() == 1
-    return (
-        x.ndim == 3
-        and jax.default_backend() == "tpu"
-        and local
-        and not initializing
-    )
-
-
-class _FusedGradDense(nn.Dense):
-    """``nn.Dense`` whose backward computes dW and db in ONE pass over
-    the upstream gradient (``ops/pallas/fused_grads.bias_dense``) instead
-    of XLA's matmul + separate bias-grad reduction. Same param names,
-    shapes, and init — checkpoint-compatible with ``nn.Dense``. dp-engine
-    experiment (the Pallas custom call is opaque to GSPMD); enabled via
-    ``FUSED_DENSE_GRAD=1``."""
-
-    @nn.compact
-    def __call__(self, inputs):
-        kernel = self.param(
-            "kernel",
-            self.kernel_init,
-            (inputs.shape[-1], self.features),
-            self.param_dtype,
-        )
-        bias = self.param(
-            "bias", self.bias_init, (self.features,), self.param_dtype
-        )
-        from distributeddeeplearning_tpu.ops.pallas import fused_grads
-
-        if fused_grads.gspmd_active():
-            # Inside a pjit-partitioned trace the Pallas custom call is
-            # opaque to GSPMD — keep the stock XLA dense (same forward
-            # numerics; backward is XLA's).
-            return (
-                jnp.dot(inputs.astype(self.dtype), nn.unbox(kernel).astype(self.dtype))
-                + nn.unbox(bias).astype(self.dtype)
-            )
-        interpret = jax.default_backend() != "tpu"
-        return fused_grads.bias_dense(
-            inputs, nn.unbox(kernel), nn.unbox(bias), self.dtype, interpret
-        )
-
-
 def _dense(features, name, kernel_axes, dtype, use_bias=True):
-    import os
-
-    cls = nn.Dense
-    if use_bias and os.environ.get("FUSED_DENSE_GRAD", "") == "1":
-        cls = _FusedGradDense
-    return cls(
+    return nn.Dense(
         features,
         dtype=dtype,
         param_dtype=jnp.float32,
@@ -146,9 +94,10 @@ class MlpBlock(nn.Module):
 class Attention(nn.Module):
     num_heads: int
     dtype: Any = jnp.bfloat16
-    # "auto" resolves per call: the packed small-T Pallas kernel
-    # (ops/pallas/flash_packed.py) where it applies, XLA einsum otherwise.
-    # Explicit values ("xla" | "pallas" | "ring" | "fused") force a path.
+    # "auto" resolves per call (ops/attention.resolve_impl): the packed
+    # small-T kernel or the streaming flash kernel where one applies,
+    # the XLA einsum otherwise. Explicit values ("xla" | "pallas" |
+    # "ring" | "fused") force a path.
     attn_impl: str = "xla"
     dropout: float = 0.0
     causal: bool = False  # decoder-only use (models/transformer_lm.py)
@@ -466,39 +415,6 @@ class Attention(nn.Module):
             k_all, v_all = ck.value, cv.value
         return self._masked_decode_scores(q, k_all, v_all, q_pos)
 
-    def _resolve_impl(self, x, head_dim: int) -> str:
-        """The attention core's lowering for this call, chosen from what
-        the call can see. An explicit ``attn_impl`` is taken as given.
-        ``"auto"`` takes a Pallas kernel where a custom call is safe and
-        pays: on a TPU, with operands that are already local (one
-        device, or inside ``shard_map``: the dp/sp engines; under
-        multi-device GSPMD, the pjit engine, operands carry no varying
-        axes and a custom call would force replication), and not while
-        initializing (parameters do not depend on the path, and the
-        weight draw should lower no kernel it never runs). There, by
-        shape: the packed small-T kernel where it takes the sequence
-        (``flash_packed.supports``: T <= 512, the ViT regime), the
-        streaming flash kernel where it is ahead (``flash.supports``:
-        T >= 640 on the v5e's measurement, head blocks that tile the
-        lanes), else the XLA einsum. ``decode=True`` never comes here.
-        What was chosen is counted at trace time (``attn.impl.<path>``)."""
-        impl = self.attn_impl
-        if impl == "auto":
-            from distributeddeeplearning_tpu.ops.pallas import flash, flash_packed
-
-            impl = "xla"
-            if kernel_is_safe(x, self.is_initializing()):
-                shape = (x.shape[1], self.num_heads, head_dim)
-                if flash_packed.supports(*shape):
-                    impl = "fused"
-                elif flash.supports(*shape):
-                    impl = "pallas"
-        obs.counter(
-            f"attn.impl.{impl}", asked=self.attn_impl, shape=list(x.shape),
-            heads=self.num_heads,
-        )
-        return impl
-
     @nn.compact
     def __call__(self, x, train: bool = True):
         d = x.shape[-1]
@@ -506,7 +422,12 @@ class Attention(nn.Module):
         qkv_flat = _dense(3 * d, "qkv", ("embed", "heads"), self.dtype)(x)
         # Params don't depend on the impl, and ring needs a bound mesh
         # axis — init (traced outside shard_map) uses the xla path.
-        impl = None if self.decode else self._resolve_impl(x, head_dim)
+        # The core's lowering for this call (ops/attention.resolve_impl);
+        # ``decode=True`` never asks.
+        impl = None if self.decode else resolve_impl(
+            self.attn_impl, x, heads=self.num_heads, head_dim=head_dim,
+            initializing=self.is_initializing(), packed_qkv=True,
+        )
         if impl == "ring" and self.is_initializing():
             impl = "xla"
         packed = None

@@ -20,10 +20,18 @@ training objective (a row runs as ``[noised ‖ clean]`` under a
 block-granular mask, grouped query heads): the einsum over the dense
 mask, or three passes of the flash kernels and a merge by logsumexp.
 
-All take ``[batch, seq, heads, head_dim]`` (BTHD) tensors. A caller
-names one; ``models/vit.Attention`` with ``attn_impl="auto"`` (the
-models' default) picks by shape and platform, and takes the packed
-small-T kernel (``ops/pallas/flash_packed.py``) before this layer.
+All take ``[batch, seq, heads, head_dim]`` (BTHD) tensors; keys and
+values may have fewer heads than the queries (grouped queries: ``H //
+KV`` query heads to a key head).
+
+**Which of them a call runs is decided here**, in :func:`resolve_impl`:
+a model states what it was asked (``attn_impl``; ``"auto"`` is the
+models' default), what it can see of the call (operands, head geometry,
+whether it is initializing) and whether a fused ``[B, T, 3·H·d]``
+projection feeds the core (only then can the packed small-T kernel,
+``ops/pallas/flash_packed.py``, be taken, and the model calls it
+itself). :func:`kernel_interpreted` answers the engines' question about
+a value of ``attn_impl``.
 """
 
 from __future__ import annotations
@@ -32,25 +40,107 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+
+from distributeddeeplearning_tpu import obs
+
+# Values of ``impl`` whose core is a Pallas kernel: the streaming flash
+# kernels, the packed small-T kernel.
+_KERNEL_IMPLS = ("pallas", "fused")
 
 
-def _xla_attention(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
+def kernel_interpreted(impl: Optional[str]) -> bool:
+    """Whether a model built with ``attn_impl=impl`` would run a Pallas
+    kernel in interpret mode (off the TPU): the HLO interpreter's
+    internal slicing trips ``shard_map``'s varying-axes checker
+    (upstream limitation; its own error message recommends
+    ``check_vma=False``), so the engines drop the check for exactly this
+    case. ``"auto"`` takes a kernel on a TPU alone, so it needs no
+    exception."""
+    return impl in _KERNEL_IMPLS and jax.default_backend() != "tpu"
+
+
+def kernel_is_safe(x, initializing: bool) -> bool:
+    """Whether a Pallas kernel may stand in this call's attention core:
+    on a TPU, ``[B, T, D]`` operands that are already local (one device,
+    or inside ``shard_map``: the dp/sp engines; under multi-device
+    GSPMD, the pjit engine, operands carry no varying axes and a custom
+    call would force replication), and not while initializing
+    (parameters do not depend on the path, and the weight draw should
+    lower no kernel it never runs)."""
+    local = bool(getattr(jax.typeof(x), "vma", ())) or jax.device_count() == 1
+    return (
+        x.ndim == 3
+        and jax.default_backend() == "tpu"
+        and local
+        and not initializing
+    )
+
+
+def resolve_impl(
+    asked: str,
+    x,
     *,
-    causal: bool = False,
-    scale: Optional[float] = None,
-) -> jnp.ndarray:
-    scale = scale if scale is not None else q.shape[-1] ** -0.5
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
-    if causal:
-        tq, tk = scores.shape[-2], scores.shape[-1]
-        mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+    heads: int,
+    head_dim: int,
+    initializing: bool,
+    packed_qkv: bool = False,
+    kv_heads: Optional[int] = None,
+    mask: Optional[str] = None,
+) -> str:
+    """The attention core's lowering for one call, chosen from what the
+    call can see: ``x [B, T, D]`` is the attention module's input.
+
+    An explicit ``asked`` is taken as given. ``"auto"`` takes a Pallas
+    kernel where a custom call is safe (:func:`kernel_is_safe`) and
+    pays, by shape: the packed small-T kernel (``"fused"``) where a
+    fused QKV projection feeds the core (``packed_qkv``, the caller's
+    statement) and it takes the sequence (``flash_packed.supports``:
+    T <= 512, the ViT regime); the streaming flash kernels
+    (``"pallas"``) where they are ahead (``flash.supports``: T >= 640 on
+    the v5e's measurement, head blocks that tile the lanes); else the
+    XLA einsum. Under ``mask="block_diffusion"`` a row is ``[noised ‖
+    clean]`` and a pass of the kernels walks one half of it: the length
+    judged is T // 2.
+
+    What was chosen is counted at trace time: ``attn.impl.<path>``
+    (labels ``asked``, ``shape``, ``heads``, and ``kv_heads``, ``mask``
+    where the caller names them), and ``attn.mask.<mask>`` (label
+    ``impl``) for a caller that names its mask."""
+    impl = asked
+    if impl == "auto":
+        from distributeddeeplearning_tpu.ops.pallas import flash, flash_packed
+
+        impl = "xla"
+        if kernel_is_safe(x, initializing):
+            seq_len = x.shape[1] // 2 if mask == "block_diffusion" else x.shape[1]
+            if packed_qkv and flash_packed.supports(seq_len, heads, head_dim):
+                impl = "fused"
+            elif flash.supports(seq_len, heads, head_dim):
+                impl = "pallas"
+    labels = dict(asked=asked, shape=list(x.shape), heads=heads)
+    if kv_heads is not None:
+        labels["kv_heads"] = kv_heads
+    if mask is not None:
+        labels["mask"] = mask
+    obs.counter(f"attn.impl.{impl}", **labels)
+    if mask is not None:
+        obs.counter(f"attn.mask.{mask}", impl=impl)
+    return impl
+
+
+def _xla_attention(q, k, v, *, mask, scale: float):
+    """Masked-softmax einsum over BTHD operands with grouped query
+    heads; ``mask`` is ``[Tq, Tk]`` bool, True where a query sees a key,
+    or None. The ``[Tq, Tk]`` scores and weights go through HBM."""
+    b, tq, h, d = q.shape
+    kv = k.shape[2]
+    scores = jnp.einsum(
+        "bqgrd,bkgd->bgrqk", q.reshape(b, tq, kv, h // kv, d), k
+    ) * scale
+    if mask is not None:
         scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
     weights = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", weights, v).reshape(b, tq, h, d)
 
 
 def dot_product_attention(
@@ -63,18 +153,29 @@ def dot_product_attention(
     impl: str = "xla",
     axis_name: Optional[str] = None,
 ) -> jnp.ndarray:
-    """Multi-head attention over BTHD tensors.
+    """Multi-head attention over BTHD tensors, full or causal: ``q [B,
+    Tq, H, d]`` against ``k``, ``v`` ``[B, Tk, KV, d]``, ``H // KV``
+    query heads to a key head (``xla`` and ``pallas``).
 
     ``impl='ring'`` requires running inside ``shard_map`` with the
     sequence dimension sharded over ``axis_name`` (default: the mesh
     convention's ``"seq"`` axis, ``parallel/mesh.py``).
     """
     if impl == "xla":
-        return _xla_attention(q, k, v, causal=causal, scale=scale)
+        mask = None
+        if causal:
+            tq, tk = q.shape[1], k.shape[1]
+            mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        scale = scale if scale is not None else q.shape[-1] ** -0.5
+        return _xla_attention(q, k, v, mask=mask, scale=scale)
     if impl == "pallas":
-        from distributeddeeplearning_tpu.ops.pallas.flash import flash_attention
+        from distributeddeeplearning_tpu.ops.pallas import flash
 
-        return flash_attention(q, k, v, causal=causal, scale=scale)
+        if k.shape[2] == q.shape[2]:
+            return flash.flash_attention(q, k, v, causal=causal, scale=scale)
+        return flash.flash_attention_stats(
+            q, k, v, mask=flash.Mask(causal), scale=scale
+        )[0]
     if impl == "ring":
         axis_name = axis_name or "seq"
         from distributeddeeplearning_tpu.parallel.ring_attention import (
@@ -94,21 +195,6 @@ def block_diffusion_mask(length: int, block_len: int) -> jnp.ndarray:
     beta = jnp.arange(length) // block_len
     q, k = beta[:, None], beta[None, :]
     return jnp.block([[q == k, k < q], [jnp.zeros_like(q == k), k <= q]])
-
-
-def _grouped(q, kv_heads: int):
-    """``[B, T, H, d]`` queries as ``[B, T, KV, H // KV, d]``."""
-    b, t, h, d = q.shape
-    return q.reshape(b, t, kv_heads, h // kv_heads, d)
-
-
-def _xla_block_diffusion(q, k, v, block_len: int, scale: float):
-    b, t2, h, d = q.shape
-    scores = jnp.einsum("bqgrd,bkgd->bgrqk", _grouped(q, k.shape[2]), k) * scale
-    mask = block_diffusion_mask(t2 // 2, block_len)
-    scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
-    weights = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bgrqk,bkgd->bqgrd", weights, v).reshape(b, t2, h, d)
 
 
 def _flash_block_diffusion(q, k, v, block_len: int, scale: float):
@@ -166,7 +252,8 @@ def block_diffusion_attention(
         )
     scale = float(scale if scale is not None else q.shape[-1] ** -0.5)
     if impl == "xla":
-        return _xla_block_diffusion(q, k, v, block_len, scale)
+        mask = block_diffusion_mask(q.shape[1] // 2, block_len)
+        return _xla_attention(q, k, v, mask=mask, scale=scale)
     if impl == "pallas":
         return _flash_block_diffusion(q, k, v, block_len, scale)
     raise ValueError(f"unknown block-diffusion attention impl {impl!r}")

@@ -37,7 +37,7 @@ This kernel removes all three at once by changing the *boundary*:
   grid axis that revisits the same resident blocks: part 0 computes
   dq/dk/dv into VMEM scratch, parts 0/1/2 store them — no XLA concat.
 
-Used automatically by ``models/vit.py`` (``attn_impl="auto"``) for
+Chosen by ``ops/attention.resolve_impl`` (``attn_impl="auto"``) for
 T ≤ ``MAX_T`` on TPU; the streaming kernel (``flash.py``, from
 ``flash.MIN_T`` on) and the XLA einsum are the other regimes'
 implementations (``ops/attention.py``).

@@ -46,7 +46,6 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from distributeddeeplearning_tpu.ops.pallas.fused_grads import gspmd_trace
 from distributeddeeplearning_tpu.parallel.mesh import (
     batch_sharding as _mesh_batch_sharding,
 )
@@ -135,7 +134,7 @@ def create_sharded_train_state(
     active_rules = list(rules_for_mesh(mesh, tuple(rules)))
 
     def init_fn(r):
-        with nn.logical_axis_rules(active_rules), gspmd_trace():
+        with nn.logical_axis_rules(active_rules):
             variables = model.init(r, jnp.zeros(shape, input_dtype), train=False)
         params = lax.with_sharding_constraint(
             nn.unbox(variables["params"]), param_shardings
@@ -238,8 +237,7 @@ def make_pjit_train_step(
             # The rules context makes in-model nn.with_logical_constraint
             # calls real (MoE's expert-major activation layout — the
             # all-to-all boundary); without it they are silent no-ops.
-            with mesh, nn.logical_axis_rules(rules), per_replica_bn(bn_groups), \
-                    gspmd_trace():
+            with mesh, nn.logical_axis_rules(rules), per_replica_bn(bn_groups):
                 logits, mutated = model.apply(
                     {"params": params, "batch_stats": state.batch_stats},
                     images,
@@ -321,7 +319,7 @@ def make_pjit_train_step(
 
             def loss_fn(params):
                 with mesh, nn.logical_axis_rules(rules), \
-                        per_replica_bn(bn_groups), gspmd_trace():
+                        per_replica_bn(bn_groups):
                     logits, mutated = model.apply(
                         {"params": params, "batch_stats": bs},
                         normalize_staged_images(mb_images),
@@ -424,7 +422,7 @@ def make_pjit_eval_step(
         labels = lax.with_sharding_constraint(labels, batch_sharding)
         weights = lax.with_sharding_constraint(weights, batch_sharding)
         images = normalize_staged_images(images)  # uint8 staging
-        with mesh, nn.logical_axis_rules(rules), gspmd_trace():
+        with mesh, nn.logical_axis_rules(rules):
             logits = model.apply(
                 {"params": state.params, "batch_stats": state.batch_stats},
                 images,
@@ -478,9 +476,6 @@ def build_pjit_state(
     (``models/norm.py``) — dp-identical semantics, oracle-tested against
     the dp engine — unless ``config.allow_sync_bn`` (env
     ``ALLOW_SYNC_BN=1``) opts into GLOBAL-batch (sync) statistics.
-    The one exception is the fused Pallas bottleneck experiment
-    (``ResNet(fused=True)``): its in-kernel statistics don't group, so
-    it is refused here rather than silently training sync-BN.
     """
     from distributeddeeplearning_tpu.models.sharding import rules_table
 

@@ -50,6 +50,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributeddeeplearning_tpu import obs
 from distributeddeeplearning_tpu.config import TrainConfig
+from distributeddeeplearning_tpu.ops.attention import kernel_interpreted
 from distributeddeeplearning_tpu.parallel.mesh import batch_axes, replicated_sharding
 from distributeddeeplearning_tpu.training.overlap import overlap_scope
 from distributeddeeplearning_tpu.training.state import TrainState
@@ -288,27 +289,6 @@ def flat_axis_index(mesh: Mesh, axes) -> jnp.ndarray:
     return idx
 
 
-def _pallas_interpreted(model) -> bool:
-    """True when this model's attention would run a Pallas kernel in
-    interpreter mode (non-TPU backend): the HLO interpreter's internal
-    slicing trips shard_map's varying-axes checker (upstream limitation;
-    its own error message recommends check_vma=False), so the engines
-    drop the check for exactly this case. The compiled TPU path keeps
-    checking on — verified on hardware. Covers both explicit kernel
-    impls ("pallas" = streaming flash, "fused" = packed small-T); "auto",
-    the models' default, takes either kernel on a TPU alone and resolves
-    to "xla" off it (models/vit.Attention._resolve_impl), so it needs no
-    exception."""
-    import os
-
-    uses_pallas = getattr(model, "attn_impl", None) in ("pallas", "fused") or (
-        # FUSED_DENSE_GRAD=1 routes every Dense backward through a Pallas
-        # kernel (models/vit._FusedGradDense) — same interpreter caveat.
-        os.environ.get("FUSED_DENSE_GRAD", "") == "1"
-    )
-    return uses_pallas and jax.default_backend() != "tpu"
-
-
 def make_train_step(
     model,
     tx,
@@ -335,14 +315,14 @@ def make_train_step(
     folded sequentially into the running stats).
 
     ``check_vma=None`` auto-resolves: on except for interpreter-mode
-    Pallas attention (see :func:`_pallas_interpreted`).
+    Pallas attention (``ops/attention.kernel_interpreted``).
     """
     from distributeddeeplearning_tpu.training import accum
 
     cfg = config or TrainConfig()
     accum_steps = accum.resolve_accum_steps(cfg)
     if check_vma is None:
-        check_vma = not _pallas_interpreted(model)
+        check_vma = not kernel_interpreted(getattr(model, "attn_impl", None))
     axes = batch_axes(mesh)
     if not axes:
         raise ValueError(f"mesh {mesh.axis_names} has no batch axis")
@@ -641,7 +621,7 @@ def make_eval_step(
         raise ValueError(f"mesh {mesh.axis_names} has no batch axis")
     axis = axes if len(axes) > 1 else axes[0]
     if check_vma is None:
-        check_vma = not _pallas_interpreted(model)
+        check_vma = not kernel_interpreted(getattr(model, "attn_impl", None))
 
     def local_eval(state: TrainState, batch):
         images, labels, weights = batch

@@ -7,7 +7,7 @@ Three analyzer families over one finding/suppression substrate
     hlo       hlo-donation, hlo-collectives, hlo-cache-key
               (lowers every engine step + the SlotEngine program set
               on the forced-8-CPU-device mesh)
-    contract  env-docs, obs-registry, protocol-vars
+    contract  env-docs, env-free-tiers, obs-registry, protocol-vars
 
 Usage::
 

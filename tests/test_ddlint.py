@@ -205,6 +205,30 @@ def test_env_docs_self_hosting():
     assert [f.format() for f in out if not f.suppressed] == []
 
 
+# -- contracts: env-free-tiers --------------------------------------------
+
+
+def test_env_touches_fixture():
+    src = textwrap.dedent("""
+        import os
+        from os import getenv
+
+        def dense(features):
+            if os.environ.get("SOME_KNOB", "") == "1":
+                return 1
+            e = os.environ
+            return os.getenv("OTHER") or os.path.join("a", "b")
+    """)
+    assert contracts.env_touches(src) == [3, 6, 8, 9]
+    assert contracts.env_touches("import os\np = os.path.sep\n") == []
+
+
+def test_env_free_tiers_self_hosting():
+    """``models/`` and ``ops/`` read no environment (until PR 29
+    ``models/vit._dense`` read one at trace time for every Dense)."""
+    assert [f.format() for f in contracts.run_env_free_tiers()] == []
+
+
 # -- contracts: obs-registry ----------------------------------------------
 
 

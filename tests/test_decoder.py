@@ -111,6 +111,65 @@ def test_a_gpt2_spec_is_the_lm_of_that_size():
     assert gap(got, lm.apply({"params": params}, tokens, train=False)) < 1e-5
 
 
+def _causal_sdar(attn_impl):
+    """``sdar_tiny`` on the causal mask: grouped heads (4 over 2)
+    through ``ops/attention.dot_product_attention``."""
+    return get_model(
+        "sdar_tiny", num_classes=VOCAB, dtype="float32", attn_impl=attn_impl,
+        block_len=0, max_seq_len=L,
+    )
+
+
+def _logits_and_grads(model, params, tokens):
+    def loss(p):
+        logits = model.apply({"params": p}, tokens, train=False)
+        return jnp.sum(logits ** 2) / logits.size, logits
+
+    (_, logits), grads = jax.value_and_grad(loss, has_aux=True)(params)
+    return logits, grads
+
+
+@pytest.mark.parametrize("against", ["pallas", "repeated_key_heads"])
+def test_the_causal_spec_model_with_grouped_heads(against):
+    """The einsum over grouped heads against the flash kernels (interpret
+    mode), and against the einsum over the same weights with every key
+    and value head's projection written out once a query head."""
+    tokens = jnp.asarray(clean_rows(2))
+    model = _causal_sdar("xla")
+    params = model.init(jax.random.PRNGKey(2), tokens, train=False)["params"]
+    logits, grads = _logits_and_grads(model, params, tokens)
+    assert logits.shape == (2, L, VOCAB)
+    if against == "pallas":
+        got, got_grads = _logits_and_grads(_causal_sdar("pallas"), params, tokens)
+        assert gap(got, logits) < 1e-5
+        for a, b in zip(jax.tree.leaves(got_grads), jax.tree.leaves(grads)):
+            assert gap(a, b) < 1e-4
+        return
+    spec = model.spec
+    group = spec.heads // spec.kv_heads
+
+    def widen(kernel):  # [hidden, KV·d] -> [hidden, H·d], key head g for queries g·r..
+        w = kernel.reshape(spec.hidden, spec.kv_heads, spec.head_dim)
+        return jnp.repeat(w, group, axis=1).reshape(spec.hidden, -1)
+
+    wide = dict(params)
+    for i in range(spec.layers):
+        attn = dict(wide[f"block{i}"]["attn"])
+        attn["k"] = {"kernel": widen(attn["k"]["kernel"])}
+        attn["v"] = {"kernel": widen(attn["v"]["kernel"])}
+        wide[f"block{i}"] = {**wide[f"block{i}"], "attn": attn}
+    equal = get_model(
+        "sdar_tiny", num_classes=VOCAB, dtype="float32", attn_impl="xla",
+        block_len=0, max_seq_len=L, kv_heads=spec.heads,
+    )
+    got, got_grads = _logits_and_grads(equal, wide, tokens)
+    assert gap(got, logits) < 1e-5
+    # what does not feed a key head has the gradient it had
+    assert gap(got_grads["block0"]["attn"]["q"]["kernel"],
+               grads["block0"]["attn"]["q"]["kernel"]) < 1e-4
+    assert gap(got_grads["tok_embed"], grads["tok_embed"]) < 1e-4
+
+
 # -- the attention core -------------------------------------------------------
 
 def test_the_mask_is_the_reference_s():
@@ -373,7 +432,11 @@ def test_the_two_element_batch_compiles_to_the_step_it_compiled_to():
     text for text what it was (the weighted objective, the sown
     statistics and the batch's prefix spec add nothing to a step that
     does not use them). Taken anew in PR 28, whose loss reads the logits
-    in place: PR 27's text held the float32 copy and the gather."""
+    in place (PR 27's text held the float32 copy and the gather), and in
+    PR 29, whose one einsum core (``ops/attention._xla_attention``)
+    carries the group axis at width 1 for equal heads: the attention
+    core's operations alone differ from PR 28's text, each by a unit
+    axis."""
     if jax.__version__ != "0.9.0" or jax.device_count() != 8:
         pytest.skip("the text was taken under jax 0.9.0 on the tests' 8 host devices")
     from distributeddeeplearning_tpu.config import TrainConfig
@@ -396,5 +459,5 @@ def test_the_two_element_batch_compiles_to_the_step_it_compiled_to():
     x = jnp.zeros((2, 32), jnp.int32)
     text = step._resolve(state, False).lower(state, (x, x)).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "21b602cce0a9be9f0f1452c84abd757c2917c280f837262ec88c16ce7a72e663"
+        "99cf36b5bedc2b98b3bfdacd00ff4a04beddf5939bc5fad27076a4ed98b3cae3"
     )

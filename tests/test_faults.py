@@ -238,13 +238,17 @@ def test_supervisor_treats_nonfinite_exit_as_terminal(tmp_path):
 
 def test_supervisor_recovers_watchdog_killed_hang(tmp_path):
     """Hang → watchdog kill (125) → classified retryable → relaunch →
-    resume past the hang step → clean exit."""
+    resume past the hang step → clean exit. The watchdog counts from the
+    launch, and ``--hang-timeout`` holds for the restarted child too: it
+    must fit a process start (importing the package takes 3 s on an
+    idle machine, more beside five other test workers), or the watchdog
+    kills the recovery it is testing. The injected hang is 300 s."""
     res = _run_launcher(
         [
             "--num-processes", "1",
             "--max-restarts", "1",
             "--restart-backoff", "0.1",
-            "--hang-timeout", "3",
+            "--hang-timeout", "15",
             "--timeout", "120",
             "--env", "JAX_PLATFORMS=cpu",
             "--env", "FAULT_PLAN=hang:step=2,secs=300",
@@ -291,22 +295,24 @@ _HB_CHILD = textwrap.dedent(
     from distributeddeeplearning_tpu.utils import heartbeat
     print("alive", flush=True)
     with heartbeat.during("aot_compile"):
-        time.sleep(8)  # silent-but-compiling: used to be watchdog bait
+        time.sleep(20)  # silent-but-compiling: used to be watchdog bait
     print("HB_CHILD_OK", flush=True)
     """
 )
 
 
 def test_heartbeat_keeps_compiling_world_alive(tmp_path):
-    """An 8s-silent 'compile' under a 3s hang watchdog survives because
+    """A 20s-silent 'compile' under a 12s hang watchdog survives because
     the launcher exports DDL_HEARTBEAT_EVERY_S and counts the magic
-    lines as liveness — while keeping them out of the streamed log."""
+    lines as liveness — while keeping them out of the streamed log. (12
+    s, not 3: the watchdog counts from the launch, and the child's
+    imports alone take 3 s on an idle machine.)"""
     script = tmp_path / "hb.py"
     script.write_text(_HB_CHILD)
     res = _run_launcher(
         [
             "--num-processes", "1",
-            "--hang-timeout", "3",
+            "--hang-timeout", "12",
             "--timeout", "120",
             "--env", "JAX_PLATFORMS=cpu",
             str(script),

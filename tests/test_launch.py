@@ -338,7 +338,7 @@ def test_watchdog_accepts_telemetry_as_liveness(tmp_path):
         from distributeddeeplearning_tpu import obs
 
         bus = obs.configure_from_env()
-        for i in range(18):          # ~7.2s of stdout silence
+        for i in range(45):          # ~18s of stdout silence
             bus.point("tick", i=i)
             time.sleep(0.4)
         bus.flush()
@@ -349,7 +349,9 @@ def test_watchdog_accepts_telemetry_as_liveness(tmp_path):
         [
             "--num-processes", "1",
             "--obs-dir", str(obs_dir),
-            "--hang-timeout", "6",   # > child import time, < its runtime
+            # > the child's import time (3 s idle, several times that
+            # beside five other test workers), < its runtime
+            "--hang-timeout", "15",
             "--timeout", "120",
             "--env", "JAX_PLATFORMS=cpu",
             "--env", "OBS_FLUSH_EVERY_S=0.5",
@@ -374,7 +376,9 @@ def test_obs_killed_child_leaves_flight_dump(tmp_path):
         [
             "--num-processes", "2",
             "--obs-dir", str(obs_dir),
-            "--hang-timeout", "6",
+            # long enough for both children to import the package and
+            # arm the flight recorder before the watchdog's SIGTERM
+            "--hang-timeout", "15",
             "--timeout", "120",
             "--env", "JAX_PLATFORMS=cpu",
             "--env", "HANG=1",

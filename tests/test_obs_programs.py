@@ -525,7 +525,7 @@ def test_trace_controller_leaves_the_scope_tables_beside_a_capture(tmp_path, cap
 
 
 def test_the_resolved_attention_path_is_counted_at_trace_time_and_reported():
-    """``Attention._resolve_impl`` counts what it chose, once a trace:
+    """``ops/attention.resolve_impl`` counts what it chose, once a trace:
     ``bus.totals()`` holds it, the event carries the shape, and ``make
     trace-report`` prints it from a run's event files."""
     from distributeddeeplearning_tpu.models.vit import Attention

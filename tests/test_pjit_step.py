@@ -403,18 +403,15 @@ def test_sync_bn_opt_in_differs_from_per_replica(mesh8):
 
 def test_incapable_bn_models_still_refused_under_pjit(mesh8):
     """The narrowed guard: per-replica semantics only exist for models
-    whose norm layers are the group-capable subclass. ResNet(fused=True)
-    (in-kernel statistics) and any plain-``nn.BatchNorm`` model are
-    still refused rather than silently training sync-BN."""
+    whose norm layers are the group-capable subclass. Any
+    plain-``nn.BatchNorm`` model is still refused rather than silently
+    training sync-BN."""
     import flax.linen as nn
 
     from distributeddeeplearning_tpu.training.pjit_step import build_pjit_state
 
     cfg = CFG.replace(engine="pjit", image_size=16)
     tx = optax.sgd(0.05)
-    fused = ResNet(depth=50, num_classes=10, dtype=jnp.float32, fused=True)
-    with pytest.raises(ValueError, match="per_replica_bn_capable"):
-        build_pjit_state(fused, cfg, tx, mesh8)
 
     class PlainBNNet(nn.Module):
         @nn.compact
@@ -425,7 +422,7 @@ def test_incapable_bn_models_still_refused_under_pjit(mesh8):
 
     with pytest.raises(ValueError, match="per_replica_bn_capable"):
         build_pjit_state(PlainBNNet(), cfg, tx, mesh8)
-    # sync-BN opt-in still admits both
+    # the sync-BN opt-in still admits it
     state = build_pjit_state(
         PlainBNNet(), cfg.replace(allow_sync_bn=True), tx, mesh8
     )

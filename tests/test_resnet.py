@@ -85,17 +85,44 @@ def test_bfloat16_compute_f32_params():
     assert out.dtype == jnp.float32
 
 
-def test_perf_knobs_bf16_stats_and_s2d_stem():
-    # PROFILE.md roadmap knobs (measured no-win on v5e but supported):
-    # bf16 statistics reduction + MLPerf space-to-depth stem.
-    model = ResNet(depth=18, num_classes=10, dtype=jnp.bfloat16,
-                   stats_dtype=jnp.bfloat16, s2d_stem=True)
-    variables, x = _init(model, size=64)
-    stem = variables["params"]["stem_conv_s2d"]["kernel"]
-    assert stem.shape == (4, 4, 12, 64)  # 112²×12 input, 2× fold into channels
-    out, mutated = model.apply(variables, jnp.asarray(x, jnp.bfloat16),
-                               train=True, mutable=["batch_stats"])
-    assert out.shape == (2, 10) and out.dtype == jnp.float32
-    # running stats stay f32 regardless of the reduction dtype
-    for leaf in jax.tree.leaves(mutated["batch_stats"]):
-        assert leaf.dtype == jnp.float32
+def test_resnet50_default_tree_is_what_a_checkpoint_restores_into():
+    """Paths, shapes and dtypes of ResNet50's ``params`` and
+    ``batch_stats``, written out from the architecture (flax's automatic
+    names in a bottleneck block: ``Conv_0..2``, ``BatchNorm_0..2``, the
+    projection under ``proj_conv`` / ``proj_bn``): a checkpoint of any
+    earlier build restores into this tree leaf for leaf."""
+    classes = 1000
+    params = {"stem_conv/kernel": (7, 7, 3, 64), "head/kernel": (2048, classes),
+              "head/bias": (classes,)}
+    norms = {"stem_bn": 64}
+    cin = 64
+    for stage, blocks in enumerate((3, 4, 6, 3)):
+        f = 64 * 2**stage
+        for b in range(blocks):
+            name = f"stage{stage + 1}_block{b + 1}"
+            convs = {"Conv_0": (1, 1, cin, f), "Conv_1": (3, 3, f, f),
+                     "Conv_2": (1, 1, f, 4 * f)}
+            widths = {"BatchNorm_0": f, "BatchNorm_1": f, "BatchNorm_2": 4 * f}
+            if b == 0:
+                convs["proj_conv"] = (1, 1, cin, 4 * f)
+                widths["proj_bn"] = 4 * f
+            params.update({f"{name}/{k}/kernel": v for k, v in convs.items()})
+            norms.update({f"{name}/{k}": v for k, v in widths.items()})
+            cin = 4 * f
+    params.update({f"{k}/{leaf}": (c,) for k, c in norms.items() for leaf in ("scale", "bias")})
+    stats = {f"{k}/{leaf}": (c,) for k, c in norms.items() for leaf in ("mean", "var")}
+
+    model = get_model("resnet50", num_classes=classes)
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 64, 64, 3)), train=False)
+    )
+
+    def flat(tree):
+        return {
+            "/".join(k.key for k in path): (leaf.shape, leaf.dtype)
+            for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+        }
+
+    assert sorted(shapes) == ["batch_stats", "params"]
+    assert flat(shapes["params"]) == {k: (v, jnp.float32) for k, v in params.items()}
+    assert flat(shapes["batch_stats"]) == {k: (v, jnp.float32) for k, v in stats.items()}

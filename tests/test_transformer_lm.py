@@ -157,7 +157,8 @@ def test_lm_trains_through_keras_frontend(mesh8):
 
 
 # ---- the attention core's lowering, chosen from shape and platform ---------
-# (models/vit.Attention._resolve_impl; the counter `attn.impl.<path>` says
+# (ops/attention.resolve_impl through models/vit.Attention; the rule's own
+# table is in tests/test_attention_ops.py; the counter `attn.impl.<path>` says
 # what a trace chose)
 
 

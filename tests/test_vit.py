@@ -221,7 +221,7 @@ def test_vit_fused_packed_attention_matches_xla(mesh8):
         np.asarray(logits_fused), np.asarray(logits_xla), atol=2e-4
     )
     state = replicate_state(state, mesh8)
-    # default check_vma: _pallas_interpreted covers impl='fused' off-TPU
+    # default check_vma: ops/attention.kernel_interpreted covers impl='fused' off-TPU
     step = make_train_step(m_fused, tx, mesh8, CFG, donate_state=False)
     new_state, metrics = step(state, shard_batch((img, lbl), mesh8))
     assert np.isfinite(float(metrics["loss"]))
