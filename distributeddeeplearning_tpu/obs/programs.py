@@ -302,8 +302,8 @@ def kernel_calls_by_group(scopes: Dict[str, str], groups: Groups) -> Dict[str, i
     """How many Mosaic kernels (``tpu_custom_call`` instructions) a
     table holds under each group, ``unscoped`` for those in none:
     whether a model part runs the kernel it was given. A GPT-2 step on
-    the flash kernel holds 36 under ``attn_core``, a forward and two
-    backward kernels a layer."""
+    the flash kernels holds 24 under ``attn_core``, a forward and a
+    backward kernel a layer."""
     compiled = [(name, re.compile(p)) for name, p in groups]
     out: Dict[str, int] = {}
     for path in scopes.values():

@@ -7,7 +7,7 @@ matrix in HBM, forward and backward; these kernels keep one tile of it
 in VMEM at a time, with the online-softmax recurrence, so memory is
 ``O(T·d)`` and nothing of size ``T × T`` leaves the chip.
 
-Three Mosaic kernels under one custom VJP, all of one shape: a grid
+Two Mosaic kernels under one custom VJP, of one shape: a grid
 ``(batch, head group, owned block, resident block)`` in which a program
 owns one ``b``-row block of one operand (its accumulators live in VMEM
 scratch across the last, sequential grid axis) and meets a resident
@@ -17,15 +17,24 @@ the softmax, the program's fixed cost paid once; at T = 1,024 the
 resident block is the whole sequence and K and V are fetched once a
 head group):
 
-  forward : owns a q block. Running row-max ``m``, normaliser ``l`` and
-            the f32 accumulator in scratch; two matmuls a tile
-            (``q·kᵀ``, ``p·v``). Saves the per-row logsumexp.
-  dq      : owns a q block: ``p = exp(s − lse)``,
-            ``ds = p ⊙ (do·vᵀ − Δ)``, ``dq = Σ ds·k·scale``.
-  dk/dv   : owns a k/v block, meets q/do, on transposed tiles
-            (``sᵀ = k·qᵀ``: keys on sublanes, queries on lanes), so
-            ``dv = Σ pᵀ·do`` and ``dk = Σ dsᵀ·q·scale`` are plain
-            products and no score tile is transposed.
+  forward  : owns a q block. Running row-max ``m``, normaliser ``l`` and
+             the f32 accumulator in scratch; two matmuls a tile
+             (``q·kᵀ``, ``p·v``). Saves the per-row logsumexp.
+  backward : owns a k/v block, meets q/do, on transposed tiles
+             (``sᵀ = k·qᵀ``: keys on sublanes, queries on lanes). Each
+             tile is formed once and gives all three gradients, five
+             matmuls: ``pᵀ = exp(sᵀ − lse)``, ``dv = Σ pᵀ·do``,
+             ``dsᵀ = pᵀ ⊙ (v·doᵀ − Δ)``, ``dk = Σ dsᵀ·q·scale`` (plain
+             products, its own accumulators) and ``dq = Σ ds·k·scale``,
+             the one product that contracts the tile's first axis.
+             ``dq`` belongs to the resident blocks, not to the program:
+             its f32 sums for the whole query side stay in VMEM scratch
+             (``[heads a program, T, d]``: 0.5 MiB at GPT-2's shapes;
+             with grouped queries a slot for each query head of the
+             group, 16 MiB at 8 × 4,096 × 128) while the grid walks the
+             k blocks, which is therefore a sequential axis too, and
+             each block is written once, in the operands' dtype, in the
+             last k block's pass. No partial gradient goes through HBM.
 
 Causal attention computes no block above the diagonal: of a resident
 block a q block meets the first ``c`` sub-blocks only, up to its own
@@ -44,11 +53,11 @@ the lanes (d = 96) are transposed to ``[B·H, T, d]`` first
 
 ``lse`` and ``Δ = rowsum(do ⊙ o)`` travel as rows, ``[B, H, major, 8,
 sub·b]`` (queries on lanes, 8 equal sublanes: the smallest f32 tile),
-16 times smaller than lane-replicated columns; the dk/dv kernel reads
-them as they lie, the q-owning kernels turn their block once a program.
+16 times smaller than lane-replicated columns; the backward kernel
+reads them as they lie.
 
 ``_flash_bwd_scan`` is the kept pure-JAX reference for the backward
-kernels. On non-TPU backends the kernels run in Pallas interpreter
+kernel. On non-TPU backends the kernels run in Pallas interpreter
 mode, so the CPU test mesh exercises the identical code path (§7 hard
 part (d)).
 """
@@ -65,6 +74,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributeddeeplearning_tpu import obs
+
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() NaN-free
 _LANES = 128  # VPU lane width: m/l scratch rows are lane-replicated
 _SUBLANES = 8
@@ -74,6 +85,10 @@ _SUBLANES = 8
 # each). Longer sequences stream such blocks along the last grid axis.
 _TILE_ELEMS = 512 * 1024
 _MAX_SUB = 8
+# The most of dq's sums that the backward kernel may hold beside its 32
+# MiB of blocks and tiles (the v5e's VMEM is 128 MiB): 131,072 rows at
+# one 128-lane block a program, 16,384 with eight query heads a key head.
+_DQ_VMEM = 64 * 2**20
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -207,6 +222,15 @@ def _dot_nt(a, b):
     )
 
 
+def _dot_tn(a, b):
+    """``aᵀ·b``. Mosaic takes the contraction over both first axes as it
+    stands; an explicit transpose of the tile before a plain product, in
+    f32 or in the operands' dtype, measured the same to 0.1%."""
+    return lax.dot_general(
+        a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
 def _scores(a, b, scale: float):
     """``a·bᵀ·scale`` in f32 from operands in the input dtype (bf16 →
     full-rate MXU). A power-of-two scale (d = 64, 256) is folded into
@@ -237,11 +261,6 @@ def _down(x, rows: int):
 def _to_rows(x):
     """Lane-replicated ``[b, 128]`` column statistics as ``[8, b]`` rows."""
     return x.T[:_SUBLANES]
-
-
-def _to_cols(x):
-    """``[8, b]`` rows (all equal) as lane-replicated ``[b, 128]`` columns."""
-    return jnp.tile(x, (_LANES // _SUBLANES, 1)).T
 
 
 def _walk_keys(step, i, g0, n_sub: int, mask: Mask, kv_len: int, b: int):
@@ -329,108 +348,85 @@ def _flash_fwd_kernel(
         o_ref[0] = jnp.concatenate(outs, axis=1).astype(o_ref.dtype)
 
 
-def _flash_bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-    dq_scr, lse_scr, delta_scr,
-    *, scale: float, mask: Mask, kv_len: int, b: int, hp: int,
+def _flash_bwd_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    dq_scr, dk_scr, dv_scr,
+    *, scale: float, mask: Mask, kv_len: int, b: int, hp: int, major: int = 0,
 ):
-    """dq of one q block: the forward's walk with the saved statistics."""
-    i, jm = pl.program_id(2), pl.program_id(3)
-    n_sub = k_ref.shape[1] // b
-    d = q_ref.shape[2] // hp
-
-    @pl.when(jm == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-        for h in range(hp):
-            lse_scr[h] = _to_cols(lse_ref[0, h, 0])
-            delta_scr[h] = _to_cols(delta_ref[0, h, 0])
-
-    def step(c, keep):
-        qs, dos = q_ref[0], do_ref[0]
-        ks, vs = k_ref[0, : c * b, :], v_ref[0, : c * b, :]
-        for h in range(hp):
-            k = _head(ks, h, d)
-            s = _scores(_head(qs, h, d), k, scale)
-            p = jnp.exp(s - _across(lse_scr[h], s.shape[1]))
-            if keep is not None:
-                p = jnp.where(keep, p, 0.0)
-            dp = _dot_nt(_head(dos, h, d), _head(vs, h, d))
-            ds = p * (dp - _across(delta_scr[h], s.shape[1]))
-            dq_scr[h] += _dot(ds.astype(k.dtype), k)
-
-    _walk_keys(step, i, jm * n_sub, n_sub, mask, kv_len, b)
-
-    @pl.when(jm == pl.num_programs(3) - 1)
-    def _finalize():
-        dq = jnp.concatenate([dq_scr[h] for h in range(hp)], axis=1)
-        dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_scr, dv_scr,
-    *, scale: float, mask: Mask, b: int, hp: int, major: int = 0,
-):
-    """dk/dv of one k/v block against one resident Q/dO block, on a
-    transposed tile ``[keys, queries]`` of the sub-blocks from the
-    diagonal one on (causal: the ones before it are skipped, a static
-    branch a start). Padded query rows carry ``do = Δ = 0`` and add
-    nothing. ``major`` (grouped queries): the last grid axis walks the
-    query heads of this key head's group, ``major`` resident blocks
-    each, into the same accumulators."""
-    i, jm = pl.program_id(2), pl.program_id(3)
-    first = jm == 0
-    if major:
-        jm = jm % major
+    """All three gradients from one k/v block against one resident Q/dO
+    block, on one transposed tile ``[keys, queries]`` of the sub-blocks
+    from the diagonal one on (causal: the ones before it are skipped, a
+    static branch a start): five products, one exponential, one
+    ``p ⊙ (dp − Δ)``. ``dk`` and ``dv`` are the program's own, summed
+    along the last grid axis. ``dq`` is the resident blocks': one f32
+    slot of ``dq_scr`` for each step of the last axis, summed over the k
+    blocks (the axis before it, sequential too) and written in the pass
+    of the last one (``_specs``: ``summed``). Under ``Mask.own`` the one
+    tile is a q block's whole work: one slot, written at once. Padded
+    query rows carry ``do = Δ = 0`` and add nothing. ``major`` (grouped
+    queries): the last grid axis walks the query heads of this key
+    head's group, ``major`` resident blocks each."""
+    i, jt = pl.program_id(2), pl.program_id(3)
+    jm = jt % major if major else jt
     n_sub = q_ref.shape[1] // b
     d = k_ref.shape[2] // hp
+    slot = 0 if mask.own else jt
 
-    @pl.when(first)
+    @pl.when(jt == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def step(lo, masked):
+    @pl.when(mask.own or i == 0)
+    def _init_dq():
+        dq_scr[slot] = jnp.zeros(dq_scr.shape[1:], dq_scr.dtype)
+
+    def step(lo, keep):
         ks, vs = k_ref[0], v_ref[0]
         qs, dos = q_ref[0, lo * b :, :], do_ref[0, lo * b :, :]
-        if masked:  # the first sub-block is the diagonal one
-            tile = (b, qs.shape[0])
-            keep = _sees(
-                lax.broadcasted_iota(jnp.int32, tile, 1),
-                lax.broadcasted_iota(jnp.int32, tile, 0), mask,
-            )
         for h in range(hp):
-            q, do = _head(qs, h, d), _head(dos, h, d)
+            q, do, k = _head(qs, h, d), _head(dos, h, d), _head(ks, h, d)
             lse = _down(lse_ref[0, h, 0, :, lo * b :], b)
-            pt = jnp.exp(_scores(_head(ks, h, d), q, scale) - lse)  # [b, c·b]
-            if masked:
+            pt = jnp.exp(_scores(k, q, scale) - lse)  # [b, c·b]
+            if keep is not None:
                 pt = jnp.where(keep, pt, 0.0)
             dv_scr[h] += _dot(pt.astype(do.dtype), do)
             dpt = _dot_nt(_head(vs, h, d), do)
             dst = pt * (dpt - _down(delta_ref[0, h, 0, :, lo * b :], b))
-            dk_scr[h] += _dot(dst.astype(q.dtype), q)
+            dst = dst.astype(q.dtype)
+            dk_scr[h] += _dot(dst, q)
+            dq_scr[slot, h, lo * b :, :] += _dot_tn(dst, k)
+
+    def tile(lo, axis):
+        return lax.broadcasted_iota(jnp.int32, (b, (n_sub - lo) * b), axis)
+
+    def diagonal(lo):  # the tile's first sub-block is the diagonal one
+        step(lo, _sees(tile(lo, 1), tile(lo, 0), mask))
 
     if mask.own:
-        step(0, True)
+        diagonal(0)
     elif mask.causal:
         behind = i - jm * n_sub  # q sub-blocks of this resident block before k's
-
-        @pl.when(behind < 0)
-        def _whole():
-            step(0, False)
-
+        pl.when(behind < 0)(functools.partial(step, 0, None))
         for lo in range(n_sub):
-            pl.when(behind == lo)(functools.partial(step, lo, True))
+            pl.when(behind == lo)(functools.partial(diagonal, lo))
+    elif kv_len % b:  # the last k block holds the keys' padding
+        pl.when(i < kv_len // b)(functools.partial(step, 0, None))
+        pl.when(i == kv_len // b)(lambda: step(0, tile(0, 0) < kv_len % b))
     else:
-        step(0, False)
+        step(0, None)
 
-    @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
+    @pl.when(jt == pl.num_programs(3) - 1)
     def _finalize():
         dk = jnp.concatenate([dk_scr[h] for h in range(hp)], axis=1)
         dv = jnp.concatenate([dv_scr[h] for h in range(hp)], axis=1)
         dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(mask.own or i == pl.num_programs(2) - 1)
+    def _finalize_dq():
+        dq = jnp.concatenate([dq_scr[slot, h] for h in range(hp)], axis=1)
+        dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _specs(owner: _Plan, walked: _Plan, w: int, hp: int, groups: int,
@@ -453,7 +449,14 @@ def _specs(owner: _Plan, walked: _Plan, w: int, hp: int, groups: int,
     counts key heads, and its last axis walks the ``rep`` query heads of
     its group, ``walked.major`` resident blocks each. ``diagonal``
     (``Mask.own``): the resident block is the one of the owner's own
-    index, whatever the last axis says (one step a walked head)."""
+    index, whatever the last axis says (one step a walked head).
+
+    ``summed``: a block of the walked operand's gradient, which every
+    owner adds to (a k owner to ``dq``). The kernel holds the sums in
+    VMEM and writes a block in the last owner's pass; until then the
+    spec names the block that pass writes first, so nothing goes back to
+    HBM before it holds its sum. With ``diagonal`` one owner meets a
+    block, and it is written at once."""
     b = owner.b
     major = 1 if diagonal else walked.major
 
@@ -494,15 +497,25 @@ def _specs(owner: _Plan, walked: _Plan, w: int, hp: int, groups: int,
         (1, hp, 1, _SUBLANES, walked.sub * b),
         lambda n, g, i, jm: (n, walked_head(g, jm), resident(i, jm), 0, 0),
     )
-    return own, walk, own_stat, walk_stat
+
+    def summed_index(n, g, i, jm):
+        if diagonal:
+            return n, i, walked_head(g, jm)
+        jm = jnp.where(i == owner.blocks - 1, jm, 0)
+        return n, jm % major, walked_head(g, jm)
+
+    summed = pl.BlockSpec((1, walked.sub * b, w), summed_index)
+    return own, walk, own_stat, walk_stat, summed
 
 
-# The last grid axis carries the accumulators and stays sequential; the
-# others are free to parallelise.
-_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-    vmem_limit_bytes=32 * 2**20,
-)
+def _params(sequential: int, vmem_bytes: int = 32 * 2**20):
+    """The last ``sequential`` grid axes carry accumulators and run in
+    order; the others are free to parallelise."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (4 - sequential)
+        + ("arbitrary",) * sequential,
+        vmem_limit_bytes=vmem_bytes,
+    )
 
 
 def _geometry(q, k, heads: int, block: Optional[int], packed: bool,
@@ -538,11 +551,11 @@ def _flash(q, k, v, heads, causal, scale, block, interpret, packed=False, rep=1)
     if rep > 1 and (hp != 1 or packed or d % _LANES):
         raise ValueError("grouped queries need one head a program (d % 128 == 0)")
     w, b, hd = hp * d, pq.b, heads * d
-    # every block of the padded q is computed: the dk/dv kernel reads
+    # every block of the padded q is computed: the backward kernel reads
     # the statistics of all of them
     qp = _pad_rows(q, pq.rows)
     kp, vp = _pad_rows(k, pk.rows), _pad_rows(v, pk.rows)
-    own, walk, own_stat, _ = _specs(
+    own, walk, own_stat, _, _ = _specs(
         pq, pk, w, hp, heads // hp, causal, owner_first=True, rep=rep,
         diagonal=mask.own,
     )
@@ -567,7 +580,7 @@ def _flash(q, k, v, heads, causal, scale, block, interpret, packed=False, rep=1)
             pltpu.VMEM((hp, b, _LANES), jnp.float32),
             pltpu.VMEM((hp, b, d), jnp.float32),
         ],
-        compiler_params=_PARAMS,
+        compiler_params=_params(1),
         interpret=interpret,
     )(qp, kp, vp)
     return out[:, :tq], lse
@@ -604,16 +617,24 @@ def _flash_qkv_bwd_rule(heads, causal, scale, block, interpret, res, do):
         heads, causal, scale, block, interpret, (qkv, qkv, qkv, out, lse), do,
         packed=True,
     )
-    return (jnp.concatenate(grads, axis=-1),)
+    # A concatenation of one call's three outputs XLA:TPU writes as three
+    # in-place updates of a `[B, T, 3·H·d]` buffer; with dq behind a
+    # barrier it fuses the concatenation into the projection's backward
+    # products, as it did when dq came from a call of its own (GPT-2's
+    # step: 105.7 -> 102.9 ms, 36 update fusions and 12 bias sums fewer).
+    dq, dk, dv = grads
+    return (jnp.concatenate([lax.optimization_barrier(dq), dk, dv], axis=-1),)
 
 
 def _flash_bwd_rule(heads, causal, scale, block, interpret, res, do,
                     packed=False, rep=1, dlse=None):
-    """Flash backward as two Mosaic kernels (dq; dk/dv) of the forward's
-    shape. ``_flash_bwd_scan`` below is the kept reference
-    implementation (parity-tested in ``tests/test_attention_ops.py``).
-    ``dlse [B, T, heads]``: the cotangent of the rows' logsumexp where
-    the caller used it (``d lse / d s = p``, so it enters as ``−Δ``)."""
+    """Flash backward as one Mosaic kernel of the forward's shape
+    (module docstring), counted at trace time as ``attn.bwd.fused``
+    (labels ``shape``, ``rep``, ``mask``). ``_flash_bwd_scan`` below is
+    the kept reference implementation (parity-tested in
+    ``tests/test_attention_ops.py``). ``dlse [B, T, heads]``: the
+    cotangent of the rows' logsumexp where the caller used it
+    (``d lse / d s = p``, so it enters as ``−Δ``)."""
     mask = _as_mask(causal)
     causal = mask.causal
     q, k, v, out, lse = res
@@ -634,64 +655,60 @@ def _flash_bwd_rule(heads, causal, scale, block, interpret, res, do,
         delta.transpose(0, 2, 1).reshape(n, heads, pq.major, 1, -1), lse.shape
     )
     vma = _vma(q, k, v, do)
-
-    own, walk, own_stat, _ = _specs(
-        pq, pk, w, hp, heads // hp, causal, owner_first=True, rep=rep,
-        diagonal=mask.own,
+    obs.counter(
+        "attn.bwd.fused", shape=list(q.shape), rep=rep, mask=mask._asdict()
     )
-    dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel, scale=scale, mask=mask, kv_len=tk, b=b, hp=hp
-        ),
-        grid=(n, heads // hp, pq.blocks, 1 if mask.own else pk.major),
-        in_specs=[own(pq_), walk(pk_), walk(pv_), own(), own_stat, own_stat],
-        out_specs=own(),
-        out_shape=jax.ShapeDtypeStruct((n, pq.rows, hd), q.dtype, vma=vma),
-        scratch_shapes=[
-            pltpu.VMEM((hp, b, d), jnp.float32),
-            pltpu.VMEM((hp, b, _LANES), jnp.float32),
-            pltpu.VMEM((hp, b, _LANES), jnp.float32),
-        ],
-        compiler_params=_PARAMS,
-        interpret=interpret,
-    )(qp, kp, vp, dop, lse, delta)
 
-    own, walk, _, walk_stat = _specs(
+    own, walk, _, walk_stat, summed = _specs(
         pk, pq, w, hp, heads // hp, causal, owner_first=False, rep=rep,
         diagonal=mask.own,
     )
     q_major = 1 if mask.own else pq.major
     grouped = {"major": q_major} if rep > 1 else {}
-    dk, dv = pl.pallas_call(
+    # dq's sums: a slot for each step of the last axis (under `own` one)
+    dq_scr = (1 if mask.own else rep * q_major, hp, pq.sub * b, d)
+    dq_bytes = 4 * math.prod(dq_scr[:3]) * _ceil_to(d, _LANES)
+    if dq_bytes > _DQ_VMEM:
+        raise ValueError(
+            f"the flash backward keeps dq's f32 sums {dq_scr} in VMEM: "
+            f"{dq_bytes} B is over its {_DQ_VMEM} B"
+        )
+    dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dkv_kernel, scale=scale, mask=mask, b=b, hp=hp, **grouped
+            _flash_bwd_kernel, scale=scale, mask=mask, kv_len=tk, b=b, hp=hp,
+            **grouped,
         ),
         grid=(n, heads // hp // rep, pk.blocks, rep * q_major),
         in_specs=[walk(pq_), own(pk_), own(pv_), walk(), walk_stat, walk_stat],
-        out_specs=[own(), own()],
+        out_specs=[summed, own(), own()],
         out_shape=[
+            jax.ShapeDtypeStruct((n, pq.rows, hd), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((n, pk.rows, hd // rep), k.dtype, vma=vma),
             jax.ShapeDtypeStruct((n, pk.rows, hd // rep), v.dtype, vma=vma),
         ],
         scratch_shapes=[
+            pltpu.VMEM(dq_scr, jnp.float32),
             pltpu.VMEM((hp, b, d), jnp.float32),
             pltpu.VMEM((hp, b, d), jnp.float32),
         ],
-        compiler_params=_PARAMS,
+        compiler_params=_params(2, 32 * 2**20 + dq_bytes),
         interpret=interpret,
     )(qp, kp, vp, dop, lse, delta)
 
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
 
-def _flash_bwd_scan(heads, causal, scale, block, interpret, res, do):
+def _flash_bwd_scan(heads, causal, scale, block, interpret, res, do, dlse=None):
     """Blockwise flash backward (pure JAX): lax.scan over K blocks.
 
     With p = exp(s − lse):  dv = pᵀ·do;  ds = p ⊙ (do·vᵀ − D) where
     D = rowsum(do ⊙ o);  dq = Σ_blocks ds·k·scale;  dk = dsᵀ·q·scale.
     Peak memory is O(T·block_k) per (b,h) — no [T, T] residual. Kept as
-    the independent reference implementation for the Mosaic backward.
+    the independent reference implementation for the Mosaic backward
+    (``causal`` a bool or a :class:`Mask`; ``dlse`` as there; key heads
+    written out once a query head).
     """
+    mask = _as_mask(causal)
     n, tq, hd = res[0].shape
     d = hd // heads
 
@@ -710,6 +727,8 @@ def _flash_bwd_scan(heads, causal, scale, block, interpret, res, do):
     qf = q.astype(jnp.float32)
     dof = do.astype(jnp.float32)
     delta = jnp.sum(dof * out.astype(jnp.float32), axis=-1)  # [bh, tq]
+    if dlse is not None:
+        delta = delta - dlse.astype(jnp.float32).transpose(0, 2, 1).reshape(bh, tq)
 
     kp = jnp.pad(k, ((0, 0), (0, tk_p - tk), (0, 0))).astype(jnp.float32)
     vp = jnp.pad(v, ((0, 0), (0, tk_p - tk), (0, 0))).astype(jnp.float32)
@@ -723,10 +742,10 @@ def _flash_bwd_scan(heads, causal, scale, block, interpret, res, do):
         j, kb, vb = inp
         s = jnp.einsum("bqd,bkd->bqk", qf, kb) * scale
         k_idx = j * bk + lax.broadcasted_iota(jnp.int32, (tq, bk), 1)
-        mask = k_idx < tk
-        if causal:
-            mask = jnp.logical_and(mask, q_idx >= k_idx)
-        p = jnp.where(mask, jnp.exp(s - lse[..., None]), 0.0)
+        keep = k_idx < tk
+        if mask.causal or mask.own:
+            keep = jnp.logical_and(keep, _sees(q_idx, k_idx, mask))
+        p = jnp.where(keep, jnp.exp(s - lse[..., None]), 0.0)
         dv_b = jnp.einsum("bqk,bqd->bkd", p, dof)
         dp = jnp.einsum("bqd,bkd->bqk", dof, vb)
         ds = p * (dp - delta[..., None]) * scale

@@ -204,8 +204,9 @@ def render_device(rep: dict) -> str:
 def chosen_paths(events, prefix: str = "attn.impl.") -> str:
     """What the program chose each time it was traced, one line, from
     the counters under ``prefix``: ``attn.impl.<path>`` (the attention
-    core, ``models/vit.Attention``) or ``loss.impl.<path>`` (the loss,
-    ``training/train_step.loss_and_hits``)."""
+    core, ``models/vit.Attention``), ``attn.bwd.<path>`` (the flash
+    kernels' backward, ``ops/pallas/flash.py``) or ``loss.impl.<path>``
+    (the loss, ``training/train_step.loss_and_hits``)."""
     chosen: dict = {}
     for e in events:
         name = str(e.get("name", ""))
@@ -280,7 +281,10 @@ def main(argv=None) -> int:
         )
     else:
         print(render(recon, training, args.top))
-    for what, prefix in (("attention core", "attn.impl."), ("loss", "loss.impl.")):
+    for what, prefix in (
+        ("attention core", "attn.impl."), ("attention backward", "attn.bwd."),
+        ("loss", "loss.impl."),
+    ):
         paths_chosen = chosen_paths(loaded["events"], prefix)
         if paths_chosen:
             print(f"{what}, as chosen at trace time: " + paths_chosen)

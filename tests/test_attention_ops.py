@@ -20,7 +20,7 @@ from distributeddeeplearning_tpu.ops.attention import (
     kernel_interpreted,
     resolve_impl,
 )
-from distributeddeeplearning_tpu.ops.pallas.flash import flash_attention
+from distributeddeeplearning_tpu.ops.pallas.flash import Mask, flash_attention
 from distributeddeeplearning_tpu.parallel.mesh import create_mesh
 from distributeddeeplearning_tpu.parallel.ring_attention import ring_attention
 
@@ -216,6 +216,42 @@ def test_a_caller_that_names_no_mask_gets_the_labels_it_had(monkeypatch):
     assert event["labels"] == {"asked": "auto", "shape": [2, 16, 32], "heads": 4}
 
 
+def test_the_fused_backward_is_counted_once_a_traced_backward():
+    """``attn.bwd.fused`` at trace time, as ``attn.impl.<path>`` is: one
+    a traced backward of the flash kernels, with the operands' shape as
+    the kernels take them, the query heads a key head and the mask; a
+    forward alone counts none."""
+    from distributeddeeplearning_tpu.ops.pallas.flash import flash_attention_stats
+
+    q = jnp.zeros((1, 64, 4, 128))
+    kv = jnp.zeros((1, 64, 2, 128))
+
+    def core(q, k, v):
+        out, _ = flash_attention_stats(
+            q, k, v, mask=Mask(True, 4), block=32, interpret=True
+        )
+        return jnp.sum(out)
+
+    def counted():
+        return [
+            e for e in obs.get_bus().ring
+            if e["kind"] == "counter" and e["name"].startswith("attn.bwd.")
+        ]
+
+    jax.clear_caches()  # the jitted core traces once a signature
+    obs.reset()
+    jax.jit(core).lower(q, kv, kv)
+    assert counted() == []
+    jax.jit(jax.grad(core, (0, 1, 2))).lower(q, kv, kv)
+    (event,) = counted()
+    obs.reset()
+    assert event["name"] == "attn.bwd.fused" and event["value"] == 1
+    assert event["labels"] == {
+        "shape": [1, 64, 512], "rep": 2,
+        "mask": {"causal": True, "gran": 4, "strict": False, "own": False},
+    }
+
+
 @pytest.mark.parametrize(
     "impl,backend,interpreted",
     [
@@ -235,36 +271,124 @@ def _packed(seed, n, t, heads, d):
     return jnp.asarray(rng.randn(n, t, heads * d).astype(np.float32))
 
 
-@pytest.mark.parametrize("causal", [False, True])
+def _bwd_case(t, heads, d, block, causal=False, kv=None, tile_elems=None, dlse=False):
+    return dict(t=t, heads=heads, kv=kv or heads, d=d, block=block, mask=causal,
+                tile_elems=tile_elems, dlse=dlse)
+
+
 @pytest.mark.parametrize(
-    "t,heads,d,block",
+    "case",
     [
-        (70, 2, 8, 32),  # three ragged 32-blocks: q and k padding
-        (300, 2, 64, None),  # GPT-2's head width, the rule's own block:
-        # two heads a program, three 128-blocks on both axes
+        # three ragged 32-blocks: q and k padding
+        pytest.param(_bwd_case(70, 2, 8, 32), id="ragged-full"),
+        pytest.param(_bwd_case(70, 2, 8, 32, True), id="ragged-causal"),
+        # GPT-2's head width, the rule's own block: two heads a program,
+        # three 128-blocks on both axes
+        pytest.param(_bwd_case(300, 2, 64, None), id="d64-full"),
+        pytest.param(_bwd_case(300, 2, 64, None, True), id="d64-causal"),
+        # grouped query heads read a key head in place: dq has a slot a
+        # query head, dk and dv sum over the group
+        pytest.param(_bwd_case(200, 4, 128, 64, True, kv=2), id="rep2-causal"),
+        pytest.param(_bwd_case(200, 8, 128, 64, kv=1), id="rep8-full"),
+        # the block-diffusion passes: the diagonal tile's rule in units of
+        # 4, the rows' logsumexp used by the caller
+        pytest.param(
+            _bwd_case(200, 4, 128, 64, Mask(True, 4), kv=2, dlse=True), id="blocks<="
+        ),
+        pytest.param(
+            _bwd_case(200, 4, 128, 64, Mask(True, 4, True), kv=2, dlse=True), id="blocks<"
+        ),
+        pytest.param(
+            _bwd_case(200, 4, 128, 64, Mask(False, 4, own=True), kv=2, dlse=True),
+            id="own-block",
+        ),
+        # ten 32-blocks in five resident ones of two: dq's sums cross the
+        # programs of ten k blocks, each over several resident blocks,
+        # and are written in the last one's pass
+        pytest.param(
+            _bwd_case(300, 2, 64, 32, True, tile_elems=2 * 32 * 32), id="streamed-d64"
+        ),
+        pytest.param(
+            _bwd_case(300, 8, 128, 32, Mask(True, 4), kv=1, tile_elems=2 * 32 * 32,
+                      dlse=True),
+            id="streamed-rep8-blocks<=",
+        ),
+        pytest.param(
+            _bwd_case(300, 4, 128, 32, kv=2, tile_elems=2 * 32 * 32), id="streamed-full"
+        ),
     ],
 )
-def test_flash_bwd_kernels_match_scan_reference(causal, t, heads, d, block):
-    """The Mosaic backward kernels (dq; dk/dv on transposed tiles, the
-    statistics as rows) against the kept pure-JAX scan backward they
-    replaced, on ragged lengths that span several blocks on both axes,
-    so the causal skip and the padding masks are exercised."""
-    from distributeddeeplearning_tpu.ops.pallas.flash import (
-        _flash,
-        _flash_bwd_rule,
-        _flash_bwd_scan,
+def test_flash_bwd_kernels_match_scan_reference(monkeypatch, case):
+    """The Mosaic backward kernel (dq, dk, dv from one transposed tile,
+    the statistics as rows) against the kept pure-JAX scan backward, and
+    against the derivative of the dense masked softmax, on ragged
+    lengths that span several blocks on both axes, so the causal skip
+    and the padding masks are exercised; every row of dq, dk and dv is
+    compared, so every block's."""
+    from distributeddeeplearning_tpu.ops.pallas import flash
+
+    t, heads, kv, d, block = (case[x] for x in ("t", "heads", "kv", "d", "block"))
+    mask = flash._as_mask(case["mask"])
+    rep = heads // kv
+    if case["tile_elems"]:
+        monkeypatch.setattr(flash, "_TILE_ELEMS", case["tile_elems"])
+        assert flash._plan(t, block).major > 1
+    q, do = (_packed(seed, 2, t, heads, d) for seed in (3, 6))
+    k, v = (_packed(seed, 2, t, kv, d) for seed in (4, 5))
+    dlse = _packed(7, 2, t, heads, 1) if case["dlse"] else None
+    if mask.strict:  # the first rows see no key: their cotangents are nought
+        do, dlse = do.at[:, : mask.gran].set(0.0), dlse.at[:, : mask.gran].set(0.0)
+    scale = d**-0.5
+    out, lse = flash._flash(q, k, v, heads, mask, scale, block, True, rep=rep)
+    got = flash._flash_bwd_rule(
+        heads, mask, scale, block, True, (q, k, v, out, lse), do, rep=rep, dlse=dlse
     )
 
-    q, k, v, do = (_packed(seed, 2, t, heads, d) for seed in (3, 4, 5, 6))
-    scale = d**-0.5
-    out, lse = _flash(q, k, v, heads, causal, scale, block, True)
-    res = (q, k, v, out, lse)
-    got = _flash_bwd_rule(heads, causal, scale, block, True, res, do)
-    ref = _flash_bwd_scan(heads, causal, scale, block, True, res, do)
-    for name, a, b in zip(("dq", "dk", "dv"), got, ref):
+    def wide(x):  # key heads written out once a query head
+        return jnp.repeat(x.reshape(2, t, kv, d), rep, axis=2).reshape(2, t, -1)
+
+    def narrow(dx):  # and their gradients summed back
+        return dx.reshape(2, t, kv, rep, d).sum(3).reshape(2, t, -1)
+
+    dq, dk, dv = flash._flash_bwd_scan(
+        heads, mask, scale, block, True, (q, wide(k), wide(v), out, lse), do, dlse=dlse
+    )
+    for name, a, b in zip(("dq", "dk", "dv"), got, (dq, narrow(dk), narrow(dv))):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=2e-4, err_msg=name
         )
+
+    rows, cols = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    sees = flash._sees(rows, cols, mask) if mask.causal or mask.own else cols >= 0
+
+    def dense(q, k, v):
+        split = lambda x: x.reshape(2, t, heads, d)
+        s = jnp.einsum("bqhd,bkhd->bhqk", split(q), split(wide(k))) * scale
+        s = jnp.where(sees, s, -1e30)
+        stat = jax.nn.logsumexp(s, -1)
+        p = jnp.where(sees, jnp.exp(s - stat[..., None]), 0.0)
+        out = jnp.einsum("bhqk,bkhd->bqhd", p, split(wide(v)))
+        return out.reshape(2, t, -1), stat.transpose(0, 2, 1)
+
+    cts = (do, dlse if case["dlse"] else jnp.zeros((2, t, heads)))
+    want = jax.vjp(dense, q, k, v)[1](cts)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=1e-4, err_msg=name
+        )
+
+
+def test_flash_bwd_refuses_more_of_dq_than_vmem_holds(monkeypatch):
+    """dq's sums for the whole query side stay in VMEM; a call whose
+    padded length x heads a program x width is over the budget is told
+    so, not handed to the compiler."""
+    from distributeddeeplearning_tpu.ops.pallas import flash
+
+    monkeypatch.setattr(flash, "_DQ_VMEM", 2 * 256 * 128 * 4 - 1)
+    q, k, v = _qkv(b=1, t=256, h=2, d=64)
+    flash_attention(q, k, v, causal=True)  # the forward holds no such sums
+    with pytest.raises(ValueError, match="dq's f32 sums"):
+        jax.grad(lambda q: jnp.sum(flash_attention(q, k, v, causal=True)))(q)
 
 
 @pytest.mark.parametrize("tile_elems", [None, 2 * 128 * 128])

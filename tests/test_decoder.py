@@ -254,16 +254,21 @@ def test_a_block_causal_pass_gives_the_rows_logsumexp_and_its_gradient(strict):
         assert float(kernel(q, k, v)[1][0, 0, 0]) < -1e29
 
 
+@pytest.mark.parametrize("heads", [2, 8], ids=["rep2", "rep8"])
 @pytest.mark.parametrize(
     "mask", [flash.Mask(True), flash.Mask(True, B), flash.Mask(True, B, strict=True),
              flash.Mask(False, B, own=True)],
     ids=["causal", "blocks<=", "blocks<", "own-block"],
 )
-def test_a_pass_over_several_resident_blocks(mask):
+def test_a_pass_over_several_resident_blocks(mask, heads):
     """Sixteen blocks in two resident ones: a program's index maps have
     to name the resident block and the walked head they mean (a wrong
-    one reads a neighbour's rows, which short sequences cannot show)."""
-    t, heads, kv, d = 1024, 2, 1, 128
+    one reads a neighbour's rows, which short sequences cannot show).
+    The one backward kernel sums ``dq`` over the sixteen k blocks'
+    programs, a slot a query head and resident block, and writes each
+    block in the last one's pass: every row of ``dq``, ``dk``, ``dv`` is
+    compared, with the rows' logsumexp in the loss (``dlse``)."""
+    t, kv, d = 1024, 1, 128
     key = jax.random.PRNGKey(3)
     q = jax.random.normal(key, (1, t, heads, d))
     k, v = (jax.random.normal(jax.random.fold_in(key, i), (1, t, kv, d)) for i in (1, 2))
@@ -276,18 +281,24 @@ def test_a_pass_over_several_resident_blocks(mask):
 
     def dense(q, k, v):
         s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, heads, 2)) * d**-0.5
-        p = jax.nn.softmax(jnp.where(m, s, -1e30), -1) * m
-        return jnp.einsum("bhqk,bkhd->bqhd", p, jnp.repeat(v, heads, 2))
+        s = jnp.where(m, s, -1e30)
+        lse = jax.nn.logsumexp(s, -1)
+        p = jnp.where(m, jnp.exp(s - lse[..., None]), 0.0)
+        out = jnp.einsum("bhqk,bkhd->bqhd", p, jnp.repeat(v, heads, 2))
+        return out, lse.transpose(0, 2, 1)
 
     def kernel(q, k, v):
-        return flash.flash_attention_stats(q, k, v, mask=mask, block=64, interpret=True)[0]
+        return flash.flash_attention_stats(q, k, v, mask=mask, block=64, interpret=True)
 
     def loss(f):
-        return lambda q, k, v: jnp.sum(
-            jnp.where(live[None, :, None, None], jnp.sin(f(q, k, v)), 0.0)
-        )
+        def g(q, k, v):
+            out, lse = f(q, k, v)
+            return jnp.sum(
+                jnp.where(live[None, :, None, None], jnp.sin(out), 0.0)
+            ) + jnp.sum(jnp.where(live[None, :, None], jnp.cos(lse), 0.0))
+        return g
 
-    assert gap(kernel(q, k, v)[:, B:], dense(q, k, v)[:, B:]) < 1e-5
+    assert gap(kernel(q, k, v)[0][:, B:], dense(q, k, v)[0][:, B:]) < 1e-5
     got = jax.grad(loss(kernel), (0, 1, 2))(q, k, v)
     want = jax.grad(loss(dense), (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):

@@ -557,6 +557,31 @@ def test_the_loss_path_is_reported_beside_the_attentions():
     obs.reset()
 
 
+def test_the_flash_backward_is_reported_beside_the_attentions(tmp_path, capsys):
+    """``attn.bwd.<path>`` (``ops/pallas/flash._flash_bwd_rule``, once a
+    traced backward) rides the same report line by its prefix, and
+    ``make trace-report`` prints it from a run's event files."""
+    from distributeddeeplearning_tpu.ops.pallas.flash import flash_attention
+
+    run = tmp_path / "run"
+    obs.configure(str(run), install_handlers=False)
+    try:
+        x = jnp.zeros((1, 64, 2, 64))
+        jax.clear_caches()  # the jitted core traces once a signature
+        jax.jit(jax.grad(
+            lambda q: jnp.sum(flash_attention(q, x, x, causal=True, interpret=True))
+        )).lower(x)
+        events = [e for e in obs.get_bus().ring if e["kind"] == "counter"]
+        obs.flush()
+    finally:
+        obs.reset()
+    report = _trace_report()
+    assert report.chosen_paths(events, "attn.bwd.") == "fused x1 at [1, 64, 128]"
+    assert report.main([str(run)]) == 0
+    out = capsys.readouterr().out
+    assert "attention backward, as chosen at trace time: fused x1 at [1, 64, 128]" in out
+
+
 @pytest.mark.parametrize("path", [
     "jit(local_step)/jvp(TransformerLM)/head/btd,vd->btv/dot_general",
     "jit(local_step)/jvp(loss)/reduce_max",
