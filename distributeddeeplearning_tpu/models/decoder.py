@@ -7,20 +7,27 @@ in every one of those places, so this module builds the block from a
 :class:`DecoderSpec`: RMSNorm or LayerNorm with a settable epsilon,
 rotary or learned positions, grouped-query heads whose width is not
 ``hidden / heads``, a per-head q/k norm, biases or none, a GELU MLP or
-routed gated experts of which this process holds a share
-(``ops/moe.py``), a tied or an untied head, and a causal mask or the
-block-diffusion one. Both masks' cores are ``ops/attention``'s
-(``dot_product_attention``, ``block_diffusion_attention``), and which
-lowering a call takes is that module's rule (``resolve_impl``): this
-model states its heads and its mask, and that no fused QKV projection
-feeds the core.
+routed gated experts (SiLU or ReLU gate) of which this process holds a
+share (``ops/moe.py``), a router that reads the FFN's normed input or
+attention's, a tied or an untied head. The layers need not be alike: a
+``pattern`` of :class:`LayerKind` repeats over the depth and gives each
+layer its attention window and whether it has rotary positions.
+
+**Three masks**: the causal one (a layer whose window is 0), the causal
+one within a window of the last keys (``LayerKind.window``), and the
+block-diffusion one (``spec.block_len``, every layer, no window). Their
+cores are ``ops/attention``'s (``dot_product_attention``, ``block_
+diffusion_attention``), and which lowering a call takes is that
+module's rule (``resolve_impl``): this model states its heads and its
+mask, and that no fused QKV projection feeds the core.
 
 Scope names are the ones ``transformer_lm.TRAIN_STEP_GROUPS`` reads:
 module ``attn`` with ``attn_core`` inside it, the FFN module ``mlp``,
-norms ``ln*``, scopes ``embed``, ``residual``, ``head``. Inside ``mlp``
-the expert layer has four scopes of its own, which :data:`MOE_GROUPS`
-reads: ``moe_route``, ``moe_dispatch``, ``moe_experts``,
-``moe_combine``.
+norms ``ln*``, scopes ``embed``, ``residual``, ``head``. Inside
+``attn_core`` a causal layer's core runs under ``attn_window`` or
+``attn_full`` (:data:`ATTN_KIND_GROUPS`). Inside ``mlp`` the expert
+layer has four scopes of its own, which :data:`MOE_GROUPS` reads:
+``moe_route``, ``moe_dispatch``, ``moe_experts``, ``moe_combine``.
 
 **Block diffusion** (``spec.block_len`` > 0; BD3-LM, arXiv:2503.09573,
 as SDAR, arXiv:2510.06303, trains with it): the model reads a row as
@@ -33,7 +40,7 @@ is the input path's (``data/noise.py``), the weighted loss the step's.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict
+from typing import Any, Dict, NamedTuple, Tuple
 
 import flax.linen as nn
 import jax
@@ -71,9 +78,28 @@ MOE_GROUPS = (
 )
 
 
+# The attention core of a causal layer by its kind, inside `attn_core`:
+# what `LayerKind.window` decides.
+ATTN_WINDOW, ATTN_FULL = "attn_window", "attn_full"
+ATTN_KIND_GROUPS = (
+    (ATTN_WINDOW, part(ATTN_WINDOW)),
+    (ATTN_FULL, part(ATTN_FULL)),
+)
+
+
+class LayerKind(NamedTuple):
+    """What the layers of one model differ in. ``window``: a query sees
+    its own key and the ``window − 1`` before it (0: every key up to its
+    own). ``rope``: q and k get the spec's rotary positions (False: no
+    positions at all in this layer)."""
+
+    window: int = 0
+    rope: bool = True
+
+
 @dataclasses.dataclass(frozen=True)
 class DecoderSpec:
-    """One decoder layer, and how many of it."""
+    """One decoder layer, how many of it, and what differs among them."""
 
     hidden: int
     layers: int
@@ -94,6 +120,13 @@ class DecoderSpec:
     experts_per_token: int = 0
     tied_head: bool = False
     block_len: int = 0  # block-diffusion mask over [noised ‖ clean]; 0 = causal
+    # layer l is pattern[l % len(pattern)]; (): every layer LayerKind()
+    pattern: Tuple[LayerKind, ...] = ()
+    route_before_attention: bool = False  # the router reads ln1's output
+    activation: str = "silu"  # the experts' gate: "silu" | "relu" (ReGLU)
+
+    def kind(self, layer: int) -> LayerKind:
+        return self.pattern[layer % len(self.pattern)] if self.pattern else LayerKind()
 
 
 # name -> spec. `sdar_30b_a3b`: SDAR-30B-A3B-Chat's published layer
@@ -114,6 +147,31 @@ SPECS: Dict[str, DecoderSpec] = {
         hidden=64, layers=2, heads=4, kv_heads=2, head_dim=16,
         qk_norm=True, ffn="moe", ffn_dim=32, experts=8, experts_held=8,
         experts_per_token=2, block_len=4,
+    ),
+    # SmallThinker-21BA3B-Instruct's published layers (huggingface.co/
+    # PowerInfer/SmallThinker-21BA3B-Instruct config.json; the family's
+    # report is arXiv:2507.20984): 52 layers in periods of four, a full-
+    # attention layer without positions, then three with rotary positions
+    # and a window of 4,096 (`sliding_window_layout`, `rope_layout`); the
+    # router reads attention's normed input (from the catalog's "router
+    # placed before attention"; not checked against the modelling code,
+    # PERF.md section 7); ReGLU experts, 6 of 64, no shared expert. A run
+    # states its share, as for `sdar_30b_a3b`.
+    "smallthinker_21b_a3b": DecoderSpec(
+        hidden=2560, layers=52, heads=28, kv_heads=4, head_dim=128,
+        norm="rms", norm_eps=1e-6, positions="rope", rope_theta=1.5e6,
+        qk_norm=False, bias=False, ffn="moe", ffn_dim=768, experts=64,
+        experts_held=64, experts_per_token=6, tied_head=False,
+        pattern=(LayerKind(0, False),) + (LayerKind(4096, True),) * 3,
+        route_before_attention=True, activation="relu",
+    ),
+    # the same at a size for tests: two periods, a window of 8
+    "smallthinker_tiny": DecoderSpec(
+        hidden=64, layers=8, heads=4, kv_heads=2, head_dim=16,
+        ffn="moe", ffn_dim=32, experts=8, experts_held=8,
+        experts_per_token=2,
+        pattern=(LayerKind(0, False),) + (LayerKind(8, True),) * 3,
+        route_before_attention=True, activation="relu",
     ),
     # GPT-2's block (models/transformer_lm.py `tiny`), to show the spec
     # reaches it: the q, k, v kernels are the thirds of its fused one
@@ -168,10 +226,11 @@ class SpecAttention(nn.Module):
     spec: DecoderSpec
     dtype: Any = jnp.bfloat16
     attn_impl: str = "auto"
+    kind: LayerKind = LayerKind()
 
     @nn.compact
     def __call__(self, x, positions):
-        spec = self.spec
+        spec, kind = self.spec, self.kind
         b, t, _ = x.shape
         h, kv, hd = spec.heads, spec.kv_heads, spec.head_dim
         q = _dense(h * hd, "q", spec, self.dtype)(x).reshape(b, t, h, hd)
@@ -180,7 +239,7 @@ class SpecAttention(nn.Module):
         if spec.qk_norm:
             q = RMSNorm(spec.norm_eps, name="q_norm")(q)
             k = RMSNorm(spec.norm_eps, name="k_norm")(k)
-        if spec.positions == "rope":
+        if spec.positions == "rope" and kind.rope:
             q = rotary(q, positions, spec.rope_theta)
             k = rotary(k, positions, spec.rope_theta)
         q, k = q.astype(self.dtype), k.astype(self.dtype)
@@ -188,7 +247,8 @@ class SpecAttention(nn.Module):
         impl = resolve_impl(
             self.attn_impl, x, heads=h, head_dim=hd,
             initializing=self.is_initializing(), kv_heads=kv,
-            mask="block_diffusion" if spec.block_len else "causal",
+            mask="block_diffusion" if spec.block_len
+            else "window" if kind.window else "causal",
         )
         with jax.named_scope(ATTN_CORE):
             if spec.block_len:
@@ -196,7 +256,10 @@ class SpecAttention(nn.Module):
                     q, k, v, block_len=spec.block_len, impl=impl
                 )
             else:
-                out = dot_product_attention(q, k, v, causal=True, impl=impl)
+                with jax.named_scope(ATTN_WINDOW if kind.window else ATTN_FULL):
+                    out = dot_product_attention(
+                        q, k, v, causal=True, window=kind.window, impl=impl
+                    )
         return _dense(spec.hidden, "o", spec, self.dtype)(out.reshape(b, t, h * hd))
 
 
@@ -217,13 +280,17 @@ class ExpertMlp(nn.Module):
     """The routed FFN: a router over all ``spec.experts`` in float32, no
     capacity and no dropped token, and the part of the output that the
     ``spec.experts_held`` experts from ``spec.first_expert`` on give
-    (``ops/moe.held_experts_ffn``)."""
+    (``ops/moe.held_experts_ffn``). The router reads ``x``, the experts'
+    input, or ``route_on`` where the block hands it another (``spec.
+    route_before_attention``: attention's normed input, so that the
+    routing hangs on nothing attention computes and the step's schedule
+    may place it, and a fetch of the chosen experts, beside attention)."""
 
     spec: DecoderSpec
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, route_on=None):
         spec = self.spec
         b, t, d = x.shape
         held, f = spec.experts_held, spec.ffn_dim
@@ -231,20 +298,24 @@ class ExpertMlp(nn.Module):
         w1 = _Kernel((held, d, f), name="w1")()
         w3 = _Kernel((held, d, f), name="w3")()
         w2 = _Kernel((held, f, d), name="w2")()
-        flat = x.reshape(b * t, d)
+        flat = read = x.reshape(b * t, d)
+        if route_on is not None:
+            read = route_on.reshape(b * t, d)
+            obs.counter("moe.route.before_attention", tokens=b * t, experts=spec.experts)
         with jax.named_scope(moe_ops.ROUTE):
             logits = jnp.matmul(
-                flat.astype(jnp.float32), router,
+                read.astype(jnp.float32), router,
                 precision=jax.lax.Precision.HIGHEST,
             )
             routed = moe_ops.route_top_k(logits, spec.experts_per_token)
         obs.counter(
             "moe.impl.ragged_dot", tokens=b * t, experts=spec.experts, held=held,
             first=spec.first_expert, per_token=spec.experts_per_token,
+            activation=spec.activation,
         )
         y, drawn = moe_ops.held_experts_ffn(
             flat, routed, w1, w3, w2, first=spec.first_expert,
-            num_experts=spec.experts,
+            num_experts=spec.experts, activation=spec.activation,
         )
         # kept only by a caller that asks for "intermediates" (the
         # benchmark's comparison of choices); a train step does not
@@ -262,17 +333,22 @@ class SpecBlock(nn.Module):
     spec: DecoderSpec
     dtype: Any = jnp.bfloat16
     attn_impl: str = "auto"
+    kind: LayerKind = LayerKind()
 
     @nn.compact
     def __call__(self, x, positions, train: bool = True):
         spec = self.spec
-        y = _norm(spec, "ln1")(x).astype(self.dtype)
-        a = SpecAttention(spec, self.dtype, self.attn_impl, name="attn")(y, positions)
+        u = _norm(spec, "ln1")(x)  # float32
+        a = SpecAttention(
+            spec, self.dtype, self.attn_impl, self.kind, name="attn"
+        )(u.astype(self.dtype), positions)
         with jax.named_scope(RESIDUAL):
             x = x + a
         y = _norm(spec, "ln2")(x).astype(self.dtype)
         if spec.ffn == "moe":
-            m = ExpertMlp(spec, self.dtype, name="mlp")(y)
+            m = ExpertMlp(spec, self.dtype, name="mlp")(
+                y, route_on=u if spec.route_before_attention else None
+            )
         else:
             m = MlpBlock(spec.ffn_dim, self.dtype, name="mlp")(y, train)
         with jax.named_scope(RESIDUAL):
@@ -301,6 +377,8 @@ class SpecDecoder(nn.Module):
             and 0 < spec.experts_per_token <= spec.experts
         ):
             raise ValueError(f"no such share of the experts: {spec}")
+        if spec.block_len and any(k.window for k in spec.pattern):
+            raise ValueError("the block-diffusion mask takes no window")
         b, t = tokens.shape
         length = t // 2 if spec.block_len else t
         if length > self.max_seq_len:
@@ -324,9 +402,17 @@ class SpecDecoder(nn.Module):
         if self.remat:
             block = nn.remat(SpecBlock, static_argnums=(3,))  # `train`
         for i in range(spec.layers):
-            x = block(spec, self.dtype, self.attn_impl, name=f"block{i}")(
-                x, positions, train
+            kind = spec.kind(i)
+            obs.counter(
+                "decoder.layer." + (
+                    "block_diffusion" if spec.block_len
+                    else "window" if kind.window else "full"
+                ),
+                layer=i, window=kind.window, rope=kind.rope,
             )
+            x = block(
+                spec, self.dtype, self.attn_impl, kind, name=f"block{i}"
+            )(x, positions, train)
         x = _norm(spec, "ln_final")(x)
         if spec.block_len:
             x = x[:, :length]  # the head reads the noised half alone

@@ -3,22 +3,27 @@
 The reference has no attention anywhere (vision-only, SURVEY.md §2b) —
 this op layer exists because the BASELINE.json configs add ViT-B/16 and
 because long-context support is first-class in this framework. One
-signature, three implementations:
+signature (:func:`dot_product_attention`: every key, the causal rule, or
+the causal rule within a window of the last keys), three
+implementations:
 
 * ``xla``   — einsum softmax attention: the ``[T, T]`` scores and the
   softmax weights go through HBM, forward and backward.
 * ``pallas`` — flash-attention TPU kernels (``ops/pallas/flash.py``):
-  nothing of size ``T × T`` leaves the chip. Ahead of the einsum from
-  T = 640 on (v5e, d = 64; ``flash.supports``), and the only way a long
-  context fits.
+  nothing of size ``T × T`` leaves the chip, and blocks the mask rules
+  out (above the diagonal, behind the window) are neither computed nor
+  fetched. Ahead of the einsum from T = 640 on (v5e, d = 64;
+  ``flash.supports``), and the only way a long context fits.
 * ``ring``  — sequence-parallel blockwise attention over a ``seq`` mesh
   axis (``parallel/ring_attention.py``): K/V blocks rotate around the
-  ring via ``ppermute`` while each shard holds only T/n of the sequence.
+  ring via ``ppermute`` while each shard holds only T/n of the sequence
+  (full or causal; no window).
 
-:func:`block_diffusion_attention` is the core of the block-diffusion
-training objective (a row runs as ``[noised ‖ clean]`` under a
-block-granular mask, grouped query heads): the einsum over the dense
-mask, or three passes of the flash kernels and a merge by logsumexp.
+:func:`block_diffusion_attention` is a fourth mask with an entry of its
+own, the core of the block-diffusion training objective (a row runs as
+``[noised ‖ clean]`` under a block-granular mask, grouped query heads):
+the einsum over the dense mask, or three passes of the flash kernels and
+a merge by logsumexp.
 
 All take ``[batch, seq, heads, head_dim]`` (BTHD) tensors; keys and
 values may have fewer heads than the queries (grouped queries: ``H //
@@ -105,7 +110,10 @@ def resolve_impl(
     What was chosen is counted at trace time: ``attn.impl.<path>``
     (labels ``asked``, ``shape``, ``heads``, and ``kv_heads``, ``mask``
     where the caller names them), and ``attn.mask.<mask>`` (label
-    ``impl``) for a caller that names its mask."""
+    ``impl``) for a caller that names its mask (``"causal"``,
+    ``"block_diffusion"``, or ``"window"``, a causal layer that hands
+    :func:`dot_product_attention` a window: judged as the causal mask
+    is)."""
     impl = asked
     if impl == "auto":
         from distributeddeeplearning_tpu.ops.pallas import flash, flash_packed
@@ -149,32 +157,42 @@ def dot_product_attention(
     v: jnp.ndarray,
     *,
     causal: bool = False,
+    window: int = 0,
     scale: Optional[float] = None,
     impl: str = "xla",
     axis_name: Optional[str] = None,
 ) -> jnp.ndarray:
     """Multi-head attention over BTHD tensors, full or causal: ``q [B,
     Tq, H, d]`` against ``k``, ``v`` ``[B, Tk, KV, d]``, ``H // KV``
-    query heads to a key head (``xla`` and ``pallas``).
+    query heads to a key head (``xla`` and ``pallas``). ``window``
+    (with ``causal``; ``xla`` and ``pallas``): a query sees its own key
+    and the ``window − 1`` before it; 0, or a window that covers the
+    sequence, is the causal rule.
 
     ``impl='ring'`` requires running inside ``shard_map`` with the
     sequence dimension sharded over ``axis_name`` (default: the mesh
     convention's ``"seq"`` axis, ``parallel/mesh.py``).
     """
+    if window and (not causal or impl not in ("xla", "pallas")):
+        raise ValueError(f"a window needs causal=True and impl xla or pallas, got {impl!r}")
+    if window >= k.shape[1]:  # the band is the whole triangle: the causal rule
+        window = 0
     if impl == "xla":
         mask = None
         if causal:
             tq, tk = q.shape[1], k.shape[1]
             mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+            if window:
+                mask &= ~jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq - window)
         scale = scale if scale is not None else q.shape[-1] ** -0.5
         return _xla_attention(q, k, v, mask=mask, scale=scale)
     if impl == "pallas":
         from distributeddeeplearning_tpu.ops.pallas import flash
 
-        if k.shape[2] == q.shape[2]:
+        if k.shape[2] == q.shape[2] and not window:
             return flash.flash_attention(q, k, v, causal=causal, scale=scale)
         return flash.flash_attention_stats(
-            q, k, v, mask=flash.Mask(causal), scale=scale
+            q, k, v, mask=flash.Mask(causal, window=window), scale=scale
         )[0]
     if impl == "ring":
         axis_name = axis_name or "seq"

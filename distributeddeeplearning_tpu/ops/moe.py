@@ -20,8 +20,9 @@ output that those give. No capacity and no drop:
   ``cap`` rows (the held experts' rows, and nought for the rest: a
   layer's load on the held experts swings with what its tokens have in
   common, between nothing and several times its share, and a step that
-  took as long as its routing asked could not be timed to a percent) (``W1``, ``W3`` up, gated by SiLU, ``W2`` down):
-  ``jax.lax.ragged_dot``.
+  took as long as its routing asked could not be timed to a percent):
+  ``W1``, ``W3`` up, gated by SiLU or, in a ReGLU layer, by ReLU
+  (:data:`ACTIVATIONS`), ``W2`` down: ``jax.lax.ragged_dot``.
 * ``moe_combine`` — each pair's row times its gate, added into its
   token's row (float32).
 
@@ -49,6 +50,8 @@ _ROW_TILE = 512  # the usual branch holds whole tiles of this many rows
 ROUTE, DISPATCH, EXPERTS, COMBINE = (
     "moe_route", "moe_dispatch", "moe_experts", "moe_combine"
 )
+# the gate of an expert's up-projection, by the name a spec gives it
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 class Routed(NamedTuple):
@@ -92,7 +95,8 @@ def _held_keys(experts, first: int, held: int):
     return key, drawn
 
 
-def _held_part(x, routed: Routed, order, drawn, w1, w3, w2, start, *, cap: int):
+def _held_part(x, routed: Routed, order, drawn, w1, w3, w2, start, *,
+               cap: int, activation: str):
     """The held experts' part of the layer from the ``cap`` sorted pairs
     ``order[start : start + cap]``."""
     t, k = routed.experts.shape
@@ -117,7 +121,7 @@ def _held_part(x, routed: Routed, order, drawn, w1, w3, w2, start, *, cap: int):
         rows = jnp.where(there[:, None], x[token], 0)
     with jax.named_scope(EXPERTS):
         up = _grouped_matmul(rows, w1, sizes).astype(jnp.float32)
-        up = jax.nn.silu(up) * _grouped_matmul(rows, w3, sizes)
+        up = ACTIVATIONS[activation](up) * _grouped_matmul(rows, w3, sizes)
         up = jnp.where(there[:, None], up, 0.0)  # as for `rows`
         out = _grouped_matmul(up.astype(x.dtype), w2, sizes)
     with jax.named_scope(COMBINE):
@@ -143,11 +147,13 @@ def held_experts_ffn(
     *,
     first: int,
     num_experts: int,
+    activation: str = "silu",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """``y[t] = Σ_{e held, e chosen by t} gate[t, e] · W2_e(silu(W1_e x_t) ⊙
+    """``y[t] = Σ_{e held, e chosen by t} gate[t, e] · W2_e(act(W1_e x_t) ⊙
     W3_e x_t)`` for ``x [T, D]`` and weights ``[held, D, F]``, ``[held, D,
-    F]``, ``[held, F, D]`` (cast to ``x``'s dtype here). Returns ``y [T,
-    D]`` and the pairs each held expert drew, ``[held]`` int32.
+    F]``, ``[held, F, D]`` (cast to ``x``'s dtype here); ``activation``
+    names ``act`` (:data:`ACTIVATIONS`). Returns ``y [T, D]`` and the
+    pairs each held expert drew, ``[held]`` int32.
 
     The whole of it is recomputed in the backward pass (``jax.
     checkpoint``): kept are ``x`` and the routing, not the gathered
@@ -158,13 +164,14 @@ def held_experts_ffn(
         key, drawn = _held_keys(routed.experts, first, held)
         order = jnp.argsort(key, stable=True)
     cap = usual_cap(routed.experts.size, held, num_experts)
-    y = jax.checkpoint(functools.partial(_stretches, cap=cap))(
-        x, routed, order, drawn, w1, w3, w2
-    )
+    y = jax.checkpoint(
+        functools.partial(_stretches, cap=cap, activation=activation)
+    )(x, routed, order, drawn, w1, w3, w2)
     return y, drawn
 
 
-def _stretches(x, routed: Routed, order, drawn, w1, w3, w2, *, cap: int):
+def _stretches(x, routed: Routed, order, drawn, w1, w3, w2, *, cap: int,
+               activation: str):
     pairs = routed.experts.size
     stretches = -(-pairs // cap)
     if stretches > 1:  # the last stretch may reach past the pairs
@@ -172,7 +179,8 @@ def _stretches(x, routed: Routed, order, drawn, w1, w3, w2, *, cap: int):
     operands = (x, routed, order, drawn) + tuple(
         w.astype(x.dtype) for w in (w1, w3, w2)
     )
-    y = _held_part(*operands, 0, cap=cap)
+    held_part = functools.partial(_held_part, cap=cap, activation=activation)
+    y = held_part(*operands, 0)
     if stretches > 1:
         # Past the usual stretch: only a step whose held experts drew
         # more than `cap` pairs goes in here at all (one `cond`, whose
@@ -183,7 +191,7 @@ def _stretches(x, routed: Routed, order, drawn, w1, w3, w2, *, cap: int):
         def further(start):
             return lax.cond(
                 start < jnp.sum(drawn),
-                lambda: _held_part(*operands, start, cap=cap),
+                lambda: held_part(*operands, start),
                 lambda: jnp.zeros_like(y),
             )
 

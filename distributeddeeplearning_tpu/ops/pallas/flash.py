@@ -104,9 +104,18 @@ def _vma(*arrays):
 
 
 class Mask(NamedTuple):
-    """Which keys a query sees, as the kernels take it. ``causal``: no
-    key after the query's own place; resident blocks above the diagonal
-    are neither computed nor fetched. ``gran`` and ``strict`` coarsen
+    """Which keys a query sees, as the kernels take it: every key (the
+    default), the causal rule, the causal rule within a window, or one
+    of the block-diffusion objective's three block-granular rules.
+    ``causal``: no key after the query's own place; resident blocks
+    above the diagonal are neither computed nor fetched. ``window``
+    (with ``causal``, ``gran`` 1): of those keys the query's own and the
+    ``window − 1`` before it alone, a band; resident blocks wholly
+    behind the band are neither computed nor fetched either, and the
+    tiles that hold the band's two edges are masked. A window that
+    covers the sequence is the causal rule, and ``ops/attention.
+    dot_product_attention`` hands it on as that. ``gran`` and
+    ``strict`` coarsen
     the diagonal tile's rule to blocks of ``gran`` positions (the
     block-diffusion objective, ``ops/attention.block_diffusion_
     attention``): a query of block β sees the keys of blocks ≤ β, or
@@ -123,6 +132,7 @@ class Mask(NamedTuple):
     gran: int = 1
     strict: bool = False
     own: bool = False
+    window: int = 0
 
 
 def _as_mask(causal) -> Mask:
@@ -131,9 +141,11 @@ def _as_mask(causal) -> Mask:
 
 def _sees(rows, cols, mask: Mask):
     """Where query ``rows`` see key ``cols`` (one origin) under a causal
-    or an own-block ``mask``."""
+    (windowed or block-granular) or an own-block ``mask``."""
     if mask.own:
         return rows // mask.gran == cols // mask.gran
+    if mask.window:
+        return (cols <= rows) & (cols > rows - mask.window)
     if mask.gran == 1 and not mask.strict:
         return cols <= rows
     first_unseen = rows // mask.gran * mask.gran
@@ -263,13 +275,80 @@ def _to_rows(x):
     return x.T[:_SUBLANES]
 
 
+def _reach(window: int, b: int) -> int:
+    """How far a window's band reaches in blocks of ``b`` rows: q block
+    ``i`` holds a query that sees a key of k block ``j`` iff ``i − reach
+    <= j <= i``."""
+    return (window + b - 2) // b
+
+
+def _band_steps(owner: _Plan, walked: _Plan, window: int, owner_first: bool):
+    """``(visited, skipped)``: of the ``owner.blocks × walked.major``
+    steps a head's grid takes under a window, how many meet a resident
+    block that holds part of the band, and how many meet none (nothing
+    computed, nothing fetched)."""
+    reach = _reach(window, owner.b)
+    visited = 0
+    for i in range(owner.blocks):
+        lo, hi = (max(i - reach, 0), i) if owner_first else (
+            i, min(i + reach, walked.blocks - 1))
+        visited += hi // walked.sub - lo // walked.sub + 1
+    return visited, owner.blocks * walked.major - visited
+
+
+def _walk_band(visit, off, n_sub: int, window: int, b: int, owner_first: bool):
+    """A window's walk of one resident block, for the forward kernel
+    (``owner_first``: a q block against resident keys) and the backward
+    (a k block against resident queries). ``off`` is the place the
+    owner's own block has among the resident block's ``n_sub``
+    sub-blocks (before it, inside it or past it); the band's live
+    sub-blocks are ``off − reach .. off`` of the keys, ``off .. off +
+    reach`` of the queries. One call ``visit(lo, hi, masked)`` for the
+    live span ``[lo, hi)``, a static branch a span. A span that ends
+    inside the resident block holds one of the band's edges (the
+    diagonal, or the sub-block the window's far edge cuts), and so may
+    the whole block: only that one has an unmasked branch too."""
+    reach, clear = _reach(window, b), window // b
+    if owner_first:
+        first, last = off - reach, off
+        whole = jnp.logical_and(off >= n_sub, off < clear)
+    else:
+        first, last = off, off + reach
+        whole = jnp.logical_and(off < 0, off >= n_sub - clear)
+    for lo in range(n_sub):
+        for hi in range(lo + 1, n_sub + 1):
+            here = jnp.logical_and(
+                first <= 0 if lo == 0 else first == lo,
+                last >= n_sub - 1 if hi == n_sub else last == hi - 1,
+            )
+            if (lo, hi) == (0, n_sub):
+                pl.when(jnp.logical_and(here, whole))(
+                    functools.partial(visit, lo, hi, False)
+                )
+                here = jnp.logical_and(here, jnp.logical_not(whole))
+            pl.when(here)(functools.partial(visit, lo, hi, True))
+
+
 def _walk_keys(step, i, g0, n_sub: int, mask: Mask, kv_len: int, b: int):
     """For a q block ``i`` against the resident K/V block that starts at
     global block ``g0``: one call ``step(c, keep)`` for the tile of its
     first ``c`` sub-blocks, the live ones (a static branch a width).
     ``keep`` masks the tile (queries on rows) where its last sub-block
     is the diagonal one or, without ``causal``, holds the keys' padding;
-    a resident block wholly below the diagonal gets none."""
+    a resident block wholly below the diagonal gets none. Under a
+    window the live sub-blocks start where the band does:
+    ``step(c, keep, lo)`` (:func:`_walk_band`)."""
+    if mask.window:
+        def visit(lo, hi, masked):
+            keep = None
+            if masked:  # positions from the q block's first row on
+                rows = lax.broadcasted_iota(jnp.int32, (b, (hi - lo) * b), 0)
+                cols = lax.broadcasted_iota(jnp.int32, (b, (hi - lo) * b), 1)
+                keep = _sees(rows, cols + (lo - (i - g0)) * b, mask)
+            step(hi, keep, lo)
+
+        _walk_band(visit, i - g0, n_sub, mask.window, b, owner_first=True)
+        return
     if mask.own:  # the resident block is the q block's own, one tile
         step(1, _sees(
             lax.broadcasted_iota(jnp.int32, (b, b), 0),
@@ -320,8 +399,9 @@ def _flash_fwd_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def step(c, keep):
-        qs, ks, vs = q_ref[0], k_ref[0, : c * b, :], v_ref[0, : c * b, :]
+    def step(c, keep, lo=0):
+        rows = slice(lo * b, c * b)
+        qs, ks, vs = q_ref[0], k_ref[0, rows, :], v_ref[0, rows, :]
         for h in range(hp):
             v = _head(vs, h, d)
             s = _scores(_head(qs, h, d), _head(ks, h, d), scale)  # [b, c·b] f32
@@ -356,7 +436,8 @@ def _flash_bwd_kernel(
     """All three gradients from one k/v block against one resident Q/dO
     block, on one transposed tile ``[keys, queries]`` of the sub-blocks
     from the diagonal one on (causal: the ones before it are skipped, a
-    static branch a start): five products, one exponential, one
+    static branch a start; under a window the ones past the band too,
+    a branch a span): five products, one exponential, one
     ``p ⊙ (dp − Δ)``. ``dk`` and ``dv`` are the program's own, summed
     along the last grid axis. ``dq`` is the resident blocks': one f32
     slot of ``dq_scr`` for each step of the last axis, summed over the k
@@ -381,21 +462,22 @@ def _flash_bwd_kernel(
     def _init_dq():
         dq_scr[slot] = jnp.zeros(dq_scr.shape[1:], dq_scr.dtype)
 
-    def step(lo, keep):
+    def step(lo, keep, hi=None):
+        rows = slice(lo * b, None if hi is None else hi * b)
         ks, vs = k_ref[0], v_ref[0]
-        qs, dos = q_ref[0, lo * b :, :], do_ref[0, lo * b :, :]
+        qs, dos = q_ref[0, rows, :], do_ref[0, rows, :]
         for h in range(hp):
             q, do, k = _head(qs, h, d), _head(dos, h, d), _head(ks, h, d)
-            lse = _down(lse_ref[0, h, 0, :, lo * b :], b)
+            lse = _down(lse_ref[0, h, 0, :, rows], b)
             pt = jnp.exp(_scores(k, q, scale) - lse)  # [b, c·b]
             if keep is not None:
                 pt = jnp.where(keep, pt, 0.0)
             dv_scr[h] += _dot(pt.astype(do.dtype), do)
             dpt = _dot_nt(_head(vs, h, d), do)
-            dst = pt * (dpt - _down(delta_ref[0, h, 0, :, lo * b :], b))
+            dst = pt * (dpt - _down(delta_ref[0, h, 0, :, rows], b))
             dst = dst.astype(q.dtype)
             dk_scr[h] += _dot(dst, q)
-            dq_scr[slot, h, lo * b :, :] += _dot_tn(dst, k)
+            dq_scr[slot, h, rows, :] += _dot_tn(dst, k)
 
     def tile(lo, axis):
         return lax.broadcasted_iota(jnp.int32, (b, (n_sub - lo) * b), axis)
@@ -403,8 +485,18 @@ def _flash_bwd_kernel(
     def diagonal(lo):  # the tile's first sub-block is the diagonal one
         step(lo, _sees(tile(lo, 1), tile(lo, 0), mask))
 
+    def band(lo, hi, masked):
+        keep = None
+        if masked:  # positions from the k block's first row on
+            keys = lax.broadcasted_iota(jnp.int32, (b, (hi - lo) * b), 0)
+            queries = lax.broadcasted_iota(jnp.int32, (b, (hi - lo) * b), 1)
+            keep = _sees(queries + (lo - (i - jm * n_sub)) * b, keys, mask)
+        step(lo, keep, hi)
+
     if mask.own:
         diagonal(0)
+    elif mask.window:
+        _walk_band(band, i - jm * n_sub, n_sub, mask.window, b, owner_first=False)
     elif mask.causal:
         behind = i - jm * n_sub  # q sub-blocks of this resident block before k's
         pl.when(behind < 0)(functools.partial(step, 0, None))
@@ -431,7 +523,7 @@ def _flash_bwd_kernel(
 
 def _specs(owner: _Plan, walked: _Plan, w: int, hp: int, groups: int,
            causal: bool, owner_first: bool, rep: int = 1,
-           diagonal: bool = False):
+           diagonal: bool = False, window: int = 0):
     """Block specs of a kernel's grid ``(batch, head group, owned block,
     resident block)`` over ``[B, T, H·d]`` operands: ``own(part)`` a
     ``[b, w]`` block (``w = hp·d`` lanes), ``walk(part)`` a resident
@@ -441,7 +533,8 @@ def _specs(owner: _Plan, walked: _Plan, w: int, hp: int, groups: int,
     array: ``groups`` lane blocks each. Causal programs that have no
     live tile in a resident block name the nearest one that has, so
     nothing is fetched for them: a q owner (``owner_first``) lives at or
-    after its keys, a k owner at or before its queries.
+    after its keys, a k owner at or before its queries; under a
+    ``window`` no further than the band reaches (:func:`_reach`).
 
     ``rep`` > 1 (grouped queries, one head a program): ``rep`` query
     heads read one key head. A q owner's grid counts query heads and
@@ -459,6 +552,7 @@ def _specs(owner: _Plan, walked: _Plan, w: int, hp: int, groups: int,
     block, and it is written at once."""
     b = owner.b
     major = 1 if diagonal else walked.major
+    reach = _reach(window, b)
 
     def resident(i, jm):
         if diagonal:
@@ -468,7 +562,14 @@ def _specs(owner: _Plan, walked: _Plan, w: int, hp: int, groups: int,
         if not causal:
             return jm
         near = i // walked.sub
-        return jnp.minimum(jm, near) if owner_first else jnp.maximum(jm, near)
+        jm = jnp.minimum(jm, near) if owner_first else jnp.maximum(jm, near)
+        if window and owner_first:
+            jm = jnp.maximum(jm, jnp.maximum(i - reach, 0) // walked.sub)
+        elif window:
+            jm = jnp.minimum(
+                jm, jnp.minimum(i + reach, walked.blocks - 1) // walked.sub
+            )
+        return jm
 
     def walked_head(g, jm):
         """Lane block (and statistics row) of the walked operand."""
@@ -508,6 +609,18 @@ def _specs(owner: _Plan, walked: _Plan, w: int, hp: int, groups: int,
     return own, walk, own_stat, walk_stat, summed
 
 
+def _count_band(owner: _Plan, walked: _Plan, mask: Mask, which: str,
+                owner_first: bool) -> None:
+    """``attn.window.blocks`` at trace time: what a head's grid walks
+    under a window (labels ``visited``, ``skipped``, ``pass``)."""
+    if mask.window:
+        visited, skipped = _band_steps(owner, walked, mask.window, owner_first)
+        obs.counter(
+            "attn.window.blocks", visited=visited, skipped=skipped,
+            window=mask.window, block=owner.b, **{"pass": which},
+        )
+
+
 def _params(sequential: int, vmem_bytes: int = 32 * 2**20):
     """The last ``sequential`` grid axes carry accumulators and run in
     order; the others are free to parallelise."""
@@ -528,6 +641,10 @@ def _geometry(q, k, heads: int, block: Optional[int], packed: bool,
     b = block or _pick_block(max(q.shape[1], k.shape[1]))
     if b % mask.gran:
         raise ValueError(f"kernel block {b} is no multiple of the mask's {mask.gran}")
+    if mask.window and (
+        not mask.causal or mask.gran != 1 or mask.strict or mask.own
+    ):
+        raise ValueError(f"a window stands with the plain causal rule alone: {mask}")
     hp = heads_per_program(heads, d) or 1  # 0: transposed, one head an array row
     parts = (0, 1, 2) if packed else (0, 0, 0)
     if mask.own:  # a block meets its own: nothing resident beside it
@@ -557,8 +674,9 @@ def _flash(q, k, v, heads, causal, scale, block, interpret, packed=False, rep=1)
     kp, vp = _pad_rows(k, pk.rows), _pad_rows(v, pk.rows)
     own, walk, own_stat, _, _ = _specs(
         pq, pk, w, hp, heads // hp, causal, owner_first=True, rep=rep,
-        diagonal=mask.own,
+        diagonal=mask.own, window=mask.window,
     )
+    _count_band(pq, pk, mask, "forward", owner_first=True)
     # vma: inside shard_map (the DP/SP engines) outputs vary over the
     # same mesh axes as the inputs; check_vma requires saying so.
     vma = _vma(q, k, v)
@@ -661,8 +779,9 @@ def _flash_bwd_rule(heads, causal, scale, block, interpret, res, do,
 
     own, walk, _, walk_stat, summed = _specs(
         pk, pq, w, hp, heads // hp, causal, owner_first=False, rep=rep,
-        diagonal=mask.own,
+        diagonal=mask.own, window=mask.window,
     )
+    _count_band(pk, pq, mask, "backward", owner_first=False)
     q_major = 1 if mask.own else pq.major
     grouped = {"major": q_major} if rep > 1 else {}
     # dq's sums: a slot for each step of the last axis (under `own` one)

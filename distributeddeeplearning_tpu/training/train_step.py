@@ -244,6 +244,18 @@ def l2_kernel_penalty(params: PyTree, weight_decay: float) -> jnp.ndarray:
     return weight_decay * sum(leaves)
 
 
+def init_kept(model, rng, x):
+    """``model.init``'s variables that a train state keeps. A model that
+    sows statistics of its forward pass (an expert layer's counts,
+    ``models/decoder.STATS``) hands them back from ``init`` too, and
+    computing them is the whole forward at the run's sequence length:
+    the einsum attention over 16,384 positions is 14 GiB that no step
+    ever needs. Left out of a jitted function's result, XLA drops the
+    forward and the draw alone is left."""
+    variables = model.init(rng, x, train=False)
+    return {k: variables[k] for k in ("params", "batch_stats") if k in variables}
+
+
 def create_train_state(
     model,
     config: TrainConfig,
@@ -262,10 +274,9 @@ def create_train_state(
     """
     rng = rng if rng is not None else jax.random.PRNGKey(config.seed)
     shape = input_shape or (1, config.image_size, config.image_size, 3)
-    variables = jax.jit(model.init, static_argnames=("train",))(
+    variables = jax.jit(functools.partial(init_kept, model))(
         rng,
         jnp.zeros(shape, input_dtype if input_dtype is not None else jnp.float32),
-        train=False,
     )
     # Unbox nn.with_logical_partitioning metadata: boxed leaves would hide
     # the `kernel` path component from l2_kernel_penalty. Both engines

@@ -204,15 +204,25 @@ def render_device(rep: dict) -> str:
 def chosen_paths(events, prefix: str = "attn.impl.") -> str:
     """What the program chose each time it was traced, one line, from
     the counters under ``prefix``: ``attn.impl.<path>`` (the attention
-    core, ``models/vit.Attention``), ``attn.bwd.<path>`` (the flash
-    kernels' backward, ``ops/pallas/flash.py``) or ``loss.impl.<path>``
-    (the loss, ``training/train_step.loss_and_hits``)."""
+    core, ``models/vit.Attention``), ``attn.mask.<mask>`` (the mask a
+    spec-built layer named), ``attn.bwd.<path>`` and ``attn.window.
+    blocks`` (the flash kernels' backward, and the steps a window's
+    walk visits and skips, ``ops/pallas/flash.py``), ``loss.impl.
+    <path>`` (the loss, ``training/train_step.loss_and_hits``),
+    ``decoder.layer.<kind>`` and ``moe.*`` (the layers a spec-built
+    decoder built, ``models/decoder.py``). A counter with no ``shape``
+    label is keyed by ``visited``/``skipped`` (the window's walk) or
+    ``window`` where it has them."""
     chosen: dict = {}
     for e in events:
         name = str(e.get("name", ""))
         if e.get("kind") == "counter" and name.startswith(prefix):
-            shape = (e.get("labels") or {}).get("shape")
-            key = (name[len(prefix):], tuple(shape or ()))
+            labels = e.get("labels") or {}
+            shape = labels.get("shape") or [
+                labels[k] for k in ("pass", "visited", "skipped", "window")
+                if k in labels
+            ]
+            key = (name[len(prefix):], tuple(shape))
             chosen[key] = chosen.get(key, 0) + int(e.get("value", 1))
     return ", ".join(
         f"{path} x{n} at {list(shape)}" for (path, shape), n in sorted(chosen.items())
@@ -282,8 +292,11 @@ def main(argv=None) -> int:
     else:
         print(render(recon, training, args.top))
     for what, prefix in (
-        ("attention core", "attn.impl."), ("attention backward", "attn.bwd."),
-        ("loss", "loss.impl."),
+        ("attention core", "attn.impl."), ("attention mask", "attn.mask."),
+        ("attention backward", "attn.bwd."),
+        ("window walk (pass, steps visited, skipped, window)", "attn.window."),
+        ("loss", "loss.impl."), ("decoder layers", "decoder.layer."),
+        ("expert layer", "moe."),
     ):
         paths_chosen = chosen_paths(loaded["events"], prefix)
         if paths_chosen:

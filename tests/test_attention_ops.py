@@ -201,6 +201,25 @@ def test_resolve_impl_table(monkeypatch, case, path):
     assert _resolved(monkeypatch, want=path, **case) == path
 
 
+def test_resolve_impl_judges_and_counts_a_window_as_a_named_mask(monkeypatch):
+    """``mask="window"`` is judged as the causal mask is (the whole
+    length) and counted as any named mask: ``attn.mask.window`` with the
+    path taken, the shape and the name on ``attn.impl.<path>``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    x = jax.ShapeDtypeStruct((1, 16384, 28 * 128), jnp.bfloat16)
+    obs.reset()
+    assert resolve_impl(
+        "auto", x, heads=28, head_dim=128, initializing=False, kv_heads=4,
+        mask="window",
+    ) == "pallas"
+    events = {e["name"]: e["labels"] for e in obs.get_bus().ring if e["kind"] == "counter"}
+    obs.reset()
+    assert events["attn.mask.window"] == {"impl": "pallas"}
+    assert events["attn.impl.pallas"]["mask"] == "window"
+    assert events["attn.impl.pallas"]["shape"] == [1, 16384, 3584]
+
+
 def test_a_caller_that_names_no_mask_gets_the_labels_it_had(monkeypatch):
     """``models/vit.Attention`` states neither key heads nor a mask: its
     counter carries ``asked``, ``shape``, ``heads`` and there is no
@@ -248,8 +267,169 @@ def test_the_fused_backward_is_counted_once_a_traced_backward():
     assert event["name"] == "attn.bwd.fused" and event["value"] == 1
     assert event["labels"] == {
         "shape": [1, 64, 512], "rep": 2,
-        "mask": {"causal": True, "gran": 4, "strict": False, "own": False},
+        "mask": {"causal": True, "gran": 4, "strict": False, "own": False,
+                 "window": 0},
     }
+
+
+# -- the window rule ----------------------------------------------------------
+
+def _window_operands(t, h, kv, d, seed=0):
+    key = jax.random.PRNGKey(seed)
+    return (
+        jax.random.normal(key, (1, t, h, d)),
+        jax.random.normal(jax.random.fold_in(key, 1), (1, t, kv, d)),
+        jax.random.normal(jax.random.fold_in(key, 2), (1, t, kv, d)),
+    )
+
+
+def _rel_gap(a, b):
+    return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30))
+
+
+@pytest.mark.parametrize(
+    "t,h,kv,d,window,block",
+    [
+        (1024, 2, 1, 128, 40, 64),    # a window inside one block
+        (1024, 2, 1, 128, 64, 64),    # = a block
+        (1024, 2, 1, 128, 65, 64),    # a block and one key
+        (1024, 2, 1, 128, 100, 64),   # between blocks
+        (1024, 2, 1, 128, 512, 64),   # = a resident block of eight
+        (1024, 2, 1, 128, 2, 64),     # a query and the key before it
+        (1024, 2, 1, 128, 5000, 64),  # covers the sequence: the whole triangle
+        (1024, 7, 1, 128, 200, 128),  # seven query heads a key head, in place
+        (1000, 2, 2, 128, 200, 128),  # a sequence that pads
+        (700, 4, 2, 64, 130, 128),    # narrow heads, two a program
+    ],
+    ids=["lt-block", "eq-block", "block+1", "between", "eq-resident", "two",
+         "ge-seq", "rep7", "pads", "narrow"],
+)
+def test_the_window_kernels_match_the_einsum_over_the_band(t, h, kv, d, window, block):
+    """``Mask(causal, window=w)`` in the flash kernels (interpret mode,
+    sixteen blocks in two resident ones at the first shapes) against the
+    einsum over the same band: the output and all three gradients."""
+    from distributeddeeplearning_tpu.ops.pallas.flash import flash_attention_stats
+
+    q, k, v = _window_operands(t, h, kv, d)
+
+    def kernel(q, k, v):
+        return flash_attention_stats(
+            q, k, v, mask=Mask(True, window=window), block=block, interpret=True
+        )[0]
+
+    def einsum(q, k, v):
+        return dot_product_attention(q, k, v, causal=True, window=window, impl="xla")
+
+    rows, cols = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    band = (cols <= rows) & (cols > rows - window)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, h // kv, 2)) * d**-0.5
+    want = jnp.einsum(
+        "bhqk,bkhd->bqhd", jax.nn.softmax(jnp.where(band, s, -jnp.inf), -1),
+        jnp.repeat(v, h // kv, 2),
+    )
+    assert _rel_gap(einsum(q, k, v), want) < 1e-5  # the einsum path masks the band
+    assert _rel_gap(kernel(q, k, v), want) < 1e-5
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(kernel(*a))), (0, 1, 2))(q, k, v)
+    ref = jax.grad(lambda *a: jnp.sum(jnp.sin(einsum(*a))), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, ref):
+        assert _rel_gap(a, b) < 1e-5
+
+
+def test_the_pallas_entry_takes_the_window_and_the_other_paths_refuse_it():
+    q, k, v = _window_operands(1024, 2, 2, 128)
+    got = dot_product_attention(q, k, v, causal=True, window=300, impl="pallas")
+    want = dot_product_attention(q, k, v, causal=True, window=300, impl="xla")
+    assert _rel_gap(got, want) < 1e-5
+    assert _rel_gap(want, dot_product_attention(q, k, v, causal=True)) > 1e-2
+    with pytest.raises(ValueError, match="window"):
+        dot_product_attention(q, k, v, causal=False, window=300)
+    with pytest.raises(ValueError, match="window"):
+        dot_product_attention(q, k, v, causal=True, window=300, impl="ring")
+    with pytest.raises(ValueError, match="window"):
+        from distributeddeeplearning_tpu.ops.pallas.flash import flash_attention_stats
+
+        flash_attention_stats(q, k, v, mask=Mask(True, 4, window=300), interpret=True)
+
+
+@pytest.mark.parametrize("owner_first", [True, False], ids=["forward", "backward"])
+@pytest.mark.parametrize(
+    "t,b,window", [(16384, 512, 4096), (2048, 128, 300), (1024, 64, 64), (1024, 64, 1)]
+)
+def test_a_block_behind_the_window_is_neither_computed_nor_fetched(t, b, window, owner_first):
+    """The index maps name, for every step of a head's grid, a resident
+    block that holds part of the band (so one wholly behind it, or above
+    the diagonal, is never fetched), ``_walk_band`` computes in exactly
+    the steps whose own resident block does, and ``_band_steps`` counts
+    those: at the cell's shapes (16,384 rows, blocks of 512, two a
+    resident block) 112 of a head's 512 steps forward."""
+    from distributeddeeplearning_tpu.ops.pallas import flash
+
+    plan = flash._plan(t, b)
+    _, walk, _, _, _ = flash._specs(
+        plan, plan, 128, 1, 1, True, owner_first=owner_first, window=window
+    )
+    live = lambda i, j: j <= i and (i - j - 1) * b + 1 < window  # q block i meets k block j
+
+    def holds_band(i, jm):  # resident block jm against owner block i
+        subs = range(jm * plan.sub, (jm + 1) * plan.sub)
+        return any(live(i, j) if owner_first else live(j, i) for j in subs)
+
+    visited = 0
+    for i in range(plan.blocks):
+        for jm in range(plan.major):
+            named = int(walk().index_map(0, 0, i, jm)[1])
+            assert holds_band(i, named), (i, jm, named)
+            if holds_band(i, jm):
+                assert named == jm
+                visited += 1
+    assert flash._band_steps(plan, plan, window, owner_first) == (
+        visited, plan.blocks * plan.major - visited)
+    if (t, owner_first) == (16384, True):
+        assert (visited, plan.blocks * plan.major) == (
+            sum(i // 2 - max(i - 8, 0) // 2 + 1 for i in range(32)), 512)
+
+
+def test_the_window_walk_is_counted_forward_and_backward():
+    """``attn.window.blocks`` at trace time, each time a pass is traced
+    (a differentiated call traces the forward as the primal and as the
+    rule's): the steps visited and skipped of a head's grid, forward and
+    backward; a window that covers the sequence ``dot_product_
+    attention`` hands on as the causal rule, whose kernels count
+    nothing."""
+    from distributeddeeplearning_tpu.ops.pallas import flash
+
+    q, k, v = (jnp.zeros((1, 1024, 1, 128)),) * 3
+
+    def core(window):
+        return lambda q, k, v: jnp.sum(flash.flash_attention_stats(
+            q, k, v, mask=Mask(True, window=window), block=64, interpret=True
+        )[0])
+
+    def counted():
+        return [
+            e["labels"] for e in obs.get_bus().ring
+            if e["kind"] == "counter" and e["name"] == "attn.window.blocks"
+        ]
+
+    jax.clear_caches()
+    obs.reset()
+    jax.jit(jax.grad(core(100), (0, 1, 2))).lower(q, k, v)
+    *forwards, backward = counted()
+    forward = forwards[0]
+    assert all(f == forward for f in forwards)
+    plan = flash._plan(1024, 64)
+    assert forward == {
+        "visited": flash._band_steps(plan, plan, 100, True)[0],
+        "skipped": flash._band_steps(plan, plan, 100, True)[1],
+        "window": 100, "block": 64, "pass": "forward"}
+    assert backward["pass"] == "backward" and backward["skipped"] > 0
+    assert forward["visited"] + forward["skipped"] == 16 * 2
+    obs.reset()
+    covered = lambda q, k, v: jnp.sum(dot_product_attention(
+        q, k, v, causal=True, window=1024, impl="pallas"))
+    jax.jit(jax.grad(covered, (0, 1, 2))).lower(q, k, v)
+    assert counted() == []
+    obs.reset()
 
 
 @pytest.mark.parametrize(

@@ -6,6 +6,7 @@ reference of the ``sdar`` family (``benchmarks/references/sdar.py``) at a
 small size: hidden 64, 2 layers, 4/2 heads of 16, 8 experts top-2,
 L = 32, B = 4, seeded weights, float32."""
 
+import dataclasses
 import hashlib
 
 import jax
@@ -168,6 +169,205 @@ def test_the_causal_spec_model_with_grouped_heads(against):
     assert gap(got_grads["block0"]["attn"]["q"]["kernel"],
                grads["block0"]["attn"]["q"]["kernel"]) < 1e-4
     assert gap(got_grads["tok_embed"], grads["tok_embed"]) < 1e-4
+
+
+# -- layers that differ: window and full attention, a router before attention --
+
+def _st_config(held=8, first=0, layers=8):
+    return {
+        "hidden_size": 64, "layers": layers, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 16, "moe_ffn_hidden_size": 32,
+        "moe_num_primary_experts": held, "first_expert": first,
+        "moe_num_active_primary_experts": 2, "vocab_size": VOCAB,
+        "rms_norm_eps": 1e-6, "rope_theta": 1e6, "sliding_window_size": 8,
+        "sliding_window_layout": [0, 1, 1, 1] * 2, "rope_layout": [0, 1, 1, 1] * 2,
+        "published": {"moe_num_primary_experts": 8, "layers": 8},
+        "assumed": {"router_input": "ln1"},
+    }
+
+
+def _st_params(cfg, seed):
+    """The family's seeded weights with q, k and the experts' kernels
+    ten times as loud: at hidden 64 the seed's N(0, 0.02) kernels give
+    scores of a hundredth, so that every mask's softmax is all but
+    uniform, and experts that add a thousandth of the residual stream;
+    at the published width the scores are of order one, as here."""
+    from benchmarks.references import smallthinker as st
+
+    params = st.init_params(cfg, seed)
+    for i in range(cfg["layers"]):
+        for name in ("q", "k"):
+            kernel = params[f"block{i}"]["attn"][name]["kernel"]
+            params[f"block{i}"]["attn"][name]["kernel"] = 10.0 * kernel
+        for name in ("w1", "w3", "w2"):
+            kernel = params[f"block{i}"]["mlp"][name]["kernel"]
+            params[f"block{i}"]["mlp"][name]["kernel"] = 10.0 * kernel
+    return params
+
+
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
+def test_the_mixed_layer_model_matches_its_reference(attn_impl):
+    """``smallthinker_tiny`` (two periods of a full layer without
+    positions and three window-8 layers with rotary ones, router on
+    ``ln1``'s output, ReLU gate) against ``references/smallthinker.py``
+    on seeded weights at L = 32: logits, loss, every leaf's gradient,
+    the experts chosen. float32 on both sides: 1e-4 of the largest
+    logit, 2e-3 of a leaf's largest gradient entry (a bfloat16 product
+    would read 1e-2)."""
+    from benchmarks.references import smallthinker as st
+
+    cfg = _st_config()
+    params = _st_params(cfg, 11)
+    rows = clean_rows(3, seed=5)
+    tokens, labels = jnp.asarray(rows), jnp.asarray(np.roll(rows, -1, axis=1))
+    model = get_model(
+        "smallthinker_tiny", num_classes=VOCAB, dtype="float32",
+        attn_impl=attn_impl, max_seq_len=L,
+    )
+
+    def loss(params):
+        logits, seen = model.apply(
+            {"params": params}, tokens, train=True, mutable=["intermediates"]
+        )
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        return jnp.mean(logz - picked), (logits, seen)
+
+    (l, (logits, seen)), grads = jax.value_and_grad(loss, has_aux=True)(params)
+    want, chosen = st.forward(params, tokens, cfg)
+    assert logits.shape == (3, L, VOCAB) and gap(logits, want) < 1e-4
+    got_chosen = jnp.stack([
+        seen["intermediates"][f"block{i}"]["mlp"]["experts"][0] for i in range(8)
+    ])
+    assert bool(jnp.all(jnp.sort(got_chosen, -1) == jnp.sort(chosen, -1)))
+    lr, gr = jax.value_and_grad(st.token_loss)(params, tokens, labels, cfg)
+    assert abs(float(l) - float(lr)) < 1e-5 * abs(float(lr))
+    flat_got, flat_want = st.flatten(grads), st.flatten(gr)
+    assert set(flat_got) == set(flat_want)
+    for k in flat_want:
+        assert gap(flat_got[k], flat_want[k]) < 2e-3, k
+
+
+@pytest.mark.parametrize(
+    "variant", ["every_layer_full", "rope_everywhere", "router_after_attention", "silu"]
+)
+def test_the_reference_sees_each_thing_the_model_adds(variant):
+    """A reference without one of the four (the window, the layers
+    without positions, the router's input, the ReLU gate) is another
+    model: its logits leave the program's by far more than the 1e-4 the
+    sound one keeps."""
+    from benchmarks.references import smallthinker as st
+
+    cfg = _st_config()
+    params = _st_params(cfg, 11)
+    tokens = jnp.asarray(clean_rows(2, seed=5))
+    model = get_model(
+        "smallthinker_tiny", num_classes=VOCAB, dtype="float32",
+        attn_impl="xla", max_seq_len=L,
+    )
+    logits = model.apply({"params": params}, tokens, train=False)
+    other = dict(cfg)
+    if variant == "every_layer_full":
+        other["sliding_window_layout"] = [0] * 8
+    elif variant == "rope_everywhere":
+        other["rope_layout"] = [1] * 8
+    elif variant == "router_after_attention":
+        other["assumed"] = {"router_input": "ln2"}
+    if variant == "silu":
+        import benchmarks.references.smallthinker as module
+
+        relu, module.jax.nn.relu = jax.nn.relu, jax.nn.silu
+        try:
+            wrong, _ = st.forward(params, tokens, cfg)
+        finally:
+            module.jax.nn.relu = relu
+    else:
+        wrong, _ = st.forward(params, tokens, other)
+    assert gap(logits, st.forward(params, tokens, cfg)[0]) < 1e-4
+    assert gap(logits, wrong) > 1e-3
+
+
+@pytest.mark.parametrize("kind", ["full-nope", "window-rope"])
+def test_the_eight_shares_of_a_mixed_layer_add_up_to_the_uncut_reference(kind):
+    """One layer of the new model, 8 experts as eight shares of one: the
+    parts the shares give (each a whole layer's output: what every chip
+    computes alike, the residual stream and attention, counted once)
+    add up to what the uncut reference gives for the layer, with the
+    router on ``ln1``'s output and the ReLU gate."""
+    from benchmarks.references import smallthinker as st
+    from distributeddeeplearning_tpu.models import decoder
+
+    cfg = _st_config()
+    s = st.sizes(cfg)
+    layer = 0 if kind == "full-nope" else 1
+    p = _st_params(cfg, 3)[f"block{layer}"]
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, L, 64))
+    positions = jnp.arange(L)
+    spec = decoder.SPECS["smallthinker_tiny"]
+    assert spec.kind(layer) == decoder.LayerKind(*((0, False) if layer == 0 else (8, True)))
+
+    def share(first):
+        mlp = {k: {"kernel": v["kernel"][first:first + 1]} for k, v in p["mlp"].items()
+               if k != "router"}
+        mlp["router"] = p["mlp"]["router"]
+        block = decoder.SpecBlock(
+            dataclasses.replace(spec, experts_held=1, first_expert=first),
+            jnp.float32, "xla", spec.kind(layer),
+        )
+        return block.apply({"params": {**p, "mlp": mlp}}, x, positions, False)
+
+    want, _ = st._layer(x, p, positions, s, None, s["windowed"][layer], s["rope"][layer])
+    no_experts = {**p, "mlp": {**p["mlp"], "w2": {"kernel": 0.0 * p["mlp"]["w2"]["kernel"]}}}
+    alike, _ = st._layer(x, no_experts, positions, s, None, s["windowed"][layer], s["rope"][layer])
+    parts = [share(first) - alike for first in range(8)]
+    assert gap(alike + sum(parts), want) < 1e-5
+    assert gap(sum(parts), want - alike) < 1e-4  # the experts' part alone
+    assert float(jnp.max(jnp.abs(want - alike))) > 1e-3
+
+
+def test_the_specs_that_were_there_build_and_compute_what_they_did():
+    """``sdar_tiny`` and ``gpt2_tiny`` after the spec gained its pattern,
+    the router's input and the activation: the parameter trees they had,
+    and a loss and its gradients that lower, text for text, to what they
+    lowered to before (taken from the parent commit's tree, PR 31)."""
+    if jax.__version__ != "0.9.0" or jax.device_count() != 8:
+        pytest.skip("the text was taken under jax 0.9.0 on the tests' 8 host devices")
+    tokens = jnp.asarray(clean_rows(2))
+    was = {
+        "sdar_tiny": ("0d7216bd0236f92ee025fa82202e1ab40432b0462aaa11440c5b6d6c04035b05",
+                      "797057832e8bff9b"),
+        "gpt2_tiny": ("280764fbc3855e0c2df55564e1aeaad9db5ab255357f1110b43e8c224de4b947",
+                      "a1539845ca3f9ad3"),
+    }
+    for name, kw, toks in (
+        ("sdar_tiny", {}, jnp.concatenate([tokens, tokens], 1)),
+        ("gpt2_tiny", {"max_seq_len": L}, tokens),
+    ):
+        model = get_model(name, num_classes=VOCAB, dtype="float32", attn_impl="xla", **kw)
+        params = model.init(jax.random.PRNGKey(1), toks, train=False)["params"]
+
+        def loss(p):
+            return jnp.sum(
+                model.apply({"params": p}, toks, train=True, mutable=["stats"])[0] ** 2
+            )
+
+        text = jax.jit(jax.value_and_grad(loss)).lower(params).as_text()
+        shapes = sorted(
+            (jax.tree_util.keystr(k), v.shape)
+            for k, v in jax.tree_util.tree_leaves_with_path(params)
+        )
+        assert (
+            hashlib.sha256(text.encode()).hexdigest(),
+            hashlib.sha256(repr(shapes).encode()).hexdigest()[:16],
+        ) == was[name], name
+
+
+def test_the_block_diffusion_mask_takes_no_window():
+    model = get_model(
+        "smallthinker_tiny", num_classes=VOCAB, dtype="float32", block_len=4
+    )
+    with pytest.raises(ValueError, match="window"):
+        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 2 * L), jnp.int32), train=False)
 
 
 # -- the attention core -------------------------------------------------------

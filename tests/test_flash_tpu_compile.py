@@ -166,3 +166,73 @@ def test_an_sdar_layer_reaches_its_kernels_under_their_scopes(one_chip, monkeypa
     assert programs.groups_in(scopes, TRAIN_STEP_GROUPS) >= {
         "attn_core", "attn_proj", "mlp", "head_loss", "norm_residual"
     }
+
+
+@pytest.mark.parametrize(
+    "t,heads,window", [(16384, 28, 4096), (16384, 28, 0), (4352, 4, 1000)],
+    ids=["cell-window", "cell-full", "b256-sub8"],
+)
+def test_mosaic_takes_the_window_rule_at_the_long_cell_s_shapes(one_chip, t, heads, window):
+    """``Mask(causal, window)`` at the 16k cell's shapes: 28 query heads
+    reading 4 key heads of 128 in place over 16,384 rows (blocks of 512,
+    two a resident block; the backward's ``dq`` sums 7 × 16,384 × 128 × 4
+    B = 56 MiB of VMEM), and a length that takes blocks of 256, eight a
+    resident block (a static branch a live span)."""
+    from distributeddeeplearning_tpu.ops.pallas.flash import Mask, flash_attention_stats
+
+    q = jax.ShapeDtypeStruct((1, t, heads, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, t, 4, 128), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out, _ = flash_attention_stats(
+            q, k, v, mask=Mask(True, window=window), interpret=False
+        )
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_a_period_of_mixed_layers_reaches_its_kernels_under_their_kinds(one_chip, monkeypatch):
+    """One period of ``smallthinker_21b_a3b`` at its published widths (8
+    of the 64 experts held, 16,384 positions), left at its defaults and
+    asked as on the chip: a full layer's kernels under ``attn_full``,
+    three window layers' under ``attn_window``, the router's product
+    under ``moe_route``, every group of the three tables present."""
+    from distributeddeeplearning_tpu.models.decoder import ATTN_KIND_GROUPS, MOE_GROUPS
+
+    model = get_model(
+        "smallthinker_21b_a3b", num_classes=18992, dtype="bfloat16", layers=4,
+        experts_held=8, remat=True,
+    )
+    tokens = jnp.zeros((1, 16384), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens, train=False)["params"]
+    )
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one_chip), params
+    )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    jax.clear_caches()
+    obs.reset()
+
+    def objective(params, tokens):
+        logits = model.apply({"params": params}, tokens, train=True)
+        return jnp.sum(logits.astype(jnp.float32))
+
+    tok = jax.ShapeDtypeStruct(tokens.shape, tokens.dtype, sharding=one_chip)
+    compiled = jax.jit(jax.grad(objective)).lower(params, tok).compile()
+    totals = obs.get_bus().totals()
+    obs.reset()
+    assert totals["attn.mask.window"]["count"] == 3 * totals["attn.mask.causal"]["count"]
+    assert totals["decoder.layer.window"]["count"] == 3 * totals["decoder.layer.full"]["count"]
+    assert totals["moe.route.before_attention"]["count"] == totals["moe.impl.ragged_dot"]["count"]
+    assert totals["attn.window.blocks"]["count"] >= 2  # forward and backward, traced once
+    scopes = programs.parse_hlo_scopes(compiled.as_text())
+    by_kind = programs.kernel_calls_by_group(scopes, ATTN_KIND_GROUPS)
+    # a layer's forward, its recomputed forward (block remat) and its backward
+    assert (by_kind["attn_full"], by_kind["attn_window"]) == (3, 9)
+    assert programs.kernel_calls_by_group(scopes, TRAIN_STEP_GROUPS)["attn_core"] == 12
+    assert programs.groups_in(scopes, MOE_GROUPS) == {g for g, _ in MOE_GROUPS}
+    assert programs.groups_in(scopes, ATTN_KIND_GROUPS) == {g for g, _ in ATTN_KIND_GROUPS}

@@ -259,3 +259,48 @@ def test_top1_router_gets_output_gradient():
     router = [g for p, g in flat if "router" in str(p).lower() or "gate" in str(p).lower()]
     assert router, [str(p) for p, _ in flat]
     assert any(float(jnp.abs(g).max()) > 0 for g in router)
+
+
+@pytest.mark.parametrize("activation", ["silu", "relu"])
+def test_the_held_experts_gate_is_the_one_named(activation):
+    """``ops/moe.held_experts_ffn(activation=)``: SiLU (the default) or
+    ReLU gates the up-projection, output and gradients the dense sum's;
+    a name that is no gate is refused."""
+    from distributeddeeplearning_tpu.ops import moe
+
+    key = jax.random.PRNGKey(0)
+    x = jax.random.normal(key, (64, 32))
+    router, w1, w3 = (
+        jax.random.normal(jax.random.fold_in(key, i), shape)
+        for i, shape in enumerate([(32, 4), (4, 32, 16), (4, 32, 16)], 1)
+    )
+    w2 = jax.random.normal(jax.random.fold_in(key, 4), (4, 16, 32))
+    act = {"silu": jax.nn.silu, "relu": jax.nn.relu}[activation]
+
+    def mine(x, w1, w3, w2):
+        routed = moe.route_top_k(x @ router, 2)
+        kw = {} if activation == "silu" else {"activation": activation}
+        return moe.held_experts_ffn(
+            x, routed, w1, w3, w2, first=0, num_experts=4, **kw)[0]
+
+    def dense(x, w1, w3, w2):
+        routed = moe.route_top_k(x @ router, 2)
+        y = 0.0
+        for e in range(4):
+            gate = jnp.sum(jnp.where(routed.experts == e, routed.gates, 0.0), -1)
+            y = y + gate[:, None] * ((act(x @ w1[e]) * (x @ w3[e])) @ w2[e])
+        return y
+
+    assert float(jnp.max(jnp.abs(mine(x, w1, w3, w2) - dense(x, w1, w3, w2)))) < 1e-3
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(mine(*a))), (0, 1, 2, 3))(x, w1, w3, w2)
+    want = jax.grad(lambda *a: jnp.sum(jnp.sin(dense(*a))), (0, 1, 2, 3))(x, w1, w3, w2)
+    for a, b in zip(got, want):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-3 * float(jnp.max(jnp.abs(b)) + 1.0)
+    other = {"silu": "relu", "relu": "silu"}[activation]
+    routed = moe.route_top_k(x @ router, 2)
+    swapped = moe.held_experts_ffn(
+        x, routed, w1, w3, w2, first=0, num_experts=4, activation=other)[0]
+    assert float(jnp.max(jnp.abs(swapped - dense(x, w1, w3, w2)))) > 1e-2
+    with pytest.raises(KeyError):
+        moe.held_experts_ffn(x, routed, w1, w3, w2, first=0, num_experts=4,
+                             activation="gelu")

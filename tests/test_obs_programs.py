@@ -582,6 +582,46 @@ def test_the_flash_backward_is_reported_beside_the_attentions(tmp_path, capsys):
     assert "attention backward, as chosen at trace time: fused x1 at [1, 64, 128]" in out
 
 
+def test_a_mixed_layer_decoder_s_counters_ride_the_report(tmp_path, capsys):
+    """What PR 31 counts at trace time — the mask a layer named, the
+    window's walk, the layers built by kind, the router's input, the
+    experts' gate — printed by ``make trace-report`` beside the rest."""
+    from distributeddeeplearning_tpu.models import get_model
+
+    run = tmp_path / "run"
+    obs.configure(str(run), install_handlers=False)
+    try:
+        model = get_model(
+            "smallthinker_tiny", num_classes=64, dtype="float32",
+            attn_impl="pallas", layers=4,
+        )
+        tokens = jnp.zeros((1, 32), jnp.int32)
+        params = model.init(jax.random.PRNGKey(0), tokens, train=False)["params"]
+        jax.clear_caches()
+        obs.get_bus().ring.clear()
+        jax.jit(jax.grad(
+            lambda p: jnp.sum(model.apply({"params": p}, tokens, train=True))
+        )).lower(params)
+        events = [e for e in obs.get_bus().ring if e["kind"] == "counter"]
+        obs.flush()
+    finally:
+        obs.reset()
+    report = _trace_report()
+    assert report.chosen_paths(events, "attn.mask.") == "causal x1 at [], window x3 at []"
+    assert report.chosen_paths(events, "decoder.layer.") == "full x1 at [0], window x3 at [8]"
+    walk = report.chosen_paths(events, "attn.window.")
+    assert "blocks x" in walk and "'forward'" in walk and "'backward'" in walk
+    routed = [e for e in events if e["name"] == "moe.route.before_attention"]
+    gated = [e for e in events if e["name"] == "moe.impl.ragged_dot"]
+    assert len(routed) == len(gated) == 4
+    assert {e["labels"]["activation"] for e in gated} == {"relu"}
+    assert report.main([str(run)]) == 0
+    out = capsys.readouterr().out
+    for line in ("attention mask, as chosen", "window walk (pass, steps visited, skipped, window), as chosen",
+                 "decoder layers, as chosen", "expert layer, as chosen"):
+        assert line in out, line
+
+
 @pytest.mark.parametrize("path", [
     "jit(local_step)/jvp(TransformerLM)/head/btd,vd->btv/dot_general",
     "jit(local_step)/jvp(loss)/reduce_max",

@@ -404,3 +404,35 @@ def test_the_steps_accuracy_is_the_argmaxs(name, mesh8):
     step = make_train_step(model, tx, mesh8, cfg, donate_state=False)
     _, metrics = step(replicate_state(state, mesh8), shard_batch((tokens, labels), mesh8))
     assert float(metrics["accuracy"]) == 0.5
+
+
+def test_the_weight_draw_runs_no_forward_for_statistics_nobody_keeps():
+    """A spec-built decoder's expert layers sow their pair counts, which
+    ``model.init`` hands back beside the parameters; the state keeps
+    ``params`` (and ``batch_stats``) alone, so the jitted draw returns
+    those and XLA drops the forward: no product is left in the compiled
+    program, and the parameters are flax's own draw, bit for bit."""
+    import functools
+
+    from distributeddeeplearning_tpu.models import get_model
+    from distributeddeeplearning_tpu.training.train_step import init_kept
+
+    model = get_model("smallthinker_tiny", num_classes=64, dtype="float32", layers=4)
+    x = jnp.zeros((1, 32), jnp.int32)
+    rng = jax.random.PRNGKey(3)
+    whole = jax.jit(lambda r, x: model.init(r, x, train=False))
+    assert "stats" in whole(rng, x)
+    assert " dot(" in whole.lower(rng, x).compile().as_text()
+    kept = jax.jit(functools.partial(init_kept, model))
+    assert " dot(" not in kept.lower(rng, x).compile().as_text()
+    variables = kept(rng, x)
+    assert set(variables) == {"params"}
+    for a, b in zip(jax.tree.leaves(variables["params"]),
+                    jax.tree.leaves(whole(rng, x)["params"])):
+        assert a.dtype == b.dtype and bool(jnp.all(a == b))
+    cfg = TrainConfig(model="smallthinker_tiny", num_classes=64, optimizer="adamw")
+    state = create_train_state(
+        model, cfg, optax.sgd(0.1), rng=rng, input_shape=(1, 32), input_dtype=jnp.int32
+    )
+    for a, b in zip(jax.tree.leaves(state.params), jax.tree.leaves(variables["params"])):
+        assert bool(jnp.all(a == b))
