@@ -320,6 +320,12 @@ class ExpertMlp(nn.Module):
         # kept only by a caller that asks for "intermediates" (the
         # benchmark's comparison of choices); a train step does not
         self.sow("intermediates", "experts", routed.experts)
+        self.sow(
+            STATS, "moe.rows_live_share",
+            moe_ops.rows_live_share(
+                drawn, b * t * spec.experts_per_token, spec.experts
+            ),
+        )
         drawn = drawn.astype(jnp.float32)
         self.sow(STATS, "moe.pairs_local", jnp.sum(drawn))
         self.sow(

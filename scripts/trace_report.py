@@ -229,6 +229,26 @@ def chosen_paths(events, prefix: str = "attn.impl.") -> str:
     )
 
 
+def step_statistics(events, prefix: str = "moe.") -> str:
+    """The step's own statistics under ``prefix``, one line: what the
+    model's layers sowed and a log sync read back (``frontends/explicit``
+    counts each with the ``epoch`` it was read in and no other label:
+    ``moe.rows_live_share``, ``moe.pairs_local``, ``moe.
+    expert_load_max_over_mean``), each as the mean of its readings, since
+    a share or a ratio does not add up."""
+    read: dict = {}
+    for e in events:
+        name = str(e.get("name", ""))
+        if (
+            e.get("kind") == "counter" and name.startswith(prefix)
+            and set(e.get("labels") or {}) == {"epoch"}
+        ):
+            read.setdefault(name, []).append(float(e.get("value", 0.0)))
+    return ", ".join(
+        f"{name} {sum(xs) / len(xs):.4g} (n={len(xs)})" for name, xs in sorted(read.items())
+    )
+
+
 def find_captures(paths: List[str]) -> List[str]:
     """Directories under ``paths`` that hold an ``.xplane.pb`` at their
     top or below ``plugins/profile``: one per capture."""
@@ -301,6 +321,9 @@ def main(argv=None) -> int:
         paths_chosen = chosen_paths(loaded["events"], prefix)
         if paths_chosen:
             print(f"{what}, as chosen at trace time: " + paths_chosen)
+    statistics = step_statistics(loaded["events"])
+    if statistics:
+        print("expert layer, the step's own statistics (mean of the readings): " + statistics)
     for d in devices:
         print()
         print(render_device(d))

@@ -219,7 +219,12 @@ def test_a_period_of_mixed_layers_reaches_its_kernels_under_their_kinds(one_chip
 
     def objective(params, tokens):
         logits = model.apply({"params": params}, tokens, train=True)
-        return jnp.sum(logits.astype(jnp.float32))
+        # not the plain sum: a cotangent of all ones makes the head's
+        # gradient a broadcast of column sums, and with the row kernel in
+        # the step XLA:TPU's simplifier writes that broadcast with the
+        # wrong dimension and fails its own verifier (PR 32); no loss
+        # hands the head a constant
+        return jnp.sum(jnp.square(logits.astype(jnp.float32)))
 
     tok = jax.ShapeDtypeStruct(tokens.shape, tokens.dtype, sharding=one_chip)
     compiled = jax.jit(jax.grad(objective)).lower(params, tok).compile()
@@ -228,6 +233,7 @@ def test_a_period_of_mixed_layers_reaches_its_kernels_under_their_kinds(one_chip
     assert totals["attn.mask.window"]["count"] == 3 * totals["attn.mask.causal"]["count"]
     assert totals["decoder.layer.window"]["count"] == 3 * totals["decoder.layer.full"]["count"]
     assert totals["moe.route.before_attention"]["count"] == totals["moe.impl.ragged_dot"]["count"]
+    assert totals["moe.rows.impl.kernel"]["count"] == totals["moe.impl.ragged_dot"]["count"]
     assert totals["attn.window.blocks"]["count"] >= 2  # forward and backward, traced once
     scopes = programs.parse_hlo_scopes(compiled.as_text())
     by_kind = programs.kernel_calls_by_group(scopes, ATTN_KIND_GROUPS)
@@ -236,3 +242,67 @@ def test_a_period_of_mixed_layers_reaches_its_kernels_under_their_kinds(one_chip
     assert programs.kernel_calls_by_group(scopes, TRAIN_STEP_GROUPS)["attn_core"] == 12
     assert programs.groups_in(scopes, MOE_GROUPS) == {g for g, _ in MOE_GROUPS}
     assert programs.groups_in(scopes, ATTN_KIND_GROUPS) == {g for g, _ in ATTN_KIND_GROUPS}
+
+
+@pytest.mark.parametrize(
+    "tokens,width,k,experts,held",
+    [(16384, 2560, 6, 64, 8), (16384, 2048, 8, 128, 16)],
+    ids=["smallthinker-t16k", "sdar-bd4k"],
+)
+def test_mosaic_takes_the_row_kernels_and_the_layer_sorts_once(
+    one_chip, monkeypatch, tokens, width, k, experts, held
+):
+    """``ops/moe.held_experts_ffn`` at both expert cells' shapes, the rule
+    answered as on the chip: Mosaic takes ``to_tiles`` and ``rows_by_place``
+    (``ops/pallas/moe_rows.py``) forward and backward (a row of 2,560 as 24
+    pieces of 128 lanes, one of 2,048 as 16); the compiled layer sorts the
+    pairs once (``order``) and nothing else under the dispatch and combine
+    scopes, and scatters nothing there: not rows, not gate cotangents; and
+    the scope table puts the kernels (the products' rows tiled and summed
+    by place, forward; ``d rows`` tiled and summed by place, backward: four
+    for the usual stretch and four in the branch that computes further
+    ones) under ``moe_dispatch``, where the device metric reads them."""
+    import re
+
+    from distributeddeeplearning_tpu.models.decoder import MOE_GROUPS
+    from distributeddeeplearning_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    jax.clear_caches()
+    obs.reset()
+
+    def layer(x, logits, w1, w3, w2):
+        with jax.named_scope(moe.ROUTE):
+            routed = moe.route_top_k(logits, k)
+        y, _ = moe.held_experts_ffn(x, routed, w1, w3, w2, first=0, num_experts=experts)
+        return jnp.sum(y.astype(jnp.float32))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ffn = 768
+    compiled = jax.jit(jax.value_and_grad(layer, (0, 1, 2, 3, 4))).lower(
+        arg((tokens, width), jnp.bfloat16), arg((tokens, experts), jnp.float32),
+        arg((held, width, ffn), jnp.float32), arg((held, width, ffn), jnp.float32),
+        arg((held, ffn, width), jnp.float32),
+    ).compile()
+    totals = obs.get_bus().totals()
+    obs.reset()
+    assert totals["moe.rows.impl.kernel"]["count"] == 1
+    text = compiled.as_text()
+    assert text.count("moe_rows_to_tiles") and text.count("moe_rows_by_place")
+    moved = [
+        line for line in text.splitlines()
+        if re.search(r"op_name=\"[^\"]*(moe_dispatch|moe_combine)", line)
+    ]
+    sorts = [line for line in moved if re.search(r" sort\(", line)]
+    assert len(sorts) == 1 and f"s32[{tokens * k}]" in sorts[0]  # the pairs, once
+    assert not [line for line in moved if re.search(r" scatter\(", line)]
+    scopes = programs.parse_hlo_scopes(text)
+    assert programs.kernel_calls_by_group(scopes, MOE_GROUPS)["moe_dispatch"] == 8
+    kernels = [
+        p for p in scopes.values()
+        if p.endswith("/" + programs.KERNEL_CALL) and "moe_rows" in p
+    ]
+    assert len(kernels) == 8 and sum(programs.BACKWARD in p for p in kernels) == 4

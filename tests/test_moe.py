@@ -304,3 +304,58 @@ def test_the_held_experts_gate_is_the_one_named(activation):
     with pytest.raises(KeyError):
         moe.held_experts_ffn(x, routed, w1, w3, w2, first=0, num_experts=4,
                              activation="gelu")
+
+
+@pytest.mark.parametrize("routing", ["random", "all-alike", "none-held"])
+def test_places_by_counting_are_the_stable_argsort_s(routing):
+    """``ops/moe._places`` counts each pair's place with no sort: the
+    inverse of the order ``jnp.argsort(key, stable=True)`` gives over
+    the pairs of held experts (tokens ascending inside an expert's
+    group), and a place no stretch holds for every other pair."""
+    from distributeddeeplearning_tpu.ops import moe
+
+    tokens, k, experts, first, held = 200, 3, 16, 4, 5
+    if routing == "random":
+        scores = jax.random.normal(jax.random.PRNGKey(2), (tokens, experts))
+        chosen = jax.lax.top_k(scores, k)[1].astype(jnp.int32)
+    elif routing == "all-alike":  # every token draws the same held experts
+        chosen = jnp.broadcast_to(jnp.asarray([6, 4, 15], jnp.int32), (tokens, k))
+    else:
+        chosen = jnp.broadcast_to(jnp.asarray([0, 1, 15], jnp.int32), (tokens, k))
+    key, drawn = moe._held_keys(chosen, first, held)
+    order = np.asarray(jnp.argsort(key, stable=True))
+    place = np.asarray(jax.jit(moe._places)(key, drawn))
+    total = int(drawn.sum())
+    assert np.array_equal(place[order[:total]], np.arange(total))
+    assert np.all(place[order[total:]] == tokens * k)  # not held: no place
+    assert total == {"random": total, "all-alike": 2 * tokens, "none-held": 0}[routing]
+
+
+def test_the_rows_rule_takes_xla_off_the_chip_and_counts_it():
+    """``ops/moe.rows_impl`` sees a backend that is no TPU (or a width
+    that is not whole lanes) and keeps XLA's gather and scatter-add;
+    ``held_experts_ffn`` counts what it took once a traced layer, with
+    the stretch's rows, the width, the tokens and k, and the layer's
+    share of live rows is what the kernels would move."""
+    from distributeddeeplearning_tpu import obs
+    from distributeddeeplearning_tpu.ops import moe
+
+    x = jnp.zeros((64, 256))
+    assert jax.default_backend() != "tpu" and moe.rows_impl(x) == "xla"
+    routed = moe.route_top_k(jax.random.normal(jax.random.PRNGKey(0), (64, 8)), 2)
+    w = jnp.zeros((2, 256, 16))
+    obs.reset()
+    jax.jit(
+        lambda x: moe.held_experts_ffn(
+            x, routed, w, w, w.transpose(0, 2, 1), first=0, num_experts=8
+        )[0]
+    ).lower(x)
+    totals = obs.get_bus().totals()
+    (event,) = [e for e in obs.get_bus().ring if e["name"].startswith("moe.rows.impl.")]
+    obs.reset()
+    assert totals["moe.rows.impl.xla"]["count"] == 1
+    assert event["labels"] == {"rows": 128, "width": 256, "tokens": 64, "k": 2}
+    assert moe.usual_cap(128, 2, 8) == 128
+    assert float(moe.rows_live_share(jnp.asarray([30, 34]), 128, 8)) == 0.5
+    # past the first stretch two are computed
+    assert float(moe.rows_live_share(jnp.asarray([100, 92]), 128, 8)) == 0.75

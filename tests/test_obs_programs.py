@@ -622,6 +622,40 @@ def test_a_mixed_layer_decoder_s_counters_ride_the_report(tmp_path, capsys):
         assert line in out, line
 
 
+def test_the_expert_layer_s_live_share_rides_the_report(tmp_path, capsys):
+    """What a step's expert layers sow and a log sync reads back
+    (``frontends/explicit`` counts each under its name with the epoch):
+    ``make trace-report`` prints the mean of the readings, since a share
+    does not add up; the counters of trace time keep their own line, and
+    there ``moe.rows.impl.<path>`` says what moved the rows."""
+    from distributeddeeplearning_tpu.ops import moe
+
+    run = tmp_path / "run"
+    obs.configure(str(run), install_handlers=False)
+    try:
+        routed = moe.route_top_k(jax.random.normal(jax.random.PRNGKey(0), (64, 8)), 2)
+        w = jnp.zeros((2, 128, 16))
+        jax.jit(lambda x: moe.held_experts_ffn(
+            x, routed, w, w, w.transpose(0, 2, 1), first=0, num_experts=8)[0]
+        ).lower(jnp.zeros((64, 128)))
+        for share in (0.5, 0.25):
+            obs.counter("moe.rows_live_share", share, epoch=0)
+        obs.counter("moe.pairs_local", 12288.0, epoch=0)
+        events = [e for e in obs.get_bus().ring if e["kind"] == "counter"]
+        obs.flush()
+    finally:
+        obs.reset()
+    report = _trace_report()
+    assert report.step_statistics(events) == (
+        "moe.pairs_local 1.229e+04 (n=1), moe.rows_live_share 0.375 (n=2)"
+    )
+    assert "rows.impl.xla x1" in report.chosen_paths(events, "moe.")
+    assert report.main([str(run)]) == 0
+    out = capsys.readouterr().out
+    assert "moe.rows_live_share 0.375 (n=2)" in out
+    assert "expert layer, as chosen at trace time" in out and "rows.impl.xla" in out
+
+
 @pytest.mark.parametrize("path", [
     "jit(local_step)/jvp(TransformerLM)/head/btd,vd->btv/dot_general",
     "jit(local_step)/jvp(loss)/reduce_max",
