@@ -14,7 +14,8 @@ A path that holds a profiler capture (an ``.xplane.pb``, as
 also gets the device's view: device seconds by scope group for each
 program compiled ahead (``obs/programs.py``; the scope tables lie
 beside the capture, the groups beside the model:
-``models/transformer_lm.TRAIN_STEP_GROUPS``), and
+``models/transformer_lm.TRAIN_STEP_GROUPS``, or ``models/decoder.
+HYBRID_STEP_GROUPS`` for a step with state-space layers), and
 the device's idle gaps by the innermost ``ddl:`` span — the bus's own
 spans on the profiler's clock — over each gap's middle.
 
@@ -156,6 +157,24 @@ def device_report(capture_dir: str, groups, profile=None, tables=None) -> dict:
     return out
 
 
+def step_groups(capture_dir: str, tables=None):
+    """The groups that stand beside the model whose step a capture
+    holds: ``models/decoder.HYBRID_STEP_GROUPS`` where a program's table
+    has a state-space mixer's scope, else ``models/transformer_lm.
+    TRAIN_STEP_GROUPS`` (a table is read by the groups it has all of)."""
+    from distributeddeeplearning_tpu.models.decoder import HYBRID_STEP_GROUPS
+    from distributeddeeplearning_tpu.models.transformer_lm import TRAIN_STEP_GROUPS
+    from distributeddeeplearning_tpu.obs import programs
+
+    if tables is None:
+        tables = programs.load_tables(capture_dir)
+    mixer = HYBRID_STEP_GROUPS[0][0]
+    if any(mixer in programs.groups_in(scopes, HYBRID_STEP_GROUPS[:1])
+           for scopes in tables.values()):
+        return HYBRID_STEP_GROUPS
+    return TRAIN_STEP_GROUPS
+
+
 def render_device(rep: dict) -> str:
     out: List[str] = [f"device trace: {rep['capture']}"]
     add = out.append
@@ -210,7 +229,8 @@ def chosen_paths(events, prefix: str = "attn.impl.") -> str:
     walk visits and skips, ``ops/pallas/flash.py``), ``loss.impl.
     <path>`` (the loss, ``training/train_step.loss_and_hits``),
     ``decoder.layer.<kind>`` and ``moe.*`` (the layers a spec-built
-    decoder built, ``models/decoder.py``). A counter with no ``shape``
+    decoder built, ``models/decoder.py``), ``ssm.impl.<path>`` (a
+    state-space layer's scan, ``ops/ssm.py``). A counter with no ``shape``
     label is keyed by ``visited``/``skipped`` (the window's walk) or
     ``window`` where it has them."""
     chosen: dict = {}
@@ -278,12 +298,7 @@ def main(argv=None) -> int:
     devices = []
     captures = find_captures(args.paths)
     if captures:
-        # the groups stand beside the model whose step the captures hold
-        from distributeddeeplearning_tpu.models.transformer_lm import (
-            TRAIN_STEP_GROUPS,
-        )
-
-        devices = [device_report(c, TRAIN_STEP_GROUPS) for c in captures]
+        devices = [device_report(c, step_groups(c)) for c in captures]
     try:
         loaded = report.load(args.paths)
     except FileNotFoundError as e:
@@ -316,7 +331,7 @@ def main(argv=None) -> int:
         ("attention backward", "attn.bwd."),
         ("window walk (pass, steps visited, skipped, window)", "attn.window."),
         ("loss", "loss.impl."), ("decoder layers", "decoder.layer."),
-        ("expert layer", "moe."),
+        ("state-space scan", "ssm.impl."), ("expert layer", "moe."),
     ):
         paths_chosen = chosen_paths(loaded["events"], prefix)
         if paths_chosen:

@@ -365,6 +365,176 @@ def test_the_specs_that_were_there_build_and_compute_what_they_did():
         ) == was[name], name
 
 
+# -- state-space layers beside an attention layer -----------------------------
+
+def _granite_config(**over):
+    """``granite_tiny`` as the ``granite`` reference reads it."""
+    cfg = {
+        "hidden_size": 64, "layers": 5, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "shared_intermediate_size": 96,
+        "vocab_size": VOCAB, "rms_norm_eps": 1e-5,
+        "layer_types": ["mamba", "mamba", "attention", "mamba", "mamba"],
+        "mamba_n_heads": 4, "mamba_d_head": 16, "mamba_d_state": 16,
+        "mamba_n_groups": 1, "mamba_d_conv": 4, "mamba_chunk_size": 8,
+        "embedding_multiplier": 12, "attention_multiplier": 0.0625,
+        "residual_multiplier": 0.22, "logits_scaling": 8,
+        "published": {"layers": 5},
+    }
+    cfg.update(over)
+    return cfg
+
+
+def _granite_params(cfg, seed):
+    """The family's seeded weights, with ``D`` drawn too (its published
+    initialisation is 1: a side that dropped it would not show)."""
+    from benchmarks.references import granite as gr
+
+    params = gr.init_params(cfg, seed)
+    for i, kind in enumerate(cfg["layer_types"][:cfg["layers"]]):
+        if kind == "mamba":
+            key = jax.random.fold_in(jax.random.PRNGKey(seed), i)
+            params[f"block{i}"]["ssm"]["D"] = 1.0 + 0.3 * jax.random.normal(key, (4,))
+    return params
+
+
+GRANITE_L = 27  # chunk 8: three chunks and a ragged fourth
+
+
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
+def test_the_state_space_model_matches_its_reference(attn_impl):
+    """``granite_tiny`` (two Mamba-2 layers, an attention layer without
+    positions whose scores take the spec's scale, two more Mamba-2
+    layers; a dense gated MLP; the four multipliers; tied head) against
+    ``references/granite.py``, whose state-space layer is the token-by-
+    token recurrence, on seeded weights at L = 27: logits, loss, every
+    leaf's gradient. float32 on both sides: 1e-5 of the largest logit,
+    1e-4 of a leaf's largest gradient entry."""
+    from benchmarks.references import granite as gr
+
+    cfg = _granite_config()
+    params = _granite_params(cfg, 11)
+    rows = np.random.default_rng(5).integers(0, VOCAB, (3, GRANITE_L), dtype=np.int32)
+    tokens, labels = jnp.asarray(rows), jnp.asarray(np.roll(rows, -1, axis=1))
+    model = get_model(
+        "granite_tiny", num_classes=VOCAB, dtype="float32", attn_impl=attn_impl,
+        max_seq_len=32,
+    )
+
+    def loss(params):
+        logits = model.apply({"params": params}, tokens, train=True)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+        return jnp.mean(logz - picked), logits
+
+    (l, logits), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+    want = jax.jit(lambda p, t: gr.forward(p, t, cfg))(params, tokens)
+    assert logits.shape == (3, GRANITE_L, VOCAB) and gap(logits, want) < 1e-5
+    lr, gr_ = jax.jit(jax.value_and_grad(
+        lambda p: gr.token_loss(p, tokens, labels, cfg)
+    ))(params)
+    assert abs(float(l) - float(lr)) < 1e-6 * abs(float(lr))
+    flat_got, flat_want = gr.flatten(grads), gr.flatten(gr_)
+    assert set(flat_got) == set(flat_want)
+    for k in flat_want:
+        assert gap(flat_got[k], flat_want[k]) < 1e-4, k
+    # the tree is the one the family's shapes state, and block remat's is the same
+    assert {k: v.shape for k, v in flat_got.items()} == gr.param_shapes(cfg)
+
+
+@pytest.mark.parametrize(
+    "variant", ["no_decay", "no_convolution", "gate_after_the_norm", "residual_one"]
+)
+def test_the_reference_sees_each_mechanism_of_the_state_space_layer(variant):
+    """A reference without one of the four (the state's decay, the
+    convolution, the gate before the norm, the residual multiplier) is
+    another model: its logits leave the program's by more than ten times
+    the 1e-5 the sound one keeps."""
+    from benchmarks.references import granite as gr
+
+    cfg = _granite_config()
+    params = _granite_params(cfg, 11)
+    tokens = jnp.asarray(
+        np.random.default_rng(5).integers(0, VOCAB, (2, GRANITE_L), dtype=np.int32)
+    )
+    model = get_model(
+        "granite_tiny", num_classes=VOCAB, dtype="float32", attn_impl="xla",
+        max_seq_len=32,
+    )
+    logits = jax.jit(lambda p: model.apply({"params": p}, tokens, train=False))(params)
+    other = {
+        "no_decay": {"without": ["decay"]},
+        "no_convolution": {"without": ["conv"]},
+        "gate_after_the_norm": {"without": ["gate_first"]},
+        "residual_one": {"residual_multiplier": 1.0},
+    }[variant]
+    reference = lambda c: jax.jit(lambda p: gr.forward(p, tokens, c))(params)  # noqa: E731
+    assert gap(logits, reference(cfg)) < 1e-5
+    assert gap(logits, reference(_granite_config(**other))) > 1e-4
+
+
+def test_the_published_state_space_spec_against_the_catalog_s_numbers():
+    """``granite_4_0_h_micro`` is the catalog row's ``config``, number
+    for number, and at the cell's cut it builds the parameters ISSUE 33
+    counts: 76,182,976 a Mamba-2 layer, 60,821,504 the attention layer,
+    772,160,448 in ten layers and an eighth of the vocabulary."""
+    from distributeddeeplearning_tpu.models import decoder
+
+    spec = decoder.SPECS["granite_4_0_h_micro"]
+    assert (spec.hidden, spec.layers, spec.heads, spec.kv_heads, spec.head_dim) == (
+        2048, 40, 32, 8, 2048 // 32)
+    assert spec.ssm_heads * spec.ssm_head_dim == 2 * spec.hidden  # mamba_expand
+    assert (spec.ssm_state, spec.ssm_groups, spec.ssm_conv, spec.ssm_chunk) == (128, 1, 4, 256)
+    assert [i for i in range(40) if spec.kind(i).mixer == "attention"] == [5, 15, 25, 35]
+    assert (spec.embed_scale, spec.attn_scale, spec.residual_scale, spec.logits_scale) == (
+        12.0, 0.015625, 0.22, 8.0)
+    assert spec.norm_eps == 1e-5 and spec.tied_head and not spec.bias and spec.ffn == "glu"
+    model = get_model("granite_4_0_h_micro", layers=10, num_classes=12544)
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32), train=False)
+    )["params"]
+    count = lambda tree: sum(int(np.prod(v.shape)) for v in jax.tree.leaves(tree))  # noqa: E731
+    assert count(shapes["block0"]) == 76_182_976
+    assert count(shapes["block5"]) == 60_821_504
+    assert count(shapes) == 772_160_448
+    assert set(shapes["block0"]["ssm"]) == {
+        "in_proj", "conv", "dt_bias", "A_log", "D", "norm", "out_proj"}
+    assert shapes["block0"]["ssm"]["in_proj"]["kernel"].shape == (2048, 8512)
+    assert shapes["block0"]["ssm"]["conv"]["kernel"].shape == (4352, 4)
+    assert "attn" in shapes["block5"] and "ssm" not in shapes["block5"]
+
+
+@pytest.mark.parametrize("fault", ["block_len", "window", "rope", "heads", "mixer"])
+def test_a_state_space_spec_that_cannot_be_built_is_refused(fault):
+    from distributeddeeplearning_tpu.models import decoder
+
+    spec = decoder.SPECS["granite_tiny"]
+    bad = {
+        "block_len": dict(block_len=4),
+        "window": dict(pattern=(decoder.LayerKind(8, False, "mamba2"),)),
+        "rope": dict(pattern=(decoder.LayerKind(0, True, "mamba2"),)),
+        "heads": dict(ssm_groups=3),
+        "mixer": dict(pattern=(decoder.LayerKind(0, False, "retention"),)),
+    }[fault]
+    model = decoder.SpecDecoder(
+        dataclasses.replace(spec, **bad), vocab_size=VOCAB, dtype=jnp.float32,
+        attn_impl="xla",
+    )
+    with pytest.raises(ValueError):
+        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32), train=False)
+
+
+def test_the_model_s_own_draw_is_the_mixers_published_initialisation():
+    model = get_model("granite_tiny", num_classes=VOCAB, dtype="float32", attn_impl="xla")
+    p = model.init(jax.random.PRNGKey(2), jnp.zeros((1, 16), jnp.int32), train=False)["params"]
+    ssm = p["block0"]["ssm"]
+    assert bool(jnp.all(ssm["D"] == 1.0))
+    a = jnp.exp(ssm["A_log"])
+    assert bool(jnp.all((a >= 1.0) & (a <= 16.0)))
+    step = jax.nn.softplus(ssm["dt_bias"])
+    assert bool(jnp.all((step >= 1e-3 * 0.999) & (step <= 1e-1 * 1.001)))
+    assert float(jnp.max(jnp.abs(ssm["conv"]["kernel"]))) <= 0.5
+
+
 def test_the_block_diffusion_mask_takes_no_window():
     model = get_model(
         "smallthinker_tiny", num_classes=VOCAB, dtype="float32", block_len=4

@@ -306,3 +306,89 @@ def test_mosaic_takes_the_row_kernels_and_the_layer_sorts_once(
         if p.endswith("/" + programs.KERNEL_CALL) and "moe_rows" in p
     ]
     assert len(kernels) == 8 and sum(programs.BACKWARD in p for p in kernels) == 4
+
+
+def test_the_state_space_cell_s_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
+    """``granite-4.0-h-micro-train-t4k``'s whole train step (ten layers
+    at the published widths, 2 rows of 4,096 tokens, AdamW, block remat;
+    ``make_train_step`` as ``explicit.setup`` builds it) for the
+    described v5e: ISSUE 33's ladder, by the compiler. The state (float32
+    weights and two Adam moments, 8.63 GiB) and the step's temporaries
+    stay under the chip's 15.75 GiB with the 2.88 GiB copy of the first
+    parameters that ``benchmarks/runners/train.py`` holds through its
+    checked steps: rows 2 are taken. Nine scans and nine convolutions
+    under their scopes, the attention layer's flash kernels under
+    ``attn_full`` with the spec's scale, every group of both tables."""
+    import json
+    import os
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from distributeddeeplearning_tpu.config import TrainConfig
+    from distributeddeeplearning_tpu.models.decoder import HYBRID_STEP_GROUPS, SSM_GROUPS
+    from distributeddeeplearning_tpu.training.optimizer import create_optimizer
+    from distributeddeeplearning_tpu.training.train_step import (
+        create_train_state,
+        make_train_step,
+    )
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "traffic", "t4k.json")) as fh:
+        job = json.load(fh)
+    with open(os.path.join(root, "benchmarks", "configs", "granite-4.0-h-micro.json")) as fh:
+        config = json.load(fh)
+    rows, seq = job["batch_per_chip"], job["seq_len"]
+    assert (rows, seq, job["remat"]) == (2, 4096, True)
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("data",))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    jax.clear_caches()
+    obs.reset()
+    opt = job["optimizer"]
+    cfg = TrainConfig(
+        model=config["program"]["model"], num_classes=config["vocab_size"],
+        compute_dtype="bfloat16", batch_size_per_device=rows, remat=True,
+        optimizer=opt["name"], base_lr=opt["learning_rate"],
+        adam_beta1=opt["adam_beta1"], adam_beta2=opt["adam_beta2"],
+        adam_eps=opt["adam_eps"], decoupled_weight_decay=opt["decoupled_weight_decay"],
+        weight_decay=0.0, label_smoothing=0.0, warmup_epochs=0,
+        lr_schedule="constant", scale_lr_by_world_size=False, fake=True, epochs=1,
+    )
+    model = get_model(cfg.model, **cfg.model_kwargs(), layers=config["layers"])
+    tx, _ = create_optimizer(cfg, 1000, world_size=1)
+    state = jax.eval_shape(lambda: create_train_state(
+        model, cfg, tx, input_shape=(1, seq), input_dtype=jnp.int32
+    ))
+    held = NamedSharding(mesh, P())
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=held), state
+    )
+    batch = (jax.ShapeDtypeStruct(
+        (rows, seq), jnp.int32, sharding=NamedSharding(mesh, P("data"))
+    ),) * 2
+    step = make_train_step(model, tx, mesh, cfg)
+    compiled = step._resolve(state, False).lower(state, batch).compile()
+    totals = obs.get_bus().totals()
+    obs.reset()
+    # the step and block remat's second forward each trace a layer
+    assert totals["decoder.layer.mamba2"]["count"] == 9 * totals["decoder.layer.full"]["count"]
+    assert totals["ssm.impl.xla"]["count"] == totals["decoder.layer.mamba2"]["count"]
+    assert totals["attn.impl.pallas"]["count"] >= 1 and "attn.impl.xla" in totals  # init: einsum
+    memory = compiled.memory_analysis()
+    gib = 2.0 ** 30
+    weights = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(state.params))
+    assert weights == 772_160_448
+    resident = memory.argument_size_in_bytes / gib
+    assert resident == pytest.approx(12 * weights / gib, rel=0.001)  # 8.63 GiB
+    assert memory.alias_size_in_bytes == pytest.approx(memory.argument_size_in_bytes, rel=0.001)
+    temporaries = memory.temp_size_in_bytes / gib
+    copy = 4 * weights / gib  # runners/train.py's theta0
+    assert temporaries < 2.5, temporaries  # 1.70 when this was written
+    assert resident + temporaries + copy < 15.75
+    scopes = programs.parse_hlo_scopes(compiled.as_text())
+    assert programs.groups_in(scopes, HYBRID_STEP_GROUPS) == {g for g, _ in HYBRID_STEP_GROUPS}
+    assert programs.groups_in(scopes, SSM_GROUPS) == {g for g, _ in SSM_GROUPS}
+    # the attention layer: forward, block remat's forward, one fused backward
+    assert programs.kernel_calls_by_group(scopes, HYBRID_STEP_GROUPS)["attn_core"] == 3

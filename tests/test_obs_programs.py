@@ -705,3 +705,97 @@ def test_kernel_calls_by_group_counts_the_mosaic_custom_calls_alone():
     }
     assert programs.group_of(scopes["_attention_core.6"], TRAIN_STEP_GROUPS) == "attn_core"
     assert programs.BACKWARD in scopes["_attention_core.6"]
+
+
+# -- a state-space mixer's parts (models/decoder.py, ops/ssm.py) ----------------
+
+SSM_HLO = """HloModule jit_local_step
+
+ENTRY %main () -> f32[4] {
+  %fusion.1 = f32[4]{0} fusion(%a), metadata={op_name="jit(local_step)/jvp(SpecDecoder)/block0/ssm/in_proj/dot_general"}
+  %fusion.2 = f32[4]{0} fusion(%a), metadata={op_name="jit(local_step)/jvp(SpecDecoder)/block0/ssm/ssm_conv/add"}
+  %fusion.3 = f32[4]{0} fusion(%a), metadata={op_name="jit(local_step)/transpose(jvp(SpecDecoder))/block0/ssm/ssm_scan/while/body/checkpoint/dot_general"}
+  %fusion.4 = f32[4]{0} fusion(%a), metadata={op_name="jit(local_step)/jvp(SpecDecoder)/block0/mlp/w_in/dot_general"}
+  %fusion.5 = f32[4]{0} fusion(%a), metadata={op_name="jit(local_step)/jvp(SpecDecoder)/block5/attn/attn_core/attn_full/dot_general"}
+  ROOT %fusion.6 = f32[4]{0} fusion(%a), metadata={op_name="jit(local_step)/optimizer/mul"}
+}
+"""
+
+
+def test_the_state_space_mixer_is_a_group_of_its_own_table_and_splits_in_three():
+    """``HYBRID_STEP_GROUPS`` puts module ``ssm`` before the groups every
+    decoder has (which then read what they read); ``SSM_GROUPS`` splits
+    it by its two scopes and what is left of the module. Under
+    ``TRAIN_STEP_GROUPS`` alone the mixer would be unscoped: a new part
+    of the model gets a name, not a wider pattern."""
+    from distributeddeeplearning_tpu.models.decoder import (
+        HYBRID_STEP_GROUPS,
+        SSM_GROUPS,
+    )
+
+    assert HYBRID_STEP_GROUPS[0][0] == "ssm" and HYBRID_STEP_GROUPS[1:] == tuple(TRAIN_STEP_GROUPS)
+    scopes = programs.parse_hlo_scopes(SSM_HLO)
+    step = lambda path: programs.group_of(path, HYBRID_STEP_GROUPS)  # noqa: E731
+    assert [step(scopes[f"fusion.{i}"]) for i in range(1, 7)] == [
+        "ssm", "ssm", "ssm", "mlp", "attn_core", "optimizer"]
+    part = lambda path: programs.group_of(path, SSM_GROUPS)  # noqa: E731
+    assert [part(scopes[f"fusion.{i}"]) for i in range(1, 7)] == [
+        "ssm_proj", "ssm_conv", "ssm_scan", "unscoped", "unscoped", "unscoped"]
+    assert programs.group_of(scopes["fusion.1"], TRAIN_STEP_GROUPS) == "unscoped"
+    assert programs.groups_in(scopes, SSM_GROUPS) == {g for g, _ in SSM_GROUPS}
+    ops = [(f"fusion.{i}", (i - 1) * MS, i * MS) for i in range(1, 7)]
+    by = programs.device_seconds_by_scope(ops, scopes, SSM_GROUPS)
+    assert by["groups"]["ssm_scan"]["seconds"] == pytest.approx(1e-3)
+    assert by["groups"]["ssm_scan"]["backward_s"] == pytest.approx(1e-3)
+    assert by["unscoped_s"] == pytest.approx(3e-3)
+
+
+def test_a_program_without_the_mixer_reads_nothing_under_its_tables():
+    """The steps that were there have no ``ssm`` scope: their tables
+    lack the group, so a reader that asks for a table's every group
+    (``benchmarks/programs/obs.py``) reports nothing, and the operator's
+    report falls back to the groups they have."""
+    from distributeddeeplearning_tpu.models.decoder import (
+        HYBRID_STEP_GROUPS,
+        SSM_GROUPS,
+    )
+
+    scopes = programs.parse_hlo_scopes(HLO)
+    assert programs.groups_in(scopes, SSM_GROUPS) == set()
+    assert "ssm" not in programs.groups_in(scopes, HYBRID_STEP_GROUPS)
+    report = _trace_report()
+    assert report.step_groups("capture", {"jit_local_step": scopes}) is TRAIN_STEP_GROUPS
+    hybrid = {"jit_local_step": programs.parse_hlo_scopes(SSM_HLO)}
+    assert report.step_groups("capture", hybrid) is HYBRID_STEP_GROUPS
+
+
+def test_the_scan_s_choice_and_the_layers_built_are_reported():
+    """``ops/ssm.resolve_impl`` counts what it chose once a traced layer
+    (``ssm.impl.xla`` with the call's shape, chunk and padding) and
+    ``SpecDecoder`` each state-space layer it builds (``decoder.layer.
+    mamba2``): ``bus.totals()`` holds both and ``make trace-report``
+    prints them beside the attention's and the expert layer's."""
+    import jax.numpy as jnp
+
+    from distributeddeeplearning_tpu.models import get_model
+
+    obs.reset()
+    model = get_model("granite_tiny", num_classes=64, dtype="float32", attn_impl="xla")
+    jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((2, 27), jnp.int32), train=False)
+    )
+    totals = obs.get_bus().totals()
+    events = list(obs.get_bus().ring)
+    obs.reset()
+    assert totals["decoder.layer.mamba2"]["count"] == 4
+    assert totals["decoder.layer.full"]["count"] == 1
+    assert totals["ssm.impl.xla"]["count"] == 4
+    scan = next(e for e in events if e["name"] == "ssm.impl.xla")
+    assert scan["labels"] == {
+        "shape": [2, 27, 4, 16], "heads": 4, "head_dim": 16, "state": 16,
+        "chunk": 8, "chunks": 4, "padded": 5}
+    layer = next(e for e in events if e["name"] == "decoder.layer.mamba2")
+    assert layer["labels"] == {"layer": 0, "heads": 4, "state": 16, "chunk": 8}
+    report = _trace_report()
+    assert report.chosen_paths(events, "ssm.impl.") == "xla x4 at [2, 27, 4, 16]"
+    assert "mamba2 x4" in report.chosen_paths(events, "decoder.layer.")
