@@ -18,9 +18,11 @@ joins the two:
 * :func:`device_seconds_by_scope` — the reduction, over plain ``(name,
   start_ns, end_ns)`` tuples so that it can be checked on hand-made
   events: device seconds by scope group (a group is a regular
-  expression over the path), forward against backward, and the seconds
-  that fell to no group. :func:`program_by_scope` applies it to the
-  runs of one program in a capture. The groups are the caller's: they
+  expression over the path) and by pass (:func:`pass_of`: the forward,
+  the forward that ``jax.checkpoint`` runs again inside the backward,
+  the backward, and what differentiation never touched), and the
+  seconds that fell to no group. :func:`program_by_scope` applies it to
+  the runs of one program in a capture. The groups are the caller's: they
   name a model's modules and a step's scopes, so they stand beside the
   model (``models/transformer_lm.TRAIN_STEP_GROUPS``), and this module
   knows no model.
@@ -33,7 +35,8 @@ joins the two:
 **A fusion has one ``op_name``: its root's.** XLA fuses across scope
 boundaries (a LayerNorm's last multiply into the matrix product that
 reads it), and the fused instruction carries the metadata of its root
-alone, so a group's seconds are those of the fusions *rooted* in it.
+alone, so a group's seconds are those of the fusions *rooted* in it,
+and a pass's likewise (:func:`pass_of`).
 The check that this is good enough is the reduction's own: the
 ``unscoped`` share, and the groups' sum against the program's time.
 """
@@ -59,6 +62,12 @@ _INSTRUCTION = re.compile(
     r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?\bop_name="((?:[^"\\]|\\.)*)"'
 )
 BACKWARD = "transpose(jvp("
+FORWARD = "jvp("
+# the path component under which `jax.checkpoint` (flax's `nn.remat`)
+# replays a block's forward inside the transposed computation
+RECOMPUTE = "rematted_computation"
+_RECOMPUTED = re.compile(rf"(?:^|/){RECOMPUTE}(?:/|$)")
+PASSES = ("forward", "recompute", "backward", "other")
 UNSCOPED = "unscoped"
 # A Pallas kernel is one custom call of this target on the TPU; the
 # parser hangs the target on the instruction's path as its last part.
@@ -286,6 +295,37 @@ def group_of(path: Optional[str], groups: Sequence[Tuple[str, Any]]) -> str:
     return UNSCOPED
 
 
+def pass_of(path: Optional[str]) -> str:
+    """The pass of a step that an instruction's ``op_name`` path puts it
+    in, one of :data:`PASSES`, by the marks JAX's transforms leave:
+
+    * ``recompute`` where a component of the path is
+      ``rematted_computation``: a forward that ``jax.checkpoint`` runs
+      again inside the backward (``.../transpose(jvp(M))/checkpoint/
+      rematted_computation/block0/mlp/...``). The mark counts at any
+      depth, and before the others: a checkpointed ``scan`` body
+      replayed inside a replayed block, or inside the backward's own
+      loop, is recomputed work wherever it stands.
+    * ``backward`` where the path holds ``transpose(jvp(`` and no such
+      component.
+    * ``forward`` where it holds ``jvp(`` alone.
+    * ``other`` for the rest: the optimizer, the metrics, copies the
+      compiler put in with no path, and what it named anew, path and
+      all (XLA:TPU's ``ragged-dot-*`` calls, whichever pass asked for
+      them); the whole of a program that takes no gradient.
+
+    A fusion counts for the pass of its root, as it does for its root's
+    group (module docstring): a recomputed multiply fused into a
+    backward product reads as backward."""
+    if not path:
+        return "other"
+    if _RECOMPUTED.search(path):
+        return "recompute"
+    if BACKWARD in path:
+        return "backward"
+    return "forward" if FORWARD in path else "other"
+
+
 def groups_in(scopes: Dict[str, str], groups: Groups) -> Set[str]:
     """The groups that at least one instruction of a table falls in. A
     table that lacks a group its program must have was not compiled from
@@ -298,19 +338,43 @@ def groups_in(scopes: Dict[str, str], groups: Groups) -> Set[str]:
     return found - {UNSCOPED}
 
 
-def kernel_calls_by_group(scopes: Dict[str, str], groups: Groups) -> Dict[str, int]:
+def kernel_calls_by_pass(scopes: Dict[str, str], groups: Groups) -> Dict[str, Dict[str, int]]:
     """How many Mosaic kernels (``tpu_custom_call`` instructions) a
-    table holds under each group, ``unscoped`` for those in none:
-    whether a model part runs the kernel it was given. A GPT-2 step on
-    the flash kernels holds 24 under ``attn_core``, a forward and a
-    backward kernel a layer."""
+    table holds under each group, ``unscoped`` for those in none, by
+    the pass each stands in (:func:`pass_of`; every pass is there, 0
+    where it holds none): whether a model part runs the kernel it was
+    given, and whether block remat runs it again. A GPT-2 step on the
+    flash kernels holds 12 forward and 12 backward under ``attn_core``;
+    a block-diffusion layer's three passes under block remat, which
+    keeps nothing of them, 18 forward, 18 recompute and 18 backward
+    over six layers."""
     compiled = [(name, re.compile(p)) for name, p in groups]
-    out: Dict[str, int] = {}
+    out: Dict[str, Dict[str, int]] = {}
     for path in scopes.values():
         if path.rsplit("/", 1)[-1] == KERNEL_CALL:
-            group = group_of(path, compiled)
-            out[group] = out.get(group, 0) + 1
+            by_pass = out.setdefault(group_of(path, compiled), dict.fromkeys(PASSES, 0))
+            by_pass[pass_of(path)] += 1
     return out
+
+
+def kernel_calls_by_group(scopes: Dict[str, str], groups: Groups) -> Dict[str, int]:
+    """:func:`kernel_calls_by_pass` with the passes added up: a GPT-2
+    step on the flash kernels holds 24 under ``attn_core``."""
+    return {
+        group: sum(by_pass.values())
+        for group, by_pass in kernel_calls_by_pass(scopes, groups).items()
+    }
+
+
+def _empty_reduction(groups: Groups) -> Dict[str, Any]:
+    return {
+        "groups": {
+            name: {"seconds": 0.0, "forward_s": 0.0, "recompute_s": 0.0, "backward_s": 0.0}
+            for name, _ in groups
+        },
+        "by_pass": dict.fromkeys(PASSES, 0.0),
+        "unscoped_s": 0.0, "total_s": 0.0, "unscoped_top": [],
+    }
 
 
 def device_seconds_by_scope(
@@ -319,48 +383,52 @@ def device_seconds_by_scope(
     groups: Groups,
     window: Tuple[Optional[int], Optional[int]] = (None, None),
 ) -> Dict[str, Any]:
-    """Device seconds of one device line's ``events`` by scope group.
+    """Device seconds of one device line's ``events`` by scope group
+    and by pass.
 
     ``scopes`` is a :class:`ScopeTable`'s mapping; an event whose
     instruction is not in it, or whose path matches no group, counts as
-    unscoped. Returns ``{"groups": {name: {"seconds", "backward_s"}},
+    unscoped. Returns ``{"groups": {name: {"seconds", "forward_s",
+    "recompute_s", "backward_s"}}, "by_pass": {pass: seconds},
     "unscoped_s", "total_s", "unscoped_top": [[instruction, seconds]]}``
-    (the eight largest):
-    ``backward_s`` is the part under ``transpose(jvp(...))``, and groups
-    plus unscoped add up to ``total_s``, the union of the events
-    (:func:`innermost_durations`). A fusion counts whole for the group
-    of its root (module docstring).
+    (the eight largest). Groups plus unscoped add up to ``total_s``, the
+    union of the events (:func:`innermost_durations`), and so do the
+    four passes of ``by_pass`` (:func:`pass_of`), which cover the whole
+    program, unscoped instructions too, and need no groups. In a group,
+    ``backward_s`` is everything under ``transpose(jvp(...))``, the
+    recomputed forward with it (a rematerialised block is replayed
+    inside the transposed computation), and ``recompute_s`` is that
+    part: the backward proper is ``backward_s - recompute_s``
+    (``by_pass["backward"]`` is the backward proper). A fusion counts
+    whole for the group and the pass of its root (module docstring).
     """
-    compiled = [(name, re.compile(p)) for name, p in groups]
-    by_group = {name: {"seconds": 0.0, "backward_s": 0.0} for name, _ in groups}
-    cache: Dict[str, Tuple[str, bool]] = {}
-    unscoped: Dict[str, float] = {}
-    unscoped_s = total_s = 0.0
+    ns_of: Dict[str, int] = {}  # an instruction's nanoseconds, each event's added up
     for name, ns in innermost_durations(events, *window):
-        if ns <= 0:
-            continue
-        instruction = instruction_of(name)
-        hit = cache.get(instruction)
-        if hit is None:
-            path = scopes.get(instruction)
-            hit = cache[instruction] = (
-                group_of(path, compiled), bool(path) and BACKWARD in path
-            )
-        group, backward = hit
+        if ns > 0:
+            instruction = instruction_of(name)
+            ns_of[instruction] = ns_of.get(instruction, 0) + ns
+    compiled = [(name, re.compile(p)) for name, p in groups]
+    out = _empty_reduction(groups)
+    unscoped: Dict[str, float] = {}
+    for instruction, ns in ns_of.items():
         seconds = ns / 1e9
-        total_s += seconds
+        path = scopes.get(instruction)
+        group, in_pass = group_of(path, compiled), pass_of(path)
+        out["total_s"] += seconds
+        out["by_pass"][in_pass] += seconds
         if group == UNSCOPED:
-            unscoped_s += seconds
-            unscoped[instruction] = unscoped.get(instruction, 0.0) + seconds
-        else:
-            by_group[group]["seconds"] += seconds
-            if backward:
-                by_group[group]["backward_s"] += seconds
+            out["unscoped_s"] += seconds
+            unscoped[instruction] = seconds
+            continue
+        g = out["groups"][group]
+        g["seconds"] += seconds
+        if path and BACKWARD in path:
+            g["backward_s"] += seconds
+        if in_pass in ("forward", "recompute"):
+            g[in_pass + "_s"] += seconds
     top = sorted(unscoped.items(), key=lambda kv: -kv[1])[:8]
-    return {
-        "groups": by_group, "unscoped_s": unscoped_s, "total_s": total_s,
-        "unscoped_top": [[k, v] for k, v in top],
-    }
+    out["unscoped_top"] = [[k, v] for k, v in top]
+    return out
 
 
 def module_of(event_name: str) -> str:
@@ -382,15 +450,11 @@ def program_by_scope(
     ``XLA Modules`` lines), the operations inside the runs whose name is
     exactly ``program`` (``jit_local_step_microbatched`` is another
     program, with another table) and that lie whole inside ``window``.
-    Seconds are sums over the runs and the devices; ``runs`` counts the
-    runs and ``run_s`` sums their own durations. None where the program
-    did not run."""
+    Seconds (by group and by pass) are sums over the runs and the
+    devices; ``runs`` counts the runs and ``run_s`` sums their own
+    durations. None where the program did not run."""
     lo, hi = window
-    out: Dict[str, Any] = {
-        "groups": {name: {"seconds": 0.0, "backward_s": 0.0} for name, _ in groups},
-        "unscoped_s": 0.0, "total_s": 0.0, "unscoped_top": [],
-        "runs": 0, "run_s": 0.0, "devices": 0,
-    }
+    out = dict(_empty_reduction(groups), runs=0, run_s=0.0, devices=0)
     for dev, dev_ops in sorted(ops.items()):
         runs = sorted(
             (a, b) for name, a, b in modules.get(dev, ())
@@ -405,9 +469,11 @@ def program_by_scope(
         out["run_s"] += sum(b - a for a, b in runs) / 1e9
         for key in ("unscoped_s", "total_s", "unscoped_top"):
             out[key] += one[key]
+        for in_pass, seconds in one["by_pass"].items():
+            out["by_pass"][in_pass] += seconds
         for group, g in one["groups"].items():
-            out["groups"][group]["seconds"] += g["seconds"]
-            out["groups"][group]["backward_s"] += g["backward_s"]
+            for key, seconds in g.items():
+                out["groups"][group][key] += seconds
     if not out["runs"]:
         return None
     out["unscoped_top"] = sorted(out["unscoped_top"], key=lambda kv: -kv[1])[:8]

@@ -11,7 +11,8 @@ the dominant culprit. Training runs get the same treatment per step
 
 A path that holds a profiler capture (an ``.xplane.pb``, as
 ``TRACE_EVERY_N_EPOCHS`` / SIGUSR1 leave under ``<OBS_DIR>/traces``)
-also gets the device's view: device seconds by scope group for each
+also gets the device's view: device seconds by scope group and by pass
+(forward, the forward that remat runs again, backward, other) for each
 program compiled ahead (``obs/programs.py``; the scope tables lie
 beside the capture, the groups beside the model:
 ``models/transformer_lm.TRAIN_STEP_GROUPS``, or ``models/decoder.
@@ -130,9 +131,9 @@ def render(recon: dict, training, top_k: int) -> str:
 
 def device_report(capture_dir: str, groups, profile=None, tables=None) -> dict:
     """One capture reduced: for each program with a scope table beside
-    the capture, device seconds by scope group (``groups``) over its
-    runs on every device; for each device, the idle gaps by ``ddl:``
-    span."""
+    the capture, device seconds by scope group (``groups``) and by pass
+    over its runs on every device, and its Mosaic kernels by group and
+    pass; for each device, the idle gaps by ``ddl:`` span."""
     from distributeddeeplearning_tpu.obs import programs
 
     if profile is None:
@@ -148,7 +149,7 @@ def device_report(capture_dir: str, groups, profile=None, tables=None) -> dict:
         if by is not None:
             out["programs"].append(dict(
                 by, program=program,
-                kernel_calls=programs.kernel_calls_by_group(scopes, groups),
+                kernel_calls=programs.kernel_calls_by_pass(scopes, groups),
             ))
     for dev, ops in sorted(profile.ops.items()):
         gaps = programs.idle_gaps_by_span(ops, profile.host)
@@ -175,6 +176,19 @@ def step_groups(capture_dir: str, tables=None):
     return TRAIN_STEP_GROUPS
 
 
+def kernels_by_pass(calls: dict) -> str:
+    """``kernel_calls_by_pass``'s counts on one line: ``attn_core 18
+    forward / 18 recompute / 18 backward`` (a kernel under ``recompute``
+    is one that remat runs again; ``other`` where a program that takes
+    no gradient holds kernels)."""
+    return ", ".join(
+        f"{group} " + " / ".join(
+            f"{n} {p}" for p, n in by_pass.items() if n or p != "other"
+        )
+        for group, by_pass in sorted(calls.items())
+    ) or "none"
+
+
 def render_device(rep: dict) -> str:
     out: List[str] = [f"device trace: {rep['capture']}"]
     add = out.append
@@ -191,23 +205,35 @@ def render_device(rep: dict) -> str:
             f"{1e3 * by['run_s'] / n:.2f} ms a run, "
             f"{1e3 * by['total_s'] / n:.2f} ms in operations"
         )
-        add(f"    {'group':<16}{'ms/run':>10}{'share':>8}{'backward':>10}")
+        add(
+            f"    {'group':<16}{'ms/run':>10}{'share':>8}"
+            f"{'forward':>10}{'recompute':>10}{'backward':>10}"
+        )
         total = by["total_s"] or 1.0
         rows = sorted(by["groups"].items(), key=lambda kv: -kv[1]["seconds"])
         for group, g in rows:
             add(
                 f"    {group:<16}{1e3 * g['seconds'] / n:>10.3f}"
                 f"{100 * g['seconds'] / total:>7.1f}%"
-                f"{1e3 * g['backward_s'] / n:>10.3f}"
+                f"{1e3 * g['forward_s'] / n:>10.3f}{1e3 * g['recompute_s'] / n:>10.3f}"
+                f"{1e3 * (g['backward_s'] - g['recompute_s']) / n:>10.3f}"
             )
         add(
             f"    {'unscoped':<16}{1e3 * by['unscoped_s'] / n:>10.3f}"
             f"{100 * by['unscoped_s'] / total:>7.1f}%   "
             + ", ".join(f"{k} {1e3 * v / n:.3f}" for k, v in by["unscoped_top"][:5])
         )
-        add("    Mosaic kernels in the program, by group: " + (", ".join(
-            f"{g} {k}" for g, k in sorted(by.get("kernel_calls", {}).items())
-        ) or "none"))
+        in_pass = {p: 1e3 * s / n for p, s in by["by_pass"].items()}
+        add(
+            f"    {'program':<16}{1e3 * by['total_s'] / n:>10.3f}{100.0:>7.1f}%"
+            f"{in_pass['forward']:>10.3f}{in_pass['recompute']:>10.3f}"
+            f"{in_pass['backward']:>10.3f}   and {in_pass['other']:.3f} in no pass "
+            "(the optimizer, the metrics, the compiler's pathless copies, and its "
+            "ragged-dot-* calls, whichever pass asked for them)"
+        )
+        add("    Mosaic kernels in the program, by group: " + kernels_by_pass(
+            by.get("kernel_calls", {})
+        ))
     for idle in rep["idle"]:
         gaps = idle["gaps"]
         add(

@@ -353,6 +353,8 @@ def _cell_step(one_chip, monkeypatch, traffic, configuration, block_diffusion=Fa
     opt = job["optimizer"]
     noising, width = {}, seq
     share = {k: config[v] for k, v in share.items()}
+    if "layers" in config:  # a spec-built decoder's depth as run
+        share["layers"] = config["layers"]
     if block_diffusion:
         assumed = config["assumed"]
         noising = dict(
@@ -362,7 +364,7 @@ def _cell_step(one_chip, monkeypatch, traffic, configuration, block_diffusion=Fa
         share["block_len"], width = assumed["block_length"], 2 * seq
     cfg = TrainConfig(
         model=config["program"]["model"], num_classes=config["vocab_size"],
-        compute_dtype="bfloat16", batch_size_per_device=rows, remat=job["remat"],
+        compute_dtype="bfloat16", batch_size_per_device=rows, remat=job.get("remat", False),
         optimizer=opt["name"], base_lr=opt["learning_rate"],
         adam_beta1=opt["adam_beta1"], adam_beta2=opt["adam_beta2"],
         adam_eps=opt["adam_eps"], decoupled_weight_decay=opt["decoupled_weight_decay"],
@@ -370,7 +372,7 @@ def _cell_step(one_chip, monkeypatch, traffic, configuration, block_diffusion=Fa
         lr_schedule="constant", scale_lr_by_world_size=False, fake=True, epochs=1,
         **noising,
     )
-    model = get_model(cfg.model, **cfg.model_kwargs(), layers=config["layers"], **share)
+    model = get_model(cfg.model, **cfg.model_kwargs(), **share)
     tx, _ = create_optimizer(cfg, 1000, world_size=1)
     state = jax.eval_shape(lambda: create_train_state(
         model, cfg, tx, input_shape=(1, width), input_dtype=jnp.int32
@@ -419,6 +421,24 @@ def _fits_the_chip(compiled, state, weights, temporaries_under):
     assert resident + temporaries + copy < 15.75
 
 
+def test_the_gpt2_cell_s_step_holds_a_forward_and_a_backward_kernel_a_layer(
+    one_chip, monkeypatch
+):
+    """``gpt2-train-t1024``'s whole train step (``lm_base``, 16 rows of
+    1,024 tokens, AdamW, no remat) for the described v5e: by pass, 12
+    flash kernels forward, 12 backward and none that runs again, which is
+    what ``step_recompute_device_ms.train`` reads as 0.0 in that cell."""
+    compiled, _, totals, named = _cell_step(
+        one_chip, monkeypatch, "t1024.json", "gpt2-124m.json", max_seq_len="n_positions"
+    )
+    assert totals["attn.impl.pallas"]["count"] == 12
+    scopes = programs.parse_hlo_scopes(compiled.as_text())
+    assert programs.groups_in(scopes, TRAIN_STEP_GROUPS) == {g for g, _ in TRAIN_STEP_GROUPS}
+    assert programs.kernel_calls_by_pass(scopes, TRAIN_STEP_GROUPS) == {
+        "attn_core": {"forward": 12, "recompute": 0, "backward": 12, "other": 0}}
+    assert not [p for p in scopes.values() if programs.pass_of(p) == "recompute"]
+
+
 def test_the_state_space_cell_s_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
     """``granite-4.0-h-micro-train-t4k``'s whole train step (ten layers
     at the published widths, 2 rows of 4,096 tokens, AdamW, block remat)
@@ -446,6 +466,8 @@ def test_the_state_space_cell_s_step_compiles_and_fits_the_chip(one_chip, monkey
     # the attention layer: forward and one fused backward; block remat
     # starts from what the forward wrote (3 until PR 34)
     assert programs.kernel_calls_by_group(scopes, HYBRID_STEP_GROUPS)["attn_core"] == 2
+    assert programs.kernel_calls_by_pass(scopes, HYBRID_STEP_GROUPS) == {
+        "attn_core": {"forward": 1, "recompute": 0, "backward": 1, "other": 0}}
 
 
 def test_the_long_cell_s_step_keeps_its_forwards_and_fits_the_chip(one_chip, monkeypatch):
@@ -471,6 +493,9 @@ def test_the_long_cell_s_step_keeps_its_forwards_and_fits_the_chip(one_chip, mon
     by_kind = programs.kernel_calls_by_group(scopes, ATTN_KIND_GROUPS)
     assert (by_kind["attn_full"], by_kind["attn_window"]) == (4, 12)
     assert programs.kernel_calls_by_group(scopes, TRAIN_STEP_GROUPS)["attn_core"] == 16
+    # by pass: none of the 16 is block remat's (8 / 8 / 8 until PR 34)
+    assert programs.kernel_calls_by_pass(scopes, TRAIN_STEP_GROUPS)["attn_core"] == {
+        "forward": 8, "recompute": 0, "backward": 8, "other": 0}
 
 
 def test_the_block_diffusion_cell_s_step_lowers_as_it_did_and_fits_the_chip(
@@ -494,3 +519,6 @@ def test_the_block_diffusion_cell_s_step_lowers_as_it_did_and_fits_the_chip(
     _fits_the_chip(compiled, state, 645_623_296, temporaries_under=5.0)
     scopes = programs.parse_hlo_scopes(compiled.as_text())
     assert programs.kernel_calls_by_group(scopes, TRAIN_STEP_GROUPS)["attn_core"] == 54
+    # six layers of three passes: forward, block remat's forward, backward
+    assert programs.kernel_calls_by_pass(scopes, TRAIN_STEP_GROUPS)["attn_core"] == {
+        "forward": 18, "recompute": 18, "backward": 18, "other": 0}

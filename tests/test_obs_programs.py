@@ -4,6 +4,7 @@
 
 import gc
 import json
+import re
 import sys
 
 import jax
@@ -844,3 +845,215 @@ def test_the_scan_s_choice_and_the_layers_built_are_reported():
     report = _trace_report()
     assert report.chosen_paths(events, "ssm.impl.") == "xla x4 at [2, 27, 4, 16]"
     assert "mamba2 x4" in report.chosen_paths(events, "decoder.layer.")
+
+
+# -- the step by pass: forward, remat's recomputed forward, backward ----------
+
+_FWD = "jit(local_step)/jvp(SpecDecoder)"
+_BWD = "jit(local_step)/transpose(jvp(SpecDecoder))/jvp(SpecDecoder)/checkpoint"
+_REPLAY = _BWD + "/rematted_computation"
+PASS_PATHS = {
+    # the forward, a loop of it, and the loss
+    "fusion.1": _FWD + "/block0/attn/attn_core/attn_full/jit(_core)/pallas_call",
+    "while.2": _FWD + "/block0/ssm/ssm_scan/while",
+    "fusion.3": _FWD + "/block0/ssm/ssm_scan/while/body/closed_call/checkpoint/dot_general",
+    "fusion.4": "jit(local_step)/jvp(loss)/reduce_max",
+    # block remat's replay: a kernel, a product, the scan's loop
+    "kernel.5": _REPLAY + "/block0/attn/attn_core/attn_full/jit(_core)/pallas_call",
+    "fusion.6": _REPLAY + "/block0/mlp/w_in/dot_general",
+    "fusion.7": _REPLAY + "/block0/ssm/ssm_scan/while/body/closed_call/dot_general",
+    # the backward's own loop replays its checkpointed body: a nested mark
+    "while.8": _BWD + "/block0/ssm/ssm_scan/while",
+    "fusion.9": _BWD + "/block0/ssm/ssm_scan/while/body/closed_call/checkpoint/"
+                "rematted_computation/dot_general",
+    "fusion.10": _BWD + "/block0/ssm/ssm_scan/while/body/closed_call/checkpoint/transpose",
+    "kernel.11": _BWD + "/block0/attn/attn_core/attn_full/jit(_core)/pallas_call",
+    "fusion.12": "jit(local_step)/transpose(jvp(loss))/sub",
+    # neither: the optimizer, the metrics, and a copy the compiler put in
+    "fusion.13": "jit(local_step)/optimizer/mul",
+    "fusion.14": "jit(local_step)/metrics/reduce_sum",
+}
+PASS_HLO = "ENTRY %main () -> f32[4] {\n" + "\n".join(
+    f"  %{name} = f32[4]{{0}} " + (
+        'custom-call(%a), custom_call_target="tpu_custom_call"' if name.startswith("kernel")
+        else "fusion(%a)"
+    ) + f', metadata={{op_name="{path}"}}'
+    for name, path in PASS_PATHS.items()
+) + "\n  %copy.15 = f32[4]{0} copy(%a)\n}"
+PASS_OF = {
+    "forward": ["fusion.1", "while.2", "fusion.3", "fusion.4"],
+    "recompute": ["kernel.5", "fusion.6", "fusion.7", "fusion.9"],
+    "backward": ["while.8", "fusion.10", "kernel.11", "fusion.12"],
+    "other": ["fusion.13", "fusion.14", "copy.15"],
+}
+
+
+@pytest.mark.parametrize("path,expect", [
+    ("jit(f)/jvp(block0)/attn/dot_general", "forward"),
+    ("jit(f)/transpose(jvp(block0))/jvp(block0)/checkpoint/mlp/mul", "backward"),
+    ("jit(f)/transpose(jvp(block0))/jvp(block0)/checkpoint/rematted_computation/mlp/sub",
+     "recompute"),
+    # flax's nn.remat: the module path after the mark
+    ("jit(local_step)/transpose(jvp(SpecDecoder))/jvp(SpecDecoder)/checkpoint/"
+     "rematted_computation/block0/mlp/w_in/dot_general", "recompute"),
+    # a checkpointed scan body inside the backward's loop
+    ("jit(f)/transpose(jvp(M))/ssm_scan/while/body/closed_call/checkpoint/"
+     "rematted_computation/mul", "recompute"),
+    ("jit(f)/rematted_computation", "recompute"),
+    # a whole component, not a scope someone named alike
+    ("jit(f)/jvp(M)/my_rematted_computation_notes/add", "forward"),
+    ("jit(local_step)/optimizer/mul", "other"),
+    ("jit(local_step)/metrics/reduce_sum", "other"),
+    ("jit(decode)/block0/attn/attn_core/pallas_call", "other"),
+    ("", "other"), (None, "other"),
+])
+def test_pass_of_reads_the_marks_the_transforms_leave(path, expect):
+    assert programs.pass_of(path) == expect
+    assert expect in programs.PASSES
+
+
+def test_the_reduction_tells_the_four_passes_apart_and_they_add_up():
+    """Hand-made events over hand-made paths of all four passes: each
+    instruction its number of milliseconds, the loops over their bodies
+    (a loop keeps what its body leaves, in the loop's own pass)."""
+    from distributeddeeplearning_tpu.models.decoder import HYBRID_STEP_GROUPS
+
+    scopes = programs.parse_hlo_scopes(PASS_HLO)
+    assert {n: programs.pass_of(scopes.get(n)) for ns in PASS_OF.values() for n in ns} == {
+        n: p for p, ns in PASS_OF.items() for n in ns}
+    events, t = [], 0
+    for name in list(PASS_PATHS) + ["copy.15"]:
+        ms = int(name.split(".")[1])
+        events.append((name, t, t + ms * MS))
+        t += ms * MS
+    # the loops cover their bodies: while.2 over fusion.3, while.8 over 9 and 10
+    events[1] = ("while.2", events[1][1], events[2][2])
+    events[7] = ("while.8", events[7][1], events[9][2])
+    by = programs.device_seconds_by_scope(events, scopes, HYBRID_STEP_GROUPS)
+    expect = {p: sum(int(n.split(".")[1]) for n in ns) / 1e3 for p, ns in PASS_OF.items()}
+    assert by["by_pass"] == pytest.approx(expect)
+    assert list(by["by_pass"]) == list(programs.PASSES)
+    assert sum(by["by_pass"].values()) == pytest.approx(by["total_s"]) == pytest.approx(0.120)
+    groups = by["groups"]
+    # today's backward_s: everything under transpose(jvp(, the replay with it
+    assert groups["ssm"]["backward_s"] == pytest.approx(0.034)  # 7 + 8 + 9 + 10
+    assert groups["ssm"]["recompute_s"] == pytest.approx(0.016)  # outer 7, inner 9
+    assert groups["ssm"]["forward_s"] == pytest.approx(0.005)
+    assert groups["attn_core"] == pytest.approx(
+        {"seconds": 0.017, "forward_s": 0.001, "recompute_s": 0.005, "backward_s": 0.016})
+    assert groups["mlp"] == pytest.approx(
+        {"seconds": 0.006, "forward_s": 0.0, "recompute_s": 0.006, "backward_s": 0.006})
+    assert groups["head_loss"] == pytest.approx(  # the metrics' 14 ms in no pass
+        {"seconds": 0.030, "forward_s": 0.004, "recompute_s": 0.0, "backward_s": 0.012})
+    assert groups["optimizer"] == pytest.approx(
+        {"seconds": 0.013, "forward_s": 0.0, "recompute_s": 0.0, "backward_s": 0.0})
+    for g in groups.values():
+        assert g["recompute_s"] <= g["backward_s"] <= g["seconds"]
+    in_groups = {
+        "forward": sum(g["forward_s"] for g in groups.values()),
+        "recompute": sum(g["recompute_s"] for g in groups.values()),
+        "backward": sum(g["backward_s"] - g["recompute_s"] for g in groups.values()),
+    }
+    assert in_groups == pytest.approx({p: expect[p] for p in in_groups})  # nothing unscoped in them
+    assert by["unscoped_s"] == pytest.approx(0.015)  # the copy, in `other`
+    # the kernels by pass: the replayed forward is the one under `recompute`
+    assert programs.kernel_calls_by_pass(scopes, HYBRID_STEP_GROUPS) == {
+        "attn_core": {"forward": 0, "recompute": 1, "backward": 1, "other": 0}}
+    assert programs.kernel_calls_by_group(scopes, HYBRID_STEP_GROUPS) == {"attn_core": 2}
+
+
+def test_program_by_scope_adds_the_passes_up_over_runs_and_devices():
+    scopes = programs.parse_hlo_scopes(PASS_HLO)
+    one = [("fusion.1", 0, MS), ("kernel.5", MS, 3 * MS), ("kernel.11", 3 * MS, 6 * MS),
+           ("fusion.13", 6 * MS, 7 * MS), ("copy.15", 7 * MS, 8 * MS)]
+    ops = {0: one + [(n, a + 10 * MS, b + 10 * MS) for n, a, b in one], 1: one}
+    modules = {0: [("jit_local_step(7)", 0, 8 * MS), ("jit_local_step(7)", 10 * MS, 18 * MS)],
+               1: [("jit_local_step(7)", 0, 8 * MS)]}
+    by = programs.program_by_scope(ops, modules, "jit_local_step", scopes, TRAIN_STEP_GROUPS)
+    assert by["runs"] == 3 and by["total_s"] == pytest.approx(0.024)
+    assert by["by_pass"] == pytest.approx(
+        {"forward": 0.003, "recompute": 0.006, "backward": 0.009, "other": 0.006})
+    assert by["groups"]["attn_core"] == pytest.approx(
+        {"seconds": 0.018, "forward_s": 0.003, "recompute_s": 0.006, "backward_s": 0.015})
+
+
+def test_trace_report_prints_groups_by_pass_the_program_by_pass_and_the_kernels():
+    """What ``make trace-report`` shows a trainer who turned ``remat``
+    on: each group's forward, recomputed forward and backward, the whole
+    program by pass, and whether a kernel is among what runs again."""
+    from distributeddeeplearning_tpu.models.decoder import HYBRID_STEP_GROUPS
+
+    scopes = programs.parse_hlo_scopes(PASS_HLO)
+    ops = [(n, i * MS, (i + 1) * MS) for i, n in enumerate(list(PASS_PATHS) + ["copy.15"])]
+    ops = [e for e in ops if not e[0].startswith("while")]
+    profile = programs.Profile(
+        ops={0: ops}, modules={0: [("jit_local_step(1)", 0, 15 * MS)]}, host=[])
+    report = _trace_report()
+    tables = {"jit_local_step": scopes}
+    assert report.step_groups("capture", tables) is HYBRID_STEP_GROUPS
+    rep = report.device_report("capture", HYBRID_STEP_GROUPS, profile, tables)
+    (by,) = rep["programs"]
+    assert by["kernel_calls"]["attn_core"]["recompute"] == 1
+    text = report.render_device(rep)
+    lines = {line.split()[0]: line.split() for line in text.splitlines() if line.strip()}
+    assert lines["group"] == ["group", "ms/run", "share", "forward", "recompute", "backward"]
+    assert lines["ssm"][1:] == ["4.000", "30.8%", "1.000", "2.000", "1.000"]
+    assert lines["attn_core"][1:] == ["3.000", "23.1%", "1.000", "1.000", "1.000"]
+    assert lines["optimizer"][1:] == ["1.000", "7.7%", "0.000", "0.000", "0.000"]
+    assert lines["program"][1:9] == [
+        "13.000", "100.0%", "3.000", "4.000", "3.000", "and", "3.000", "in"]
+    assert ("Mosaic kernels in the program, by group: "
+            "attn_core 0 forward / 1 recompute / 1 backward") in text
+
+
+def _spec_step_scopes(name, impl, remat):
+    """The scope table of a small spec-built decoder's gradient,
+    compiled here (the flash kernels in interpret mode, where a kernel's
+    grid is a ``while`` under its jitted core)."""
+    from distributeddeeplearning_tpu.models import get_model
+
+    jax.clear_caches()  # the jitted cores keep their traces
+    model = get_model(name, num_classes=64, dtype="float32", attn_impl=impl, remat=remat)
+    tokens = jnp.zeros((1, 64 if name == "sdar_tiny" else 32), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens, train=False)["params"]
+    )
+
+    def objective(p, tokens):
+        return jnp.sum(model.apply({"params": p}, tokens, train=True, mutable=["stats"])[0])
+
+    compiled = jax.jit(jax.grad(objective)).lower(params, tokens).compile()
+    return programs.parse_hlo_scopes(compiled.as_text())
+
+
+@pytest.mark.parametrize("name,impl,remat,recomputed,replays_the_kernel", [
+    ("gpt2_tiny", "xla", False, set(), False),
+    ("gpt2_tiny", "xla", True, {"attn_core", "attn_proj", "mlp", "norm_residual"}, False),
+    # a causal layer under block remat keeps what the flash forward wrote
+    ("smallthinker_tiny", "pallas", True,
+     {"attn_core", "attn_proj", "mlp", "norm_residual"}, False),
+    # a block-diffusion layer keeps nothing: its three passes run again
+    ("sdar_tiny", "pallas", True, {"attn_core", "attn_proj", "mlp", "norm_residual"}, True),
+])
+def test_a_spec_decoder_recomputes_its_blocks_under_remat_alone(
+    name, impl, remat, recomputed, replays_the_kernel
+):
+    """``SpecDecoder`` compiled with ``remat`` on and off: the table
+    holds ``recompute`` instructions only when on, under the groups a
+    block has (never the head's, the loss's or the optimizer's); the
+    forward and the backward are there either way; and with the policy
+    of a causal spec the flash forward is not among what runs again."""
+    compiled = [(g, re.compile(p)) for g, p in TRAIN_STEP_GROUPS]
+    by: dict = {}
+    kernel_grids = {p: 0 for p in programs.PASSES}
+    for path in set(_spec_step_scopes(name, impl, remat).values()):
+        group, in_pass = programs.group_of(path, compiled), programs.pass_of(path)
+        by.setdefault(in_pass, set()).add(group)
+        if group == "attn_core" and "/while/body/" in path:
+            kernel_grids[in_pass] += 1
+    assert by.get("recompute", set()) - {programs.UNSCOPED} == recomputed
+    assert {"attn_core", "attn_proj", "mlp", "norm_residual"} <= by["forward"] & by["backward"]
+    assert "head_loss" in by["forward"] | by["backward"]
+    if impl == "pallas":
+        assert kernel_grids["forward"] and kernel_grids["backward"]
+        assert bool(kernel_grids["recompute"]) == replays_the_kernel
