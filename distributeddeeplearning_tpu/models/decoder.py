@@ -434,7 +434,7 @@ class Mamba2Mixer(nn.Module):
                 jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
                 -jnp.exp(a_log),
                 b_in.reshape(b, t, g, n), c_out.reshape(b, t, g, n),
-                skip, chunk=spec.ssm_chunk,
+                skip, chunk=spec.ssm_chunk, initializing=self.is_initializing(),
             ).reshape(b, t, inner)
         gated = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
         normed = RMSNorm(spec.norm_eps, name="norm")(gated)

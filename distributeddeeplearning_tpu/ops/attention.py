@@ -71,21 +71,23 @@ def kernel_interpreted(impl: Optional[str]) -> bool:
     return impl in _KERNEL_IMPLS and jax.default_backend() != "tpu"
 
 
+def custom_call_is_safe(x, initializing: bool) -> bool:
+    """Whether a Pallas kernel may take ``x`` in this call: on a TPU,
+    operands that are already local (one device, or inside
+    ``shard_map``: the dp/sp engines; under multi-device GSPMD, the pjit
+    engine, operands carry no varying axes and a custom call would force
+    replication), and not while initializing (parameters do not depend
+    on the path, and the weight draw should lower no kernel it never
+    runs). The state-space scan's rule (``ops/ssm.resolve_impl``) asks
+    the same."""
+    local = bool(getattr(jax.typeof(x), "vma", ())) or jax.device_count() == 1
+    return jax.default_backend() == "tpu" and local and not initializing
+
+
 def kernel_is_safe(x, initializing: bool) -> bool:
     """Whether a Pallas kernel may stand in this call's attention core:
-    on a TPU, ``[B, T, D]`` operands that are already local (one device,
-    or inside ``shard_map``: the dp/sp engines; under multi-device
-    GSPMD, the pjit engine, operands carry no varying axes and a custom
-    call would force replication), and not while initializing
-    (parameters do not depend on the path, and the weight draw should
-    lower no kernel it never runs)."""
-    local = bool(getattr(jax.typeof(x), "vma", ())) or jax.device_count() == 1
-    return (
-        x.ndim == 3
-        and jax.default_backend() == "tpu"
-        and local
-        and not initializing
-    )
+    ``[B, T, D]`` operands where :func:`custom_call_is_safe` holds."""
+    return x.ndim == 3 and custom_call_is_safe(x, initializing)
 
 
 def resolve_impl(

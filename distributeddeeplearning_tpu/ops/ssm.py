@@ -38,9 +38,14 @@ that Mamba-2 puts before the scan: channel ``c`` at position ``t`` reads
 its own last ``K`` values, noughts before the row's start.
 
 Which lowering a call takes is this module's rule, as ``ops/attention.
-resolve_impl`` is the attention core's: :func:`resolve_impl`. Today
-there is one, XLA's (``"xla"``); a kernel for the intra-chunk products
-would be chosen here, from what the call can see, and nowhere else.
+resolve_impl`` is the attention core's: :func:`resolve_impl`. There are
+two of the one algorithm: XLA's (``"xla"``, the ``lax.scan`` above: the
+CPU's path, the weight draw's, short rows' and odd sizes', and what the
+kernels are tested against) and the Pallas kernels of
+``ops/pallas/ssd.py`` (``"pallas"``), which make, use and drop a chunk's
+decay matrix in VMEM and keep the state as it entered each chunk for
+their backward. The choice is made here, from what the call can see, and
+nowhere else.
 """
 
 from __future__ import annotations
@@ -49,6 +54,8 @@ import jax
 import jax.numpy as jnp
 
 from distributeddeeplearning_tpu import obs
+from distributeddeeplearning_tpu.ops.attention import custom_call_is_safe
+from distributeddeeplearning_tpu.ops.pallas import ssd
 
 # Scopes of the two operations inside a state-space mixer (the module
 # that holds them is named `SSM`): `models/decoder.SSM_GROUPS` reads them.
@@ -57,21 +64,30 @@ SSM_CONV = "ssm_conv"
 SSM_SCAN = "ssm_scan"
 
 
-def resolve_impl(xs, *, state: int, chunk: int) -> str:
+def resolve_impl(xs, *, state: int, chunk: int, groups: int = 1,
+                 initializing: bool = False) -> str:
     """The scan's lowering for one call, chosen from what the call can
-    see (``xs [B, T, H, P]``), and counted at trace time:
+    see (``xs [B, T, H, P]``): the kernels (``"pallas"``) where a custom
+    call is safe (``ops/attention.custom_call_is_safe``: a TPU, local
+    operands, not the weight draw) and the shapes are the kernels'
+    (``ops/pallas/ssd.supports``: a chunk of 128 or 256, heads of 32, 64
+    or 128, a state of whole lanes, a group's heads in whole blocks of
+    8); the XLA form otherwise. Counted at trace time:
     ``ssm.impl.<path>`` with the labels ``shape``, ``heads``,
-    ``head_dim``, ``state``, ``chunk``, ``chunks`` (a row's) and
-    ``padded`` (positions added to fill the last chunk). The XLA form is
-    the only one there is."""
+    ``head_dim``, ``state``, ``chunk``, ``chunks`` (a row's), ``padded``
+    (positions added to fill the last chunk) and ``head_block`` (heads a
+    kernel program; 0 under XLA)."""
     b, t, h, p = xs.shape
     q = min(chunk, t)
     chunks = -(-t // q)
+    impl, hb = "xla", 0
+    if custom_call_is_safe(xs, initializing) and ssd.supports(q, h, groups, p, state):
+        impl, hb = "pallas", ssd.HEAD_BLOCK
     obs.counter(
-        "ssm.impl.xla", shape=[b, t, h, p], heads=h, head_dim=p, state=state,
-        chunk=q, chunks=chunks, padded=chunks * q - t,
+        f"ssm.impl.{impl}", shape=[b, t, h, p], heads=h, head_dim=p, state=state,
+        chunk=q, chunks=chunks, padded=chunks * q - t, head_block=hb,
     )
-    return "xla"
+    return impl
 
 
 def causal_conv1d(x, w, bias):
@@ -129,25 +145,13 @@ def _chunk(state, inputs, *, a, groups: int):
     return state, y.astype(dtype)
 
 
-def ssd_scan(xs, dt, a, b, c, d, *, chunk: int):
-    """The recurrence at the top, in chunks: ``xs [B, T, H, P]``, ``dt
-    [B, T, H]`` (the step ``Δ``, positive), ``a [H]`` (negative), ``b``,
-    ``c`` ``[B, T, G, N]``, ``d [H]`` -> ``y [B, T, H, P]`` in ``xs``'s
-    dtype. Differentiable in all six."""
-    batch, t, h, p = xs.shape
+def _xla_scan(xs, dt, b, c, a, *, q: int, chunks: int):
+    """The chunks of a padded row walked by a ``lax.scan``: ``y`` less
+    the ``D`` term, ``[B, chunks·Q, H, P]``."""
+    batch, _, h, p = xs.shape
     g, n = b.shape[2], b.shape[3]
-    if h % g:
-        raise ValueError(f"{h} heads do not divide into {g} groups")
-    resolve_impl(xs, state=n, chunk=chunk)
-    q = min(chunk, t)
-    chunks = -(-t // q)
-    dt = dt.astype(jnp.float32)
-    a = a.astype(jnp.float32)
-    pad = chunks * q - t
 
     def by_chunk(x):  # [B, T, ...] -> [chunks, B, Q, ...]
-        if pad:
-            x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
         x = x.reshape((batch, chunks, q) + x.shape[2:])
         return jnp.moveaxis(x, 1, 0)
 
@@ -163,7 +167,41 @@ def ssd_scan(xs, dt, a, b, c, d, *, chunk: int):
     _, y = jax.lax.scan(
         body, first, (by_chunk(xs), by_chunk(dt), by_chunk(b), by_chunk(c)),
     )
-    y = jnp.moveaxis(y, 0, 1).reshape(batch, chunks * q, h, p)[:, :t]
+    return jnp.moveaxis(y, 0, 1).reshape(batch, chunks * q, h, p)
+
+
+def _kernel_scan(xs, dt, b, c, a, *, q: int, chunks: int):
+    """The same by ``ops/pallas/ssd.py``'s kernels, which take the
+    running sum ``c_t`` of ``Δ·a`` within each chunk from here: autodiff
+    carries its cotangent back to ``Δ`` and ``a``."""
+    batch, t, h, _ = xs.shape
+    cum = jnp.cumsum((dt * a).reshape(batch, chunks, q, h), axis=2)
+    return ssd.ssd_chunks(xs, dt, cum.reshape(batch, t, h), b, c, chunk=q)
+
+
+def ssd_scan(xs, dt, a, b, c, d, *, chunk: int, initializing: bool = False):
+    """The recurrence at the top, in chunks: ``xs [B, T, H, P]``, ``dt
+    [B, T, H]`` (the step ``Δ``, positive), ``a [H]`` (negative), ``b``,
+    ``c`` ``[B, T, G, N]``, ``d [H]`` -> ``y [B, T, H, P]`` in ``xs``'s
+    dtype. Differentiable in all six. ``initializing``: the caller's
+    statement that this is the weight draw (:func:`resolve_impl`)."""
+    batch, t, h, p = xs.shape
+    g, n = b.shape[2], b.shape[3]
+    if h % g:
+        raise ValueError(f"{h} heads do not divide into {g} groups")
+    impl = resolve_impl(xs, state=n, chunk=chunk, groups=g, initializing=initializing)
+    q = min(chunk, t)
+    chunks = -(-t // q)
+    dt = dt.astype(jnp.float32)
+    a = a.astype(jnp.float32)
+    operands = xs, dt, b, c
+    if chunks * q != t:  # Δ = 0: neither decays the state nor writes into it
+        operands = tuple(
+            jnp.pad(x, ((0, 0), (0, chunks * q - t)) + ((0, 0),) * (x.ndim - 2))
+            for x in operands
+        )
+    scan = _kernel_scan if impl == "pallas" else _xla_scan
+    y = scan(*operands, a, q=q, chunks=chunks)[:, :t]
     return (
         y.astype(jnp.float32) + d.astype(jnp.float32)[:, None] * xs.astype(jnp.float32)
     ).astype(xs.dtype)

@@ -256,9 +256,10 @@ def chosen_paths(events, prefix: str = "attn.impl.") -> str:
     walk visits and skips, ``ops/pallas/flash.py``), ``loss.impl.
     <path>`` (the loss, ``training/train_step.loss_and_hits``),
     ``decoder.layer.<kind>`` and ``moe.*`` (the layers a spec-built
-    decoder built, ``models/decoder.py``), ``ssm.impl.<path>`` (a
-    state-space layer's scan, ``ops/ssm.py``). A counter with no ``shape``
-    label is keyed by ``visited``/``skipped`` (the window's walk) or
+    decoder built, ``models/decoder.py``), ``ssm.impl.<path>`` and
+    ``ssm.bwd.pallas`` (a state-space layer's scan, ``ops/ssm.py``, and
+    its kernels' traced backward, ``ops/pallas/ssd.py``). A counter with
+    no ``shape`` label is keyed by ``visited``/``skipped`` (the window's walk) or
     ``window`` where it has them; ``mib`` (the named results) follows
     the shape."""
     chosen: dict = {}
@@ -363,7 +364,8 @@ def main(argv=None) -> int:
         ("attention backward", "attn.bwd."),
         ("window walk (pass, steps visited, skipped, window)", "attn.window."),
         ("loss", "loss.impl."), ("decoder layers", "decoder.layer."),
-        ("state-space scan", "ssm.impl."), ("expert layer", "moe."),
+        ("state-space scan", "ssm.impl."), ("state-space scan's backward", "ssm.bwd."),
+        ("expert layer", "moe."),
     ):
         paths_chosen = chosen_paths(loaded["events"], prefix)
         if paths_chosen:

@@ -316,6 +316,39 @@ def test_mosaic_takes_the_row_kernels_and_the_layer_sorts_once(
     assert len(kernels) == 8 and sum(programs.BACKWARD in p for p in kernels) == 4
 
 
+@pytest.mark.parametrize(
+    "shape,groups,state,chunk,dtype",
+    [
+        ((2, 4096, 64, 64), 1, 128, 256, jnp.bfloat16),  # the Granite cell's: two heads a lane block
+        ((1, 1024, 16, 128), 1, 128, 256, jnp.bfloat16),  # a head fills a lane block
+        ((1, 1024, 16, 32), 2, 256, 128, jnp.bfloat16),  # four heads a block, two groups, one tile a chunk
+        ((1, 1024, 8, 64), 1, 128, 256, jnp.float32),
+    ],
+)
+def test_mosaic_takes_the_scan_kernels(one_chip, shape, groups, state, chunk, dtype):
+    """``ops/pallas/ssd.py`` at every kind of shape ``supports`` admits
+    (a chunk of 512 and heads of 16 stop the compiler on a check of its
+    own, and the rule keeps them on the XLA form): forward and backward,
+    one Mosaic call each."""
+    from distributeddeeplearning_tpu.ops.pallas import ssd
+
+    b, t, h, _ = shape
+    assert ssd.supports(chunk, h, groups, shape[3], state)
+    assert not ssd.supports(512, h, groups, shape[3], state)
+    assert not ssd.supports(chunk, h, groups, 16, state)
+    like = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    args = (
+        like(shape, dtype), like((b, t, h), jnp.float32), like((b, t, h), jnp.float32),
+        like((b, t, groups, state), dtype), like((b, t, groups, state), dtype),
+    )
+
+    def loss(*v):
+        return jnp.sum(ssd.ssd_chunks(*v, chunk=chunk, interpret=False).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(*args).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+
+
 def _cell_step(one_chip, monkeypatch, traffic, configuration, block_diffusion=False, **share):
     """A cell's whole train step (its traffic file's rows, length,
     optimizer and remat; its configuration's model with the run's
@@ -442,10 +475,13 @@ def test_the_gpt2_cell_s_step_holds_a_forward_and_a_backward_kernel_a_layer(
 def test_the_state_space_cell_s_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
     """``granite-4.0-h-micro-train-t4k``'s whole train step (ten layers
     at the published widths, 2 rows of 4,096 tokens, AdamW, block remat)
-    for the described v5e: ISSUE 33's ladder, by the compiler. The state
-    (8.63 GiB) and the step's temporaries stay under the chip's 15.75 GiB
-    with the runner's 2.88 GiB copy: rows 2 are taken. Nine scans and
-    nine convolutions under their scopes, the attention layer's flash
+    for the described v5e, under the step's one-device ``shard_map``:
+    ISSUE 33's ladder, by the compiler. The state (8.63 GiB) and the
+    step's temporaries stay under the chip's 15.75 GiB with the runner's
+    2.88 GiB copy: rows 2 are taken. Nine scans, each the Pallas kernels
+    of ``ops/pallas/ssd.py`` (forward, block remat's replay of it, and
+    the backward: 9 / 9 / 9 under ``ssm``; the weight draw's is XLA's),
+    and nine convolutions under their scopes, the attention layer's flash
     kernels under ``attn_full`` with the spec's scale, every group of
     both tables."""
     from distributeddeeplearning_tpu.models.decoder import HYBRID_STEP_GROUPS, SSM_GROUPS
@@ -454,11 +490,16 @@ def test_the_state_space_cell_s_step_compiles_and_fits_the_chip(one_chip, monkey
         one_chip, monkeypatch, "t4k.json", "granite-4.0-h-micro.json"
     )
     assert named == [40.0]  # 2 rows x 4,096 x 2,048 bfloat16 and the logsumexp
-    # the step and block remat's second forward each trace a layer
+    # the weight draw (`create_train_state` under `eval_shape`) and the
+    # step each trace a layer once: the draw takes XLA's scan, the step
+    # the kernels
     assert totals["decoder.layer.mamba2"]["count"] == 9 * totals["decoder.layer.full"]["count"]
-    assert totals["ssm.impl.xla"]["count"] == totals["decoder.layer.mamba2"]["count"]
+    assert totals["ssm.impl.pallas"]["count"] == 9 and totals["ssm.impl.xla"]["count"] == 9
+    assert totals["ssm.bwd.pallas"]["count"] == 9
     assert totals["attn.impl.pallas"]["count"] >= 1 and "attn.impl.xla" in totals  # init: einsum
-    # 1.70 when PR 33 wrote this, 1.74 with the attention layer's 40 MiB kept
+    # 1.70 when PR 33 wrote this, 1.74 with the attention layer's 40 MiB kept,
+    # 1.70 with the scan's kernels: a layer's entering states (64 MiB) stand
+    # where the `while` kept its own residuals
     _fits_the_chip(compiled, state, 772_160_448, temporaries_under=2.5)
     scopes = programs.parse_hlo_scopes(compiled.as_text())
     assert programs.groups_in(scopes, HYBRID_STEP_GROUPS) == {g for g, _ in HYBRID_STEP_GROUPS}
@@ -467,7 +508,9 @@ def test_the_state_space_cell_s_step_compiles_and_fits_the_chip(one_chip, monkey
     # starts from what the forward wrote (3 until PR 34)
     assert programs.kernel_calls_by_group(scopes, HYBRID_STEP_GROUPS)["attn_core"] == 2
     assert programs.kernel_calls_by_pass(scopes, HYBRID_STEP_GROUPS) == {
+        "ssm": {"forward": 9, "recompute": 9, "backward": 9, "other": 0},
         "attn_core": {"forward": 1, "recompute": 0, "backward": 1, "other": 0}}
+    assert programs.kernel_calls_by_group(scopes, SSM_GROUPS)["ssm_scan"] == 27
 
 
 def test_the_long_cell_s_step_keeps_its_forwards_and_fits_the_chip(one_chip, monkeypatch):

@@ -839,12 +839,46 @@ def test_the_scan_s_choice_and_the_layers_built_are_reported():
     scan = next(e for e in events if e["name"] == "ssm.impl.xla")
     assert scan["labels"] == {
         "shape": [2, 27, 4, 16], "heads": 4, "head_dim": 16, "state": 16,
-        "chunk": 8, "chunks": 4, "padded": 5}
+        "chunk": 8, "chunks": 4, "padded": 5, "head_block": 0}
     layer = next(e for e in events if e["name"] == "decoder.layer.mamba2")
     assert layer["labels"] == {"layer": 0, "heads": 4, "state": 16, "chunk": 8}
     report = _trace_report()
     assert report.chosen_paths(events, "ssm.impl.") == "xla x4 at [2, 27, 4, 16]"
     assert "mamba2 x4" in report.chosen_paths(events, "decoder.layer.")
+
+
+def test_a_capture_that_holds_both_of_the_scan_s_paths_reports_both(monkeypatch):
+    """What the Granite cell's run leaves on the bus: the weight draw's
+    scans on the XLA form (``initializing``), the step's on the kernels
+    (the rule answered as on the chip; ``eval_shape`` traces and runs
+    nothing), and a traced backward counted ``ssm.bwd.pallas``. The
+    report's line for the state-space scan names both paths with their
+    shapes."""
+    import jax.numpy as jnp
+
+    from distributeddeeplearning_tpu.ops import ssm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    obs.reset()
+    sizes = dict(xs=(2, 512, 16, 64), dt=(2, 512, 16), a=(16,), b=(2, 512, 1, 128), d=(16,))
+    like = lambda name: jax.ShapeDtypeStruct(sizes[name], jnp.float32)  # noqa: E731
+    args = tuple(like(n) for n in ("xs", "dt", "a", "b", "b", "d"))
+    for initializing in (True, True, False):
+        jax.eval_shape(
+            lambda *v, i=initializing: jax.grad(
+                lambda *w: jnp.sum(ssm.ssd_scan(*w, chunk=256, initializing=i))
+            )(*v), *args,
+        )
+    events = list(obs.get_bus().ring)
+    totals = obs.get_bus().totals()
+    obs.reset()
+    assert totals["ssm.impl.xla"]["count"] == 2 and totals["ssm.impl.pallas"]["count"] == 1
+    report = _trace_report()
+    assert report.chosen_paths(events, "ssm.impl.") == (
+        "pallas x1 at [2, 512, 16, 64], xla x2 at [2, 512, 16, 64]"
+    )
+    assert report.chosen_paths(events, "ssm.bwd.") == "pallas x1 at [2, 512, 16, 64]"
 
 
 # -- the step by pass: forward, remat's recomputed forward, backward ----------
