@@ -9,8 +9,11 @@ One file, and the topology inside a fixture: only the worker that is
 given this file loads the TPU's library.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -568,7 +571,18 @@ def test_the_block_diffusion_cell_s_step_lowers_as_it_did_and_fits_the_chip(
         "forward": 18, "recompute": 18, "backward": 18, "other": 0}
 
 
-def test_the_latent_attention_cell_s_step_compiles_and_fits_the_chip(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def glm_step(one_chip):
+    """``glm-4.7-flash-train-t8k``'s train step, compiled once for the
+    tests that read it (``_cell_step``'s four results)."""
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        return _cell_step(
+            one_chip, monkeypatch, "t8k.json", "glm-4.7-flash.json",
+            experts_held="n_routed_experts", max_seq_len="max_position_embeddings",
+        )
+
+
+def test_the_latent_attention_cell_s_step_compiles_and_fits_the_chip(glm_step):
     """``glm-4.7-flash-train-t8k``'s whole train step (the dense layer,
     four expert layers and the MTP module at the published widths, 8
     experts held, 2 rows of 8,192 tokens, AdamW, block remat) for the
@@ -585,10 +599,7 @@ def test_the_latent_attention_cell_s_step_compiles_and_fits_the_chip(one_chip, m
         SHARED_MOE_GROUPS,
     )
 
-    compiled, state, totals, named = _cell_step(
-        one_chip, monkeypatch, "t8k.json", "glm-4.7-flash.json",
-        experts_held="n_routed_experts", max_seq_len="max_position_embeddings",
-    )
+    compiled, state, totals, named = glm_step
     # one jitted core, traced once: 2 rows x 8,192 x 20 heads x 256 of
     # output in bfloat16 and the logsumexp, kept a layer by block remat
     assert named == [170.0]
@@ -599,9 +610,85 @@ def test_the_latent_attention_cell_s_step_compiles_and_fits_the_chip(one_chip, m
     scopes = programs.parse_hlo_scopes(compiled.as_text())
     for groups in (MTP_STEP_GROUPS, MLA_GROUPS, SHARED_MOE_GROUPS):
         assert programs.groups_in(scopes, groups) == {g for g, _ in groups}
-    assert programs.kernel_calls_by_pass(scopes, MLA_GROUPS)["attn_full"] == {
-        "forward": 6, "recompute": 0, "backward": 6, "other": 0}
+    by_pass = programs.kernel_calls_by_pass(scopes, MLA_GROUPS)
+    assert by_pass["attn_full"] == {"forward": 6, "recompute": 0, "backward": 6, "other": 0}
+    # q's rotary lanes (``ops/pallas/rope_lanes``): the forward, the layer's
+    # own replay that makes q again for the core's backward, the backward
+    assert by_pass["mla_latent"] == {"forward": 6, "recompute": 6, "backward": 6, "other": 0}
     by_pass = programs.kernel_calls_by_pass(scopes, MTP_STEP_GROUPS)
     assert by_pass["attn_core"] == {"forward": 5, "recompute": 0, "backward": 5, "other": 0}
-    # the MTP block's core and its expert layer's row kernels
-    assert by_pass["mtp"]["recompute"] == 0 and by_pass["mtp"]["forward"] >= 1
+    # the MTP block's core and its expert layer's row kernels are not run
+    # again; its queries' rotary kernel is, once
+    assert by_pass["mtp"]["recompute"] == 1 and by_pass["mtp"]["forward"] >= 1
+
+
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2, "u16": 2,
+          "f32": 4, "s32": 4, "u32": 4}
+# what a data-movement instruction is named after: its opcode, or a
+# fusion's root (`pad_maximum_fusion`, `slice_convert_fusion`); the async
+# `copy-start` / `copy-done` pairs move between memory spaces and are left out
+_MOVES = re.compile(r"(copy|slice|concatenate|pad|transpose)[._]")
+_RESULT = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*?) [a-z][a-z0-9\-]*\(")
+_ARRAY = re.compile(r"\b([a-z]+[0-9]*)\[([0-9,]*)\]")
+
+
+def movement(text, scope):
+    """``[(pass, kind, shapes, bytes)]``: the data-movement instructions of
+    compiled HLO ``text`` that run as operations of their own (outside
+    fused computations) under a path that holds ``scope``, with their
+    results' shapes and bytes."""
+    fused = set(re.findall(r"\bcalls=%?([\w.\-]+)", text))
+    rows, inside = [], None
+    for line in text.splitlines():
+        if line.startswith(("%", "ENTRY")) and line.rstrip().endswith("{"):
+            name = line.split()[1 if line.startswith("ENTRY") else 0].lstrip("%")
+            inside = name not in fused
+            continue
+        m = _RESULT.match(line)
+        op = re.search(r'op_name="([^"]*)"', line)
+        if not (inside and m and op and _MOVES.match(m.group(1)) and scope.search(op.group(1))):
+            continue
+        shapes = [
+            (dtype, tuple(int(n) for n in dims.split(",") if n))
+            for dtype, dims in _ARRAY.findall(m.group(2))
+        ]
+        size = sum(_BYTES[d] * int(np.prod(s)) for d, s in shapes)
+        kind = _MOVES.match(m.group(1)).group(1)
+        rows.append((programs.pass_of(op.group(1)), kind, shapes, size))
+    return rows
+
+
+def test_latent_attention_moves_no_q_k_or_v_between_its_products_and_the_cores(glm_step):
+    """In the GLM cell's compiled step, the data movement under
+    ``mla_latent`` and ``attn_full`` by pass (copies, slices, the
+    concatenation and pad fusions, transposes that run as operations of
+    their own; printed under ``pytest -s``): the forward and the replays
+    hold none of the size of q, k or v, ``[2, 8192, 20, >= 64]`` or
+    ``[2·8192, >= 5120]`` in whatever view, where the expanded form wrote
+    1,282 MiB a layer in each; the backward (the flash wrapper's own
+    copies of ``dq``/``dk``/``dv`` and the rotary key's gradient) at most
+    0.6 GiB a layer of the six."""
+    compiled = glm_step[0]
+    rows, tokens, heads, width = 2, 8192, 20, 20 * 256
+    moves = movement(compiled.as_text(), re.compile(r"(?:^|/)(?:mla_latent|attn_full)(?:/|$)"))
+    table = {}
+    for passed, kind, _, size in moves:
+        table[passed, kind] = table.get((passed, kind), 0) + size / 2**20
+    for (passed, kind), mib in sorted(table.items()):
+        print(f"{passed:10s} {kind:12s} {mib:9.1f} MiB")
+
+    def of_q_k_or_v(shape):
+        lead = 2 if shape[:2] == (rows, tokens) else 1 if shape[:1] == (rows * tokens,) else 0
+        rest = shape[lead:] if lead else None
+        return rest is not None and (
+            (len(rest) == 1 and rest[0] >= width)
+            or (len(rest) == 2 and rest[0] == heads and rest[1] >= 64)
+        )
+
+    large = [
+        (passed, kind, shapes) for passed, kind, shapes, _ in moves
+        if passed in ("forward", "recompute") and any(of_q_k_or_v(s) for _, s in shapes)
+    ]
+    assert not large, large
+    backward = sum(size for passed, _, _, size in moves if passed == "backward")
+    assert 0 < backward <= 6 * 0.6 * 2**30, backward / 2**30

@@ -20,8 +20,10 @@ import numpy as np
 import pytest
 
 from benchmarks.references import glm as ref
+from distributeddeeplearning_tpu import obs
 from distributeddeeplearning_tpu.models import decoder, get_model
 from distributeddeeplearning_tpu.ops import moe
+from distributeddeeplearning_tpu.ops.attention import dot_product_attention
 
 L, VOCAB = 32, 96
 SPEC = decoder.SPECS["glm_tiny"]
@@ -105,6 +107,103 @@ def test_the_latent_attention_layer_matches_the_reference(attn_impl):
     # the rotary part alone: over every dim of q and k it is another layer
     other = ref._mla(x, p, POSITIONS, ref.sizes(config(without=["partial_rope"])), None)
     assert gap(got, other) > 1e-2
+
+
+def _expanded(p, x, positions, spec):
+    """Latent attention in the expanded form as ``MlaAttention`` wrote it
+    until q, k and v came straight from their products: ``q_b``'s output
+    split ``[q_nope | q_rope]`` and joined again round ``rotary``, ``kv_b``'s
+    split into ``k_nope`` and ``v``, the rotary key broadcast to every head
+    and joined to ``k_nope``; the einsum core."""
+    b, t, _ = x.shape
+    h, hd, r = spec.heads, spec.head_dim, spec.qk_rope_dim
+    nope = hd - r
+
+    def rms(v, scale):
+        return v * jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True) + spec.norm_eps) * scale
+
+    c_q = rms(x @ p["q_a"]["kernel"], p["q_norm"]["scale"])
+    q = (c_q @ p["q_b"]["kernel"]).reshape(b, t, h, hd)
+    c_kv, k_rope = jnp.split(x @ p["kv_a"]["kernel"], [spec.kv_rank], axis=-1)
+    kv = (rms(c_kv, p["kv_norm"]["scale"]) @ p["kv_b"]["kernel"]).reshape(b, t, h, nope + hd)
+    k_nope, v = kv[..., :nope], kv[..., nope:]
+    q = jnp.concatenate(
+        [q[..., :nope], decoder.rotary(q[..., nope:], positions, spec.rope_theta)], axis=-1
+    )
+    k_rope = decoder.rotary(k_rope.reshape(b, t, 1, r), positions, spec.rope_theta)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, t, h, r))], axis=-1)
+    out = dot_product_attention(q, k, v, causal=True, impl="xla")
+    return out.reshape(b, t, h * hd) @ p["o"]["kernel"]
+
+
+# glm_tiny; three heads of 32 with a rotary part of 8 (the kernel's block
+# is the whole row); two of 256 with the published 64 (its block the last
+# 128 lanes of a head)
+LATENT_SHAPES = {
+    "glm_tiny": {},
+    "h3_d32_r8": dict(heads=3, kv_heads=3, head_dim=32, qk_rope_dim=8),
+    "h2_d256_r64": dict(heads=2, kv_heads=2, head_dim=256, qk_rope_dim=64),
+}
+
+
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
+@pytest.mark.parametrize("shape", list(LATENT_SHAPES))
+def test_the_products_form_equals_the_expanded_form(shape, attn_impl):
+    """q, k and v as the products write them (q's rotary lanes turned in
+    place: XLA's roll, or the kernels of ``ops/pallas/rope_lanes`` in
+    interpret mode; k's product over ``[c_kv | k_rope]`` with an identity
+    under its rotary lanes; v's over ``kv_b``'s v columns) against the
+    expanded form, float32: the layer's output and its gradients with
+    respect to ``x`` and the five kernels. The parameter tree is the one
+    the expanded form reads. A partner lane taken the wrong way, or one
+    lane off, moves the output by its own order."""
+    spec = dataclasses.replace(SPEC, **LATENT_SHAPES[shape])
+    layer = decoder.MlaAttention(spec, jnp.float32, attn_impl)
+    x = _x()
+    p = layer.init(jax.random.PRNGKey(7), x, POSITIONS)["params"]
+    p = jax.tree_util.tree_map_with_path(  # latents loud enough that attention is not uniform
+        lambda path, w: 10.0 * w if "q_b" in str(path) or "kv_b" in str(path) else w, p
+    )
+    assert {k: v["kernel"].shape for k, v in p.items() if "kernel" in v} == {
+        "q_a": (64, spec.q_rank), "q_b": (spec.q_rank, spec.heads * spec.head_dim),
+        "kv_a": (64, spec.kv_rank + spec.qk_rope_dim),
+        "kv_b": (spec.kv_rank, spec.heads * (2 * spec.head_dim - spec.qk_rope_dim)),
+        "o": (spec.heads * spec.head_dim, 64),
+    }
+    g = jax.random.normal(jax.random.PRNGKey(8), (2, L, 64))
+
+    def objective(fn):
+        return lambda p, x: jnp.sum(fn(p, x) * g)
+
+    got = layer.apply({"params": p}, x, POSITIONS)
+    want = _expanded(p, x, POSITIONS, spec)
+    assert gap(got, want) < 1e-5
+    (dp_got, dx_got) = jax.grad(
+        objective(lambda p, x: layer.apply({"params": p}, x, POSITIONS)), argnums=(0, 1)
+    )(p, x)
+    (dp_want, dx_want) = jax.grad(
+        objective(lambda p, x: _expanded(p, x, POSITIONS, spec)), argnums=(0, 1)
+    )(p, x)
+    assert gap(dx_got, dx_want) < 1e-5
+    for name in ("q_a", "q_b", "kv_a", "kv_b", "o"):
+        assert gap(dp_got[name]["kernel"], dp_want[name]["kernel"]) < 1e-5, name
+    # the rotary part turned the other way is another layer
+    assert gap(got, _expanded(p, x, -POSITIONS, spec)) > 1e-3
+
+
+def test_a_latent_layer_is_counted_with_the_form_it_runs():
+    """``decoder.layer.mla`` names the products form and k's padded width,
+    once for each latent layer the trace builds: three blocks, and the
+    MTP block's at the held depth."""
+    obs.reset()
+    jax.eval_shape(
+        lambda: _model().init(jax.random.PRNGKey(0), jnp.zeros((1, L), jnp.int32), train=False)
+    )
+    counted = [e["labels"] for e in obs.get_bus().ring if e.get("name") == "decoder.layer.mla"]
+    obs.reset()
+    assert sorted(c["layer"] for c in counted) == [0, 1, 2, SPEC.layers]
+    assert all(c["qkv"] == "products" for c in counted)
+    assert all(c["k_width"] == SPEC.heads * SPEC.head_dim for c in counted)
 
 
 def test_the_sigmoid_router_chooses_by_score_and_bias_and_gates_by_score():
@@ -336,9 +435,12 @@ def test_the_selection_bias_is_state_the_step_carries_and_no_parameter():
     assert float(metrics["loss"]) > float(np.log(VOCAB))  # the MTP term rides on it
 
 
-@pytest.mark.parametrize("fault", ["kv_heads", "rope_odd", "window", "mtp_tied", "router"])
+@pytest.mark.parametrize(
+    "fault", ["kv_heads", "rope_odd", "window", "mtp_tied", "router", "bias"]
+)
 def test_a_spec_that_cannot_be_built_is_refused(fault):
     change = {
+        "bias": {"bias": True},  # k and v are products of kv_b's columns, with no bias
         "kv_heads": {"kv_heads": 2},
         "rope_odd": {"qk_rope_dim": 3},
         "window": {"pattern": (decoder.LayerKind(8, True),)},
