@@ -20,7 +20,8 @@ from distributeddeeplearning_tpu.ops.attention import (
     kernel_interpreted,
     resolve_impl,
 )
-from distributeddeeplearning_tpu.ops.pallas.flash import Mask, flash_attention
+from distributeddeeplearning_tpu.ops.pallas import flash
+from distributeddeeplearning_tpu.ops.pallas.flash import Mask, flash_attention, flash_attention_stats
 from distributeddeeplearning_tpu.parallel.mesh import create_mesh
 from distributeddeeplearning_tpu.parallel.ring_attention import ring_attention
 
@@ -240,8 +241,6 @@ def test_the_fused_backward_is_counted_once_a_traced_backward():
     a traced backward of the flash kernels, with the operands' shape as
     the kernels take them, the query heads a key head and the mask; a
     forward alone counts none."""
-    from distributeddeeplearning_tpu.ops.pallas.flash import flash_attention_stats
-
     q = jnp.zeros((1, 64, 4, 128))
     kv = jnp.zeros((1, 64, 2, 128))
 
@@ -290,26 +289,28 @@ def _rel_gap(a, b):
 @pytest.mark.parametrize(
     "t,h,kv,d,window,block",
     [
-        (1024, 2, 1, 128, 40, 64),    # a window inside one block
-        (1024, 2, 1, 128, 64, 64),    # = a block
-        (1024, 2, 1, 128, 65, 64),    # a block and one key
-        (1024, 2, 1, 128, 100, 64),   # between blocks
-        (1024, 2, 1, 128, 512, 64),   # = a resident block of eight
-        (1024, 2, 1, 128, 2, 64),     # a query and the key before it
-        (1024, 2, 1, 128, 5000, 64),  # covers the sequence: the whole triangle
-        (1024, 7, 1, 128, 200, 128),  # seven query heads a key head, in place
-        (1000, 2, 2, 128, 200, 128),  # a sequence that pads
-        (700, 4, 2, 64, 130, 128),    # narrow heads, two a program
+        (512, 2, 1, 128, 20, 32),    # a window inside one block
+        (512, 2, 1, 128, 32, 32),    # = a block
+        (512, 2, 1, 128, 33, 32),    # a block and one key
+        (512, 2, 1, 128, 50, 32),    # between blocks
+        (512, 2, 1, 128, 128, 32),   # = a resident block of four
+        (512, 2, 1, 128, 2, 32),     # a query and the key before it
+        (512, 2, 1, 128, 5000, 32),  # covers the sequence: the whole triangle
+        (512, 7, 1, 128, 100, 64),   # seven query heads a key head, in place
+        (500, 2, 2, 128, 100, 64),   # a sequence that pads
+        (350, 4, 2, 64, 65, 64),     # narrow heads, two a program
     ],
     ids=["lt-block", "eq-block", "block+1", "between", "eq-resident", "two",
          "ge-seq", "rep7", "pads", "narrow"],
 )
-def test_the_window_kernels_match_the_einsum_over_the_band(t, h, kv, d, window, block):
+def test_the_window_kernels_match_the_einsum_over_the_band(monkeypatch, t, h, kv, d, window,
+                                                           block):
     """``Mask(causal, window=w)`` in the flash kernels (interpret mode,
-    sixteen blocks in two resident ones at the first shapes) against the
-    einsum over the same band: the output and all three gradients."""
-    from distributeddeeplearning_tpu.ops.pallas.flash import flash_attention_stats
-
+    four blocks a resident block: sixteen blocks in four resident ones at
+    the first shapes, a static branch a live span of the four) against
+    the einsum over the same band: the output and all three gradients."""
+    monkeypatch.setattr(flash, "_TILE_ELEMS", 4 * block * block)
+    assert flash._plan(t, block).major > 1
     q, k, v = _window_operands(t, h, kv, d)
 
     def kernel(q, k, v):
@@ -327,10 +328,15 @@ def test_the_window_kernels_match_the_einsum_over_the_band(t, h, kv, d, window, 
         "bhqk,bkhd->bqhd", jax.nn.softmax(jnp.where(band, s, -jnp.inf), -1),
         jnp.repeat(v, h // kv, 2),
     )
-    assert _rel_gap(einsum(q, k, v), want) < 1e-5  # the einsum path masks the band
-    assert _rel_gap(kernel(q, k, v), want) < 1e-5
-    got = jax.grad(lambda *a: jnp.sum(jnp.sin(kernel(*a))), (0, 1, 2))(q, k, v)
-    ref = jax.grad(lambda *a: jnp.sum(jnp.sin(einsum(*a))), (0, 1, 2))(q, k, v)
+    def run(f):  # one program a side: the gradients, and the output as their aux
+        def g(*a):
+            out = f(*a)
+            return jnp.sum(jnp.sin(out)), out
+        return jax.jit(jax.grad(g, (0, 1, 2), has_aux=True))(q, k, v)
+
+    (got, out), (ref, ref_out) = run(kernel), run(einsum)
+    assert _rel_gap(ref_out, want) < 1e-5  # the einsum path masks the band
+    assert _rel_gap(out, want) < 1e-5
     for a, b in zip(got, ref):
         assert _rel_gap(a, b) < 1e-5
 
@@ -346,8 +352,6 @@ def test_the_pallas_entry_takes_the_window_and_the_other_paths_refuse_it():
     with pytest.raises(ValueError, match="window"):
         dot_product_attention(q, k, v, causal=True, window=300, impl="ring")
     with pytest.raises(ValueError, match="window"):
-        from distributeddeeplearning_tpu.ops.pallas.flash import flash_attention_stats
-
         flash_attention_stats(q, k, v, mask=Mask(True, 4, window=300), interpret=True)
 
 
@@ -362,8 +366,6 @@ def test_a_block_behind_the_window_is_neither_computed_nor_fetched(t, b, window,
     the steps whose own resident block does, and ``_band_steps`` counts
     those: at the cell's shapes (16,384 rows, blocks of 512, two a
     resident block) 112 of a head's 512 steps forward."""
-    from distributeddeeplearning_tpu.ops.pallas import flash
-
     plan = flash._plan(t, b)
     _, walk, _, _, _ = flash._specs(
         plan, plan, 128, 1, 1, True, owner_first=owner_first, window=window
@@ -396,8 +398,6 @@ def test_the_window_walk_is_counted_forward_and_backward():
     backward; a window that covers the sequence ``dot_product_
     attention`` hands on as the causal rule, whose kernels count
     nothing."""
-    from distributeddeeplearning_tpu.ops.pallas import flash
-
     q, k, v = (jnp.zeros((1, 1024, 1, 128)),) * 3
 
     def core(window):
@@ -505,8 +505,6 @@ def test_flash_bwd_kernels_match_scan_reference(monkeypatch, case):
     lengths that span several blocks on both axes, so the causal skip
     and the padding masks are exercised; every row of dq, dk and dv is
     compared, so every block's."""
-    from distributeddeeplearning_tpu.ops.pallas import flash
-
     t, heads, kv, d, block = (case[x] for x in ("t", "heads", "kv", "d", "block"))
     mask = flash._as_mask(case["mask"])
     rep = heads // kv
@@ -530,9 +528,9 @@ def test_flash_bwd_kernels_match_scan_reference(monkeypatch, case):
     def narrow(dx):  # and their gradients summed back
         return dx.reshape(2, t, kv, rep, d).sum(3).reshape(2, t, -1)
 
-    dq, dk, dv = flash._flash_bwd_scan(
-        heads, mask, scale, block, True, (q, wide(k), wide(v), out, lse), do, dlse=dlse
-    )
+    dq, dk, dv = jax.jit(lambda *res: flash._flash_bwd_scan(
+        heads, mask, scale, block, True, res, do, dlse=dlse
+    ))(q, wide(k), wide(v), out, lse)
     for name, a, b in zip(("dq", "dk", "dv"), got, (dq, narrow(dk), narrow(dv))):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=2e-4, err_msg=name
@@ -551,7 +549,7 @@ def test_flash_bwd_kernels_match_scan_reference(monkeypatch, case):
         return out.reshape(2, t, -1), stat.transpose(0, 2, 1)
 
     cts = (do, dlse if case["dlse"] else jnp.zeros((2, t, heads)))
-    want = jax.vjp(dense, q, k, v)[1](cts)
+    want = jax.jit(lambda q, k, v: jax.vjp(dense, q, k, v)[1](cts))(q, k, v)
     for name, a, b in zip(("dq", "dk", "dv"), got, want):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=1e-4, err_msg=name
@@ -562,8 +560,6 @@ def test_flash_bwd_refuses_more_of_dq_than_vmem_holds(monkeypatch):
     """dq's sums for the whole query side stay in VMEM; a call whose
     padded length x heads a program x width is over the budget is told
     so, not handed to the compiler."""
-    from distributeddeeplearning_tpu.ops.pallas import flash
-
     monkeypatch.setattr(flash, "_DQ_VMEM", 2 * 256 * 128 * 4 - 1)
     q, k, v = _qkv(b=1, t=256, h=2, d=64)
     flash_attention(q, k, v, causal=True)  # the forward holds no such sums
@@ -578,24 +574,23 @@ def test_flash_matches_xla_at_gpt2_head_width(monkeypatch, tile_elems):
     both axes, the last one padded. With a small tile budget the walked
     operand lies in two resident blocks, so the accumulators cross grid
     steps and whole resident blocks above the diagonal are skipped."""
-    from distributeddeeplearning_tpu.ops.pallas import flash
-
     if tile_elems:
         monkeypatch.setattr(flash, "_TILE_ELEMS", tile_elems)
         assert flash._plan(300, 128) == (128, 2, 2)
     q, k, v = _qkv(b=2, t=300, h=2, d=64, seed=1)
     w = _qkv(b=2, t=300, h=2, d=64, seed=2)[0]
 
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+    def run(fn):  # one program a side: the gradients, and the output as their aux
+        def loss(q, k, v):
+            out = fn(q, k, v)
+            return jnp.sum(out * w), out
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
 
-    kernel = lambda q, k, v: flash_attention(q, k, v, causal=True)
-    xla = lambda q, k, v: dot_product_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(
-        np.asarray(kernel(q, k, v)), np.asarray(xla(q, k, v)), atol=1e-5
+    (g_kernel, out), (g_xla, want) = (
+        run(lambda q, k, v: flash_attention(q, k, v, causal=True)),
+        run(lambda q, k, v: dot_product_attention(q, k, v, causal=True)),
     )
-    g_kernel = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
-    g_xla = jax.grad(loss(xla), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
     for name, a, b in zip(("dq", "dk", "dv"), g_kernel, g_xla):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=1e-4, err_msg=name
@@ -634,13 +629,17 @@ def test_flash_qkv_reads_the_packed_projection_in_place(b, t, h, d, causal, bloc
     rng = np.random.RandomState(11)
     qkv = jnp.asarray(rng.randn(b, t, 3 * h * d).astype(np.float32))
     w = jnp.asarray(rng.randn(b, t, h * d).astype(np.float32))
-    kernel = lambda x: flash_qkv_attention(x, h, causal=causal, block=block)
-    ref = lambda x: _packed_ref(x, h, causal)
-    np.testing.assert_allclose(
-        np.asarray(kernel(qkv)), np.asarray(ref(qkv)), atol=1e-5
+    def run(fn):  # one program a side: the gradient, and the output as its aux
+        def loss(x):
+            out = fn(x)
+            return jnp.sum(out * w), out
+        return jax.jit(jax.grad(loss, has_aux=True))(qkv)
+
+    (g_kernel, out), (g_ref, want) = (
+        run(lambda x: flash_qkv_attention(x, h, causal=causal, block=block)),
+        run(lambda x: _packed_ref(x, h, causal)),
     )
-    g_kernel = jax.grad(lambda x: jnp.sum(kernel(x) * w))(qkv)
-    g_ref = jax.grad(lambda x: jnp.sum(ref(x) * w))(qkv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
     np.testing.assert_allclose(np.asarray(g_kernel), np.asarray(g_ref), atol=1e-4)
     with pytest.raises(ValueError):
         flash_qkv_attention(jnp.zeros((1, 64, 3 * 2 * 96)), 2)  # d = 96
@@ -664,8 +663,6 @@ def test_flash_block_rule(t, block):
     ],
 )
 def test_flash_supports_gating(t, h, d, ok):
-    from distributeddeeplearning_tpu.ops.pallas import flash
-
     assert flash.supports(t, h, d) is ok
 
 

@@ -7,6 +7,7 @@ small size: hidden 64, 2 layers, 4/2 heads of 16, 8 experts top-2,
 L = 32, B = 4, seeded weights, float32."""
 
 import dataclasses
+import functools
 import hashlib
 
 import jax
@@ -14,9 +15,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks.references import granite
 from benchmarks.references import sdar as ref
+from benchmarks.references import smallthinker as st
 from distributeddeeplearning_tpu.data.noise import block_diffusion_noise
-from distributeddeeplearning_tpu.models import get_model
+from distributeddeeplearning_tpu.models import decoder, get_model
 from distributeddeeplearning_tpu.ops import moe
 from distributeddeeplearning_tpu.ops.attention import (
     block_diffusion_attention,
@@ -52,15 +55,22 @@ def gap(a, b):
 # -- the model against the reference -----------------------------------------
 
 @pytest.fixture(scope="module")
-def noised():
-    return ref.noise_rows(clean_rows(), config())
+def sdar_reference():
+    """Seeded weights, the noised rows, and the reference's logits, loss
+    and gradients on them: computed once, for both implementations."""
+    cfg = config()
+    params = ref.init_params(cfg, 7)
+    inputs, targets, weights = (jnp.asarray(x) for x in ref.noise_rows(clean_rows(), cfg))
+    want, _ = jax.jit(lambda p: ref.forward(p, inputs, cfg))(params)
+    lr, gr = jax.jit(jax.value_and_grad(
+        lambda p: ref.diffusion_loss(p, inputs, targets, weights, cfg)
+    ))(params)
+    return params, (inputs, targets, weights), want, lr, gr
 
 
 @pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
-def test_the_model_matches_the_reference_on_logits_loss_and_gradients(noised, attn_impl):
-    cfg = config()
-    params = ref.init_params(cfg, 7)
-    inputs, targets, weights = (jnp.asarray(x) for x in noised)
+def test_the_model_matches_the_reference_on_logits_loss_and_gradients(sdar_reference, attn_impl):
+    params, (inputs, targets, weights), want, lr, gr = sdar_reference
     model = get_model(
         "sdar_tiny", num_classes=VOCAB, dtype="float32", attn_impl=attn_impl
     )
@@ -69,10 +79,8 @@ def test_the_model_matches_the_reference_on_logits_loss_and_gradients(noised, at
         logits = model.apply({"params": params}, inputs, train=True)
         return weighted_cross_entropy_loss(logits, targets, weights), logits
 
-    (l, logits), grads = jax.value_and_grad(loss, has_aux=True)(params)
-    want, _ = ref.forward(params, inputs, cfg)
+    (l, logits), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
     assert logits.shape == (3, L, VOCAB) and gap(logits, want) < 1e-4
-    lr, gr = jax.value_and_grad(ref.diffusion_loss)(params, inputs, targets, weights, cfg)
     assert abs(float(l) - float(lr)) < 1e-5 * abs(float(lr))
     flat_got, flat_want = ref.flatten(grads), ref.flatten(gr)
     assert set(flat_got) == set(flat_want)
@@ -126,19 +134,25 @@ def _logits_and_grads(model, params, tokens):
         logits = model.apply({"params": p}, tokens, train=False)
         return jnp.sum(logits ** 2) / logits.size, logits
 
-    (_, logits), grads = jax.value_and_grad(loss, has_aux=True)(params)
+    (_, logits), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
     return logits, grads
 
 
-@pytest.mark.parametrize("against", ["pallas", "repeated_key_heads"])
-def test_the_causal_spec_model_with_grouped_heads(against):
-    """The einsum over grouped heads against the flash kernels (interpret
-    mode), and against the einsum over the same weights with every key
-    and value head's projection written out once a query head."""
+@pytest.fixture(scope="module")
+def grouped_heads():
+    """The einsum's side of both comparisons below, computed once."""
     tokens = jnp.asarray(clean_rows(2))
     model = _causal_sdar("xla")
     params = model.init(jax.random.PRNGKey(2), tokens, train=False)["params"]
-    logits, grads = _logits_and_grads(model, params, tokens)
+    return model, params, tokens, _logits_and_grads(model, params, tokens)
+
+
+@pytest.mark.parametrize("against", ["pallas", "repeated_key_heads"])
+def test_the_causal_spec_model_with_grouped_heads(grouped_heads, against):
+    """The einsum over grouped heads against the flash kernels (interpret
+    mode), and against the einsum over the same weights with every key
+    and value head's projection written out once a query head."""
+    model, params, tokens, (logits, grads) = grouped_heads
     assert logits.shape == (2, L, VOCAB)
     if against == "pallas":
         got, got_grads = _logits_and_grads(_causal_sdar("pallas"), params, tokens)
@@ -192,8 +206,6 @@ def _st_params(cfg, seed):
     scores of a hundredth, so that every mask's softmax is all but
     uniform, and experts that add a thousandth of the residual stream;
     at the published width the scores are of order one, as here."""
-    from benchmarks.references import smallthinker as st
-
     params = st.init_params(cfg, seed)
     for i in range(cfg["layers"]):
         for name in ("q", "k"):
@@ -205,8 +217,22 @@ def _st_params(cfg, seed):
     return params
 
 
+@pytest.fixture(scope="module")
+def st_reference():
+    """Seeded weights, three rows, and what ``references/smallthinker.py``
+    gives on them (logits, the experts chosen, loss and gradients):
+    computed once, for both implementations."""
+    cfg = _st_config()
+    params = _st_params(cfg, 11)
+    rows = clean_rows(3, seed=5)
+    tokens, labels = jnp.asarray(rows), jnp.asarray(np.roll(rows, -1, axis=1))
+    want = jax.jit(lambda p: st.forward(p, tokens, cfg))(params)
+    lr, gr = jax.jit(jax.value_and_grad(lambda p: st.token_loss(p, tokens, labels, cfg)))(params)
+    return params, tokens, labels, want, lr, gr
+
+
 @pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
-def test_the_mixed_layer_model_matches_its_reference(attn_impl):
+def test_the_mixed_layer_model_matches_its_reference(st_reference, attn_impl):
     """``smallthinker_tiny`` (two periods of a full layer without
     positions and three window-8 layers with rotary ones, router on
     ``ln1``'s output, ReLU gate) against ``references/smallthinker.py``
@@ -214,12 +240,7 @@ def test_the_mixed_layer_model_matches_its_reference(attn_impl):
     the experts chosen. float32 on both sides: 1e-4 of the largest
     logit, 2e-3 of a leaf's largest gradient entry (a bfloat16 product
     would read 1e-2)."""
-    from benchmarks.references import smallthinker as st
-
-    cfg = _st_config()
-    params = _st_params(cfg, 11)
-    rows = clean_rows(3, seed=5)
-    tokens, labels = jnp.asarray(rows), jnp.asarray(np.roll(rows, -1, axis=1))
+    params, tokens, labels, (want, chosen), lr, gr = st_reference
     model = get_model(
         "smallthinker_tiny", num_classes=VOCAB, dtype="float32",
         attn_impl=attn_impl, max_seq_len=L,
@@ -233,14 +254,12 @@ def test_the_mixed_layer_model_matches_its_reference(attn_impl):
         picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
         return jnp.mean(logz - picked), (logits, seen)
 
-    (l, (logits, seen)), grads = jax.value_and_grad(loss, has_aux=True)(params)
-    want, chosen = st.forward(params, tokens, cfg)
+    (l, (logits, seen)), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
     assert logits.shape == (3, L, VOCAB) and gap(logits, want) < 1e-4
     got_chosen = jnp.stack([
         seen["intermediates"][f"block{i}"]["mlp"]["experts"][0] for i in range(8)
     ])
     assert bool(jnp.all(jnp.sort(got_chosen, -1) == jnp.sort(chosen, -1)))
-    lr, gr = jax.value_and_grad(st.token_loss)(params, tokens, labels, cfg)
     assert abs(float(l) - float(lr)) < 1e-5 * abs(float(lr))
     flat_got, flat_want = st.flatten(grads), st.flatten(gr)
     assert set(flat_got) == set(flat_want)
@@ -248,42 +267,39 @@ def test_the_mixed_layer_model_matches_its_reference(attn_impl):
         assert gap(flat_got[k], flat_want[k]) < 2e-3, k
 
 
-@pytest.mark.parametrize(
-    "variant", ["every_layer_full", "rope_everywhere", "router_after_attention", "silu"]
-)
-def test_the_reference_sees_each_thing_the_model_adds(variant):
-    """A reference without one of the four (the window, the layers
-    without positions, the router's input, the ReLU gate) is another
-    model: its logits leave the program's by far more than the 1e-4 the
-    sound one keeps."""
-    from benchmarks.references import smallthinker as st
-
-    cfg = _st_config()
-    params = _st_params(cfg, 11)
-    tokens = jnp.asarray(clean_rows(2, seed=5))
+@pytest.fixture(scope="module")
+def st_two_rows(st_reference):
+    """The program's logits on the first two rows and the sound
+    reference's: what each variant below is held against."""
+    params, tokens = st_reference[0], st_reference[1][:2]
     model = get_model(
         "smallthinker_tiny", num_classes=VOCAB, dtype="float32",
         attn_impl="xla", max_seq_len=L,
     )
-    logits = model.apply({"params": params}, tokens, train=False)
-    other = dict(cfg)
+    logits = jax.jit(lambda p: model.apply({"params": p}, tokens, train=False))(params)
+    return params, tokens, logits, jax.jit(lambda p: st.forward(p, tokens, _st_config())[0])(params)
+
+
+@pytest.mark.parametrize(
+    "variant", ["every_layer_full", "rope_everywhere", "router_after_attention", "silu"]
+)
+def test_the_reference_sees_each_thing_the_model_adds(st_two_rows, monkeypatch, variant):
+    """A reference without one of the four (the window, the layers
+    without positions, the router's input, the ReLU gate) is another
+    model: its logits leave the program's by far more than the 1e-4 the
+    sound one keeps."""
+    params, tokens, logits, sound = st_two_rows
+    other = _st_config()
     if variant == "every_layer_full":
         other["sliding_window_layout"] = [0] * 8
     elif variant == "rope_everywhere":
         other["rope_layout"] = [1] * 8
     elif variant == "router_after_attention":
         other["assumed"] = {"router_input": "ln2"}
-    if variant == "silu":
-        import benchmarks.references.smallthinker as module
-
-        relu, module.jax.nn.relu = jax.nn.relu, jax.nn.silu
-        try:
-            wrong, _ = st.forward(params, tokens, cfg)
-        finally:
-            module.jax.nn.relu = relu
-    else:
-        wrong, _ = st.forward(params, tokens, other)
-    assert gap(logits, st.forward(params, tokens, cfg)[0]) < 1e-4
+    if variant == "silu":  # read as the reference is traced
+        monkeypatch.setattr(st.jax.nn, "relu", jax.nn.silu)
+    wrong = jax.jit(lambda p: st.forward(p, tokens, other)[0])(params)
+    assert gap(logits, sound) < 1e-4
     assert gap(logits, wrong) > 1e-3
 
 
@@ -294,9 +310,6 @@ def test_the_eight_shares_of_a_mixed_layer_add_up_to_the_uncut_reference(kind):
     computes alike, the residual stream and attention, counted once)
     add up to what the uncut reference gives for the layer, with the
     router on ``ln1``'s output and the ReLU gate."""
-    from benchmarks.references import smallthinker as st
-    from distributeddeeplearning_tpu.models import decoder
-
     cfg = _st_config()
     s = st.sizes(cfg)
     layer = 0 if kind == "full-nope" else 1
@@ -316,10 +329,11 @@ def test_the_eight_shares_of_a_mixed_layer_add_up_to_the_uncut_reference(kind):
         )
         return block.apply({"params": {**p, "mlp": mlp}}, x, positions, False)
 
-    want, _ = st._layer(x, p, positions, s, None, s["windowed"][layer], s["rope"][layer])
-    no_experts = {**p, "mlp": {**p["mlp"], "w2": {"kernel": 0.0 * p["mlp"]["w2"]["kernel"]}}}
-    alike, _ = st._layer(x, no_experts, positions, s, None, s["windowed"][layer], s["rope"][layer])
-    parts = [share(first) - alike for first in range(8)]
+    uncut = jax.jit(lambda p: st._layer(x, p, positions, s, None, s["windowed"][layer],
+                                        s["rope"][layer])[0])
+    want = uncut(p)
+    alike = uncut({**p, "mlp": {**p["mlp"], "w2": {"kernel": 0.0 * p["mlp"]["w2"]["kernel"]}}})
+    parts = [part - alike for part in jax.jit(lambda: [share(first) for first in range(8)])()]
     assert gap(alike + sum(parts), want) < 1e-5
     assert gap(sum(parts), want - alike) < 1e-4  # the experts' part alone
     assert float(jnp.max(jnp.abs(want - alike))) > 1e-3
@@ -347,7 +361,9 @@ def test_the_specs_that_were_there_build_and_compute_what_they_did():
         ("gpt2_tiny", {"max_seq_len": L}, tokens),
     ):
         model = get_model(name, num_classes=VOCAB, dtype="float32", attn_impl="xla", **kw)
-        params = model.init(jax.random.PRNGKey(1), toks, train=False)["params"]
+        params = jax.eval_shape(  # the text and the tree read shapes alone
+            lambda: model.init(jax.random.PRNGKey(1), toks, train=False)
+        )["params"]
 
         def loss(p):
             return jnp.sum(
@@ -387,9 +403,7 @@ def _granite_config(**over):
 def _granite_params(cfg, seed):
     """The family's seeded weights, with ``D`` drawn too (its published
     initialisation is 1: a side that dropped it would not show)."""
-    from benchmarks.references import granite as gr
-
-    params = gr.init_params(cfg, seed)
+    params = granite.init_params(cfg, seed)
     for i, kind in enumerate(cfg["layer_types"][:cfg["layers"]]):
         if kind == "mamba":
             key = jax.random.fold_in(jax.random.PRNGKey(seed), i)
@@ -400,8 +414,24 @@ def _granite_params(cfg, seed):
 GRANITE_L = 27  # chunk 8: three chunks and a ragged fourth
 
 
+@pytest.fixture(scope="module")
+def granite_reference():
+    """Seeded weights, three rows, and what the recurrence reference gives
+    on them (logits, loss, gradients): computed once, for both
+    implementations and the variants below."""
+    cfg = _granite_config()
+    params = _granite_params(cfg, 11)
+    rows = np.random.default_rng(5).integers(0, VOCAB, (3, GRANITE_L), dtype=np.int32)
+    tokens, labels = jnp.asarray(rows), jnp.asarray(np.roll(rows, -1, axis=1))
+    want = jax.jit(lambda p: granite.forward(p, tokens, cfg))(params)
+    lr, gr = jax.jit(jax.value_and_grad(
+        lambda p: granite.token_loss(p, tokens, labels, cfg)
+    ))(params)
+    return cfg, params, tokens, labels, want, lr, gr
+
+
 @pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
-def test_the_state_space_model_matches_its_reference(attn_impl):
+def test_the_state_space_model_matches_its_reference(granite_reference, attn_impl):
     """``granite_tiny`` (two Mamba-2 layers, an attention layer without
     positions whose scores take the spec's scale, two more Mamba-2
     layers; a dense gated MLP; the four multipliers; tied head) against
@@ -409,12 +439,7 @@ def test_the_state_space_model_matches_its_reference(attn_impl):
     token recurrence, on seeded weights at L = 27: logits, loss, every
     leaf's gradient. float32 on both sides: 1e-5 of the largest logit,
     1e-4 of a leaf's largest gradient entry."""
-    from benchmarks.references import granite as gr
-
-    cfg = _granite_config()
-    params = _granite_params(cfg, 11)
-    rows = np.random.default_rng(5).integers(0, VOCAB, (3, GRANITE_L), dtype=np.int32)
-    tokens, labels = jnp.asarray(rows), jnp.asarray(np.roll(rows, -1, axis=1))
+    cfg, params, tokens, labels, want, lr, gr = granite_reference
     model = get_model(
         "granite_tiny", num_classes=VOCAB, dtype="float32", attn_impl=attn_impl,
         max_seq_len=32,
@@ -427,49 +452,47 @@ def test_the_state_space_model_matches_its_reference(attn_impl):
         return jnp.mean(logz - picked), logits
 
     (l, logits), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
-    want = jax.jit(lambda p, t: gr.forward(p, t, cfg))(params, tokens)
     assert logits.shape == (3, GRANITE_L, VOCAB) and gap(logits, want) < 1e-5
-    lr, gr_ = jax.jit(jax.value_and_grad(
-        lambda p: gr.token_loss(p, tokens, labels, cfg)
-    ))(params)
     assert abs(float(l) - float(lr)) < 1e-6 * abs(float(lr))
-    flat_got, flat_want = gr.flatten(grads), gr.flatten(gr_)
+    flat_got, flat_want = granite.flatten(grads), granite.flatten(gr)
     assert set(flat_got) == set(flat_want)
     for k in flat_want:
         assert gap(flat_got[k], flat_want[k]) < 1e-4, k
     # the tree is the one the family's shapes state, and block remat's is the same
-    assert {k: v.shape for k, v in flat_got.items()} == gr.param_shapes(cfg)
+    assert {k: v.shape for k, v in flat_got.items()} == granite.param_shapes(cfg)
 
 
-@pytest.mark.parametrize(
-    "variant", ["no_decay", "no_convolution", "gate_after_the_norm", "residual_one"]
-)
-def test_the_reference_sees_each_mechanism_of_the_state_space_layer(variant):
-    """A reference without one of the four (the state's decay, the
-    convolution, the gate before the norm, the residual multiplier) is
-    another model: its logits leave the program's by more than ten times
-    the 1e-5 the sound one keeps."""
-    from benchmarks.references import granite as gr
-
-    cfg = _granite_config()
-    params = _granite_params(cfg, 11)
-    tokens = jnp.asarray(
-        np.random.default_rng(5).integers(0, VOCAB, (2, GRANITE_L), dtype=np.int32)
-    )
+@pytest.fixture(scope="module")
+def granite_two_rows(granite_reference):
+    """The program's logits on the first two rows and the sound
+    reference's: what each variant below is held against."""
+    cfg, params, tokens = granite_reference[:3]
+    tokens = tokens[:2]
     model = get_model(
         "granite_tiny", num_classes=VOCAB, dtype="float32", attn_impl="xla",
         max_seq_len=32,
     )
     logits = jax.jit(lambda p: model.apply({"params": p}, tokens, train=False))(params)
-    other = {
+    return params, tokens, logits, jax.jit(lambda p: granite.forward(p, tokens, cfg))(params)
+
+
+@pytest.mark.parametrize(
+    "variant", ["no_decay", "no_convolution", "gate_after_the_norm", "residual_one"]
+)
+def test_the_reference_sees_each_mechanism_of_the_state_space_layer(granite_two_rows, variant):
+    """A reference without one of the four (the state's decay, the
+    convolution, the gate before the norm, the residual multiplier) is
+    another model: its logits leave the program's by more than ten times
+    the 1e-5 the sound one keeps."""
+    params, tokens, logits, sound = granite_two_rows
+    other = _granite_config(**{
         "no_decay": {"without": ["decay"]},
         "no_convolution": {"without": ["conv"]},
         "gate_after_the_norm": {"without": ["gate_first"]},
         "residual_one": {"residual_multiplier": 1.0},
-    }[variant]
-    reference = lambda c: jax.jit(lambda p: gr.forward(p, tokens, c))(params)  # noqa: E731
-    assert gap(logits, reference(cfg)) < 1e-5
-    assert gap(logits, reference(_granite_config(**other))) > 1e-4
+    }[variant])
+    assert gap(logits, sound) < 1e-5
+    assert gap(logits, jax.jit(lambda p: granite.forward(p, tokens, other))(params)) > 1e-4
 
 
 def test_the_published_state_space_spec_against_the_catalog_s_numbers():
@@ -477,8 +500,6 @@ def test_the_published_state_space_spec_against_the_catalog_s_numbers():
     for number, and at the cell's cut it builds the parameters ISSUE 33
     counts: 76,182,976 a Mamba-2 layer, 60,821,504 the attention layer,
     772,160,448 in ten layers and an eighth of the vocabulary."""
-    from distributeddeeplearning_tpu.models import decoder
-
     spec = decoder.SPECS["granite_4_0_h_micro"]
     assert (spec.hidden, spec.layers, spec.heads, spec.kv_heads, spec.head_dim) == (
         2048, 40, 32, 8, 2048 // 32)
@@ -505,8 +526,6 @@ def test_the_published_state_space_spec_against_the_catalog_s_numbers():
 
 @pytest.mark.parametrize("fault", ["block_len", "window", "rope", "heads", "mixer"])
 def test_a_state_space_spec_that_cannot_be_built_is_refused(fault):
-    from distributeddeeplearning_tpu.models import decoder
-
     spec = decoder.SPECS["granite_tiny"]
     bad = {
         "block_len": dict(block_len=4),
@@ -525,7 +544,8 @@ def test_a_state_space_spec_that_cannot_be_built_is_refused(fault):
 
 def test_the_model_s_own_draw_is_the_mixers_published_initialisation():
     model = get_model("granite_tiny", num_classes=VOCAB, dtype="float32", attn_impl="xla")
-    p = model.init(jax.random.PRNGKey(2), jnp.zeros((1, 16), jnp.int32), train=False)["params"]
+    p = jax.jit(lambda t: model.init(jax.random.PRNGKey(2), t, train=False))(
+        jnp.zeros((1, 16), jnp.int32))["params"]
     ssm = p["block0"]["ssm"]
     assert bool(jnp.all(ssm["D"] == 1.0))
     a = jnp.exp(ssm["A_log"])
@@ -563,6 +583,17 @@ def _qkv(length, heads, kv, d, seed=0):
     )
 
 
+def _dense(q, k, v, m):
+    """Softmax attention over the keys ``m [q, k]`` lets a query see (a
+    key head for its group of query heads), and the rows' logsumexp."""
+    rep = q.shape[2] // k.shape[2]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, rep, 2)) * q.shape[-1] ** -0.5
+    s = jnp.where(m, s, -1e30)
+    lse = jax.nn.logsumexp(s, -1)
+    p = jnp.where(m, jnp.exp(s - lse[..., None]), 0.0)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, jnp.repeat(v, rep, 2)), lse.transpose(0, 2, 1)
+
+
 def test_the_einsum_core_is_the_reference_s_dense_attention():
     q, k, v, _ = _qkv(L, 4, 2, 16)
     got = block_diffusion_attention(q, k, v, block_len=B, impl="xla")
@@ -578,15 +609,14 @@ def test_the_einsum_core_is_the_reference_s_dense_attention():
 def test_the_kernels_match_the_einsum_forward_and_backward(length, heads, kv, d):
     q, k, v, w = _qkv(length, heads, kv, d)
 
-    def loss(impl):
-        return lambda q, k, v: jnp.sum(
-            block_diffusion_attention(q, k, v, block_len=B, impl=impl) * w
-        )
+    def run(impl):  # one program a side: the gradients, and the output as their aux
+        def g(q, k, v):
+            out = block_diffusion_attention(q, k, v, block_len=B, impl=impl)
+            return jnp.sum(out * w), out
+        return jax.jit(jax.grad(g, (0, 1, 2), has_aux=True))(q, k, v)
 
-    out = block_diffusion_attention(q, k, v, block_len=B, impl="pallas")
-    assert gap(out, block_diffusion_attention(q, k, v, block_len=B, impl="xla")) < 1e-5
-    got = jax.grad(loss("pallas"), (0, 1, 2))(q, k, v)
-    want = jax.grad(loss("xla"), (0, 1, 2))(q, k, v)
+    (got, out), (want, want_out) = run("pallas"), run("xla")
+    assert gap(out, want_out) < 1e-5
     for a, b in zip(got, want):
         assert gap(a, b) < 1e-5
 
@@ -597,34 +627,27 @@ def test_a_block_causal_pass_gives_the_rows_logsumexp_and_its_gradient(strict):
     t = q.shape[1]
     live = jnp.arange(t) >= (B if strict else 0)  # the first block sees nothing
 
-    def dense(q, k, v):
-        kk, vv = jnp.repeat(k, 2, 2), jnp.repeat(v, 2, 2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * 128**-0.5
-        r, c = jnp.arange(t)[:, None] // B, jnp.arange(t)[None, :] // B
-        m = c < r if strict else c <= r
-        lse = jax.nn.logsumexp(jnp.where(m, s, -1e30), -1)
-        p = jnp.where(m, jnp.exp(jnp.where(m, s, -1e30) - lse[..., None]), 0.0)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, vv), lse.transpose(0, 2, 1)
+    r, c = jnp.arange(t)[:, None] // B, jnp.arange(t)[None, :] // B
+    dense = functools.partial(_dense, m=c < r if strict else c <= r)
 
     def kernel(q, k, v):
         return flash.flash_attention_stats(
             q, k, v, mask=flash.Mask(True, B, strict), block=64, interpret=True
         )
 
-    def loss(f):
+    def run(f):  # one program a side: the gradients, and the logsumexp as their aux
         def g(q, k, v):
             out, lse = f(q, k, v)
             return jnp.sum(jnp.where(live[None, :, None, None], out * w, 0.0)) + jnp.sum(
                 jnp.where(live[None, :, None], lse * w[..., 0], 0.0)
-            )
-        return g
+            ), lse
+        return jax.jit(jax.grad(g, (0, 1, 2), has_aux=True))(q, k, v)
 
-    got = jax.grad(loss(kernel), (0, 1, 2))(q, k, v)
-    want = jax.grad(loss(dense), (0, 1, 2))(q, k, v)
+    (got, lse), (want, _) = run(kernel), run(dense)
     for a, b in zip(got, want):
         assert bool(jnp.all(jnp.isfinite(a))) and gap(a, b) < 1e-5
     if strict:
-        assert float(kernel(q, k, v)[1][0, 0, 0]) < -1e29
+        assert float(lse[0, 0, 0]) < -1e29
 
 
 @pytest.mark.parametrize("heads", [2, 8], ids=["rep2", "rep8"])
@@ -633,15 +656,17 @@ def test_a_block_causal_pass_gives_the_rows_logsumexp_and_its_gradient(strict):
              flash.Mask(False, B, own=True)],
     ids=["causal", "blocks<=", "blocks<", "own-block"],
 )
-def test_a_pass_over_several_resident_blocks(mask, heads):
-    """Sixteen blocks in two resident ones: a program's index maps have
+def test_a_pass_over_several_resident_blocks(monkeypatch, mask, heads):
+    """Sixteen blocks in four resident ones: a program's index maps have
     to name the resident block and the walked head they mean (a wrong
     one reads a neighbour's rows, which short sequences cannot show).
     The one backward kernel sums ``dq`` over the sixteen k blocks'
     programs, a slot a query head and resident block, and writes each
     block in the last one's pass: every row of ``dq``, ``dk``, ``dv`` is
     compared, with the rows' logsumexp in the loss (``dlse``)."""
-    t, kv, d = 1024, 1, 128
+    monkeypatch.setattr(flash, "_TILE_ELEMS", 4 * 32 * 32)
+    t, kv, d = 512, 1, 128
+    assert flash._plan(t, 32) == (32, 4, 4)
     key = jax.random.PRNGKey(3)
     q = jax.random.normal(key, (1, t, heads, d))
     k, v = (jax.random.normal(jax.random.fold_in(key, i), (1, t, kv, d)) for i in (1, 2))
@@ -652,28 +677,21 @@ def test_a_pass_over_several_resident_blocks(mask, heads):
     else:
         m = (c // mask.gran < r // mask.gran) if mask.strict else (c // mask.gran <= r // mask.gran)
 
-    def dense(q, k, v):
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, heads, 2)) * d**-0.5
-        s = jnp.where(m, s, -1e30)
-        lse = jax.nn.logsumexp(s, -1)
-        p = jnp.where(m, jnp.exp(s - lse[..., None]), 0.0)
-        out = jnp.einsum("bhqk,bkhd->bqhd", p, jnp.repeat(v, heads, 2))
-        return out, lse.transpose(0, 2, 1)
+    dense = functools.partial(_dense, m=m)
 
     def kernel(q, k, v):
-        return flash.flash_attention_stats(q, k, v, mask=mask, block=64, interpret=True)
+        return flash.flash_attention_stats(q, k, v, mask=mask, block=32, interpret=True)
 
-    def loss(f):
+    def run(f):  # one program a side: the gradients, and the output as their aux
         def g(q, k, v):
             out, lse = f(q, k, v)
             return jnp.sum(
                 jnp.where(live[None, :, None, None], jnp.sin(out), 0.0)
-            ) + jnp.sum(jnp.where(live[None, :, None], jnp.cos(lse), 0.0))
-        return g
+            ) + jnp.sum(jnp.where(live[None, :, None], jnp.cos(lse), 0.0)), out
+        return jax.jit(jax.grad(g, (0, 1, 2), has_aux=True))(q, k, v)
 
-    assert gap(kernel(q, k, v)[0][:, B:], dense(q, k, v)[0][:, B:]) < 1e-5
-    got = jax.grad(loss(kernel), (0, 1, 2))(q, k, v)
-    want = jax.grad(loss(dense), (0, 1, 2))(q, k, v)
+    (got, out), (want, want_out) = run(kernel), run(dense)
+    assert gap(out[:, B:], want_out[:, B:]) < 1e-5
     for a, b in zip(got, want):
         assert gap(a, b) < 1e-5
 
@@ -741,12 +759,12 @@ def test_no_token_is_dropped(monkeypatch, skew):
         cut["router"] = p["router"]
         return _reference_layer(x, cut, config(held=2, first=2))
 
-    y, drawn = _share(x, p, 2, 2)
+    y, drawn = jax.jit(lambda x, p: _share(x, p, 2, 2))(x, p)
     if skew:
         assert int(drawn[0]) == 256 and int(drawn.sum()) > 256
-    assert gap(y, dense(x, p)) < 1e-5
-    got = jax.grad(lambda x, p: jnp.sum(jnp.sin(mine(x, p))), (0, 1))(x, p)
-    want = jax.grad(lambda x, p: jnp.sum(jnp.sin(dense(x, p))), (0, 1))(x, p)
+    assert gap(y, jax.jit(dense)(x, p)) < 1e-5
+    got = jax.jit(jax.grad(lambda x, p: jnp.sum(jnp.sin(mine(x, p))), (0, 1)))(x, p)
+    want = jax.jit(jax.grad(lambda x, p: jnp.sum(jnp.sin(dense(x, p))), (0, 1)))(x, p)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert gap(a, b) < 1e-4
 
@@ -757,13 +775,12 @@ def test_the_model_reports_its_experts_load():
         first_expert=2,
     )
     tokens = jnp.asarray(ref.noise_rows(clean_rows(), config())[0])
-    variables = model.init(jax.random.PRNGKey(0), tokens, train=False)
+    variables = jax.jit(lambda t: model.init(jax.random.PRNGKey(0), t, train=False))(tokens)
     assert variables["params"]["block0"]["mlp"]["w1"]["kernel"].shape == (4, 64, 32)
     assert variables["params"]["block0"]["mlp"]["router"]["kernel"].shape == (64, 8)
-    _, seen = model.apply(
-        {"params": variables["params"]}, tokens, train=True,
-        mutable=["stats", "intermediates"],
-    )
+    _, seen = jax.jit(lambda p: model.apply(
+        {"params": p}, tokens, train=True, mutable=["stats", "intermediates"],
+    ))(variables["params"])
     stats = seen["stats"]["block1"]["mlp"]
     chosen = seen["intermediates"]["block1"]["mlp"]["experts"][0]
     held = int(((chosen >= 2) & (chosen < 6)).sum())

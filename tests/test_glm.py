@@ -99,14 +99,16 @@ def test_the_latent_attention_layer_matches_the_reference(attn_impl):
     cfg = config()
     p = tree(cfg, 3)["block1"]["attn"]
     x = _x()
-    got = decoder.MlaAttention(SPEC, jnp.float32, attn_impl).apply(
+
+    def mla(c):
+        return jax.jit(lambda p: ref._mla(x, p, POSITIONS, ref.sizes(c), None))(p)
+
+    got = jax.jit(decoder.MlaAttention(SPEC, jnp.float32, attn_impl).apply)(
         {"params": p}, x, POSITIONS
     )
-    want = ref._mla(x, p, POSITIONS, ref.sizes(cfg), None)
-    assert got.shape == (2, L, 64) and gap(got, want) < 1e-5
+    assert got.shape == (2, L, 64) and gap(got, mla(cfg)) < 1e-5
     # the rotary part alone: over every dim of q and k it is another layer
-    other = ref._mla(x, p, POSITIONS, ref.sizes(config(without=["partial_rope"])), None)
-    assert gap(got, other) > 1e-2
+    assert gap(got, mla(config(without=["partial_rope"]))) > 1e-2
 
 
 def _expanded(p, x, positions, spec):
@@ -172,23 +174,20 @@ def test_the_products_form_equals_the_expanded_form(shape, attn_impl):
     }
     g = jax.random.normal(jax.random.PRNGKey(8), (2, L, 64))
 
-    def objective(fn):
-        return lambda p, x: jnp.sum(fn(p, x) * g)
+    def run(fn):  # one program a side: the gradients, and the output as their aux
+        def objective(p, x):
+            out = fn(p, x)
+            return jnp.sum(out * g), out
+        return jax.jit(jax.grad(objective, argnums=(0, 1), has_aux=True))(p, x)
 
-    got = layer.apply({"params": p}, x, POSITIONS)
-    want = _expanded(p, x, POSITIONS, spec)
+    (dp_got, dx_got), got = run(lambda p, x: layer.apply({"params": p}, x, POSITIONS))
+    (dp_want, dx_want), want = run(lambda p, x: _expanded(p, x, POSITIONS, spec))
     assert gap(got, want) < 1e-5
-    (dp_got, dx_got) = jax.grad(
-        objective(lambda p, x: layer.apply({"params": p}, x, POSITIONS)), argnums=(0, 1)
-    )(p, x)
-    (dp_want, dx_want) = jax.grad(
-        objective(lambda p, x: _expanded(p, x, POSITIONS, spec)), argnums=(0, 1)
-    )(p, x)
     assert gap(dx_got, dx_want) < 1e-5
     for name in ("q_a", "q_b", "kv_a", "kv_b", "o"):
         assert gap(dp_got[name]["kernel"], dp_want[name]["kernel"]) < 1e-5, name
     # the rotary part turned the other way is another layer
-    assert gap(got, _expanded(p, x, -POSITIONS, spec)) > 1e-3
+    assert gap(got, jax.jit(lambda p, x: _expanded(p, x, -POSITIONS, spec))(p, x)) > 1e-3
 
 
 def test_a_latent_layer_is_counted_with_the_form_it_runs():
@@ -243,8 +242,10 @@ def test_the_expert_layer_with_its_shared_expert(without):
     x = _x()
     variables = _variables(p)
     block = decoder.SpecBlock(SPEC, jnp.float32, "xla", SPEC.kind(1))
-    got = block.apply(variables, x, POSITIONS, False)
-    want, _ = ref._layer(x, p, POSITIONS, ref.sizes(config(without=list(without))), None, False)
+    got = jax.jit(block.apply, static_argnums=3)(variables, x, POSITIONS, False)
+    want, _ = jax.jit(lambda p: ref._layer(
+        x, p, POSITIONS, ref.sizes(config(without=list(without))), None, False
+    ))(p)
     if without:
         assert gap(got, want) > 1e-3
     else:
@@ -261,10 +262,9 @@ def test_the_dense_first_layer():
     assert SPEC.kind(0).ffn == "glu" and SPEC.kind(1).ffn == ""
     assert p["mlp"]["w_in"]["kernel"].shape == (64, 192)
     x = _x()
-    got = decoder.SpecBlock(SPEC, jnp.float32, "xla", SPEC.kind(0)).apply(
-        {"params": p}, x, POSITIONS, False
-    )
-    want, chosen = ref._layer(x, p, POSITIONS, ref.sizes(cfg), None, True)
+    got = jax.jit(decoder.SpecBlock(SPEC, jnp.float32, "xla", SPEC.kind(0)).apply,
+                  static_argnums=3)({"params": p}, x, POSITIONS, False)
+    want, chosen = jax.jit(lambda p: ref._layer(x, p, POSITIONS, ref.sizes(cfg), None, True))(p)
     assert chosen is None and gap(got, want) < 1e-5
 
 
@@ -285,22 +285,35 @@ def _loss_fn(model, buffers, toks, labels):
     return loss
 
 
-@pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
-def test_the_model_matches_its_reference_on_logits_loss_and_gradients(attn_impl):
-    """``glm_tiny`` against ``references/glm.py`` on seeded weights at L =
-    32: logits, the experts chosen in both expert layers and the MTP
-    block, the loss ``CE_main + 0.1 · CE_mtp`` (the model sows the second
-    term, which the train step adds), every parameter's gradient."""
+@pytest.fixture(scope="module")
+def glm_reference():
+    """Seeded weights, three rows, and what ``references/glm.py`` gives on
+    them (logits, the experts chosen, the MTP module's logits, loss and
+    gradients): computed once, for both implementations."""
     cfg = config()
     t = tree(cfg, 11)
     params, buffers = ref.split_buffers(t)
     rows = tokens(3, seed=5)
     toks, labels = jnp.asarray(rows), jnp.asarray(np.roll(rows, -1, axis=1))
+    want = jax.jit(lambda t: ref.forward(t, toks, cfg))(t)
+    lr, gr = jax.jit(jax.value_and_grad(
+        lambda p: ref.token_loss(p, buffers, toks, labels, cfg)
+    ))(params)
+    return t, toks, labels, want, lr, gr
+
+
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
+def test_the_model_matches_its_reference_on_logits_loss_and_gradients(glm_reference, attn_impl):
+    """``glm_tiny`` against ``references/glm.py`` on seeded weights at L =
+    32: logits, the experts chosen in both expert layers and the MTP
+    block, the loss ``CE_main + 0.1 · CE_mtp`` (the model sows the second
+    term, which the train step adds), every parameter's gradient."""
+    t, toks, labels, (want, chosen, _), lr, gr = glm_reference
+    params, buffers = ref.split_buffers(t)
     model = _model(attn_impl)
-    (l, (logits, seen, main, later)), grads = jax.value_and_grad(
+    (l, (logits, seen, main, later)), grads = jax.jit(jax.value_and_grad(
         _loss_fn(model, buffers, toks, labels), has_aux=True
-    )(params)
-    want, chosen, want_later = ref.forward(t, toks, cfg)
+    ))(params)
     assert logits.shape == (3, L, VOCAB) and gap(logits, want) < 1e-4
     seen = seen["intermediates"]
     got_chosen = jnp.stack([seen["block1"]["mlp"]["experts"][0],
@@ -308,7 +321,6 @@ def test_the_model_matches_its_reference_on_logits_loss_and_gradients(attn_impl)
                             seen["mtp"]["block"]["mlp"]["experts"][0]])
     assert chosen.shape == (3, 3 * L, 2)
     assert bool(jnp.all(jnp.sort(got_chosen, -1) == jnp.sort(chosen, -1)))
-    lr, gr = jax.value_and_grad(ref.token_loss)(params, buffers, toks, labels, cfg)
     assert abs(float(l) - float(lr)) < 1e-5 * abs(float(lr))
     flat_got, flat_want = ref.flatten(grads), ref.flatten(gr)
     assert set(flat_got) == set(flat_want)
@@ -330,43 +342,49 @@ def test_the_mtp_term_and_its_two_masked_positions():
     params, buffers = ref.split_buffers(t)
     toks = jnp.asarray(tokens(2, seed=7))
     model = _model()
-    _, seen = model.apply(
-        {"params": params, "batch_stats": buffers}, toks, train=True, mutable=["losses"]
-    )
+    _, seen = jax.jit(lambda p: model.apply(
+        {"params": p, "batch_stats": buffers}, toks, train=True, mutable=["losses"]
+    ))(params)
     got = float(seen["losses"]["mtp_loss"][0])
-    _, _, later = ref.forward(t, toks, cfg)
+    _, _, later = jax.jit(lambda t: ref.forward(t, toks, cfg))(t)
     ce = ref._cross_entropy(later[:, :-2], toks[:, 2:])
     assert got == pytest.approx(0.1 * float(jnp.mean(ce)), rel=1e-5)
     assert abs(got - 0.1 * float(jnp.sum(ce)) / (2 * L)) > 1e-2 * got
     # without a collection to sow into the module still runs (its choices
     # are read) and nothing is added
-    _, quiet = model.apply(
-        {"params": params, "batch_stats": buffers}, toks, train=False,
-        mutable=["intermediates"],
-    )
+    _, quiet = jax.jit(lambda p: model.apply(
+        {"params": p, "batch_stats": buffers}, toks, train=False, mutable=["intermediates"],
+    ))(params)
     assert "mtp" in quiet["intermediates"]
 
 
-def test_the_reference_sees_each_mechanism():
+def test_the_reference_sees_each_mechanism(glm_reference):
     """A reference without one of them is another model: the selection
     bias (choices by the score alone), the shared expert, the rotary part
-    (rotary over all of q and k), the MTP term (the loss alone moves)."""
+    (rotary over all of q and k), the MTP term (the loss alone moves); on
+    the first two rows."""
     cfg = config()
-    t = tree(cfg, 11)
+    t, toks = glm_reference[0], glm_reference[1][:2]
     params, buffers = ref.split_buffers(t)
-    toks = jnp.asarray(tokens(2, seed=5))
     labels = jnp.roll(toks, -1, axis=1)
-    logits = _model().apply({"params": params, "batch_stats": buffers}, toks, train=False)
-    sound, chosen, _ = ref.forward(t, toks, cfg)
+    logits = jax.jit(lambda p: _model().apply(
+        {"params": p, "batch_stats": buffers}, toks, train=False
+    ))(params)
+
+    def forward(c):
+        return jax.jit(lambda t: ref.forward(t, toks, c)[:2])(t)
+
+    def loss(c):
+        return jax.jit(lambda p: ref.token_loss(p, buffers, toks, labels, c))(params)
+
+    sound, chosen = forward(cfg)
     assert gap(logits, sound) < 1e-4
     for mechanism in ("selection_bias", "shared_expert", "partial_rope"):
-        wrong, other, _ = ref.forward(t, toks, config(without=[mechanism]))
+        wrong, other = forward(config(without=[mechanism]))
         assert gap(logits, wrong) > 1e-3, mechanism
         if mechanism == "selection_bias":
             assert float(jnp.mean(jnp.sort(other, -1) != jnp.sort(chosen, -1))) > 0.05
-    loss = ref.token_loss(params, buffers, toks, labels, cfg)
-    alone = ref.token_loss(params, buffers, toks, labels, config(without=["mtp"]))
-    assert float(loss - alone) > 0.1 * np.log(VOCAB) * 0.5
+    assert float(loss(cfg) - loss(config(without=["mtp"]))) > 0.1 * np.log(VOCAB) * 0.5
 
 
 @pytest.mark.parametrize("layer", [1, 2])
@@ -389,10 +407,10 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_reference(layer
         )
         return block.apply(_variables({**p, "mlp": mlp}), x, POSITIONS, False)
 
-    want, _ = ref._layer(x, p, POSITIONS, s, None, False)
-    no_experts = {**p, "mlp": {**p["mlp"], "w2": {"kernel": 0.0 * p["mlp"]["w2"]["kernel"]}}}
-    alike, _ = ref._layer(x, no_experts, POSITIONS, s, None, False)
-    parts = [share(first) - alike for first in range(8)]
+    uncut = jax.jit(lambda p: ref._layer(x, p, POSITIONS, s, None, False)[0])
+    want = uncut(p)
+    alike = uncut({**p, "mlp": {**p["mlp"], "w2": {"kernel": 0.0 * p["mlp"]["w2"]["kernel"]}}})
+    parts = [part - alike for part in jax.jit(lambda: [share(first) for first in range(8)])()]
     assert gap(alike + sum(parts), want) < 1e-5
     assert gap(sum(parts), want - alike) < 1e-4  # the routed experts' part alone
     assert float(jnp.max(jnp.abs(want - alike))) > 1e-3
@@ -488,7 +506,9 @@ def test_the_specs_that_were_there_lower_as_they_did():
     }
     for name in was:
         model = get_model(name, num_classes=VOCAB, dtype="float32", attn_impl="xla", max_seq_len=L)
-        params = model.init(jax.random.PRNGKey(1), toks, train=False)["params"]
+        params = jax.eval_shape(  # the text and the tree read shapes alone
+            lambda: model.init(jax.random.PRNGKey(1), toks, train=False)
+        )["params"]
 
         def loss(p):
             return jnp.sum(

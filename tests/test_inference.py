@@ -27,7 +27,7 @@ def _model(**kw):
 def _params(model, seed=0):
     import flax.linen as nn
 
-    variables = model.init(
+    variables = jax.jit(model.init, static_argnames="train")(
         jax.random.PRNGKey(seed), jnp.zeros((2, MAX_LEN), jnp.int32),
         train=False,
     )
@@ -37,8 +37,9 @@ def _params(model, seed=0):
 def _greedy_reference(model, params, prompt, n_new):
     """Token-by-token greedy via full re-forward (no cache)."""
     seq = jnp.asarray(prompt)
+    forward = jax.jit(lambda p, seq: model.apply({"params": p}, seq, train=False))
     for _ in range(n_new):
-        logits = model.apply({"params": params}, seq, train=False)
+        logits = forward(params, seq)
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
     return np.asarray(seq)

@@ -254,7 +254,7 @@ def test_top1_router_gets_output_gradient():
         )
         return jnp.sum(y)
 
-    grads = jax.grad(out_sum)(variables["params"])
+    grads = jax.jit(jax.grad(out_sum))(variables["params"])
     flat = jax.tree_util.tree_leaves_with_path(grads)
     router = [g for p, g in flat if "router" in str(p).lower() or "gate" in str(p).lower()]
     assert router, [str(p) for p, _ in flat]
@@ -291,16 +291,17 @@ def test_the_held_experts_gate_is_the_one_named(activation):
             y = y + gate[:, None] * ((act(x @ w1[e]) * (x @ w3[e])) @ w2[e])
         return y
 
-    assert float(jnp.max(jnp.abs(mine(x, w1, w3, w2) - dense(x, w1, w3, w2)))) < 1e-3
-    got = jax.grad(lambda *a: jnp.sum(jnp.sin(mine(*a))), (0, 1, 2, 3))(x, w1, w3, w2)
-    want = jax.grad(lambda *a: jnp.sum(jnp.sin(dense(*a))), (0, 1, 2, 3))(x, w1, w3, w2)
+    y = jax.jit(dense)(x, w1, w3, w2)
+    assert float(jnp.max(jnp.abs(jax.jit(mine)(x, w1, w3, w2) - y))) < 1e-3
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(mine(*a))), (0, 1, 2, 3)))(x, w1, w3, w2)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(dense(*a))), (0, 1, 2, 3)))(x, w1, w3, w2)
     for a, b in zip(got, want):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-3 * float(jnp.max(jnp.abs(b)) + 1.0)
     other = {"silu": "relu", "relu": "silu"}[activation]
     routed = moe.route_top_k(x @ router, 2)
     swapped = moe.held_experts_ffn(
         x, routed, w1, w3, w2, first=0, num_experts=4, activation=other)[0]
-    assert float(jnp.max(jnp.abs(swapped - dense(x, w1, w3, w2)))) > 1e-2
+    assert float(jnp.max(jnp.abs(swapped - y))) > 1e-2
     with pytest.raises(KeyError):
         moe.held_experts_ffn(x, routed, w1, w3, w2, first=0, num_experts=4,
                              activation="gelu")

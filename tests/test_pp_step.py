@@ -93,7 +93,7 @@ def test_pp_matches_sequential_reference(pp_mesh):
         logits = pl.apply_reference(params, jnp.asarray(tokens), train=True)
         return cross_entropy_loss(logits, jnp.asarray(labels))
 
-    loss_ref, grads_ref = jax.value_and_grad(ref_loss)(host_params)
+    loss_ref, grads_ref = jax.jit(jax.value_and_grad(ref_loss))(host_params)
     np.testing.assert_allclose(
         float(metrics["loss"]), float(loss_ref), rtol=1e-5
     )
@@ -202,7 +202,7 @@ def test_pp_1f1b_matches_sequential_reference(pp_mesh):
         logits = pl.apply_reference(params, jnp.asarray(tokens), train=True)
         return cross_entropy_loss(logits, jnp.asarray(labels))
 
-    loss_ref, grads_ref = jax.value_and_grad(ref_loss)(host_params)
+    loss_ref, grads_ref = jax.jit(jax.value_and_grad(ref_loss))(host_params)
     np.testing.assert_allclose(
         float(metrics["loss"]), float(loss_ref), rtol=1e-5
     )
@@ -244,7 +244,7 @@ def test_pp_1f1b_with_weight_decay_and_more_microbatches(pp_mesh):
             l2_kernel_penalty(params, cfg.weight_decay)
         )
 
-    loss_ref, grads_ref = jax.value_and_grad(ref_loss)(host_params)
+    loss_ref, grads_ref = jax.jit(jax.value_and_grad(ref_loss))(host_params)
     np.testing.assert_allclose(
         float(metrics["loss"]), float(loss_ref), rtol=1e-5
     )

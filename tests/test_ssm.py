@@ -81,20 +81,28 @@ def gap(got, want):
     return float(jnp.max(jnp.abs(got - want)) / (jnp.max(jnp.abs(want)) + 1e-30))
 
 
+def weighed(f, args):
+    """``f(*args)`` and the gradients of a weighted sum of it in all six
+    operands: one jitted program, the output its aux."""
+    weigh = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+
+    def loss(*v):
+        y = f(*v)
+        return jnp.sum(y.astype(jnp.float32) * weigh), y
+
+    (_, y), grads = jax.jit(jax.value_and_grad(loss, tuple(range(6)), has_aux=True))(*args)
+    return y, grads
+
+
 @pytest.mark.parametrize("groups", [1, 2])
 @pytest.mark.parametrize("length", [8, 32, 27, 5])
 def test_the_chunked_scan_is_the_recurrence_forward_and_backward(length, groups):
     """Chunk 8: one chunk, four, three and a ragged fourth, a row
     shorter than a chunk. 1e-5 of the largest entry, float32."""
     args = operands(length, groups)
-    weigh = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
-    chunked = lambda *v: ssm.ssd_scan(*v, chunk=8)  # noqa: E731
-    got, want = jax.jit(chunked)(*args), jax.jit(recurrence)(*args)
+    got, g_got = weighed(lambda *v: ssm.ssd_scan(*v, chunk=8), args)
+    want, g_want = weighed(recurrence, args)
     assert got.shape == args[0].shape and gap(got, want) < 1e-5
-    loss = lambda f: (lambda *v: jnp.sum(f(*v) * weigh))  # noqa: E731
-    every = tuple(range(6))
-    g_got = jax.jit(jax.grad(loss(chunked), every))(*args)
-    g_want = jax.jit(jax.grad(loss(recurrence), every))(*args)
     for name, x, y in zip(NAMES, g_got, g_want):
         assert gap(x, y) < 1e-5, name
 
@@ -102,13 +110,10 @@ def test_the_chunked_scan_is_the_recurrence_forward_and_backward(length, groups)
 def both_forms(on_kernels, args, chunk):
     """``(y, gradients)`` of a weighted sum of ``ssd_scan`` in all six
     operands, by the XLA form and then by the kernels, jitted."""
-    weigh = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
     out = []
     for switch in (lambda: None, on_kernels):
         switch()
-        scan = lambda *v: ssm.ssd_scan(*v, chunk=chunk)  # noqa: E731
-        loss = lambda *v: jnp.sum(scan(*v).astype(jnp.float32) * weigh)  # noqa: E731
-        out.append((jax.jit(scan)(*args), jax.jit(jax.grad(loss, tuple(range(6))))(*args)))
+        out.append(weighed(lambda *v: ssm.ssd_scan(*v, chunk=chunk), args))
     return out
 
 
@@ -189,7 +194,7 @@ def spliced(scan, length, cut, sizes):
 
 def test_a_row_s_output_hangs_on_nothing_after_it():
     cut = 13
-    same, changed = spliced(lambda *v: ssm.ssd_scan(*v, chunk=8), 27, cut, (H, P, N))
+    same, changed = spliced(jax.jit(lambda *v: ssm.ssd_scan(*v, chunk=8)), 27, cut, (H, P, N))
     assert bool(jnp.all(changed[:, :cut] == same[:, :cut]))
     assert gap(changed[:, cut:], same[:, cut:]) > 1e-2
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 27, 6))
@@ -217,11 +222,10 @@ def test_a_step_of_nought_neither_decays_nor_writes():
     would have read without it."""
     xs, dt, a, b, c, d = operands(16, seed=7)
     hole = dt.at[:, 4:12].set(0.0)
-    with_hole = ssm.ssd_scan(xs, hole, a, b, c, d, chunk=8)
+    scan = jax.jit(lambda *v: ssm.ssd_scan(*v, chunk=8))
+    with_hole = scan(xs, hole, a, b, c, d)
     keep = np.r_[0:4, 12:16]
-    without = ssm.ssd_scan(
-        xs[:, keep], dt[:, keep], a, b[:, keep], c[:, keep], d, chunk=8
-    )
+    without = scan(xs[:, keep], dt[:, keep], a, b[:, keep], c[:, keep], d)
     assert gap(with_hole[:, keep], without) < 1e-5
 
 
